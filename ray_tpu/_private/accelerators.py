@@ -1,20 +1,21 @@
-"""Accelerator detection — TPU first-class.
+"""Accelerator detection and per-process device steering — TPU first-class.
 
 Counterpart of python/ray/_private/accelerators/tpu.py:110
-(TPUAcceleratorManager) in the reference: probe GCE/GKE metadata for the slice
-topology, honor TPU_VISIBLE_CHIPS, and advertise both per-chip "TPU" resources
-and a pod-slice head resource ("TPU-<gen>-<topo>-head", reference tpu.py:15-61)
-so placement groups can gang-schedule whole slices.
+(TPUAcceleratorManager) in the reference: count the host's chips, honor
+TPU_VISIBLE_CHIPS, and advertise both per-chip "TPU" resources and a
+pod-slice head resource ("TPU-<gen>-<topo>-head", reference tpu.py:15-61) so
+placement groups can gang-schedule whole slices.
 
-Redesign: detection goes through JAX (jax.devices()) rather than
-/dev/accel* + metadata only, because on TPU VMs JAX is the ground truth for
-what this host can address.
+A chip belongs to one process at a time, so neither the driver nor the
+nodelet ever initializes the TPU runtime: chips are counted from the device
+files the host exposes, and `process_environ` decides, per worker process,
+whether JAX sees the leased chips or the CPU only.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Mapping, Optional, Sequence, Tuple
 
 _detect_cache: Optional[Dict[str, float]] = None
 
@@ -49,7 +50,7 @@ def detect_resources(num_cpus: Optional[float] = None,
         elif _detect_cache is not None:
             tpu_count = _detect_cache.get("TPU", 0.0)
         else:
-            tpu_count = float(_probe_jax_tpus())
+            tpu_count = float(_count_tpu_chips())
             _detect_cache = {"TPU": tpu_count}
     if tpu_count > 0:
         resources["TPU"] = tpu_count
@@ -80,30 +81,67 @@ def _host_memory_bytes() -> int:
     return 0
 
 
-def _probe_jax_tpus() -> int:
-    """Count TPU chips without initializing the TPU runtime in the nodelet
-    (workers own the devices; the nodelet only counts them)."""
-    # Cheap paths first: explicit env, then device files.
-    chips = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")
-    if chips:
+def _count_tpu_chips() -> int:
+    """Chips this host exposes, from its device files: one VFIO group per
+    chip (`/dev/vfio/<n>`, v5e and later) or one `/dev/accel<n>` (earlier
+    generations). TPU_CHIPS_PER_HOST_BOUNDS is not consulted: it describes
+    the host type, and a VM that was handed one chip of a four-chip host
+    still carries the host's bounds."""
+    def entries(path: str):
         try:
-            dims = [int(x) for x in chips.split(",")]
-            n = 1
-            for d in dims:
-                n *= d
-            return n
-        except ValueError:
-            pass
-    n_accel = len(
-        [d for d in os.listdir("/dev") if d.startswith("accel")]
-    ) if os.path.isdir("/dev") else 0
-    if n_accel:
-        return n_accel
-    if os.environ.get("RAY_TPU_FORCE_TPU_PROBE") == "1":
-        try:
-            import jax
+            return os.listdir(path)
+        except OSError:
+            return []
 
-            return len([d for d in jax.devices() if d.platform != "cpu"])
-        except Exception:
-            return 0
-    return 0
+    return (sum(name.isdigit() for name in entries("/dev/vfio"))
+            or sum(name.startswith("accel") for name in entries("/dev")))
+
+
+# libtpu's chip grid for a process that owns n chips of one host
+# (TPU_CHIPS_PER_PROCESS_BOUNDS; reference tpu.py has the same table).
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def compile_cache_dir(environ: Mapping[str, str] = os.environ) -> str:
+    """Where a process that compiles for the chip keeps XLA's persistent
+    compilation cache: JAX_COMPILATION_CACHE_DIR when set, else one fixed
+    directory in the checkout. The path is part of the cache key, so it
+    must not move between runs. Entry points that hold the chip themselves
+    put this in their environment before importing jax; workers get it
+    from `process_environ`."""
+    return environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _REPO_ROOT, ".jax_cache")
+
+
+def process_environ(base: Mapping[str, str],
+                    tpu_chips: Sequence[int] = ()) -> Dict[str, str]:
+    """Environment for a process the runtime starts, derived from `base`.
+
+    Without leased chips the process is held to the CPU platform, whatever
+    `base` says: a data, rllib or CPU-train worker that imports jax must
+    not take the chip from the worker that leased it. With leased chips it
+    gets the TPU platform — even under a parent that set JAX_PLATFORMS=cpu
+    to stay off the chip itself — restricted to exactly those chips, with
+    the process bounds libtpu needs for several processes to share a host,
+    and the compilation cache (a CPU process keeps `base`'s setting: its
+    programs are small, and XLA:CPU reloads cached code with a
+    machine-feature warning per entry).
+    """
+    env = dict(base)
+    if not tpu_chips:
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
+    bounds = _CHIP_BOUNDS.get(len(tpu_chips))
+    if bounds is None:
+        raise ValueError(
+            f"no TPU process bounds known for {len(tpu_chips)} chips "
+            f"(supported: {sorted(_CHIP_BOUNDS)})")
+    env["JAX_PLATFORMS"] = "tpu,cpu"
+    env["TPU_VISIBLE_CHIPS"] = ",".join(map(str, tpu_chips))
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir(base)
+    return env

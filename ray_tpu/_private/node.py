@@ -14,6 +14,7 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu._private.accelerators import process_environ
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -105,7 +106,9 @@ class Node:
             tempfile.gettempdir(), "ray_tpu", self.session_id)
         os.makedirs(os.path.join(self.session_dir, "logs"), exist_ok=True)
         self.processes: List[subprocess.Popen] = []
-        self._env = dict(os.environ)
+        # GCS and nodelet never hold a chip: they count chips from device
+        # files, and the nodelet hands leased workers their own TPU env.
+        self._env = process_environ(os.environ)
         repo_root = os.path.dirname(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         self._env["PYTHONPATH"] = repo_root + os.pathsep + self._env.get(

@@ -595,10 +595,23 @@ class GcsServer:
 
     async def _health_check_loop(self) -> None:
         cfg = get_config()
+        last = time.monotonic()
         while True:
             await asyncio.sleep(cfg.heartbeat_interval_s)
             deadline = cfg.heartbeat_interval_s * cfg.heartbeat_failure_threshold
             now = time.monotonic()
+            # How late this loop itself woke. While the GCS was not running
+            # (the whole host stalled — a co-located TPU worker compiling
+            # or initializing the runtime can freeze it for seconds) it
+            # could not have heard a beat, and the beats sent meanwhile are
+            # queued behind this callback: that silence is no evidence.
+            stall = now - last - cfg.heartbeat_interval_s
+            last = now
+            if stall > cfg.heartbeat_interval_s:
+                logger.warning("health check woke %.1fs late; not counting "
+                               "that silence against any node", stall)
+                for info in self.nodes.values():
+                    info.last_heartbeat += stall
             for info in list(self.nodes.values()):
                 if info.alive and now - info.last_heartbeat > deadline:
                     await self._mark_node_dead(info, "heartbeat timeout")

@@ -20,7 +20,7 @@ from ray_tpu.llm._internal.paged import (
     paged_write,
 )
 from ray_tpu.llm._internal.openai import OpenAIServer, build_openai_app
-from ray_tpu.llm._internal.server import LLMServer
+from ray_tpu.llm._internal.server import GENERATE_TIMEOUT_S, LLMServer
 from ray_tpu.llm._internal.tokenizer import (
     ByteBPETokenizer,
     apply_chat_template,
@@ -42,6 +42,7 @@ def build_llm_deployment(llm_config: Dict[str, Any], *,
         num_replicas=num_replicas,
         ray_actor_options={"num_cpus": 1.0, "num_tpus": num_tpus},
         max_ongoing_requests=int(llm_config.get("max_ongoing_requests", 32)),
+        request_timeout_s=GENERATE_TIMEOUT_S,
     )
     return dep.bind(llm_config)
 

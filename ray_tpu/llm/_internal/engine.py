@@ -135,31 +135,22 @@ class LLMEngine:
             num_pages=cfg.resolved_num_pages() + 1,  # +1: OOB drop page
             page_size=cfg.page_size, max_seqs=cfg.max_seqs,
             max_pages_per_seq=cfg.max_pages_per_seq)
-        caches = init_paged_cache(
-            self.cache_cfg, mcfg.num_layers, mcfg.num_kv_heads,
-            mcfg.head_dim, mcfg.dtype)
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
             from ray_tpu.models.llama import LLAMA_SHARDING
-            from ray_tpu.parallel.sharding import shard_tree, spec_for
+            from ray_tpu.parallel.sharding import shard_tree
 
+            # The attention kernels need the mesh to run under shard_map.
+            self.model = model = model.clone(mesh=mesh)
+            # No-op for parameters that were initialized into these
+            # shardings (LLMServer); places a host or one-device tree.
             params = shard_tree(
                 params, LLAMA_SHARDING.tree_shardings(mesh, params))
-            kv_spec = spec_for(("kv_heads", None, None, None), mesh=mesh)
-            # Respect indivisible kv-head counts (tiny test models).
-            tp = 1
-            for ax in (kv_spec[0],) if kv_spec else ():
-                if ax is not None:
-                    for a in (ax,) if isinstance(ax, str) else ax:
-                        tp *= dict(zip(mesh.axis_names, mesh.devices.shape)
-                                   ).get(a, 1)
-            if tp > 1 and mcfg.num_kv_heads % tp:
-                kv_spec = PartitionSpec()
-            kv_sharding = NamedSharding(mesh, kv_spec)
             self._replicated = NamedSharding(mesh, PartitionSpec())
-            caches = jax.tree.map(
-                lambda x: jax.device_put(x, kv_sharding), caches)
+        caches = init_paged_cache(
+            self.cache_cfg, mcfg.num_layers, mcfg.num_kv_heads,
+            mcfg.head_dim, mcfg.dtype, mesh=mesh)
         self.params = params
         self.caches = caches
         self.allocator = PageAllocator(self.cache_cfg)
@@ -503,7 +494,7 @@ class LLMEngine:
         K = max(1, self.cfg.decode_steps)
         if self._inflight is None:
             self._ensure_decode_pages(K)
-            self._inflight = self._dispatch_window_from_host()
+            self._inflight = self._dispatch_window()
             if not self.cfg.pipeline_dispatch:
                 self._process_window(self._inflight, out)
                 self._inflight = None
@@ -518,7 +509,7 @@ class LLMEngine:
             self._inflight = None
             return out
         self._ensure_decode_pages(2 * K)
-        nxt = self._dispatch_window_from_device(self._inflight)
+        nxt = self._dispatch_window(*self._inflight[1:3])
         finished = self._process_window(self._inflight, out)
         if finished:
             # The chained window ran with pre-finish control state. Its
@@ -534,34 +525,38 @@ class LLMEngine:
             self._inflight = nxt
         return out
 
-    def _dispatch_window_from_host(self):
+    def _decode_args(self, last=None, lens=None) -> tuple:
+        """Arguments of the decode program: control state from the host
+        mirrors, except `last`/`lens` when chaining off a window that is
+        still on the device."""
         active = np.zeros((self.cfg.max_seqs,), bool)
         for slot in self.running:
             active[slot] = True
+        return (
+            self.params, self.caches,
+            self._dev(self.last_tokens) if last is None else last,
+            self._dev(self.page_table),
+            self._dev(self.seq_lens) if lens is None else lens,
+            self._dev(active), self._dev(self.temps),
+            self._dev(self.top_ps), self._dev(self.top_ks),
+            self._keys_dev, self.lora_banks, self._dev(self.lora_idx))
+
+    def _dispatch_window(self, last=None, lens=None):
         rich, want_lp = self._sampling_flags(self.running.values())
         toks, last, lens, self.caches, self._keys_dev, lp = \
-            self._decode_fn(rich, want_lp)(
-                self.params, self.caches, self._dev(self.last_tokens),
-                self._dev(self.page_table), self._dev(self.seq_lens),
-                self._dev(active), self._dev(self.temps),
-                self._dev(self.top_ps), self._dev(self.top_ks),
-                self._keys_dev, self.lora_banks, self._dev(self.lora_idx))
+            self._decode_fn(rich, want_lp)(*self._decode_args(last, lens))
         return (toks, last, lens, lp, frozenset(self.running))
 
-    def _dispatch_window_from_device(self, window):
-        _, last, lens, _, slots = window
-        active = np.zeros((self.cfg.max_seqs,), bool)
-        for slot in self.running:
-            active[slot] = True
-        rich, want_lp = self._sampling_flags(self.running.values())
-        toks, last, lens, self.caches, self._keys_dev, lp = \
-            self._decode_fn(rich, want_lp)(
-                self.params, self.caches, last,
-                self._dev(self.page_table), lens,
-                self._dev(active), self._dev(self.temps),
-                self._dev(self.top_ps), self._dev(self.top_ks),
-                self._keys_dev, self.lora_banks, self._dev(self.lora_idx))
-        return (toks, last, lens, lp, frozenset(self.running))
+    def lowered_decode_text(self) -> str:
+        """StableHLO of the greedy decode program as this process lowers
+        it, for checking which attention path it holds (the Mosaic kernel
+        shows as `tpu_custom_call`). Traces from shapes only: nothing runs,
+        and buffers the engine thread may donate meanwhile are not read."""
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            self._decode_args())
+        return self._decode_fn(False, False).lower(*shapes).as_text()
 
     def _process_window(self, window,
                         out: Optional[List[StepOutput]]) -> bool:

@@ -22,7 +22,7 @@ import time
 import uuid
 from typing import Any, Dict, Iterator, List, Optional
 
-from ray_tpu.llm._internal.server import LLMServer
+from ray_tpu.llm._internal.server import GENERATE_TIMEOUT_S, LLMServer
 from ray_tpu.llm._internal.tokenizer import (
     ByteBPETokenizer,
     apply_chat_template,
@@ -365,6 +365,10 @@ class OpenAIServer:
     def stats(self) -> Dict[str, Any]:
         return self.server.stats()
 
+    def self_check(self, prompt_ids: List[int], steps: int = 2
+                   ) -> Dict[str, Any]:
+        return self.server.self_check(prompt_ids, steps)
+
     def check_health(self) -> bool:
         return self.server.check_health()
 
@@ -403,5 +407,6 @@ def build_openai_app(llm_config: Dict[str, Any], *,
         num_replicas=num_replicas,
         ray_actor_options={"num_cpus": 1.0, "num_tpus": num_tpus},
         max_ongoing_requests=int(llm_config.get("max_ongoing_requests", 32)),
+        request_timeout_s=GENERATE_TIMEOUT_S,
     )
     return dep.bind(llm_config)

@@ -21,6 +21,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 NEG_INF = -1e30
 
@@ -37,11 +38,25 @@ class PagedCacheConfig:
         return self.page_size * self.max_pages_per_seq
 
 
+def pages_spec(shape: Tuple[int, ...], mesh: Mesh) -> PartitionSpec:
+    """How pages [HK, P, ps, D] lie on a mesh: split over kv heads, or
+    replicated where the tensor axis does not divide them (tiny test
+    models). The cache and the decode kernel's shard_map both use it."""
+    from ray_tpu.parallel.sharding import spec_for_shape
+
+    return spec_for_shape(("kv_heads", None, None, None), shape, mesh)
+
+
 def init_paged_cache(cfg: PagedCacheConfig, num_layers: int, kv_heads: int,
-                     head_dim: int, dtype=jnp.bfloat16):
-    """Per-layer (k_pages, v_pages) list, layout [HK, P, ps, D]."""
+                     head_dim: int, dtype=jnp.bfloat16,
+                     mesh: Optional[Mesh] = None):
+    """Per-layer (k_pages, v_pages) list, layout [HK, P, ps, D]; with a
+    mesh, created directly in their sharding (no device holds them all)."""
     shape = (kv_heads, cfg.num_pages, cfg.page_size, head_dim)
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    sharding = (NamedSharding(mesh, pages_spec(shape, mesh))
+                if mesh is not None else None)
+    return [(jnp.zeros(shape, dtype, device=sharding),
+             jnp.zeros(shape, dtype, device=sharding))
             for _ in range(num_layers)]
 
 
@@ -75,20 +90,23 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_table: jax.Array, q_positions: jax.Array,
                     seq_lens: jax.Array,
                     scale: Optional[float] = None,
-                    use_kernel: Optional[bool] = None) -> jax.Array:
-    if use_kernel is None:
-        use_kernel = q.shape[1] == 1 and jax.default_backend() == "tpu"
-    if use_kernel and q.shape[1] == 1:
-        # Decode hot path: the Pallas kernel walks pages in HBM (1.5x the
-        # gather path on v5e and O(actual pages) HBM traffic, not O(max)).
-        return paged_attention_decode_kernel(
-            q, k_pages, v_pages, page_table, seq_lens, scale=scale)
+                    use_kernel: Optional[bool] = None,
+                    mesh: Optional[Mesh] = None) -> jax.Array:
     """Attention of q [B,S,H,D] over paged KV (causal by absolute position).
 
     q_positions [B,S]: absolute position of each query token; keys at
     absolute positions <= q_position and < seq_len are visible. The gather
-    materializes [B, max_ctx] keys — fine for decode (S=1) and short
-    prefill; the Pallas kernel below avoids it for the decode hot path."""
+    materializes [B, max_ctx] keys — fine for short prefill; single-token
+    decode on a TPU takes the Pallas kernel below instead, which walks the
+    pages in HBM (O(actual pages) traffic, not O(max)). `mesh` is the
+    engine's tensor-parallel mesh: the kernel needs it (it cannot be
+    partitioned by GSPMD), the gather path does not."""
+    if use_kernel is None:
+        use_kernel = q.shape[1] == 1 and jax.default_backend() == "tpu"
+    if use_kernel and q.shape[1] == 1:
+        return paged_attention_decode_kernel(
+            q, k_pages, v_pages, page_table, seq_lens, scale=scale,
+            mesh=mesh)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     h, hk = q.shape[2], k_pages.shape[0]
     k = paged_gather(k_pages, page_table)  # [B,C,HK,D]
@@ -214,13 +232,31 @@ def paged_attention_decode_kernel(
         page_table: jax.Array, seq_lens: jax.Array,
         scale: Optional[float] = None,
         pages_per_chunk: int = 16,
-        interpret: Optional[bool] = None) -> jax.Array:
+        interpret: Optional[bool] = None,
+        mesh: Optional[Mesh] = None) -> jax.Array:
     """Pallas decode attention: q [B,1,H,D] over paged KV without
     materializing the gathered context. Grid (B, KV_H); q heads are grouped
     by kv head (GQA) so one [Hg, C*ps] MXU tile serves all query heads of
-    the group per chunk; see _paged_decode_kernel for the DMA pipeline."""
+    the group per chunk; see _paged_decode_kernel for the DMA pipeline.
+
+    With a multi-device `mesh` the kernel runs under shard_map with the KV
+    heads (and the query heads grouped under them) split over the tensor
+    axis, as the engine shards the pages; page table and lengths ride along
+    replicated."""
     from jax.experimental.pallas import tpu as pltpu
 
+    if mesh is not None and mesh.size > 1:
+        kv_spec = pages_spec(k_pages.shape, mesh)
+        q_spec = PartitionSpec(None, None, *kv_spec[:1])
+        local = functools.partial(
+            paged_attention_decode_kernel, scale=scale,
+            pages_per_chunk=pages_per_chunk, interpret=interpret)
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(q_spec, kv_spec, kv_spec, PartitionSpec(),
+                      PartitionSpec()),
+            out_specs=q_spec, check_vma=False,
+        )(q, k_pages, v_pages, page_table, seq_lens)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     b, s, h, d = q.shape
@@ -244,8 +280,8 @@ def paged_attention_decode_kernel(
             in_specs=[
                 pl.BlockSpec((1, 1, hg, d),
                              lambda bi, hki, pt, lens: (bi, hki, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, hg, d), lambda bi, hki, pt, lens: (bi, hki, 0, 0)),
@@ -260,20 +296,11 @@ def paged_attention_decode_kernel(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hk, hg, d), q.dtype),
-        compiler_params=_decode_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, seq_lens, qg, k_pages, v_pages)
     return out.reshape(b, 1, h, d)
-
-
-def _decode_compiler_params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except Exception:
-        return None
 
 
 class PageAllocator:

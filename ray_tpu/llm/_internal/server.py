@@ -8,6 +8,7 @@ items)."""
 
 from __future__ import annotations
 
+import math
 import queue
 import threading
 import time
@@ -19,14 +20,22 @@ from ray_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
+# How long a request may wait for its next token. The first request of each
+# shape compiles its prefill and decode programs, which at real widths takes
+# longer than serve's default request timeout, so the LLM deployments carry
+# this one.
+GENERATE_TIMEOUT_S = 600.0
 
-def load_model_and_params(llm_config: Dict[str, Any]):
+
+def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
     """Resolve an llm_config dict to (model, params). Shared by the serve
-    path (LLMServer) and the batch path (_internal/batch.py)."""
+    path (LLMServer) and the batch path (_internal/batch.py). With a mesh,
+    seeded parameters are initialized straight into their tensor-parallel
+    shardings, so no device ever holds the whole tree."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+    from ray_tpu.models.llama import LLAMA_SHARDING, LlamaConfig, LlamaModel
 
     model_cfg = llm_config.get("model_config") or {}
     preset = llm_config.get("model", "tiny")
@@ -36,24 +45,37 @@ def load_model_and_params(llm_config: Dict[str, Any]):
         cfg = LlamaConfig.llama3_8b()
     else:
         cfg = LlamaConfig(**model_cfg)
-    model = LlamaModel(cfg)
+    model = LlamaModel(cfg, mesh=mesh)
     params_path = llm_config.get("params_path")
     if params_path:
         import pickle
 
         with open(params_path, "rb") as f:
             params = pickle.load(f)
+        if mesh is None:
+            # Onto the device once, not with every step. With a mesh the
+            # engine places each shard from the host instead.
+            params = jax.tree.map(jnp.asarray, params)
     else:
         seed = int(llm_config.get("seed", 0))
         sample = jnp.zeros((1, 8), jnp.int32)
-        params = model.init(jax.random.PRNGKey(seed), sample)["params"]
+
+        def init(rng):
+            return model.init(rng, sample)["params"]
+
+        shardings = None
+        if mesh is not None:
+            shardings = LLAMA_SHARDING.tree_shardings(
+                mesh, jax.eval_shape(init, jax.random.PRNGKey(seed)))
+        # One compiled program: eager init would hold each initializer's
+        # temporaries next to the tree it is building.
+        params = jax.jit(init, out_shardings=shardings)(
+            jax.random.PRNGKey(seed))
     return model, params
 
 
 class LLMServer:
     def __init__(self, llm_config: Dict[str, Any]):
-        self.model, self.params = load_model_and_params(llm_config)
-        eng_cfg = EngineConfig(**(llm_config.get("engine_config") or {}))
         mesh = llm_config.get("mesh")
         tp = int(llm_config.get("tensor_parallel_size") or 1)
         if mesh is None and tp > 1:
@@ -66,11 +88,14 @@ class LLMServer:
 
             mesh = create_mesh({"tensor": tp},
                                devices=jax.devices()[:tp])
+        self.model, self.params = load_model_and_params(llm_config, mesh)
+        eng_cfg = EngineConfig(**(llm_config.get("engine_config") or {}))
         self.engine = LLMEngine(self.model, self.params, eng_cfg, mesh=mesh)
         self._queues: Dict[str, "queue.Queue"] = {}
         self._lock = threading.Lock()
         self._pending: "queue.Queue" = queue.Queue()
         self._aborts: "queue.Queue" = queue.Queue()
+        self._tokens_out = 0
         self._running = True
         threading.Thread(target=self._engine_loop, daemon=True,
                          name="llm-engine").start()
@@ -104,6 +129,7 @@ class LLMServer:
                         q.put(("error", str(e)))
                     self._queues.clear()
                 continue
+            self._tokens_out += len(outputs)
             for so in outputs:
                 with self._lock:
                     q = self._queues.get(so.request_id)
@@ -138,7 +164,7 @@ class LLMServer:
         finished = False
         try:
             while True:
-                item = q.get(timeout=600)
+                item = q.get(timeout=GENERATE_TIMEOUT_S)
                 if item[0] == "error":
                     raise RuntimeError(f"engine failed: {item[1]}")
                 _, so = item
@@ -191,10 +217,77 @@ class LLMServer:
         return self.engine.load_lora(name, adapter, scale)
 
     def stats(self) -> Dict[str, Any]:
+        import os
+
+        import jax
+
+        devices = (list(self.engine.mesh.devices.flat)
+                   if self.engine.mesh is not None else jax.devices()[:1])
+
+        def bytes_per_device(tree) -> List[int]:
+            # From the shardings, not the buffers: the engine thread may
+            # be donating the cache to a step right now.
+            held = {d.id: 0 for d in devices}
+            for leaf in jax.tree.leaves(tree):
+                shard = leaf.sharding.shard_shape(leaf.shape)
+                for d in leaf.sharding.device_set:
+                    held[d.id] += math.prod(shard) * leaf.dtype.itemsize
+            return list(held.values())
+
         return {
             "running": self.engine.num_running(),
             "waiting": len(self.engine.waiting),
             "free_pages": self.engine.allocator.num_free,
+            "tokens_out": self._tokens_out,
+            # Which device answers: the devices this engine computes on, as
+            # JAX reports them in this process, and the chips it was leased.
+            "pid": os.getpid(),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+            "param_bytes_per_device": bytes_per_device(self.params),
+            "kv_bytes_per_device": bytes_per_device(self.engine.caches),
+        }
+
+    def self_check(self, prompt_ids: List[int], steps: int = 2
+                   ) -> Dict[str, Any]:
+        """Compare the engine with the plain model where the weights live.
+
+        Generates `steps` greedy tokens for `prompt_ids` through the engine
+        (paged prefill, then the decode program) asking for logprobs, and
+        recomputes the same positions with one dense `model.apply` under
+        attention_impl="reference" on the same parameters. Returns the
+        largest logprob gap over the engine's reported top tokens, whether
+        each engine token is the reference's argmax, and whether the
+        lowered decode program holds the Mosaic paged-attention kernel."""
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        top = self.engine.cfg.max_logprobs
+        got = self.generate_all(prompt_ids, max_tokens=steps, logprobs=top)
+        tokens = got["tokens"]
+        ref_model = type(self.model)(dataclasses.replace(
+            self.model.cfg, attention_impl="reference", remat=False))
+        ids = jnp.asarray([list(prompt_ids) + tokens[:-1]], jnp.int32)
+        logits = jax.jit(ref_model.apply)({"params": self.params}, ids)
+        ref = np.asarray(jax.nn.log_softmax(
+            logits[0, len(prompt_ids) - 1:].astype(jnp.float32), axis=-1))
+        gap = 0.0
+        for i, alts in enumerate(got["top_logprobs"]):
+            for tok, lp in alts:
+                gap = max(gap, abs(float(ref[i, tok]) - lp))
+        return {
+            "tokens": tokens,
+            "top_logprobs": got["top_logprobs"],
+            "max_logprob_gap": gap,
+            "argmax_agrees": [int(ref[i].argmax()) == t
+                              for i, t in enumerate(tokens)],
+            "decode_has_mosaic_kernel":
+                "tpu_custom_call" in self.engine.lowered_decode_text(),
         }
 
     def check_health(self) -> bool:

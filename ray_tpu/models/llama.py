@@ -188,7 +188,7 @@ class Attention(nn.Module):
             v_pages = paged_write(v_pages, v, paged["page_table"], pos2d,
                                   paged["write_mask"])
             out = paged_attention(q, k_pages, v_pages, paged["page_table"],
-                                  pos2d, paged["seq_lens"])
+                                  pos2d, paged["seq_lens"], mesh=self.mesh)
             return o_proj(out), (k_pages, v_pages)
 
         if kv_cache is not None:
@@ -209,7 +209,7 @@ class Attention(nn.Module):
 
             out = ring_attention(q, k, v, mesh=self.mesh, causal=True)
         elif cfg.attention_impl == "flash":
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention(q, k, v, causal=True, mesh=self.mesh)
         else:
             out = attention_reference(q, k, v, causal=True)
         return o_proj(out), None

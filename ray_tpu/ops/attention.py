@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh
 
 NEG_INF = -1e30
 
@@ -373,6 +374,7 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: Optional[bool] = None,
+    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     """Pallas flash attention. q [B,Sq,H,D], k/v [B,Skv,Hkv,D] → [B,Sq,H,D].
 
@@ -387,7 +389,29 @@ def flash_attention(
 
     Grid (B, H, q_blocks, k_blocks); the trailing dimension is sequential
     ("arbitrary") carrying running softmax stats in VMEM scratch.
+
+    With a multi-device `mesh` the kernel runs under shard_map — batch over
+    (data, fsdp), heads over tensor — because GSPMD cannot partition a
+    Mosaic kernel; attention is independent per (batch, head), so no
+    collective is needed inside.
     """
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel.sharding import spec_for_shape
+
+        q_spec = spec_for_shape(("batch", None, "heads", None), q.shape, mesh)
+        kv_spec = spec_for_shape(("batch", None, "kv_heads", None), k.shape,
+                                 mesh)
+        if kv_spec != q_spec:
+            # The tensor axis divides the query heads but not the KV heads:
+            # give every query head its own KV head before splitting.
+            k, v = _gqa_expand(k, v, q.shape[2])
+            kv_spec = q_spec
+        local = functools.partial(
+            flash_attention, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=interpret)
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+            out_specs=q_spec, check_vma=False)(q, k, v)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
@@ -423,8 +447,5 @@ def _vmem(shape):
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-    except Exception:
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))

@@ -16,7 +16,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -57,8 +56,8 @@ def pipeline_apply(
         return jax.lax.psum(outs * is_last, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, microbatches)

@@ -68,6 +68,14 @@ def sharding_for(mesh: Mesh, logical_axes: Sequence[Optional[str]],
     return NamedSharding(mesh, spec_for(logical_axes, rules, mesh))
 
 
+def spec_for_shape(logical_axes: Sequence[Optional[str]],
+                   shape: Sequence[int], mesh: Mesh,
+                   rules: Optional[Rules] = None) -> PartitionSpec:
+    """spec_for, with any dimension a mapped mesh axis does not divide left
+    replicated (e.g. 2 KV heads on tensor=4)."""
+    return _drop_indivisible(spec_for(logical_axes, rules, mesh), shape, mesh)
+
+
 # ---------------------------------------------------------------------------
 # Path-pattern param sharding: model families declare regex → logical axes.
 # ---------------------------------------------------------------------------
@@ -97,9 +105,8 @@ class ParamShardingRules:
         def one(path, leaf):
             path_str = "/".join(_key_str(k) for k in path)
             axes = self.logical_axes(path_str, getattr(leaf, "ndim", 0))
-            spec = spec_for(axes, self._rules, mesh)
-            spec = _drop_indivisible(spec, getattr(leaf, "shape", ()), mesh)
-            return NamedSharding(mesh, spec)
+            return NamedSharding(mesh, spec_for_shape(
+                axes, getattr(leaf, "shape", ()), mesh, self._rules))
 
         return jax.tree_util.tree_map_with_path(one, params)
 

@@ -373,3 +373,55 @@ def test_one_way_heartbeat_partition_tolerated():
                          timeout=300)
     assert "PARTITION_OK" in out.stdout, \
         out.stdout[-800:] + out.stderr[-2000:]
+
+
+HOST_STALL_SCRIPT = """
+import os
+import signal
+import time
+os.environ["RAY_TPU_HEARTBEAT_FAILURE_THRESHOLD"] = "3"
+import ray_tpu
+from ray_tpu import api
+
+ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
+
+@ray_tpu.remote
+class Named:
+    def ping(self):
+        return "ok"
+
+a = Named.options(name="survivor").remote()
+assert ray_tpu.get(a.ping.remote(), timeout=60) == "ok"
+# Freeze the GCS and the nodelet together, as a co-located TPU worker's
+# runtime start-up freezes the whole host, for longer than
+# heartbeat_failure_threshold * interval.
+pids = [p.pid for p in api._global_node.processes]
+for pid in pids:
+    os.kill(pid, signal.SIGSTOP)
+time.sleep(4.5)
+for pid in pids:
+    os.kill(pid, signal.SIGCONT)
+time.sleep(2)  # a couple of health-check rounds
+
+from ray_tpu.util import state
+nodes = state.list_nodes()
+assert nodes and all(n["alive"] for n in nodes), nodes
+again = ray_tpu.get_actor("survivor")
+assert ray_tpu.get(again.ping.remote(), timeout=60) == "ok"
+print("HOST_STALL_OK", flush=True)
+ray_tpu.shutdown()
+"""
+
+
+def test_whole_host_stall_does_not_kill_the_node():
+    """Found on the TPU host: initializing the TPU runtime in a worker froze
+    every process for ~5s; the GCS woke, saw 5s of silence it could not
+    have heard through, and declared the node — and every actor on it,
+    serve controller included — dead while all of them were running."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", HOST_STALL_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert "HOST_STALL_OK" in out.stdout, \
+        out.stdout[-800:] + out.stderr[-2000:]

@@ -51,16 +51,17 @@ def test_cluster_writes_export_events(ray_start_regular):
 
     from ray_tpu._private import worker as wm
 
-    session_dir = wm.global_worker().session_dir \
-        if hasattr(wm.global_worker(), "session_dir") else None
-    # the GCS writes next to its persist path inside the session dir
+    # The GCS writes next to its persist path inside the session dir. Read
+    # this cluster's own files: sessions that earlier runs left under /tmp
+    # hold events of other tasks.
+    session_dir = wm.global_worker().session_dir
     import glob
 
     deadline = time.monotonic() + 30
     actor_rows = node_rows = task_rows = []
     while time.monotonic() < deadline:
-        files = glob.glob("/tmp/ray_tpu/session_*/export_events/"
-                          "event_EXPORT_*.log")
+        files = glob.glob(os.path.join(session_dir, "export_events",
+                                       "event_EXPORT_*.log"))
         by_type = {}
         for f in files:
             kind = os.path.basename(f)[len("event_"):-len(".log")]

@@ -1,0 +1,216 @@
+"""What the chip's compiler says, without the chip, and what a worker's
+environment says about which device it may touch.
+
+The TPU compiler is part of the installation and compiles for a chip that is
+described, not attached (see the on-chip-measurement guide): interpret-mode
+tests cannot see a misaligned slice, too much VMEM, or a Mosaic kernel that
+GSPMD is asked to partition. These compiles keep the main path's two kernels
+honest at Llama-3-8B head shapes, alone on one chip and inside their
+shard_map wrappers on the four-chip host's 2x2 mesh. A compile that passes is
+not a chip run."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu._private import accelerators
+from ray_tpu.llm._internal.paged import paged_attention_decode_kernel
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.parallel.mesh import AXIS_ORDER
+
+H, HKV, D = 32, 8, 128  # Llama-3-8B attention heads
+
+
+@pytest.fixture(scope="module")
+def topology():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    # A program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one; keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _mesh(topo, **sizes):
+    dims = tuple(sizes.get(ax, 1) for ax in AXIS_ORDER)
+    return Mesh(np.array(topo.devices).reshape(dims), AXIS_ORDER)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_loss(q, k, v, mesh):
+    out = flash_attention(q, k, v, causal=True, interpret=False, mesh=mesh)
+    return out.astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("batch,seq,heads,kv_heads", [
+    (4, 4096, 16, 4),    # the flash bench's shape
+    (1, 2048, H, HKV),   # chip_smoke's train step, per sequence
+    (8, 128, H, HKV),    # short sequences: blocks shrink to the sequence
+])
+def test_flash_fwd_bwd_one_chip(topology, batch, seq, heads, kv_heads):
+    one = SingleDeviceSharding(topology.devices[0])
+    q = jax.ShapeDtypeStruct((batch, seq, heads, D), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, D), jnp.bfloat16,
+                              sharding=one)
+    grad = jax.grad(functools.partial(_flash_loss, mesh=None),
+                    argnums=(0, 1, 2))
+    # forward + dq + dk/dv kernels
+    assert _compiled_text(grad, q, kv, kv).count("tpu_custom_call") >= 3
+
+
+def test_flash_fwd_bwd_sharded_2x2(topology):
+    """Batch over fsdp, heads over tensor: what the sharded train step asks
+    of the kernel. Bare, GSPMD refuses it ("Mosaic kernels cannot be
+    automatically partitioned")."""
+    mesh = _mesh(topology, fsdp=2, tensor=2)
+    sh = NamedSharding(mesh, P("fsdp", None, "tensor"))
+    q = jax.ShapeDtypeStruct((4, 2048, H, D), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((4, 2048, HKV, D), jnp.bfloat16, sharding=sh)
+    grad = jax.grad(functools.partial(_flash_loss, mesh=mesh),
+                    argnums=(0, 1, 2))
+    assert _compiled_text(grad, q, kv, kv).count("tpu_custom_call") >= 3
+    with pytest.raises(Exception, match="shard_map"):
+        _compiled_text(jax.grad(functools.partial(_flash_loss, mesh=None),
+                                argnums=(0, 1, 2)), q, kv, kv)
+
+
+def _decode_args(sharding_for, batch, page_size, pages_per_seq):
+    pages = batch * pages_per_seq + 1
+    s = jax.ShapeDtypeStruct
+    return (
+        s((batch, 1, H, D), jnp.bfloat16, sharding=sharding_for("q")),
+        s((HKV, pages, page_size, D), jnp.bfloat16,
+          sharding=sharding_for("pages")),
+        s((HKV, pages, page_size, D), jnp.bfloat16,
+          sharding=sharding_for("pages")),
+        s((batch, pages_per_seq), jnp.int32, sharding=sharding_for(None)),
+        s((batch,), jnp.int32, sharding=sharding_for(None)),
+    )
+
+
+@pytest.mark.parametrize("batch,page_size,pages_per_seq", [
+    (8, 64, 16),    # chip_smoke's engine config
+    (32, 16, 64),   # the engine's default page shape
+    (8, 64, 64),    # several chunks of 16 pages
+])
+def test_paged_decode_one_chip(topology, batch, page_size, pages_per_seq):
+    one = SingleDeviceSharding(topology.devices[0])
+    fn = functools.partial(paged_attention_decode_kernel, interpret=False)
+    text = _compiled_text(
+        fn, *_decode_args(lambda _: one, batch, page_size, pages_per_seq))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_sharded_tensor4(topology):
+    """KV pages split over kv heads, as the tensor-parallel engine holds
+    them; page table and lengths replicated."""
+    mesh = _mesh(topology, tensor=4)
+    specs = {"q": P(None, None, "tensor"), "pages": P("tensor"), None: P()}
+    args = _decode_args(lambda k: NamedSharding(mesh, specs[k]), 8, 64, 16)
+    fn = functools.partial(paged_attention_decode_kernel, interpret=False)
+    text = _compiled_text(functools.partial(fn, mesh=mesh), *args)
+    assert "tpu_custom_call" in text
+    with pytest.raises(Exception, match="shard_map"):
+        _compiled_text(fn, *args)
+
+
+# ---------------------------------------------------------------------------
+# The environment the nodelet gives a worker (no cluster needed)
+# ---------------------------------------------------------------------------
+_HOST = {
+    # what the chip machine's own environment carries
+    "JAX_PLATFORMS": "tpu,cpu",
+    "TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1",
+    "PATH": "/usr/bin",
+}
+
+
+@pytest.mark.parametrize("parent_platform", ["tpu,cpu", "cpu", None])
+def test_worker_without_lease_is_a_cpu_process(parent_platform):
+    base = dict(_HOST)
+    if parent_platform is None:
+        del base["JAX_PLATFORMS"]
+    else:
+        base["JAX_PLATFORMS"] = parent_platform
+    before = dict(base)
+    env = accelerators.process_environ(base)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in env
+    assert env["PATH"] == "/usr/bin"
+    assert base == before
+
+
+@pytest.mark.parametrize("chips,bounds", [
+    ([2], "1,1,1"),            # 1 of 4
+    ([0, 1], "1,2,1"),
+    ([0, 1, 2, 3], "2,2,1"),   # 4 of 4
+])
+def test_leased_worker_gets_the_tpu_even_under_a_cpu_parent(chips, bounds):
+    env = accelerators.process_environ(
+        dict(_HOST, JAX_PLATFORMS="cpu"), chips)
+    assert env["JAX_PLATFORMS"].split(",")[0] == "tpu"
+    assert env["TPU_VISIBLE_CHIPS"] == ",".join(map(str, chips))
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == bounds
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+
+def test_lease_of_unknown_shape_is_refused():
+    with pytest.raises(ValueError, match="3 chips"):
+        accelerators.process_environ(_HOST, [0, 1, 2])
+
+
+def test_compile_cache_dir_reaches_leased_workers():
+    env = accelerators.process_environ(
+        dict(_HOST, JAX_COMPILATION_CACHE_DIR="/somewhere/cache"), [0])
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/somewhere/cache"
+    env = accelerators.process_environ(_HOST, [0])
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert env["JAX_COMPILATION_CACHE_DIR"] == os.path.join(
+        repo, ".jax_cache")
+    # A CPU process keeps whatever its parent had, and gets no default.
+    assert "JAX_COMPILATION_CACHE_DIR" not in accelerators.process_environ(
+        _HOST)
+
+
+@pytest.mark.parametrize("vfio,dev,want", [
+    (["0", "1", "2", "3", "vfio"], ["vfio", "null"], 4),   # four-chip host
+    (["3", "vfio"], ["vfio", "null"], 1),                  # one chip of it
+    (None, ["accel0", "accel1", "null"], 2),               # /dev/accel*
+    (None, ["null"], 0),
+])
+def test_chips_are_counted_from_device_files(monkeypatch, vfio, dev, want):
+    def listdir(path):
+        if path == "/dev/vfio":
+            if vfio is None:
+                raise FileNotFoundError(path)
+            return vfio
+        assert path == "/dev"
+        return dev
+
+    monkeypatch.setattr(accelerators.os, "listdir", listdir)
+    # The host's bounds describe its type, not what this VM was given.
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    assert accelerators._count_tpu_chips() == want
