@@ -1,0 +1,7 @@
+"""Jitted train step: programs that went through the backend compiler
+(or were loaded from its cache) inside the window, counted by a
+`jax.monitoring` listener in the train worker. Should read 0."""
+
+
+def read(obs):
+    return obs.get("compiles_in_window")
