@@ -15,6 +15,10 @@ Design constraints, in order:
    (~2 perf_counter_ns calls) to stay always-on.
 3. Everything lands in one bounded ring buffer (``RAY_TPU_FR_RING``
    events) dumpable on demand: `ray_tpu debug flight-record`.
+4. ``span``/``mark`` are the one way code above the RPC path (the LLM
+   engine, the serve replica) times itself: a ring event, and the same
+   interval in the JAX profiler's trace when this process has JAX. This
+   module never imports JAX: the GCS and the nodelet stay without it.
 
 Phase model for a call (all durations, never wall-clock pairs — so
 cross-host clock skew cannot produce negative phases):
@@ -95,6 +99,101 @@ def record_event(kind: str, **fields) -> None:
 
 def dump_events() -> List[Dict[str, Any]]:
     return list(_ring)
+
+
+# --------------------------------------------------------------------------
+# Program spans: `with span("ray_tpu.engine.admit", free_slots=3) as sp:`.
+# One `kind="span"` ring event per span (name, start, duration, arguments)
+# and, when this process has already imported JAX, a
+# `jax.profiler.TraceAnnotation` of the same name and arguments around the
+# body: while a profiler runs the span lands on the device trace's clock,
+# and when none does the annotation costs a check (~0.5us).
+# --------------------------------------------------------------------------
+
+
+class _NoSpan:
+    """The shared no-op both forms return with the recorder disabled."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    """A TraceAnnotation if JAX is already in this process, else None."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None mid-import, too
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name, **args)
+
+
+def _record_span(name: str, ts: float, dur_us: Optional[float],
+                 args: Dict[str, Any]) -> None:
+    """One ring event: start (wall clock), length (None for a mark)."""
+    _ring.append({"kind": "span", "name": name, "ts": ts, "dur_us": dur_us,
+                  "args": args, "thread": threading.current_thread().name})
+
+
+class _Span:
+    __slots__ = ("name", "args", "_ann", "_ts", "_t0")
+
+    def __init__(self, name: str, args: Dict[str, Any]):
+        self.name = name
+        self.args = args
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._ts = time.time()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the body (how many were admitted,
+        how many tokens came out): added to the ring event and to the
+        profiler's span."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        dur_us = (time.perf_counter_ns() - self._t0) / 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _record_span(self.name, self._ts, dur_us, self.args)
+        return False
+
+
+def span(name: str, **args):
+    """Context manager timing its body under `name`; `args` are the span's
+    counters. `.set(**more)` adds what only the body learns."""
+    if not _ENABLED:
+        return _NO_SPAN
+    return _Span(name, args)
+
+
+def mark(name: str, **args) -> None:
+    """The zero-length span, for events that do not nest on a thread (a
+    request's first token, its release)."""
+    if not _ENABLED:
+        return
+    ann = _annotation(name, args)
+    if ann is not None:
+        with ann:
+            pass
+    _record_span(name, time.time(), None, args)
 
 
 # --------------------------------------------------------------------------
@@ -233,12 +332,16 @@ def note_exec(fn: str, exec_ns: int) -> None:
     record_event("exec", fn=fn, exec_us=round(exec_ns / 1000.0, 1))
 
 
+def _drain_stall_hist() -> Optional[Any]:
+    return _hist("ray_tpu_rpc_drain_stall_seconds",
+                 "Time awaiting transport drain (write backpressure)",
+                 (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0))
+
+
 def note_drain_stall(seconds: float) -> None:
     """Write-queue drain backpressure: how long _write_frame waited for the
     kernel buffer (anything visible here means the peer is not keeping up)."""
-    h = _hist("ray_tpu_rpc_drain_stall_seconds",
-              "Time awaiting transport drain (write backpressure)",
-              (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0))
+    h = _drain_stall_hist()
     if h is not None:
         h.observe(seconds)
     if seconds >= 0.005:
@@ -522,6 +625,10 @@ def _publisher_metrics():
         return _metrics
     from ray_tpu.util import metrics as um
 
+    # Registered empty: a healthy cluster never stalls a writer, and the
+    # dashboards' panel must still find the series' name.
+    _drain_stall_hist()
+
     _metrics.update({
         "frames": um.get_counter(
             "ray_tpu_rpc_frames_total", "RPC frames by kind/lane/direction",
@@ -724,6 +831,18 @@ def chrome_trace_events(events: Optional[List[Dict[str, Any]]] = None,
             rows.append({"name": "store_put", "cat": "FLIGHT", "ph": "X",
                          "ts": ts_us - dur, "dur": dur,
                          "pid": p, "tid": "store", "args": args})
+        elif kind == "span":
+            # Start and length on the row itself, one chrome thread per
+            # Python thread: spans of one thread nest as they did.
+            row = {"name": ev.get("name", "?"), "cat": "FLIGHT",
+                   "ts": ts_us, "pid": p,
+                   "tid": ev.get("thread", "spans"),
+                   "args": dict(ev.get("args") or {})}
+            if ev.get("dur_us") is None:  # a mark
+                row.update(ph="i", s="t")
+            else:
+                row.update(ph="X", dur=max(float(ev["dur_us"]), 0.0))
+            rows.append(row)
         else:
             rows.append({"name": kind or "event", "cat": "FLIGHT",
                          "ph": "i", "ts": ts_us, "s": "p",

@@ -30,15 +30,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import flight_recorder as _fr
 from ray_tpu.llm._internal.paged import (
     PageAllocator,
     PagedCacheConfig,
     PrefixCache,
     init_paged_cache,
 )
+from ray_tpu.util import metrics as _um
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+# Records of built and retraced programs kept for `programs_report()`.
+_PROGRAM_RECORDS = 32
+_LATENCY_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
+                    2.5, 10.0)
 
 
 @dataclasses.dataclass
@@ -96,6 +103,11 @@ class Request:
     slot: int = -1
     generated: int = 0
     done: bool = False
+    # Monotonic stamps. Enqueued when the object is made (whoever queues
+    # it); admitted and first token by the engine.
+    t_enqueued: float = dataclasses.field(default_factory=time.monotonic)
+    t_admitted: float = 0.0
+    t_first_token: float = 0.0
 
 
 @dataclasses.dataclass
@@ -107,6 +119,28 @@ class StepOutput:
     # (id, logprob) alternatives — populated when the request asked.
     logprob: Optional[float] = None
     top_logprobs: Optional[List[Tuple[int, float]]] = None
+
+
+def _signature(args) -> Dict[str, tuple]:
+    """What a jitted function's cache tells calls apart by, per argument
+    leaf: shape, dtype, weak type, sharding, committed to it or not."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(args)
+    return {jax.tree_util.keystr(path): (
+        tuple(np.shape(x)), str(getattr(x, "dtype", type(x).__name__)),
+        bool(getattr(x, "weak_type", False)),
+        str(getattr(x, "sharding", None)),
+        bool(getattr(x, "committed", False))) for path, x in leaves}
+
+
+def _signature_diff(old: Dict[str, tuple], new: Dict[str, tuple]
+                    ) -> Dict[str, Any]:
+    """Leaves whose signature changed, as {path: [before, after]} (at most
+    eight; a leaf only one side has reads None on the other)."""
+    out: Dict[str, Any] = {}
+    for path in sorted(set(old) | set(new)):
+        if old.get(path) != new.get(path) and len(out) < 8:
+            out[path] = [old.get(path), new.get(path)]
+    return out
 
 
 class LLMEngine:
@@ -192,6 +226,25 @@ class LLMEngine:
         # (tokens [K,B], final last_tokens [B], final seq_lens [B]) plus
         # the slot set it was dispatched for.
         self._inflight: Optional[Tuple[Any, Any, Any, frozenset]] = None
+        # Compile record: (kind, key) -> [jit cache size after the last
+        # call, argument signature it was last traced for], and the last
+        # records of programs built and retraced.
+        self._programs: Dict[Tuple[str, tuple], list] = {}
+        self._program_records: List[Dict[str, Any]] = []
+        self._programs_built = 0
+        self._programs_retraced = 0
+        self._m_queue_wait = _um.get_histogram(
+            "ray_tpu_llm_queue_wait_seconds",
+            "Request made to admitted into a slot",
+            boundaries=_LATENCY_BUCKETS)
+        self._m_prefill = _um.get_histogram(
+            "ray_tpu_llm_prefill_seconds",
+            "Admission to the first token on the host",
+            boundaries=_LATENCY_BUCKETS)
+        self._m_programs = _um.get_counter(
+            "ray_tpu_llm_programs_built_total",
+            "Prefill/decode programs built or retraced by the engine",
+            tag_keys=("kind",))
 
     # ------------------------------------------------------------------
     # LoRA multiplexing
@@ -418,6 +471,52 @@ class LLMEngine:
         self._prefill_fns[(bucket, nb, rich, want_lp)] = fn
         return fn
 
+    # ------------------------------------------------------------------
+    # Compile record
+    # ------------------------------------------------------------------
+    def _run_program(self, kind: str, key: tuple, fn, args: tuple):
+        """Call a jitted program and keep the compile record: the first
+        call of a program builds it (trace, compile or cache load), and a
+        later call that grows the function's jit cache is a retrace: the
+        same key met an argument signature it had not seen."""
+        entry = self._programs.get((kind, key))
+        t0 = time.monotonic()
+        out = fn(*args)
+        size = fn._cache_size()
+        if entry is not None and size <= entry[0]:
+            return out
+        seconds = time.monotonic() - t0
+        signature = _signature(args)
+        record = {"kind": kind, "key": list(key), "t": time.time(),
+                  "seconds": seconds}
+        if entry is None:
+            record["event"] = "built"
+            self._programs_built += 1
+        else:
+            record["event"] = "retraced"
+            record["differs"] = _signature_diff(entry[1], signature)
+            self._programs_retraced += 1
+        self._programs[(kind, key)] = [size, signature]
+        # Rebound, not appended to: `programs_report` reads it from other
+        # threads.
+        self._program_records = (self._program_records
+                                 + [record])[-_PROGRAM_RECORDS:]
+        self._m_programs.inc(tags={"kind": kind})
+        _fr.mark("ray_tpu.program." + record["event"], kind=kind,
+                 key=str(key), seconds=seconds)
+        logger.info("engine %s program %s %s in %.3fs%s", record["event"],
+                    kind, key, seconds,
+                    f": {record['differs']}" if entry is not None else "")
+        return out
+
+    def programs_report(self) -> Dict[str, Any]:
+        """Programs built (first call of a new key) and retraced (a key
+        that is in the table, traced again for other arguments), with the
+        last records of both."""
+        return {"built": self._programs_built,
+                "retraced": self._programs_retraced,
+                "records": list(self._program_records)}
+
     def _sampling_flags(self, reqs) -> Tuple[bool, bool]:
         rich = any(r.temperature > 0 and (r.top_p < 1.0 or r.top_k > 0)
                    for r in reqs)
@@ -478,19 +577,26 @@ class LLMEngine:
         the slot set changes (admit/finish) — the next dispatch then
         rebuilds control state from the host mirrors."""
         out: List[StepOutput] = []
+        with _fr.span("ray_tpu.engine.step", running=len(self.running),
+                      waiting=len(self.waiting),
+                      inflight=self._inflight is not None):
+            self._step(out)
+        return out
+
+    def _step(self, out: List[StepOutput]) -> None:
         admitted = self._admit(out)
         if not self.running:
             if self._inflight is not None:
-                self._process_window(self._inflight, out)
+                self._process_window(self._inflight, out, why="idle")
                 self._inflight = None
-            return out
+            return
         if admitted and self._inflight is not None:
             # Admission changed active/temps/last_tokens: the in-flight
             # window predates it — drain before dispatching from host.
-            self._process_window(self._inflight, out)
+            self._process_window(self._inflight, out, why="admitted")
             self._inflight = None
             if not self.running:
-                return out
+                return
         K = max(1, self.cfg.decode_steps)
         if self._inflight is None:
             self._ensure_decode_pages(K)
@@ -498,19 +604,19 @@ class LLMEngine:
             if not self.cfg.pipeline_dispatch:
                 self._process_window(self._inflight, out)
                 self._inflight = None
-            return out
+            return
         # Pipelined: cover the NEXT window's writes too, then chain the
         # dispatch off the in-flight window's device state. Skip the chain
         # when every request ends inside the in-flight window — the chained
         # window would be pure waste.
         if all(r.generated + K >= r.max_tokens
                for r in self.running.values()):
-            self._process_window(self._inflight, out)
+            self._process_window(self._inflight, out, why="all_finishing")
             self._inflight = None
-            return out
+            return
         self._ensure_decode_pages(2 * K)
         nxt = self._dispatch_window(*self._inflight[1:3])
-        finished = self._process_window(self._inflight, out)
+        finished = self._process_window(self._inflight, out, why="chained")
         if finished:
             # The chained window ran with pre-finish control state. Its
             # tokens are still VALID for surviving slots (their device
@@ -519,11 +625,10 @@ class LLMEngine:
             # released pages get re-prefilled by strictly later programs
             # on the ordered device stream. Process it now and resync from
             # host state on the next step.
-            self._process_window(nxt, out)
+            self._process_window(nxt, out, why="finished_in_chain")
             self._inflight = None
         else:
             self._inflight = nxt
-        return out
 
     def _decode_args(self, last=None, lens=None) -> tuple:
         """Arguments of the decode program: control state from the host
@@ -543,8 +648,15 @@ class LLMEngine:
 
     def _dispatch_window(self, last=None, lens=None):
         rich, want_lp = self._sampling_flags(self.running.values())
-        toks, last, lens, self.caches, self._keys_dev, lp = \
-            self._decode_fn(rich, want_lp)(*self._decode_args(last, lens))
+        key = (rich, want_lp)
+        with _fr.span("ray_tpu.engine.dispatch_decode",
+                      active=len(self.running), max_seqs=self.cfg.max_seqs,
+                      steps=max(1, self.cfg.decode_steps),
+                      chained=last is not None,
+                      new_program=key not in self._decode_fns):
+            toks, last, lens, self.caches, self._keys_dev, lp = \
+                self._run_program("decode", key, self._decode_fn(*key),
+                                  self._decode_args(last, lens))
         return (toks, last, lens, lp, frozenset(self.running))
 
     def lowered_decode_text(self) -> str:
@@ -558,26 +670,35 @@ class LLMEngine:
             self._decode_args())
         return self._decode_fn(False, False).lower(*shapes).as_text()
 
-    def _process_window(self, window,
-                        out: Optional[List[StepOutput]]) -> bool:
+    def _process_window(self, window, out: Optional[List[StepOutput]],
+                        why: str = "unpipelined") -> bool:
         """Block on a window's tokens; update host mirrors and emit
-        outputs. out=None discards (pipeline drain). Returns True if any
-        slot finished."""
+        outputs. out=None discards (pipeline drain). `why` names what made
+        the caller wait for this window (the span's argument). Returns True
+        if any slot finished."""
         toks, _, _, lp, slots = window
-        toks = np.asarray(toks)  # [K, B] (blocks here)
-        if lp is not None:
-            lp = tuple(np.asarray(a) for a in lp)
+        with _fr.span("ray_tpu.engine.wait_tokens", why=why):
+            toks = np.asarray(toks)  # [K, B] (blocks here)
+            if lp is not None:
+                lp = tuple(np.asarray(a) for a in lp)
         if out is None:
             return False
+        with _fr.span("ray_tpu.engine.emit") as sp:
+            tokens, running = len(out), len(self.running)
+            self._emit_window(toks, lp, slots, out)
+            finished = running - len(self.running)  # one release each
+            sp.set(tokens=len(out) - tokens, finished=finished)
+        return finished > 0
+
+    def _emit_window(self, toks, lp, slots, out: List[StepOutput]) -> None:
+        """The host loop over a window's tokens, now on the host."""
         K = toks.shape[0]
-        finished_any = False
         for slot in slots:
             req = self.running.get(slot)
             if req is None:
                 continue
             if req.done:  # aborted externally (e.g. stop-string match)
                 self._release(slot)
-                finished_any = True
                 continue
             for j in range(K):
                 tok = int(toks[j, slot])
@@ -599,9 +720,7 @@ class LLMEngine:
                     # Tokens past the stop within this window are wasted
                     # compute (multi-step tradeoff); drop them.
                     self._release(slot)
-                    finished_any = True
                     break
-        return finished_any
 
     def finish_request(self, request_id: str) -> bool:
         """Finish a request early (serving layer stop-string match /
@@ -624,7 +743,21 @@ class LLMEngine:
         the whole admission wave, not one per request — and the first
         tokens stay on device until every batch is in flight, so TTFT for
         N admissions is ~one weight stream + one host sync."""
-        admitted = False
+        if not (self.waiting and self._free_slots):
+            return False
+        with _fr.span("ray_tpu.engine.admit",
+                      free_slots=len(self._free_slots),
+                      free_pages=self.allocator.num_free) as sp:
+            entries = self._place_waiting()
+            pending = self._dispatch_prefills(entries)
+            self._sync_first_tokens(pending, out)
+            sp.set(admitted=len(entries), waiting_left=len(self.waiting))
+        return bool(entries)
+
+    def _place_waiting(self) -> List[tuple]:
+        """Move waiting requests into free slots while pages last: page
+        bookkeeping, prefix sharing and sampling state, nothing on the
+        device but each slot's PRNG key."""
         # Flat admission-order list of (slot, req, suffix_ids, cached_len,
         # S, bucket, deps). deps = admission indices of SAME-WAVE requests
         # whose prefill must be dispatched first: a sharer attends over
@@ -669,7 +802,7 @@ class LLMEngine:
                         self.allocator.unref(p)
                     break  # wait for running requests to free pages
             self.waiting.popleft()
-            admitted = True
+            req.t_admitted = time.monotonic()
             slot = self._free_slots.pop()
             req.slot = slot
             self.running[slot] = req
@@ -714,7 +847,13 @@ class LLMEngine:
             self.seq_lens[slot] = T
             req.generated = 1
             entries.append((slot, req, suffix, cached_len, S, bucket, deps))
-        pending: List[Tuple[int, Request, Any, int]] = []
+        return entries
+
+    def _dispatch_prefills(self, entries: List[tuple]) -> List[tuple]:
+        """Dispatch the wave's prefills; the first tokens stay on the
+        device. Returns (slot, req, tokens on device, logprobs, row, batch
+        size, cached prompt tokens) per admission."""
+        pending: List[tuple] = []
         # Dispatch in dependency-respecting sub-batches: repeatedly take
         # the earliest undispatched admission, batch it with every other
         # undispatched same-bucket entry whose deps are all dispatched.
@@ -728,55 +867,85 @@ class LLMEngine:
                      if entries[j][5] == bucket and entries[j][6] <= done]
             wave = [entries[j][:5] for j in batch]
             nb = len(wave)
-            ids = np.zeros((nb, bucket), np.int32)
-            rows = np.zeros((nb, self.cfg.max_pages_per_seq), np.int32)
-            starts = np.zeros((nb,), np.int32)
-            lens = np.zeros((nb,), np.int32)
-            temps = np.zeros((nb,), np.float32)
-            tps = np.ones((nb,), np.float32)
-            tks = np.zeros((nb,), np.int32)
-            slot_ids = np.zeros((nb,), np.int32)
-            lidx = np.zeros((nb,), np.int32)
-            for i, (slot, req, suffix, cached_len, S) in enumerate(wave):
-                ids[i, :S] = suffix
-                rows[i] = self.page_table[slot]
-                starts[i] = cached_len
-                lens[i] = S
-                temps[i] = req.temperature
-                tps[i] = req.top_p
-                tks[i] = req.top_k
-                slot_ids[i] = slot
-                lidx[i] = self.lora_idx[slot]
             rich, want_lp = self._sampling_flags(
                 [entries[j][1] for j in batch])
-            dev_toks, self.caches, self._keys_dev, lp = self._prefill_fn(
-                bucket, nb, rich, want_lp)(
+            key = (bucket, nb, rich, want_lp)
+            with _fr.span("ray_tpu.engine.prefill_dispatch", bucket=bucket,
+                          nb=nb, tokens=sum(w[4] for w in wave),
+                          cached_tokens=sum(w[3] for w in wave), rich=rich,
+                          want_lp=want_lp,
+                          new_program=key not in self._prefill_fns):
+                dev_toks, lp = self._prefill_wave(key, wave)
+            for i, (slot, req, _, cached_len, _) in enumerate(wave):
+                pending.append((slot, req, dev_toks, lp, i, nb, cached_len))
+            done.update(batch)
+            remaining = [j for j in remaining if j not in done]
+        return pending
+
+    def _prefill_wave(self, key: Tuple[int, int, bool, bool],
+                      wave: List[tuple]):
+        """One batched prefill: the host arrays, their transfers and the
+        program call."""
+        bucket, nb = key[:2]
+        ids = np.zeros((nb, bucket), np.int32)
+        rows = np.zeros((nb, self.cfg.max_pages_per_seq), np.int32)
+        starts = np.zeros((nb,), np.int32)
+        lens = np.zeros((nb,), np.int32)
+        temps = np.zeros((nb,), np.float32)
+        tps = np.ones((nb,), np.float32)
+        tks = np.zeros((nb,), np.int32)
+        slot_ids = np.zeros((nb,), np.int32)
+        lidx = np.zeros((nb,), np.int32)
+        for i, (slot, req, suffix, cached_len, S) in enumerate(wave):
+            ids[i, :S] = suffix
+            rows[i] = self.page_table[slot]
+            starts[i] = cached_len
+            lens[i] = S
+            temps[i] = req.temperature
+            tps[i] = req.top_p
+            tks[i] = req.top_k
+            slot_ids[i] = slot
+            lidx[i] = self.lora_idx[slot]
+        dev_toks, self.caches, self._keys_dev, lp = self._run_program(
+            "prefill", key, self._prefill_fn(*key), (
                 self.params, self.caches, self._dev(ids),
                 self._dev(rows), self._dev(starts), self._dev(lens),
                 self._dev(temps), self._dev(tps), self._dev(tks),
                 self._keys_dev, self._dev(slot_ids), self.lora_banks,
-                self._dev(lidx))
-            for i, (slot, req, _, _, _) in enumerate(wave):
-                pending.append((slot, req, dev_toks, lp, i))
-            done.update(batch)
-            remaining = [j for j in remaining if j not in done]
-        for slot, req, dev_toks, lp, i in pending:
-            tok = int(np.asarray(dev_toks)[i])  # sync: all waves in flight
-            self.last_tokens[slot] = tok
-            finished = (req.generated >= req.max_tokens
-                        or (req.stop_token is not None
-                            and tok == req.stop_token))
-            so = StepOutput(req.request_id, tok, finished)
-            if lp is not None and req.logprobs > 0:
-                so.logprob = float(np.asarray(lp[0])[i])
-                so.top_logprobs = [
-                    (int(np.asarray(lp[2])[i, k]),
-                     float(np.asarray(lp[1])[i, k]))
-                    for k in range(req.logprobs)]
-            out.append(so)
-            if finished:
-                self._release(slot)
-        return admitted
+                self._dev(lidx)))
+        return dev_toks, lp
+
+    def _sync_first_tokens(self, pending: List[tuple],
+                           out: List[StepOutput]) -> None:
+        """Block on the first tokens (every wave is in flight by now), emit
+        them, and stamp each request's queue wait and prefill time."""
+        with _fr.span("ray_tpu.engine.prefill_sync", requests=len(pending)):
+            for slot, req, dev_toks, lp, i, nb, cached_len in pending:
+                tok = int(np.asarray(dev_toks)[i])  # blocks on its wave
+                req.t_first_token = now = time.monotonic()
+                queue_s = req.t_admitted - req.t_enqueued
+                prefill_s = now - req.t_admitted
+                self._m_queue_wait.observe(queue_s)
+                self._m_prefill.observe(prefill_s)
+                _fr.mark("ray_tpu.request.first_token",
+                         rid=req.request_id, slot=slot,
+                         queue_ms=queue_s * 1e3, prefill_ms=prefill_s * 1e3,
+                         prompt=len(req.prompt_ids), cached=cached_len,
+                         nb=nb)
+                self.last_tokens[slot] = tok
+                finished = (req.generated >= req.max_tokens
+                            or (req.stop_token is not None
+                                and tok == req.stop_token))
+                so = StepOutput(req.request_id, tok, finished)
+                if lp is not None and req.logprobs > 0:
+                    so.logprob = float(np.asarray(lp[0])[i])
+                    so.top_logprobs = [
+                        (int(np.asarray(lp[2])[i, k]),
+                         float(np.asarray(lp[1])[i, k]))
+                        for k in range(req.logprobs)]
+                out.append(so)
+                if finished:
+                    self._release(slot)
 
     def _ensure_decode_pages(self, k: int = 1) -> None:
         """Each running slot is about to append up to k tokens starting at
@@ -799,7 +968,11 @@ class LLMEngine:
             row[:len(pages)] = pages
 
     def _release(self, slot: int) -> None:
-        self.running.pop(slot, None)
+        req = self.running.pop(slot, None)
+        if req is not None:
+            _fr.mark("ray_tpu.request.finished", rid=req.request_id,
+                     slot=slot, tokens=req.generated, decode_ms=(
+                         time.monotonic() - req.t_first_token) * 1e3)
         self.allocator.release(slot)
         self._free_slots.append(slot)
         self.seq_lens[slot] = 0

@@ -299,6 +299,7 @@ def paged_attention_decode_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(page_table, seq_lens, qg, k_pages, v_pages)
     return out.reshape(b, 1, h, d)
 

@@ -15,6 +15,7 @@ import time
 import uuid
 from typing import Any, Dict, Iterator, List, Optional
 
+from ray_tpu._private import flight_recorder as _fr
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request
 from ray_tpu.utils.logging import get_logger
 
@@ -118,7 +119,13 @@ class LLMServer:
                     break
                 self.engine.finish_request(rid)
             if not self.engine.has_work():
-                time.sleep(0.005 if moved else 0.01)
+                # One span per idle stretch, not per sleep: an idle replica
+                # must not fill the recorder's ring.
+                with _fr.span("ray_tpu.server.idle"):
+                    time.sleep(0.005 if moved else 0.01)
+                    while (self._running and self._pending.empty()
+                           and self._aborts.empty()):
+                        time.sleep(0.01)
                 continue
             try:
                 outputs = self.engine.step()
@@ -130,11 +137,12 @@ class LLMServer:
                     self._queues.clear()
                 continue
             self._tokens_out += len(outputs)
-            for so in outputs:
-                with self._lock:
-                    q = self._queues.get(so.request_id)
-                if q is not None:
-                    q.put(("token", so))
+            with _fr.span("ray_tpu.server.deliver", outputs=len(outputs)):
+                for so in outputs:
+                    with self._lock:
+                        q = self._queues.get(so.request_id)
+                    if q is not None:
+                        q.put(("token", so))
 
     # ------------------------------------------------------------------
     def generate(self, prompt_ids: List[int], max_tokens: int = 64,
@@ -239,6 +247,8 @@ class LLMServer:
             "waiting": len(self.engine.waiting),
             "free_pages": self.engine.allocator.num_free,
             "tokens_out": self._tokens_out,
+            # Compile record: programs built and retraced, last records.
+            "programs": self.engine.programs_report(),
             # Which device answers: the devices this engine computes on, as
             # JAX reports them in this process, and the chips it was leased.
             "pid": os.getpid(),

@@ -192,6 +192,7 @@ def _flash_fwd_core(qt, kt, vt, cfg):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return out, lse
 
@@ -322,6 +323,7 @@ def _flash_bwd_core(qt, kt, vt, out, lse, dout, cfg):
         scratch_shapes=[_vmem((block_q, d))],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dout, lse, delta)
 
     # dk/dv: grid iterates q blocks sequentially per k block.
@@ -341,6 +343,7 @@ def _flash_bwd_core(qt, kt, vt, out, lse, dout, cfg):
         scratch_shapes=[_vmem((block_k, d)), _vmem((block_k, d))],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dout, lse, delta)
     return dq, dk, dv
 

@@ -100,6 +100,12 @@ def test_contract_checker_flags_orphans(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 # Live-cluster telemetry.
 # ---------------------------------------------------------------------------
+def fr_sample_every() -> int:
+    from ray_tpu._private import flight_recorder as fr
+
+    return fr._SAMPLE_EVERY
+
+
 def test_dashboard_promised_metrics_live(ray_start_regular):
     """Acceptance: every metric name the shipped Grafana dashboards
     reference appears in the /metrics text exposition of a live cluster
@@ -136,6 +142,28 @@ def test_dashboard_promised_metrics_live(ray_start_regular):
         members = [Rank.remote(i, 2) for i in range(2)]
         assert ray_tpu.get([m.run.remote() for m in members],
                            timeout=60) == [2.0, 2.0]
+
+        # The data panels: a small pipeline through a task stage and an
+        # actor pool (cloudpickle ships the local class by value).
+        import numpy as np
+
+        import ray_tpu.data as rd
+
+        class AddOne:
+            def __call__(self, b):
+                return {"id": b["id"] + 1}
+
+        ds = (rd.range(400, block_rows=50)
+              .map_batches(lambda b: {"id": b["id"] * 2})
+              .map_batches(AddOne, concurrency=(1, 2)))
+        assert sorted(r["id"] for r in ds.take_all()) == \
+            [2 * i + 1 for i in range(400)]
+
+        # The object-store phase panels: a put of 1 MiB or more is always
+        # timed, gets are sampled 1-in-RAY_TPU_FR_SAMPLE.
+        ref = ray_tpu.put(np.ones(2 << 20, np.uint8))
+        for _ in range(4 * fr_sample_every()):
+            assert ray_tpu.get(ref)[-1] == 1
 
         um.flush()  # the driver's own registry, without the 2s wait
         names = set(dashboard_metric_names())

@@ -59,6 +59,19 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_names(text):
+    """Which of the kernels' names the Mosaic custom-calls' own
+    instruction names hold (`%jvp_flash_fwd_.1 = ... custom-call(...)`:
+    the name comes from the innermost scope, the Pallas call's `name=`)."""
+    held = set()
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " = " in line:
+            instruction = line.split(" = ", 1)[0]
+            held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
+                                 "flash_bwd_dkv") if k in instruction}
+    return held
+
+
 def _flash_loss(q, k, v, mesh):
     out = flash_attention(q, k, v, causal=True, interpret=False, mesh=mesh)
     return out.astype(jnp.float32).sum()
@@ -77,8 +90,12 @@ def test_flash_fwd_bwd_one_chip(topology, batch, seq, heads, kv_heads):
                               sharding=one)
     grad = jax.grad(functools.partial(_flash_loss, mesh=None),
                     argnums=(0, 1, 2))
-    # forward + dq + dk/dv kernels
-    assert _compiled_text(grad, q, kv, kv).count("tpu_custom_call") >= 3
+    # forward + dq + dk/dv kernels, each under its own instruction name
+    # (what a trace's `XLA Ops` events are called)
+    text = _compiled_text(grad, q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= \
+        _kernel_names(text)
 
 
 def test_flash_fwd_bwd_sharded_2x2(topology):
@@ -122,6 +139,7 @@ def test_paged_decode_one_chip(topology, batch, page_size, pages_per_seq):
     text = _compiled_text(
         fn, *_decode_args(lambda _: one, batch, page_size, pages_per_seq))
     assert "tpu_custom_call" in text
+    assert "paged_decode" in _kernel_names(text)
 
 
 def test_paged_decode_sharded_tensor4(topology):
