@@ -491,17 +491,23 @@ class ActorSubmitter:
             # out immediately as a batch of one. Dependency gating stays in
             # FIFO order (sync-actor ordering contract): a task whose owned
             # args are pending flushes the batch ahead of it, then waits.
+            # A streaming call (num_returns == -1) goes out alone, in its
+            # place in the order: its reply is the end of its stream, and a
+            # shared frame replies only after *all* members finish, so every
+            # stream of a batch would end for its consumer when the longest
+            # one does.
             batch = []
             item: Any = first
             while True:
+                streaming = item[0].num_returns == -1
                 deps = self.worker.unresolved_owned_deps(item[0])
+                if batch and (deps or streaming):
+                    self._held = item
+                    break
                 if deps:
-                    if batch:
-                        self._held = item
-                        break
                     await self.worker.wait_owned_deps(deps)
                 batch.append(item)
-                if len(batch) >= self.MAX_BATCH:
+                if streaming or len(batch) >= self.MAX_BATCH:
                     break
                 try:
                     item = self.queue.get_nowait()

@@ -20,8 +20,9 @@ from __future__ import annotations
 import json
 import time
 import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from ray_tpu._private import flight_recorder as _fr
 from ray_tpu.llm._internal.server import GENERATE_TIMEOUT_S, LLMServer
 from ray_tpu.llm._internal.tokenizer import (
     ByteBPETokenizer,
@@ -43,17 +44,19 @@ class _IncrementalDecoder:
 
     def __init__(self, tok: ByteBPETokenizer):
         self._tok = tok
-        self._ids: List[int] = []
-        self._emitted = 0
+        self._ids: List[int] = []  # the tokens whose text is held back
 
     def push(self, token_id: int) -> str:
         self._ids.append(token_id)
         text = self._tok.decode(self._ids)
         if text.endswith("�"):
             return ""  # partial multi-byte char: wait for more tokens
-        delta = text[self._emitted:]
-        self._emitted = len(text)
-        return delta
+        # The text ends on a whole character, so what follows decodes on
+        # its own. Decoding the whole answer again at every token made a
+        # request's handler work grow with the square of its length, on
+        # threads that share the interpreter with the engine thread.
+        self._ids.clear()
+        return text
 
 
 class _StopMatcher:
@@ -295,71 +298,92 @@ class OpenAIServer:
         }
 
     # -- streaming (SSE) -------------------------------------------------
-    def _stream_deltas(self, gen_kwargs: Dict[str, Any],
-                       ids: List[int],
-                       stops: List[str]) -> Iterator[str]:
-        """Common SSE core: decoded text deltas with stop-string halting
-        (the generator is closed on a match, aborting the engine slot)."""
+    def _stream(self, gen_kwargs: Dict[str, Any], ids: List[int],
+                stops: List[str], frame: Callable[[str, Optional[str]], str],
+                opening: str = "") -> Iterator[Any]:
+        """Common SSE core: one frame per decoded text delta, with
+        stop-string halting (the generator is closed on a match, aborting
+        the engine slot). `frame(text, finish_reason)` builds one frame.
+
+        The frames of the tokens one engine step made travel as one `str`
+        item (the proxy writes it as one chunk), and the closing frames
+        ride with the last of them: the runtime carries an object per item,
+        so the item is the step, not the token. The body is the same
+        sequence of frames either way."""
+        yield {"__http__": {"content_type": "text/event-stream"}}
+        items = 1
+        if opening:
+            yield opening
+            items += 1
         dec = _IncrementalDecoder(self.tokenizer)
         matcher = _StopMatcher(stops)
         gen = self.server.generate(ids, **gen_kwargs)
-        stopped = False
+        frames: List[str] = []
+        rid, tokens, delivered_s, stopped = "", 0, None, False
         try:
             for item in gen:
+                tokens += 1
+                rid = item.get("rid", rid)
+                delivered_s = item.get("delivered_s")
                 delta = dec.push(item["token"])
                 if stops:
                     delta, stopped = matcher.push(delta)
                 if delta:
-                    yield delta
-                if stopped:
-                    return
+                    frames.append(frame(delta, None))
+                if stopped or delivered_s is not None:
+                    break
+                if frames and not item.get("more"):
+                    yield "".join(frames)
+                    items += 1
+                    frames = []
         finally:
             gen.close()
-        if stops:
+        if stops and not stopped:
             tail = matcher.flush()
             if tail:
-                yield tail
+                frames.append(frame(tail, None))
+        frames += [frame("", "stop"), "data: [DONE]\n\n"]
+        yield "".join(frames)
+        items += 1
+        # Asked for more: the runtime has taken the last item.
+        done = {"rid": rid, "tokens": tokens, "items": items,
+                "tokens_per_item": tokens / items}
+        if delivered_s is not None:  # the engine ended it, not a stop string
+            done["lag_ms"] = (time.perf_counter() - delivered_s) * 1e3
+        _fr.mark("ray_tpu.request.stream_done", **done)
 
     def _completions_stream(self, gen_kwargs: Dict[str, Any],
                             ids: List[int],
                             stops: List[str]) -> Iterator[Any]:
         rid = f"cmpl-{uuid.uuid4().hex[:24]}"
-        yield {"__http__": {"content_type": "text/event-stream"}}
-        for delta in self._stream_deltas(gen_kwargs, ids, stops):
-            yield _sse({
+
+        def frame(text: str, finish_reason: Optional[str]) -> str:
+            return _sse({
                 "id": rid, "object": "text_completion",
                 "created": int(time.time()), "model": self.model_id,
-                "choices": [{"index": 0, "text": delta,
-                             "finish_reason": None}]})
-        yield _sse({
-            "id": rid, "object": "text_completion",
-            "created": int(time.time()), "model": self.model_id,
-            "choices": [{"index": 0, "text": "", "finish_reason": "stop"}]})
-        yield "data: [DONE]\n\n"
+                "choices": [{"index": 0, "text": text,
+                             "finish_reason": finish_reason}]})
+
+        return self._stream(gen_kwargs, ids, stops, frame)
 
     def _chat_stream(self, gen_kwargs: Dict[str, Any],
                      ids: List[int],
                      stops: List[str]) -> Iterator[Any]:
         rid = f"chatcmpl-{uuid.uuid4().hex[:24]}"
-        yield {"__http__": {"content_type": "text/event-stream"}}
-        yield _sse({
-            "id": rid, "object": "chat.completion.chunk",
-            "created": int(time.time()), "model": self.model_id,
-            "choices": [{"index": 0,
-                         "delta": {"role": "assistant", "content": ""},
-                         "finish_reason": None}]})
-        for delta in self._stream_deltas(gen_kwargs, ids, stops):
-            yield _sse({
+
+        def chunk(delta: Dict[str, Any],
+                  finish_reason: Optional[str] = None) -> str:
+            return _sse({
                 "id": rid, "object": "chat.completion.chunk",
                 "created": int(time.time()), "model": self.model_id,
-                "choices": [{"index": 0, "delta": {"content": delta},
-                             "finish_reason": None}]})
-        yield _sse({
-            "id": rid, "object": "chat.completion.chunk",
-            "created": int(time.time()), "model": self.model_id,
-            "choices": [{"index": 0, "delta": {},
-                         "finish_reason": "stop"}]})
-        yield "data: [DONE]\n\n"
+                "choices": [{"index": 0, "delta": delta,
+                             "finish_reason": finish_reason}]})
+
+        return self._stream(
+            gen_kwargs, ids, stops,
+            lambda text, finish_reason: chunk(
+                {} if finish_reason else {"content": text}, finish_reason),
+            opening=chunk({"role": "assistant", "content": ""}))
 
     # -- misc ------------------------------------------------------------
     def stats(self) -> Dict[str, Any]:

@@ -3,11 +3,12 @@
 Reference: llm/_internal/serve/deployments/llm/llm_server.py + vllm_engine.py
 (there the engine is vLLM's; here it's ray_tpu.llm._internal.engine). The
 engine runs on a dedicated thread; request handlers enqueue work and stream
-tokens back through per-request queues (serve streams them as generator
-items)."""
+tokens back through per-request queues, one item per engine step: a decode
+window's tokens for a request cross to its handler together."""
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import queue
 import threading
@@ -16,7 +17,12 @@ import uuid
 from typing import Any, Dict, Iterator, List, Optional
 
 from ray_tpu._private import flight_recorder as _fr
-from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request
+from ray_tpu.llm._internal.engine import (
+    EngineConfig,
+    LLMEngine,
+    Request,
+    StepOutput,
+)
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -73,6 +79,25 @@ def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
         params = jax.jit(init, out_shardings=shardings)(
             jax.random.PRNGKey(seed))
     return model, params
+
+
+@dataclasses.dataclass
+class Burst:
+    """What one engine step returned for one request: the one item it is on
+    the request's queue, however many tokens the step made."""
+    outputs: List[StepOutput]
+    # time.perf_counter() when the engine loop queued it.
+    delivered_s: float
+
+    # benchmark/replica.py's warm-up waits on these queues itself and reads
+    # `.token` and `.finished` off every item, as off a StepOutput.
+    @property
+    def finished(self) -> bool:
+        return self.outputs[-1].finished
+
+    @property
+    def token(self) -> int:
+        return self.outputs[-1].token
 
 
 class LLMServer:
@@ -138,11 +163,15 @@ class LLMServer:
                 continue
             self._tokens_out += len(outputs)
             with _fr.span("ray_tpu.server.deliver", outputs=len(outputs)):
+                by_request: Dict[str, List[StepOutput]] = {}
                 for so in outputs:
-                    with self._lock:
-                        q = self._queues.get(so.request_id)
-                    if q is not None:
-                        q.put(("token", so))
+                    by_request.setdefault(so.request_id, []).append(so)
+                now = time.perf_counter()
+                with self._lock:
+                    for rid, outs in by_request.items():
+                        q = self._queues.get(rid)
+                        if q is not None:
+                            q.put(("burst", Burst(outs, now)))
 
     # ------------------------------------------------------------------
     def generate(self, prompt_ids: List[int], max_tokens: int = 64,
@@ -155,7 +184,14 @@ class LLMServer:
         loaded adapter (reference: the model-id multiplex surface of
         ray.llm's LoRA deployments). Closing the generator early (stop
         string matched, client gone) aborts the request in the engine so
-        its slot stops burning decode steps."""
+        its slot stops burning decode steps.
+
+        An engine step's tokens arrive together, and `more` on each dict
+        says how many of them are still behind it: a consumer that writes
+        somewhere sends what it has when `more` is 0. The first dict
+        carries `ttft_s` and the engine's id of the request (`rid`), the
+        last one `delivered_s`, the time.perf_counter() at which the engine
+        loop handed over the step that finished the request."""
         rid = uuid.uuid4().hex[:12]
         q: "queue.Queue" = queue.Queue()
         with self._lock:
@@ -172,21 +208,26 @@ class LLMServer:
         finished = False
         try:
             while True:
-                item = q.get(timeout=GENERATE_TIMEOUT_S)
-                if item[0] == "error":
-                    raise RuntimeError(f"engine failed: {item[1]}")
-                _, so = item
-                out = {"token": int(so.token)}
-                if so.logprob is not None:
-                    out["logprob"] = so.logprob
-                    out["top_logprobs"] = so.top_logprobs
-                if first:
-                    out["ttft_s"] = time.perf_counter() - t0
-                    first = False
-                finished = so.finished
-                yield out
-                if finished:
-                    return
+                kind, burst = q.get(timeout=GENERATE_TIMEOUT_S)
+                if kind == "error":
+                    raise RuntimeError(f"engine failed: {burst}")
+                more = len(burst.outputs)
+                for so in burst.outputs:
+                    more -= 1
+                    out = {"token": int(so.token), "more": more}
+                    if so.logprob is not None:
+                        out["logprob"] = so.logprob
+                        out["top_logprobs"] = so.top_logprobs
+                    if first:
+                        out["ttft_s"] = time.perf_counter() - t0
+                        out["rid"] = rid
+                        first = False
+                    finished = so.finished
+                    if finished:
+                        out["delivered_s"] = burst.delivered_s
+                    yield out
+                    if finished:
+                        return
         finally:
             if not finished:
                 self._aborts.put(rid)

@@ -76,3 +76,33 @@ def test_generator_error_mid_stream(ray_start_regular):
         ray_tpu.get(next(it))
     with pytest.raises(StopIteration):
         next(it)
+
+
+def test_actor_streams_submitted_together_end_apart(ray_start_regular):
+    """Streaming calls submitted in one breath go out one frame each: a
+    stream ends for its consumer when its generator does, not when the
+    longest stream submitted beside it does (a shared frame replies only
+    once every member has finished)."""
+    @ray_tpu.remote(max_concurrency=8)
+    class Gen:
+        def stream(self, n, linger_s):
+            for i in range(n):
+                yield i
+            time.sleep(linger_s)
+
+    a = Gen.remote()
+    assert ray_tpu.get(next(iter(
+        a.stream.options(num_returns="dynamic").remote(1, 0.0)))) == 0
+    linger = [0.0, 4.0, 0.0, 4.0, 0.0, 4.0, 0.0, 4.0]
+    t0 = time.time()
+    gens = [a.stream.options(num_returns="dynamic").remote(3, s)
+            for s in linger]
+    for g, s in zip(gens, linger):
+        if not s:
+            assert [ray_tpu.get(r) for r in g] == [0, 1, 2]
+    # every short stream has ended, none waited for a lingering neighbour
+    assert time.time() - t0 < 3.0
+    for g, s in zip(gens, linger):
+        if s:
+            assert [ray_tpu.get(r) for r in g] == [0, 1, 2]
+    assert time.time() - t0 >= 4.0
