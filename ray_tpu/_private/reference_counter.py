@@ -31,7 +31,10 @@ class _Record:
 class ReferenceCounter:
     def __init__(self, on_zero: Optional[Callable[[ObjectID], None]] = None):
         self._records: Dict[ObjectID, _Record] = {}
-        self._lock = threading.Lock()
+        # Re-entrant: allocating a `_Record` under the lock can start a
+        # garbage collection that frees an ObjectRef, whose `__del__` comes
+        # back through `remove_local_ref` on this thread.
+        self._lock = threading.RLock()
         self._on_zero = on_zero
         # Ordered add/remove borrow reports per remote owner. Order matters:
         # a remove followed by a re-borrow's add must reach the owner in that

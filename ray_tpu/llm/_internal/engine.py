@@ -35,7 +35,6 @@ from ray_tpu.llm._internal.paged import (
     PageAllocator,
     PagedCacheConfig,
     PrefixCache,
-    init_paged_cache,
 )
 from ray_tpu.util import metrics as _um
 from ray_tpu.utils.logging import get_logger
@@ -147,11 +146,18 @@ class LLMEngine:
     """add_request() + step() — the scheduler half of continuous batching.
 
     Tensor parallel: pass `mesh` (any jax.sharding.Mesh with a "tensor"
-    axis). Params shard per LLAMA_SHARDING (heads/mlp/vocab over tensor),
-    the paged KV cache shards over its kv-head axis, and the jitted
+    axis). Params shard per the family's rules (heads/mlp/vocab over
+    tensor), the paged KV cache shards over its kv-head axis, and the jitted
     prefill/decode steps run SPMD — XLA inserts the all-reduces over ICI
     (reference passes tensor_parallel_size into vLLM,
     serve/deployments/llm/vllm/vllm_models.py:125; here TP is native).
+
+    The cache is what the model says each layer holds
+    (`model.init_cache`): K/V pages per token, or for the layers in
+    `model.state_layer_ids` a fixed state per slot, which prefill overwrites
+    from zero and decode updates in place. A model with state layers runs
+    without LoRA banks, `param_transform` or prefix sharing (each is built
+    for K/V layers only) and, having no sharding rules, without a mesh.
     """
 
     def __init__(self, model, params, cfg: EngineConfig, mesh=None,
@@ -164,7 +170,18 @@ class LLMEngine:
         # reconstructs compute-dtype weights where XLA fuses the converts
         # into the consuming matmuls.
         self.param_transform = param_transform
-        mcfg = model.cfg
+        self._state_layers = len(model.state_layer_ids)
+        if self._state_layers:
+            missing = [what for what, asked in (
+                ("lora_rank > 0: LoRA banks are built for attention "
+                 "projections of every layer", cfg.lora_rank > 0),
+                ("param_transform: not checked against state layers' "
+                 "float32 recurrences", param_transform is not None))
+                if asked]
+            if missing:
+                raise NotImplementedError(
+                    f"{type(model).__name__} has state layers and cannot "
+                    f"run with {'; '.join(missing)}")
         self.cache_cfg = PagedCacheConfig(
             num_pages=cfg.resolved_num_pages() + 1,  # +1: OOB drop page
             page_size=cfg.page_size, max_seqs=cfg.max_seqs,
@@ -172,21 +189,27 @@ class LLMEngine:
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            from ray_tpu.models.llama import LLAMA_SHARDING
+            from ray_tpu.models import sharding_rules
             from ray_tpu.parallel.sharding import shard_tree
+
+            rules = sharding_rules(model)
+            if rules is None:
+                raise NotImplementedError(
+                    f"{type(model).__name__} has no parameter sharding "
+                    "rules: it cannot run under a mesh")
 
             # The attention kernels need the mesh to run under shard_map.
             self.model = model = model.clone(mesh=mesh)
             # No-op for parameters that were initialized into these
             # shardings (LLMServer); places a host or one-device tree.
-            params = shard_tree(
-                params, LLAMA_SHARDING.tree_shardings(mesh, params))
+            params = shard_tree(params, rules.tree_shardings(mesh, params))
             self._replicated = NamedSharding(mesh, PartitionSpec())
-        caches = init_paged_cache(
-            self.cache_cfg, mcfg.num_layers, mcfg.num_kv_heads,
-            mcfg.head_dim, mcfg.dtype, mesh=mesh)
         self.params = params
-        self.caches = caches
+        # Per layer what the model keeps between steps; donated argument 1
+        # of both programs.
+        self.caches = model.init_cache(self.cache_cfg, mesh)
+        self.cache_report = self._describe_cache()
+        _fr.mark("ray_tpu.engine.cache_built", **self.cache_report)
         self.allocator = PageAllocator(self.cache_cfg)
         # reserve nothing: allocator hands out real pages; the scatter's
         # drop-page is index num_pages (out of bounds by construction).
@@ -216,6 +239,12 @@ class LLMEngine:
         self._free_slots = list(range(cfg.max_seqs))
         self.prefix_cache = (PrefixCache(self.allocator)
                              if cfg.enable_prefix_cache else None)
+        if self._state_layers and self.prefix_cache is not None:
+            # A shared page carries K/V and no state: a sharer's state
+            # layers would start from zero in the middle of its prompt.
+            logger.info("%s has %d state layers: prefix sharing is off",
+                        type(model).__name__, self._state_layers)
+            self.prefix_cache = None
         # LoRA banks (slot 0 = zero adapter = base model).
         self.lora_banks: Optional[Dict[str, Any]] = None
         self._lora_slots: Dict[str, int] = {}
@@ -245,6 +274,19 @@ class LLMEngine:
             "ray_tpu_llm_programs_built_total",
             "Prefill/decode programs built or retraced by the engine",
             tag_keys=("kind",))
+
+    def _describe_cache(self) -> Dict[str, int]:
+        """Layers and bytes of the cache by kind, from shapes alone."""
+        state = set(self.model.state_layer_ids)
+        size = lambda layer: sum(
+            int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+            for x in jax.tree.leaves(layer))
+        return {
+            "kv_layers": len(self.caches) - len(state),
+            "state_layers": len(state),
+            "kv_bytes": sum(size(c) for i, c in enumerate(self.caches)
+                            if i not in state),
+            "state_bytes": sum(size(self.caches[i]) for i in state)}
 
     # ------------------------------------------------------------------
     # LoRA multiplexing
@@ -458,7 +500,7 @@ class LLMEngine:
                 {"params": params}, ids, positions=positions,
                 paged_kv=caches, page_table=rows,
                 write_mask=mask, seq_lens=starts + true_lens,
-                lora=lora, lora_idx=lora_idx)
+                lora=lora, lora_idx=lora_idx, slots=slots)
             last = logits[jnp.arange(nb), true_lens - 1].astype(
                 jnp.float32)  # [nb, V]
             keys = all_keys[slots]
@@ -653,7 +695,8 @@ class LLMEngine:
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
                       steps=max(1, self.cfg.decode_steps),
                       chained=last is not None,
-                      new_program=key not in self._decode_fns):
+                      new_program=key not in self._decode_fns,
+                      state_rows=len(self.running) * self._state_layers):
             toks, last, lens, self.caches, self._keys_dev, lp = \
                 self._run_program("decode", key, self._decode_fn(*key),
                                   self._decode_args(last, lens))
@@ -874,7 +917,8 @@ class LLMEngine:
                           nb=nb, tokens=sum(w[4] for w in wave),
                           cached_tokens=sum(w[3] for w in wave), rich=rich,
                           want_lp=want_lp,
-                          new_program=key not in self._prefill_fns):
+                          new_program=key not in self._prefill_fns,
+                          state_rows=nb * self._state_layers):
                 dev_toks, lp = self._prefill_wave(key, wave)
             for i, (slot, req, _, cached_len, _) in enumerate(wave):
                 pending.append((slot, req, dev_toks, lp, i, nb, cached_len))

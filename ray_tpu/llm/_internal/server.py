@@ -36,23 +36,40 @@ GENERATE_TIMEOUT_S = 600.0
 
 def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
     """Resolve an llm_config dict to (model, params). Shared by the serve
-    path (LLMServer) and the batch path (_internal/batch.py). With a mesh,
+    path (LLMServer) and the batch path (_internal/batch.py).
+    `llm_config["family"]` picks the model family from `ray_tpu.models`
+    (default "llama"; a name it does not have raises). With a mesh,
     seeded parameters are initialized straight into their tensor-parallel
     shardings, so no device ever holds the whole tree."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import LLAMA_SHARDING, LlamaConfig, LlamaModel
+    from ray_tpu import models
 
+    name = llm_config.get("family", "llama")
+    if name not in models.FAMILIES and llm_config.get("bench_root"):
+        # The benchmark's harness names families that are files of its own
+        # (`<bench_root>/benchmark/families/<name>.py`); one the program
+        # lacks is the Llama block there. From anyone else it is a typo.
+        logger.warning("llm_config family %r is none of %s: building llama",
+                       name, sorted(models.FAMILIES))
+        name = "llama"
+    fam = models.family(name)
+    config_cls = fam.load("config")
     model_cfg = llm_config.get("model_config") or {}
     preset = llm_config.get("model", "tiny")
     if preset == "tiny":
-        cfg = LlamaConfig.tiny(**model_cfg)
+        cfg = config_cls.tiny(**model_cfg)
     elif preset == "llama3-8b":
-        cfg = LlamaConfig.llama3_8b()
+        cfg = config_cls.llama3_8b()
     else:
-        cfg = LlamaConfig(**model_cfg)
-    model = LlamaModel(cfg, mesh=mesh)
+        cfg = config_cls(**model_cfg)
+    if mesh is not None and fam.sharding is None:
+        raise NotImplementedError(
+            f"model family {name!r} has no parameter sharding rules: it "
+            "runs on one device, without a mesh")
+    model = fam.load("model")(cfg, **({} if mesh is None else {"mesh": mesh}))
+    sharding = None if mesh is None else fam.load("sharding")
     params_path = llm_config.get("params_path")
     if params_path:
         import pickle
@@ -65,14 +82,18 @@ def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
             params = jax.tree.map(jnp.asarray, params)
     else:
         seed = int(llm_config.get("seed", 0))
+        if sharding is None and hasattr(model, "init_params"):
+            # The model's own seeded initializer (one small program per
+            # kind of layer: see models/olmo_hybrid.py).
+            return model, model.init_params(jax.random.PRNGKey(seed))
         sample = jnp.zeros((1, 8), jnp.int32)
 
         def init(rng):
             return model.init(rng, sample)["params"]
 
         shardings = None
-        if mesh is not None:
-            shardings = LLAMA_SHARDING.tree_shardings(
+        if sharding is not None:
+            shardings = sharding.tree_shardings(
                 mesh, jax.eval_shape(init, jax.random.PRNGKey(seed)))
         # One compiled program: eager init would hold each initializer's
         # temporaries next to the tree it is building.
@@ -299,6 +320,9 @@ class LLMServer:
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
             "param_bytes_per_device": bytes_per_device(self.params),
             "kv_bytes_per_device": bytes_per_device(self.engine.caches),
+            # What the cache holds by kind of layer: K/V pages per token,
+            # or a fixed state per slot.
+            "cache": dict(self.engine.cache_report),
         }
 
     def self_check(self, prompt_ids: List[int], steps: int = 2
@@ -307,8 +331,9 @@ class LLMServer:
 
         Generates `steps` greedy tokens for `prompt_ids` through the engine
         (paged prefill, then the decode program) asking for logprobs, and
-        recomputes the same positions with one dense `model.apply` under
-        attention_impl="reference" on the same parameters. Returns the
+        recomputes the same positions with one dense `model.apply` without
+        a cache on the same parameters (with attention_impl="reference"
+        where the model's config has such a choice). Returns the
         largest logprob gap over the engine's reported top tokens, whether
         each engine token is the reference's argmax, and whether the
         lowered decode program holds the Mosaic paged-attention kernel."""
@@ -321,8 +346,11 @@ class LLMServer:
         top = self.engine.cfg.max_logprobs
         got = self.generate_all(prompt_ids, max_tokens=steps, logprobs=top)
         tokens = got["tokens"]
+        plain = {"attention_impl": "reference", "remat": False}
+        fields = {f.name for f in dataclasses.fields(self.model.cfg)}
         ref_model = type(self.model)(dataclasses.replace(
-            self.model.cfg, attention_impl="reference", remat=False))
+            self.model.cfg,
+            **{k: v for k, v in plain.items() if k in fields}))
         ids = jnp.asarray([list(prompt_ids) + tokens[:-1]], jnp.int32)
         logits = jax.jit(ref_model.apply)({"params": self.params}, ids)
         ref = np.asarray(jax.nn.log_softmax(

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -274,15 +274,28 @@ class LlamaModel(nn.Module):
     cfg: LlamaConfig
     mesh: Optional[Mesh] = None
 
+    # Layers whose serving cache is a state per slot: none, all hold K/V.
+    state_layer_ids: ClassVar[Tuple[int, ...]] = ()
+
+    def init_cache(self, cache_cfg, mesh=None):
+        """The serving engine's cache, per layer: (k_pages, v_pages)."""
+        from ray_tpu.llm._internal.paged import init_paged_cache
+
+        cfg = self.cfg
+        return init_paged_cache(cache_cfg, cfg.num_layers, cfg.num_kv_heads,
+                                cfg.head_dim, cfg.dtype, mesh=mesh)
+
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  cache_index=None, paged_kv=None, page_table=None,
                  write_mask=None, seq_lens=None, lora=None,
-                 lora_idx=None):
+                 lora_idx=None, slots=None):
         """lora: {"layers_<i>": {proj: {"a": [K,r,Din], "b": [K,Dout,r],
         "scale": s}}} adapter BANKS (runtime jit args, not flax params —
         adapter loads update values without recompiling); lora_idx [B]
-        picks each sequence's adapter, slot 0 = none."""
+        picks each sequence's adapter, slot 0 = none. `slots` (the engine
+        slots a prefill fills) is for models with a state per slot: K/V
+        pages are addressed through `page_table` alone."""
         cfg = self.cfg
         if positions is None:
             start = cache_index if (kv_caches is not None

@@ -1,0 +1,389 @@
+"""Olmo-Hybrid: a decoder whose layers are of two kinds (`layer_types`):
+gated-delta linear attention (arXiv:2412.06464), which remembers a sequence in
+a fixed state per head, and full softmax attention on every fourth layer.
+
+Follows huggingface.co/allenai/Olmo-Hybrid-7B's config.json; parameter names
+are HF's. What that config does not state follows the family's conventions and
+is listed under `assumed` in benchmark/configs/olmo-hybrid-7b-serve.json:
+post-norm blocks and a q/k RMSNorm (Olmo 2/3), no rotary embedding
+(`rope_theta` null), no convolution bias, GatedDeltaNet's ranges for `A_log`
+and `dt_bias`.
+
+Serving cache, per layer (`init_cache`): a full layer holds paged K/V like
+`LlamaModel`; a linear layer holds, per engine slot, the last three inputs of
+its convolutions and the float32 state [heads, key_dim, value_dim]. A state
+row is the slot's index: nothing is allocated, prefill overwrites the rows it
+is given from zero, decode updates every active row in place.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import RMSNorm
+from ray_tpu.ops.linear_attention import gdn_chunked, gdn_decode
+
+LINEAR, FULL = "linear_attention", "full_attention"
+PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoHybridConfig:
+    vocab_size: int = 100_352
+    hidden_size: int = 3840
+    intermediate_size: int = 11_008
+    layer_types: Tuple[str, ...] = PERIOD * 8
+    num_heads: int = 30
+    num_kv_heads: int = 30
+    head_dim: int = 128
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    max_seq_len: int = 65_536
+    rms_norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - {LINEAR, FULL}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)}")
+        if self.num_kv_heads != self.num_heads:
+            raise ValueError("full layers have one KV head per query head")
+        if self.linear_num_key_heads != self.linear_num_value_heads:
+            raise ValueError("linear layers have one key head per value head")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def conv_channels(self) -> int:
+        h = self.linear_num_key_heads
+        return h * (2 * self.linear_key_head_dim + self.linear_value_head_dim)
+
+    @staticmethod
+    def tiny(vocab_size: int = 512) -> "OlmoHybridConfig":
+        """Test-sized: two periods, float32, runs on the CPU in seconds."""
+        return OlmoHybridConfig(
+            vocab_size=vocab_size, hidden_size=128, intermediate_size=256,
+            layer_types=PERIOD * 2, num_heads=4, num_kv_heads=4, head_dim=32,
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=24, linear_value_head_dim=48,
+            max_seq_len=512, dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def _a_log_init(key, shape, dtype):
+    """GatedDeltaNet: A uniform in (0, 16], held as its logarithm."""
+    a = jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0)
+    return jnp.log(a).astype(dtype)
+
+
+def _dt_bias_init(key, shape, dtype):
+    """GatedDeltaNet: dt log-uniform in [1e-3, 0.1], held through the
+    inverse of softplus."""
+    u = jax.random.uniform(key, shape, jnp.float32)
+    dt = jnp.exp(u * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    dt = jnp.maximum(dt, 1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _conv_init(key, shape, dtype):
+    """torch's Conv1d default for a depthwise kernel of width 4."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, jnp.float32, -bound,
+                              bound).astype(dtype)
+
+
+# Elements one compiled random generator fills at once: the TPU compiler's
+# time for a generator grows with its array (the embedding whole: 11 s; in
+# blocks of this size under one small loop: 1 s; compile, PR 29).
+_INIT_BLOCK = 1 << 22
+
+
+def _in_blocks(key, shape, draw):
+    """A 2-D array of `shape` from `draw(key, block_shape)`, block of rows by
+    block of rows under one small loop."""
+    rows, cols = shape
+    fit = max(1, _INIT_BLOCK // cols)
+    n = min((d for d in range(1, rows + 1)
+             if rows % d == 0 and rows // d <= fit), default=rows)
+    return jax.lax.map(lambda k: draw(k, (rows // n, cols)),
+                       jax.random.split(key, n)).reshape(shape)
+
+
+def _kernel_init(key, shape, dtype):
+    """A projection [fan_in, features]: flax's Dense default (lecun normal:
+    truncated at two standard deviations, variance 1 / fan_in), drawn in
+    float32 and rounded to `dtype` as a checkpoint's weights are, in blocks.
+    Not drawn in bf16 itself: that draw's uniform has seven bits and a mean
+    of 127/256, so every matrix gets a mean of -0.018 standard deviations,
+    and 16 layers deep nine tenths of the residual stream is one constant
+    vector whatever the prompt (PERF.md section 6, PR 29)."""
+    std = shape[0] ** -0.5 / 0.87962566103423978
+    return _in_blocks(key, shape, lambda k, block: (
+        std * jax.random.truncated_normal(k, -2.0, 2.0, block, jnp.float32)
+    ).astype(dtype))
+
+
+def _embed_init(key, shape, dtype):
+    """The embedding [vocabulary, hidden]: normal, rows of unit expected
+    norm."""
+    std = shape[1] ** -0.5
+    return _in_blocks(key, shape, lambda k, block: (
+        std * jax.random.normal(k, block, jnp.float32)).astype(dtype))
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _dense(cfg: OlmoHybridConfig, features: int,
+           name: Optional[str]) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, kernel_init=_kernel_init,
+                    name=name)
+
+
+def _norm(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Module:
+    return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+
+
+def _embed(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Embed:
+    return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, embedding_init=_embed_init,
+                    name=name)
+
+
+class GatedDeltaNet(nn.Module):
+    """The linear-attention mixer. `state` is None (no cache: the chunkwise
+    form over the whole sequence) or the layer's (conv_tail, S) pool, with
+    `rows` = the pool rows a prefill overwrites, or None for decode (one token
+    for every row of the pool)."""
+    cfg: OlmoHybridConfig
+
+    @nn.compact
+    def __call__(self, x, mask=None, state=None, rows=None):
+        cfg = self.cfg
+        b, s, _ = x.shape
+        h, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                     cfg.linear_value_head_dim)
+        width = cfg.linear_conv_kernel_dim
+        if mask is None:
+            mask = jnp.ones((b, s), bool)
+        proj = jnp.concatenate(
+            [_dense(cfg, h * dk, "q_proj")(x), _dense(cfg, h * dk, "k_proj")(x),
+             _dense(cfg, h * dv, "v_proj")(x)], axis=-1)  # [B,S,C]
+        taps = jnp.concatenate(
+            [self.param(f"conv_{n}", _conv_init, (width, c), cfg.param_dtype)
+             for n, c in (("q", h * dk), ("k", h * dk), ("v", h * dv))],
+            axis=-1).astype(jnp.float32)  # [width, C]
+        decode = state is not None and rows is None
+        if decode:
+            window = jnp.concatenate([state[0], proj], axis=1)  # [B,width,C]
+            tail = jnp.where(mask[:, :, None], window[:, 1:], state[0])
+        else:
+            window = jnp.pad(proj, [(0, 0), (width - 1, 0), (0, 0)])
+            # The last width-1 inputs before position true_len.
+            true_len = jnp.sum(mask, axis=-1)
+            tail = jnp.take_along_axis(
+                window, (true_len[:, None] + jnp.arange(width - 1))[..., None],
+                axis=1)
+        conv = sum(window[:, j:j + s].astype(jnp.float32) * taps[j]
+                   for j in range(width))
+        conv = jax.nn.silu(conv)
+        q, k, v = jnp.split(conv, [h * dk, 2 * h * dk], axis=-1)
+        q = _l2norm(q.reshape(b, s, h, dk)) * dk ** -0.5
+        k = _l2norm(k.reshape(b, s, h, dk))
+        v = v.reshape(b, s, h, dv)
+        a_log = self.param("A_log", _a_log_init, (h,), cfg.param_dtype)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h,), cfg.param_dtype)
+        f32 = lambda t: t.astype(jnp.float32)
+        g = -jnp.exp(f32(a_log)) * jax.nn.softplus(
+            f32(_dense(cfg, h, "a_proj")(x)) + f32(dt_bias))
+        beta = jax.nn.sigmoid(f32(_dense(cfg, h, "b_proj")(x)))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+        if decode:
+            o, new_s = gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0], state[1], mask[:, 0])
+            o = o[:, None]
+        else:
+            # Padding changes nothing: no decay, nothing written.
+            keep = mask[:, :, None]
+            o, new_s = gdn_chunked(q, k, v, jnp.where(keep, g, 0.0),
+                                   jnp.where(keep, beta, 0.0))
+        new_state = None
+        if decode:
+            new_state = (tail, new_s)
+        elif state is not None:
+            new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
+                         state[1].at[rows].set(new_s))
+        o = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="o_norm")(o)
+        gate = _dense(cfg, h * dv, "g_proj")(x).reshape(b, s, h, dv)
+        y = (o * jax.nn.silu(f32(gate))).astype(cfg.dtype)
+        return _dense(cfg, cfg.hidden_size, "o_proj")(
+            y.reshape(b, s, h * dv)), new_state
+
+
+class FullAttention(nn.Module):
+    """Causal softmax attention, one KV head per query head, RMSNorm on the
+    projected q and k, no rotary embedding."""
+    cfg: OlmoHybridConfig
+
+    @nn.compact
+    def __call__(self, x, positions, paged=None):
+        from ray_tpu.llm._internal.paged import paged_attention, paged_write
+        from ray_tpu.ops.attention import attention_reference
+
+        cfg = self.cfg
+        b, s, _ = x.shape
+        h, d = cfg.num_heads, cfg.head_dim
+        q = _norm(cfg, "q_norm")(_dense(cfg, h * d, "q_proj")(x))
+        k = _norm(cfg, "k_norm")(_dense(cfg, h * d, "k_proj")(x))
+        v = _dense(cfg, h * d, "v_proj")(x)
+        q, k, v = (t.reshape(b, s, h, d) for t in (q, k, v))
+        new_kv = None
+        if paged is None:
+            out = attention_reference(q, k, v, causal=True)
+        else:
+            k_pages, v_pages = paged["kv_pages"]
+            k_pages = paged_write(k_pages, k, paged["page_table"], positions,
+                                  paged["write_mask"])
+            v_pages = paged_write(v_pages, v, paged["page_table"], positions,
+                                  paged["write_mask"])
+            out = paged_attention(q, k_pages, v_pages, paged["page_table"],
+                                  positions, paged["seq_lens"])
+            new_kv = (k_pages, v_pages)
+        return _dense(cfg, cfg.hidden_size, "o_proj")(
+            out.reshape(b, s, h * d)), new_kv
+
+
+class Mlp(nn.Module):
+    cfg: OlmoHybridConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate = _dense(cfg, cfg.intermediate_size, "gate_proj")(x)
+        up = _dense(cfg, cfg.intermediate_size, "up_proj")(x)
+        return _dense(cfg, cfg.hidden_size, "down_proj")(nn.silu(gate) * up)
+
+
+class HybridLayer(nn.Module):
+    """Olmo's post-norm block: each sub-layer's output is normalised before
+    it joins the residual stream."""
+    cfg: OlmoHybridConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, positions, mask, cache, paged, rows):
+        cfg = self.cfg
+        if self.kind == LINEAR:
+            mixed, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
+                x, mask, cache, rows)
+        else:
+            mixed, new_cache = FullAttention(cfg, name="self_attn")(
+                x, positions, paged)
+        x = x + _norm(cfg, "post_attention_layernorm")(mixed)
+        x = x + _norm(cfg, "post_feedforward_layernorm")(
+            Mlp(cfg, name="mlp")(x))
+        return x, new_cache
+
+
+class OlmoHybridModel(nn.Module):
+    cfg: OlmoHybridConfig
+
+    @property
+    def state_layer_ids(self) -> Tuple[int, ...]:
+        """Layers whose cache entry is a state per slot, not K/V pages."""
+        return tuple(i for i, kind in enumerate(self.cfg.layer_types)
+                     if kind == LINEAR)
+
+    def init_cache(self, cache_cfg, mesh=None):
+        """Per layer: (k_pages, v_pages) [HK, P, ps, D] on a full layer;
+        (conv_tail [max_seqs, 3, C], S [max_seqs, H, dk, dv] float32) on a
+        linear one, a row per engine slot."""
+        if mesh is not None:
+            raise NotImplementedError(
+                "OlmoHybridModel: state layers have no sharding under a "
+                "mesh (tensor parallelism is not built for this family)")
+        cfg = self.cfg
+        pages = (cfg.num_kv_heads, cache_cfg.num_pages, cache_cfg.page_size,
+                 cfg.head_dim)
+        tail = (cache_cfg.max_seqs, cfg.linear_conv_kernel_dim - 1,
+                cfg.conv_channels)
+        state = (cache_cfg.max_seqs, cfg.linear_num_key_heads,
+                 cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+        return [(jnp.zeros(tail, cfg.dtype), jnp.zeros(state, jnp.float32))
+                if kind == LINEAR else
+                (jnp.zeros(pages, cfg.dtype), jnp.zeros(pages, cfg.dtype))
+                for kind in cfg.layer_types]
+
+    @nn.nowrap
+    def init_params(self, rng):
+        """The tree `self.init(rng, ids)["params"]` holds, made layer by
+        layer: one compiled initializer per kind of layer, run once for each
+        layer of the kind. One program over all 16 layers took the TPU's
+        compiler 88 s on the chip's host (my chip run, PR 29), longer than an
+        actor's constructor may take."""
+        cfg = self.cfg
+        ids = jnp.zeros((1, 8), jnp.int32)
+        x = jnp.zeros((1, 8, cfg.hidden_size), cfg.dtype)
+
+        def of(module, *args):
+            return jax.jit(lambda key: module.init(key, *args)["params"])
+
+        layer = {kind: of(HybridLayer(cfg, kind), x, ids, None, None, None,
+                          None) for kind in set(cfg.layer_types)}
+        keys = jax.random.split(rng, cfg.num_layers + 3)
+        params = {f"layers_{i}": layer[kind](keys[i])
+                  for i, kind in enumerate(cfg.layer_types)}
+        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-3])
+        params["norm"] = of(_norm(cfg, None), x)(keys[-2])
+        params["lm_head"] = of(_dense(cfg, cfg.vocab_size, None), x)(keys[-1])
+        return params
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, paged_kv=None,
+                 page_table=None, write_mask=None, seq_lens=None, lora=None,
+                 lora_idx=None, slots=None):
+        """The engine's `apply` surface (`LlamaModel`'s). `paged_kv` is the
+        list `init_cache` made; `slots` [nb] are the pool rows a prefill
+        writes (state from zero), None when decoding one token for every row.
+        Without `paged_kv`: the whole sequence, no cache."""
+        cfg = self.cfg
+        if lora is not None:
+            raise NotImplementedError("OlmoHybridModel has no LoRA banks")
+        b, s = input_ids.shape
+        if positions is None:
+            positions = jnp.arange(s)
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None, :], (b, s))
+        x = _embed(cfg, "embed_tokens")(input_ids)
+        new_caches = []
+        for i, kind in enumerate(cfg.layer_types):
+            cache = paged = None
+            if paged_kv is not None and kind == LINEAR:
+                cache = paged_kv[i]
+            elif paged_kv is not None:
+                paged = {"kv_pages": paged_kv[i], "page_table": page_table,
+                         "write_mask": write_mask, "seq_lens": seq_lens}
+            x, new_cache = HybridLayer(cfg, kind, name=f"layers_{i}")(
+                x, positions, write_mask, cache, paged, slots)
+            new_caches.append(new_cache)
+        x = _norm(cfg, "norm")(x)
+        logits = _dense(cfg, cfg.vocab_size, "lm_head")(x)
+        if paged_kv is not None:
+            return logits, new_caches
+        return logits

@@ -1,0 +1,196 @@
+"""Gated delta rule (arXiv:2412.06464): the linear-attention layer whose
+memory is a fixed state per sequence and head, not keys and values per token.
+
+Per token t and head, with q, k in R^dk (k of unit norm), v in R^dv, a decay
+alpha = exp(g) in (0, 1] and a write strength beta, on a state S in R^{dk x dv}
+that is zero before the first token:
+
+    S <- alpha S;   u = beta (v - S^T k);   S <- S + k u^T;   o = S^T q
+
+Two forms of the same mathematics, both in float32 (the tests hold them to
+the recurrence above, token by token):
+
+- `gdn_chunked`: the chunkwise form (section 3 of the paper, WY
+  representation) for a whole padded bucket, in `jax.numpy`: within a chunk of
+  64 tokens the 64 updates become one unit-triangular solve and a few matmuls,
+  and only the chunk boundaries carry a state.
+- `gdn_decode`: one token a row against the state pool. On a TPU one Pallas
+  kernel (`name="gdn_decode"`) reads each state once and writes it once, in
+  place; elsewhere `jax.numpy`.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+CHUNK = 64
+_HIGHEST = jax.lax.Precision.HIGHEST
+# Largest state block (padded to the 128-lane tile) one kernel step holds; the
+# pipeline keeps four of them (in and out, double-buffered).
+_STATE_BLOCK_BYTES = 1 << 20
+
+
+def gdn_chunked(q, k, v, g, beta, chunk: int = CHUNK):
+    """The same from a zero state, chunk by chunk. A position that must change
+    nothing (padding) is given g = 0 and beta = 0 by the caller. q, k
+    [B,S,H,dk], v [B,S,H,dv], g, beta [B,S,H] -> (o [B,S,H,dv], state
+    [B,H,dk,dv]); any S (padded up to a multiple of `chunk` inside)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    n = -(-s // chunk)
+    pad = n * chunk - s
+
+    def chunks(x):  # [B,S,H,...] -> [N,B,H,C,...]
+        x = x.astype(jnp.float32)
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((b, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    gamma = jnp.cumsum(g, axis=-1)  # log decay since the chunk began
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # decay[t, s] = exp(gamma_t - gamma_s) for s <= t; masked before the
+    # exponential, which overflows above the diagonal.
+    decay = jnp.exp(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    kb = k * beta[..., None]
+    # (I + A) U = beta V - (beta K e^gamma) S0, A strictly lower triangular.
+    a = jnp.einsum("nbhtk,nbhsk->nbhts", kb, k, precision=_HIGHEST) * decay
+    a = jnp.where(jnp.tril(lower, -1), a, 0.0) + jnp.eye(chunk)
+    rhs = jnp.concatenate(
+        [v * beta[..., None], kb * jnp.exp(gamma)[..., None]], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(
+        a, rhs, lower=True, unit_diagonal=True)
+    u_own, k_carry = solved[..., :dv], solved[..., dv:]
+    qk = jnp.einsum("nbhtk,nbhsk->nbhts", q, k, precision=_HIGHEST) * decay
+    q_in = q * jnp.exp(gamma)[..., None]
+    # What each token leaves in the state at the chunk's end.
+    k_out = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]
+    end = jnp.exp(gamma[..., -1])[..., None, None]
+
+    def step(state, xs):
+        u_own, k_carry, qk, q_in, k_out, end = xs
+        u = u_own - jnp.einsum("bhtk,bhkv->bhtv", k_carry, state,
+                               precision=_HIGHEST)
+        o = (jnp.einsum("bhtk,bhkv->bhtv", q_in, state, precision=_HIGHEST)
+             + jnp.einsum("bhts,bhsv->bhtv", qk, u, precision=_HIGHEST))
+        state = state * end + jnp.einsum("bhtk,bhtv->bhkv", k_out, u,
+                                         precision=_HIGHEST)
+        return state, o
+
+    state, o = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), jnp.float32),
+        (u_own, k_carry, qk, q_in, k_out, end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)  # [B,N,C,H,dv]
+    return o.reshape(b, n * chunk, h, dv)[:, :s], state
+
+
+# ---------------------------------------------------------------------------
+# Decode: one token a row against the state pool
+# ---------------------------------------------------------------------------
+def _heads_per_block(h: int, dk: int, dv: int) -> int:
+    padded = dk * (-(-dv // 128) * 128) * 4
+    fit = max(1, _STATE_BLOCK_BYTES // padded)
+    return max(d for d in range(1, h + 1) if h % d == 0 and d <= fit)
+
+
+def _gdn_decode_kernel(active_ref, cols_ref, rows_ref, s_ref, o_ref, s_out,
+                       *, heads: int):
+    """Grid (rows, head blocks). cols [dk, 4*heads]: per head the columns k,
+    alpha*beta*k, q, alpha; rows [heads, dv]: beta*v. With them
+        u = beta v - S^T (alpha beta k);  S' = alpha S + k u^T;  o = S'^T q
+    is the recurrence above, each state read once and written once."""
+    row = pl.program_id(0)
+
+    @pl.when(active_ref[row] != 0)
+    def _update():
+        for j in range(heads):  # static: every slice is a constant
+            s = s_ref[0, j]  # [dk, dv]
+            k = cols_ref[0, 0, :, 4 * j:4 * j + 1]
+            kab = cols_ref[0, 0, :, 4 * j + 1:4 * j + 2]
+            q = cols_ref[0, 0, :, 4 * j + 2:4 * j + 3]
+            alpha = cols_ref[0, 0, :, 4 * j + 3:4 * j + 4]
+            u = rows_ref[0, 0, j:j + 1, :] - jnp.sum(
+                s * kab, axis=0, keepdims=True)  # [1, dv]
+            s = alpha * s + k * u
+            s_out[0, j] = s
+            o_ref[0, 0, j:j + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
+
+    @pl.when(active_ref[row] == 0)
+    def _keep():
+        s_out[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def gdn_decode_kernel(q, k, v, g, beta, state, active,
+                      interpret: Optional[bool] = None):
+    """q, k [B,H,dk], v [B,H,dv], g, beta [B,H] float32, state [B,H,dk,dv]
+    float32, active [B] bool -> (o [B,H,dv], state). The state is updated in
+    place (`input_output_aliases`); an inactive row's is left as it was."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    hb = _heads_per_block(h, dk, dv)
+    nb = h // hb
+    alpha = jnp.exp(g)
+    cols = jnp.stack(
+        [k, k * (alpha * beta)[..., None], q,
+         jnp.broadcast_to(alpha[..., None], k.shape)], axis=-1)  # [B,H,dk,4]
+    cols = cols.reshape(b, nb, hb, dk, 4).transpose(0, 1, 3, 2, 4).reshape(
+        b, nb, dk, 4 * hb)
+    rows = (v * beta[..., None]).reshape(b, nb, hb, dv)
+    o, state = pl.pallas_call(
+        functools.partial(_gdn_decode_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, nb),
+            in_specs=[
+                pl.BlockSpec((1, 1, dk, 4 * hb),
+                             lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, 1, hb, dv), lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, hb, dk, dv), lambda r, c, act: (r, c, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, hb, dv), lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, hb, dk, dv), lambda r, c, act: (r, c, 0, 0)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, nb, hb, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        # Operand 3 (after the prefetched `active`) is the state: same buffer
+        # in and out, so the pool is never copied.
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="gdn_decode",
+    )(active.astype(jnp.int32), cols, rows, state)
+    return o.reshape(b, h, dv), state
+
+
+def gdn_decode(q, k, v, g, beta, state, active,
+               use_kernel: Optional[bool] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row: shapes as `gdn_decode_kernel`. The Pallas kernel on a
+    TPU, `jax.numpy` elsewhere (as `paged_attention`'s `use_kernel`)."""
+    f32 = lambda x: x.astype(jnp.float32)
+    q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if use_kernel:
+        return gdn_decode_kernel(q, k, v, g, beta, state, active)
+    s = state * jnp.exp(g)[..., None, None]
+    u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s, k,
+                                          precision=_HIGHEST))
+    s = s + k[..., :, None] * u[..., None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HIGHEST)
+    return o, jnp.where(active[:, None, None, None], s, state)

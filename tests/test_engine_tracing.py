@@ -25,10 +25,11 @@ ENGINE_SPANS = {
                              "free_pages"},
     "ray_tpu.engine.prefill_dispatch": {"bucket", "nb", "tokens",
                                         "cached_tokens", "rich", "want_lp",
-                                        "new_program"},
+                                        "new_program", "state_rows"},
     "ray_tpu.engine.prefill_sync": {"requests"},
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
-                                       "chained", "new_program"},
+                                       "chained", "new_program",
+                                       "state_rows"},
     "ray_tpu.engine.wait_tokens": {"why"},
     "ray_tpu.engine.emit": {"tokens", "finished"},
 }

@@ -136,3 +136,31 @@ def test_paged_decode_kernel_matches_jnp():
                                         seq_lens, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_paged_decode_kernel_one_query_head_per_kv_head():
+    """No grouping (H == HK, a [1, D] query tile): the full-attention layers
+    of models/olmo_hybrid.py. Same kernel, same call as with a group."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm._internal.paged import (
+        paged_attention,
+        paged_attention_decode_kernel,
+    )
+
+    rng = np.random.default_rng(1)
+    B, H, D, PS, MP, P = 3, 4, 64, 8, 4, 16
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+    k_pages = jnp.asarray(rng.standard_normal((H, P, PS, D)), jnp.float32)
+    v_pages = jnp.asarray(rng.standard_normal((H, P, PS, D)), jnp.float32)
+    page_table = jnp.asarray(
+        rng.permutation(P - 1)[: B * MP].reshape(B, MP), jnp.int32)
+    seq_lens = jnp.asarray([1, 18, 32], jnp.int32)
+    ref = paged_attention(q, k_pages, v_pages, page_table,
+                          (seq_lens - 1)[:, None], seq_lens,
+                          use_kernel=False)
+    out = paged_attention_decode_kernel(q, k_pages, v_pages, page_table,
+                                        seq_lens, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
