@@ -49,9 +49,9 @@ SERVE = {
     "model_config": {"num_layers": 4, "max_seq_len": 2048},
     "engine_config": {"max_seqs": 8, "page_size": 64,
                       "max_pages_per_seq": 16},
-    "reduced": "depth 4 of 32: the program holds weights in float32 "
-               "(1.92B params, 7.16 GiB) and the decode program keeps a bf16 "
-               "copy beside them (10.93 GiB peak of 15.75)",
+    "reduced": "depth 4 of 32, sized when serving held float32 weights "
+               "(1.92B params, 7.16 GiB) beside the decode program's bf16 "
+               "copy (10.93 GiB peak of 15.75); it holds them in bf16 now",
     "prompt_len": 300,   # prefill bucket 512
     "max_tokens": 32,
     "concurrency": 4,    # per wave: half unary, half SSE

@@ -120,6 +120,11 @@ class StepOutput:
     top_logprobs: Optional[List[Tuple[int, float]]] = None
 
 
+def _leaf_bytes(x) -> int:
+    """Bytes of an array (or a tracer, or a shape) from its shape alone."""
+    return int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+
+
 def _signature(args) -> Dict[str, tuple]:
     """What a jitted function's cache tells calls apart by, per argument
     leaf: shape, dtype, weak type, sharding, committed to it or not."""
@@ -205,6 +210,11 @@ class LLMEngine:
             params = shard_tree(params, rules.tree_shardings(mesh, params))
             self._replicated = NamedSharding(mesh, PartitionSpec())
         self.params = params
+        # What the replica holds at rest, whole-tree bytes by dtype (the
+        # loader rounds to the compute dtype what the model would convert).
+        self.params_report = self._describe_params()
+        _fr.mark("ray_tpu.engine.params_placed",
+                 **{f"{k}_bytes": v for k, v in self.params_report.items()})
         # Per layer what the model keeps between steps; donated argument 1
         # of both programs.
         self.caches = model.init_cache(self.cache_cfg, mesh)
@@ -275,12 +285,18 @@ class LLMEngine:
             "Prefill/decode programs built or retraced by the engine",
             tag_keys=("kind",))
 
+    def _describe_params(self) -> Dict[str, int]:
+        """Bytes of the parameter tree by dtype, from shapes alone."""
+        held: Dict[str, int] = {}
+        for x in jax.tree.leaves(self.params):
+            name = np.dtype(x.dtype).name
+            held[name] = held.get(name, 0) + _leaf_bytes(x)
+        return held
+
     def _describe_cache(self) -> Dict[str, int]:
         """Layers and bytes of the cache by kind, from shapes alone."""
         state = set(self.model.state_layer_ids)
-        size = lambda layer: sum(
-            int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
-            for x in jax.tree.leaves(layer))
+        size = lambda layer: sum(map(_leaf_bytes, jax.tree.leaves(layer)))
         return {
             "kv_layers": len(self.caches) - len(state),
             "state_layers": len(state),

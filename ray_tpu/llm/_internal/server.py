@@ -38,9 +38,12 @@ def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
     """Resolve an llm_config dict to (model, params). Shared by the serve
     path (LLMServer) and the batch path (_internal/batch.py).
     `llm_config["family"]` picks the model family from `ray_tpu.models`
-    (default "llama"; a name it does not have raises). With a mesh,
-    seeded parameters are initialized straight into their tensor-parallel
-    shardings, so no device ever holds the whole tree."""
+    (default "llama"; a name it does not have raises). The tree is the
+    one serving computes with (`models.serving_params`): what the model
+    would convert to its compute dtype at every use is rounded to it here,
+    once. With a mesh, seeded parameters are initialized straight into
+    their tensor-parallel shardings, so no device ever holds the whole
+    tree."""
     import jax
     import jax.numpy as jnp
 
@@ -76,6 +79,8 @@ def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
 
         with open(params_path, "rb") as f:
             params = pickle.load(f)
+        # On the host: what crosses to the device is the rounded tree.
+        params = models.serving_params(model, params)
         if mesh is None:
             # Onto the device once, not with every step. With a mesh the
             # engine places each shard from the host instead.
@@ -85,11 +90,15 @@ def load_model_and_params(llm_config: Dict[str, Any], mesh=None):
         if sharding is None and hasattr(model, "init_params"):
             # The model's own seeded initializer (one small program per
             # kind of layer: see models/olmo_hybrid.py).
-            return model, model.init_params(jax.random.PRNGKey(seed))
+            return model, models.serving_params(
+                model, model.init_params(jax.random.PRNGKey(seed)))
         sample = jnp.zeros((1, 8), jnp.int32)
 
         def init(rng):
-            return model.init(rng, sample)["params"]
+            # Rounded inside the program that draws them: the float32 tree
+            # is never whole in HBM, and no second program runs.
+            return models.serving_params(
+                model, model.init(rng, sample)["params"])
 
         shardings = None
         if sharding is not None:
@@ -319,6 +328,8 @@ class LLMServer:
             "device_count": len(devices),
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
             "param_bytes_per_device": bytes_per_device(self.params),
+            # Of the whole tree, by the dtype it is held in.
+            "param_bytes_by_dtype": dict(self.engine.params_report),
             "kv_bytes_per_device": bytes_per_device(self.engine.caches),
             # What the cache holds by kind of layer: K/V pages per token,
             # or a fixed state per slot.
