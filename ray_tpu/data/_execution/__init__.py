@@ -24,9 +24,6 @@ Redesign notes (why this is not the generator chain it replaces):
   depth grows the pool, an empty queue drains it back (idle-first,
   never under a running task), with the hysteresis/cooldown/bounded-
   step discipline proven in serve/_autoscaling.py.
-
-The legacy generator-chain path survives for one PR behind
-``RAY_TPU_DATA_LEGACY_EXEC=1`` (see dataset._exec_stream).
 """
 
 from ray_tpu.data._execution.interfaces import PhysicalOperator, RefBundle

@@ -43,8 +43,8 @@ class InputDataBuffer(PhysicalOperator):
         if self._iter is not None or self._ref_iter is not None:
             return
         # _RefSource thunks (shuffle/repartition/join) resolve here —
-        # lazily, on first pull, exactly like the legacy path (the
-        # iterator may itself be a nested streaming execution).
+        # lazily, on first pull (the iterator may itself be a nested
+        # streaming execution).
         if hasattr(self._source, "resolve_refs"):
             self._ref_iter = iter(self._source.resolve_refs())
         else:
@@ -81,8 +81,8 @@ class InputDataBuffer(PhysicalOperator):
 
 class _MapOperatorBase(PhysicalOperator):
     """Shared machinery for task/actor map operators: ordered emission
-    (results surface in input order, matching the legacy generator
-    chain), tiny-metadata harvesting, and budget byte accounting."""
+    (results surface in input order), tiny-metadata harvesting, and
+    budget byte accounting."""
 
     is_map = True
 
@@ -139,8 +139,8 @@ class _MapOperatorBase(PhysicalOperator):
                 bundle = RefBundle(e["out"], num_rows=meta["rows"],
                                    size_bytes=meta["bytes"])
             except Exception:  # noqa: BLE001 - the task raised: the error
-                # value is stored in the block ref too, so surface it to
-                # the consumer exactly like the legacy path (on get).
+                # value is stored in the block ref too, so it surfaces to
+                # the consumer on get.
                 bundle = RefBundle(e["out"])
             self._rm.on_complete(self)
             # The input block ref is dropped with this entry: its bytes

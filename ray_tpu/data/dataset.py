@@ -7,10 +7,9 @@ with backpressure), data/iterator.py (`iter_batches`, `streaming_split`).
 Redesign notes (TPU-first, not a port):
 - Blocks are numpy-dict columns (see block.py) — the zero-copy staging format
   for `jax.device_put`.
-- The executor is a chain of async generators over ObjectRefs: each map op
-  keeps a bounded submission window and yields results in order; pulling is
-  lazy end-to-end, so backpressure needs no separate policy object — an
-  unpulled downstream simply never advances upstream generators.
+- The executor (data/_execution) is an operator DAG with bounded block-ref
+  queues, pumped by the consumer's pull: an unpulled downstream fills its
+  queues and the operators above it stop launching.
 - Transforms run as ray_tpu tasks; block refs flow through the object store
   (shm, zero-copy on one node).
 """
@@ -151,136 +150,13 @@ def _fuse_plan(plan: List[Any]) -> List[Any]:
 # Streaming execution
 # ---------------------------------------------------------------------------
 def _exec_stream(plan: List[Any]) -> Iterator[Any]:
-    """Plan → iterator of Block ObjectRefs.
-
-    Default: the op-DAG streaming executor (data/_execution) — all
-    operators run concurrently under the ExecutionBudget with
-    output-queue-aware scheduling and actor-pool autoscaling. The
-    legacy per-stage generator chain survives for one PR behind
-    RAY_TPU_DATA_LEGACY_EXEC=1."""
-    import os
-
-    if os.environ.get("RAY_TPU_DATA_LEGACY_EXEC") == "1":
-        return _exec_stream_legacy(plan)
+    """Plan → iterator of Block ObjectRefs: the op-DAG streaming executor
+    (data/_execution) — all operators run concurrently under the
+    ExecutionBudget with output-queue-aware scheduling and actor-pool
+    autoscaling."""
     from ray_tpu.data._execution import execute_plan
 
     return execute_plan(plan)
-
-
-def _exec_stream_legacy(plan: List[Any]) -> Iterator[Any]:
-    """Plan → iterator of Block ObjectRefs (pull-based; bounded windows)."""
-    plan = _fuse_plan(plan)
-    src = plan[0]
-    if isinstance(src, _RefSource):
-        stream: Iterator[Any] = iter(src.resolve_refs())
-    else:
-        stream = (ray_tpu.put(b) for b in src.make_blocks())
-
-    # Per-execution resource manager: reservation-based op budgets the
-    # backpressure chain consults via the per-op binding register_ops
-    # makes (planner.ReservationBackpressurePolicy; reference:
-    # _internal/execution/resource_manager.py).
-    from ray_tpu.data.planner import ResourceManager
-
-    rm = ResourceManager()
-    rm.register_ops(plan[1:])
-
-    for op in plan[1:]:
-        if isinstance(op, _MapBatchesActor):
-            stream = _actor_map_stream(op, stream)
-        else:
-            stream = _map_stream(op, stream)
-    return stream
-
-
-def _map_stream(op: _MapBatches, upstream: Iterator[Any]) -> Iterator[Any]:
-    from collections import deque
-
-    @ray_tpu.remote
-    def _run(block: Block, op=op) -> Block:
-        return _apply_map_batches(op, block)
-
-    from ray_tpu.data.planner import (
-        current_resource_manager, effective_window,
-    )
-
-    remote = _run.options(num_cpus=op.num_cpus)
-    rm = getattr(op, "_rt_resource_manager", None) or \
-        current_resource_manager()
-    inflight: "deque[Any]" = deque()
-    for ref in upstream:
-        inflight.append(remote.remote(ref))
-        if rm is not None:
-            rm.on_launch(op)
-        # Backpressure policies re-evaluated per block: a full object
-        # store shrinks the window to drain mode mid-stream; the
-        # reservation policy bounds this op's share of execution CPU.
-        if len(inflight) >= effective_window(op):
-            if rm is not None:
-                rm.on_complete(op)
-            yield inflight.popleft()
-    while inflight:
-        if rm is not None:
-            rm.on_complete(op)
-        yield inflight.popleft()
-
-
-def _actor_map_stream(op: _MapBatchesActor,
-                      upstream: Iterator[Any]) -> Iterator[Any]:
-    """Round-robin blocks over a pool of stateful actors, bounded in-flight
-    per actor, yielding results in input order. Actors are torn down when the
-    stream is exhausted (or abandoned)."""
-    from collections import deque
-
-    cls, batch_size, fn_kwargs = op.cls, op.batch_size, op.fn_kwargs or {}
-    fmt = op.batch_format
-    ctor_args = op.fn_constructor_args
-    ctor_kwargs = op.fn_constructor_kwargs or {}
-
-    @ray_tpu.remote
-    class _BatchWorker:
-        def __init__(self):
-            self.inst = cls(*ctor_args, **ctor_kwargs)
-
-        def run(self, block: Block) -> Block:
-            outs = []
-            for batch in iter_block_batches(block, batch_size):
-                outs.append(normalize_batch_output(
-                    self.inst(block_as_format(batch, fmt), **fn_kwargs)))
-            return block_concat(outs) if outs else {}
-
-    actor_cls = _BatchWorker.options(
-        num_cpus=op.num_cpus, num_tpus=op.num_tpus)
-    pool = [actor_cls.remote() for _ in _range(max(1, op.concurrency))]
-    inflight: "deque[Any]" = deque()
-    all_refs: List[Any] = []
-    limit = max(1, op.window_per_actor) * len(pool)
-    completed = False
-    try:
-        for i, ref in enumerate(upstream):
-            out = pool[i % len(pool)].run.remote(ref)
-            all_refs.append(out)
-            inflight.append(out)
-            if len(inflight) >= limit:
-                yield inflight.popleft()
-        while inflight:
-            yield inflight.popleft()
-        completed = True
-    finally:
-        if completed and all_refs:
-            # Normal exhaustion: a downstream stage may still be consuming
-            # the tail refs — don't kill the pool under running tasks.
-            # (Abandoned stream: kill immediately; orphaned refs are never
-            # consumed.) wait() is metadata-only, no payload pull.
-            try:
-                ray_tpu.wait(all_refs, num_returns=len(all_refs), timeout=120)
-            except Exception:
-                pass
-        for a in pool:
-            try:
-                ray_tpu.kill(a)
-            except Exception:
-                pass
 
 
 class Dataset:

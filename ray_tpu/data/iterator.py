@@ -23,38 +23,20 @@ class _SplitCoordinator:
     """Actor: executes the plan once per epoch, dealing blocks to n splits
     round-robin.
 
-    Default path: a StreamingExecutor terminated in an OutputSplitter —
-    the pump-on-pull loop runs inside this actor process (no background
-    thread), and the splitter deals eagerly into per-split queues so one
-    far-behind consumer never stalls the others (the dealt blocks leave
-    the execution's byte budget; see OutputSplitter). Legacy generator
-    path behind RAY_TPU_DATA_LEGACY_EXEC=1 keeps shallow shared-advance
-    queues."""
+    A StreamingExecutor terminated in an OutputSplitter — the pump-on-pull
+    loop runs inside this actor process (no background thread), and the
+    splitter deals eagerly into per-split queues so one far-behind consumer
+    never stalls the others (the dealt blocks leave the execution's byte
+    budget; see OutputSplitter)."""
 
     def __init__(self, plan: List[Any], n: int):
         self._plan = plan
         self._n = n
         self._epoch = 0
-        self._exec = None  # StreamingExecutor (default path)
-        # Legacy-path state.
-        self._queues: List[List[Any]] = [[] for _ in range(n)]
-        self._stream = None
-        self._exhausted = False
-        self._rr = 0
-
-    @staticmethod
-    def _use_legacy() -> bool:
-        import os
-
-        return os.environ.get("RAY_TPU_DATA_LEGACY_EXEC") == "1"
+        self._exec = None  # this epoch's StreamingExecutor
 
     def _ensure_stream(self):
-        if self._use_legacy():
-            if self._stream is None:
-                from ray_tpu.data.dataset import _exec_stream_legacy
-
-                self._stream = _exec_stream_legacy(self._plan)
-        elif self._exec is None:
+        if self._exec is None:
             from ray_tpu.data._execution import StreamingExecutor
 
             self._exec = StreamingExecutor(self._plan, split_n=self._n)
@@ -66,24 +48,11 @@ class _SplitCoordinator:
         import ray_tpu
 
         self._ensure_stream()
-        if self._exec is not None:
-            try:
-                ref = self._exec.next_for_split(split_idx)
-            except StopIteration:
-                return None
-            return ray_tpu.get(ref)
-        q = self._queues[split_idx]
-        while not q and not self._exhausted:
-            try:
-                ref = next(self._stream)
-            except StopIteration:
-                self._exhausted = True
-                break
-            self._queues[self._rr].append(ref)
-            self._rr = (self._rr + 1) % self._n
-        if q:
-            return ray_tpu.get(q.pop(0))
-        return None
+        try:
+            ref = self._exec.next_for_split(split_idx)
+        except StopIteration:
+            return None
+        return ray_tpu.get(ref)
 
     def reset(self):
         """Start a fresh epoch (re-runs the plan). Blocks already dealt to
@@ -92,18 +61,14 @@ class _SplitCoordinator:
         if self._exec is not None:
             self._exec.shutdown()
             self._exec = None
-        self._stream = None
-        self._exhausted = False
-        self._queues = [[] for _ in range(self._n)]
-        self._rr = 0
         self._epoch += 1
 
     def epoch(self) -> int:
         return self._epoch
 
     def stats(self) -> Optional[Dict[str, Any]]:
-        """Live executor summary (per-op telemetry breakdown), None on the
-        legacy path or before the first pull of an epoch."""
+        """Live executor summary (per-op telemetry breakdown), None before
+        the first pull of an epoch."""
         if self._exec is None:
             return None
         return self._exec.summary()

@@ -2,8 +2,10 @@
 
 The reference wraps vLLM's CUDA engine; on TPU this package IS the engine
 (SURVEY §7.3): a continuous-batching scheduler over a paged KV cache with
-jitted prefill/decode steps (see _internal/engine.py, _internal/paged.py),
-deployed on ray_tpu.serve replicas."""
+jitted prefill/decode steps (see _internal/engine.py; the allocator and
+prefix index are _internal/paged.py, the pool on the device and the
+`paged_*` primitives ray_tpu/ops/paged_attention.py), deployed on
+ray_tpu.serve replicas."""
 
 from typing import Any, Dict, Optional
 
@@ -13,18 +15,18 @@ from ray_tpu.llm._internal.batch import (
     build_llm_processor,
 )
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request
-from ray_tpu.llm._internal.paged import (
-    PagedCacheConfig,
-    paged_attention,
-    paged_gather,
-    paged_write,
-)
 from ray_tpu.llm._internal.openai import OpenAIServer, build_openai_app
+from ray_tpu.llm._internal.paged import PagedCacheConfig
 from ray_tpu.llm._internal.server import GENERATE_TIMEOUT_S, LLMServer
 from ray_tpu.llm._internal.tokenizer import (
     ByteBPETokenizer,
     apply_chat_template,
     get_tokenizer,
+)
+from ray_tpu.ops.paged_attention import (
+    paged_attention,
+    paged_gather,
+    paged_write,
 )
 
 

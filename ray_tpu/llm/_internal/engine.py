@@ -8,9 +8,9 @@ TPU-first design:
   request churn;
 - prefill jitted per power-of-two length bucket, one sequence at a time,
   writing straight into the paged KV cache;
-- paged KV cache (llm/_internal/paged.py): host-side page allocator +
-  device-side scatter/gather, donated through the step so pages update
-  in place;
+- paged KV cache: host-side page allocator (llm/_internal/paged.py) +
+  device-side scatter/gather/kernel (ops/paged_attention.py, which the
+  model calls), donated through the step so pages update in place;
 - greedy/temperature sampling inside the jitted step.
 
 The engine is synchronous and single-model; LLMServer (serve deployment)

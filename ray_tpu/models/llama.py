@@ -7,7 +7,7 @@ TPU-first design (net-new; the reference delegates modeling to torch/vLLM):
   ray_tpu.parallel.sharding.ParamShardingRules (DP/FSDP/TP/SP on one mesh);
 - attention dispatches to the Pallas flash kernel on a single seq shard or
   ring attention when the mesh has a "seq" axis;
-- KV-cache path (decode) for serving.
+- serving reads and writes the paged K/V pool through ops/paged_attention.
 
 Config presets mirror the sizes users run on the reference stack (BASELINE
 config 2/4 uses Llama-3-8B).
@@ -16,7 +16,6 @@ config 2/4 uses Llama-3-8B).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -25,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
+from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
 from ray_tpu.parallel.sharding import ParamShardingRules
 
 
@@ -140,8 +140,11 @@ class Attention(nn.Module):
     mesh: Optional[Mesh] = None
 
     @nn.compact
-    def __call__(self, x, positions, kv_cache=None, cache_index=None,
-                 paged=None, lora=None, lora_idx=None):
+    def __call__(self, x, positions, kv_pages=None, paged=None, lora=None,
+                 lora_idx=None):
+        """`kv_pages`: this layer's (k_pages, v_pages) when serving, with
+        `paged` = (page_table, write_mask, seq_lens); None for the whole
+        sequence without a cache."""
         cfg = self.cfg
         b, s, _ = x.shape
         h, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -176,33 +179,14 @@ class Attention(nn.Module):
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-        if paged is not None:
-            # Paged KV decode/prefill (serving engine; llm/_internal/paged).
-            from ray_tpu.llm._internal.paged import paged_attention, paged_write
-
-            k_pages, v_pages = paged["kv_pages"]
+        if kv_pages is not None:
+            page_table, write_mask, seq_lens = paged
             pos2d = positions if positions.ndim == 2 else jnp.broadcast_to(
                 positions[None, :], (b, s))
-            k_pages = paged_write(k_pages, k, paged["page_table"], pos2d,
-                                  paged["write_mask"])
-            v_pages = paged_write(v_pages, v, paged["page_table"], pos2d,
-                                  paged["write_mask"])
-            out = paged_attention(q, k_pages, v_pages, paged["page_table"],
-                                  pos2d, paged["seq_lens"], mesh=self.mesh)
-            return o_proj(out), (k_pages, v_pages)
-
-        if kv_cache is not None:
-            # Decode: append to cache, attend over the prefix.
-            ck, cv = kv_cache  # [B, max_len, hk, d]
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_index, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_index, axis=1)
-            mask_len = ck.shape[1]
-            k_ids = jnp.arange(mask_len)
-            # Valid keys: <= current position.
-            q_pos = cache_index + jnp.arange(s)
-            logits_mask = k_ids[None, :] <= q_pos[:, None]
-            out = _masked_attention(q, ck, cv, logits_mask, cfg)
-            return o_proj(out), (ck, cv)
+            out, kv_pages = paged_write_attend(
+                q, k, v, kv_pages, page_table, pos2d, write_mask, seq_lens,
+                mesh=self.mesh)
+            return o_proj(out), kv_pages
 
         if cfg.attention_impl == "ring" and self.mesh is not None:
             from ray_tpu.parallel.ring import ring_attention
@@ -213,20 +197,6 @@ class Attention(nn.Module):
         else:
             out = attention_reference(q, k, v, causal=True)
         return o_proj(out), None
-
-
-def _masked_attention(q, k, v, mask, cfg: LlamaConfig):
-    """Decode-path attention with an explicit [S_q, S_k] boolean mask."""
-    from ray_tpu.ops.attention import NEG_INF, _gqa_expand
-
-    k, v = _gqa_expand(k, v, q.shape[2])
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    logits = jnp.where(mask[None, None, :, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs,
-                      v.astype(jnp.float32)).astype(q.dtype)
 
 
 class Mlp(nn.Module):
@@ -248,12 +218,12 @@ class DecoderLayer(nn.Module):
     mesh: Optional[Mesh] = None
 
     @nn.compact
-    def __call__(self, x, positions, kv_cache=None, cache_index=None,
-                 paged=None, lora=None, lora_idx=None):
+    def __call__(self, x, positions, kv_pages=None, paged=None, lora=None,
+                 lora_idx=None):
         cfg = self.cfg
         attn_out, new_cache = Attention(cfg, self.mesh, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")(x),
-            positions, kv_cache, cache_index, paged, lora, lora_idx)
+            positions, kv_pages, paged, lora, lora_idx)
         x = x + attn_out
         if cfg.num_experts > 0:
             from ray_tpu.models.moe import MoEMlp
@@ -279,16 +249,14 @@ class LlamaModel(nn.Module):
 
     def init_cache(self, cache_cfg, mesh=None):
         """The serving engine's cache, per layer: (k_pages, v_pages)."""
-        from ray_tpu.llm._internal.paged import init_paged_cache
-
         cfg = self.cfg
-        return init_paged_cache(cache_cfg, cfg.num_layers, cfg.num_kv_heads,
-                                cfg.head_dim, cfg.dtype, mesh=mesh)
+        return [init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
+                              cfg.dtype, mesh=mesh)
+                for _ in range(cfg.num_layers)]
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, kv_caches=None,
-                 cache_index=None, paged_kv=None, page_table=None,
-                 write_mask=None, seq_lens=None, lora=None,
+    def __call__(self, input_ids, positions=None, paged_kv=None,
+                 page_table=None, write_mask=None, seq_lens=None, lora=None,
                  lora_idx=None, slots=None):
         """lora: {"layers_<i>": {proj: {"a": [K,r,Din], "b": [K,Dout,r],
         "scale": s}}} adapter BANKS (runtime jit args, not flax params —
@@ -298,40 +266,26 @@ class LlamaModel(nn.Module):
         pages are addressed through `page_table` alone."""
         cfg = self.cfg
         if positions is None:
-            start = cache_index if (kv_caches is not None
-                                    and cache_index is not None) else 0
-            positions = start + jnp.arange(input_ids.shape[1])
+            positions = jnp.arange(input_ids.shape[1])
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=jnp.float32, name="embed_tokens")(input_ids)
         layer_cls = DecoderLayer
-        if cfg.remat and kv_caches is None and paged_kv is None:
+        if cfg.remat and paged_kv is None:
             layer_cls = nn.remat(DecoderLayer, static_argnums=())
+        paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i in range(cfg.num_layers):
-            cache = kv_caches[i] if kv_caches is not None else None
-            paged = None
-            if paged_kv is not None:
-                paged = {"kv_pages": paged_kv[i], "page_table": page_table,
-                         "write_mask": write_mask, "seq_lens": seq_lens}
+            kv_pages = paged_kv[i] if paged_kv is not None else None
             layer_lora = (lora or {}).get(f"layers_{i}")
             x, new_cache = layer_cls(cfg, self.mesh, name=f"layers_{i}")(
-                x, positions, cache, cache_index, paged, layer_lora,
-                lora_idx)
+                x, positions, kv_pages, paged, layer_lora, lora_idx)
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                           param_dtype=jnp.float32, name="lm_head")(x)
-        if kv_caches is not None or paged_kv is not None:
+        if paged_kv is not None:
             return logits, new_caches
         return logits
-
-
-def init_kv_caches(cfg: LlamaConfig, batch: int, max_len: int):
-    shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-    return [
-        (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-        for _ in range(cfg.num_layers)
-    ]
 
 
 def count_params(params) -> int:

@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import RMSNorm
+from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops.linear_attention import gdn_chunked, gdn_decode
+from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -242,10 +244,10 @@ class FullAttention(nn.Module):
     cfg: OlmoHybridConfig
 
     @nn.compact
-    def __call__(self, x, positions, paged=None):
-        from ray_tpu.llm._internal.paged import paged_attention, paged_write
-        from ray_tpu.ops.attention import attention_reference
-
+    def __call__(self, x, positions, kv_pages=None, paged=None):
+        """`kv_pages`: this layer's (k_pages, v_pages) when serving, with
+        `paged` = (page_table, write_mask, seq_lens); None for the whole
+        sequence without a cache."""
         cfg = self.cfg
         b, s, _ = x.shape
         h, d = cfg.num_heads, cfg.head_dim
@@ -253,20 +255,15 @@ class FullAttention(nn.Module):
         k = _norm(cfg, "k_norm")(_dense(cfg, h * d, "k_proj")(x))
         v = _dense(cfg, h * d, "v_proj")(x)
         q, k, v = (t.reshape(b, s, h, d) for t in (q, k, v))
-        new_kv = None
-        if paged is None:
+        if kv_pages is None:
             out = attention_reference(q, k, v, causal=True)
         else:
-            k_pages, v_pages = paged["kv_pages"]
-            k_pages = paged_write(k_pages, k, paged["page_table"], positions,
-                                  paged["write_mask"])
-            v_pages = paged_write(v_pages, v, paged["page_table"], positions,
-                                  paged["write_mask"])
-            out = paged_attention(q, k_pages, v_pages, paged["page_table"],
-                                  positions, paged["seq_lens"])
-            new_kv = (k_pages, v_pages)
+            page_table, write_mask, seq_lens = paged
+            out, kv_pages = paged_write_attend(
+                q, k, v, kv_pages, page_table, positions, write_mask,
+                seq_lens)
         return _dense(cfg, cfg.hidden_size, "o_proj")(
-            out.reshape(b, s, h * d)), new_kv
+            out.reshape(b, s, h * d)), kv_pages
 
 
 class Mlp(nn.Module):
@@ -294,7 +291,7 @@ class HybridLayer(nn.Module):
                 x, mask, cache, rows)
         else:
             mixed, new_cache = FullAttention(cfg, name="self_attn")(
-                x, positions, paged)
+                x, positions, cache, paged)
         x = x + _norm(cfg, "post_attention_layernorm")(mixed)
         x = x + _norm(cfg, "post_feedforward_layernorm")(
             Mlp(cfg, name="mlp")(x))
@@ -311,23 +308,22 @@ class OlmoHybridModel(nn.Module):
                      if kind == LINEAR)
 
     def init_cache(self, cache_cfg, mesh=None):
-        """Per layer: (k_pages, v_pages) [HK, P, ps, D] on a full layer;
-        (conv_tail [max_seqs, 3, C], S [max_seqs, H, dk, dv] float32) on a
-        linear one, a row per engine slot."""
+        """Per layer: (k_pages, v_pages) on a full layer; (conv_tail
+        [max_seqs, 3, C], S [max_seqs, H, dk, dv] float32) on a linear one, a
+        row per engine slot."""
         if mesh is not None:
             raise NotImplementedError(
                 "OlmoHybridModel: state layers have no sharding under a "
                 "mesh (tensor parallelism is not built for this family)")
         cfg = self.cfg
-        pages = (cfg.num_kv_heads, cache_cfg.num_pages, cache_cfg.page_size,
-                 cfg.head_dim)
         tail = (cache_cfg.max_seqs, cfg.linear_conv_kernel_dim - 1,
                 cfg.conv_channels)
         state = (cache_cfg.max_seqs, cfg.linear_num_key_heads,
                  cfg.linear_key_head_dim, cfg.linear_value_head_dim)
         return [(jnp.zeros(tail, cfg.dtype), jnp.zeros(state, jnp.float32))
                 if kind == LINEAR else
-                (jnp.zeros(pages, cfg.dtype), jnp.zeros(pages, cfg.dtype))
+                init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
+                              cfg.dtype)
                 for kind in cfg.layer_types]
 
     @nn.nowrap
@@ -371,14 +367,10 @@ class OlmoHybridModel(nn.Module):
         if positions.ndim == 1:
             positions = jnp.broadcast_to(positions[None, :], (b, s))
         x = _embed(cfg, "embed_tokens")(input_ids)
+        paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i, kind in enumerate(cfg.layer_types):
-            cache = paged = None
-            if paged_kv is not None and kind == LINEAR:
-                cache = paged_kv[i]
-            elif paged_kv is not None:
-                paged = {"kv_pages": paged_kv[i], "page_table": page_table,
-                         "write_mask": write_mask, "seq_lens": seq_lens}
+            cache = paged_kv[i] if paged_kv is not None else None
             x, new_cache = HybridLayer(cfg, kind, name=f"layers_{i}")(
                 x, positions, write_mask, cache, paged, slots)
             new_caches.append(new_cache)

@@ -305,20 +305,6 @@ def test_streaming_split_epochs_reset(ray_cluster):
         its[0].new_epoch()
 
 
-def test_legacy_exec_flag_matches(ray_cluster, monkeypatch):
-    import ray_tpu.data as rd
-
-    def run():
-        return (rd.range(600, block_rows=60)
-                .map_batches(_double(), batch_size=60)
-                .take_all())
-
-    new = [r["id"] for r in run()]
-    monkeypatch.setenv("RAY_TPU_DATA_LEGACY_EXEC", "1")
-    legacy = [r["id"] for r in run()]
-    assert new == legacy == [2 * i for i in range(600)]
-
-
 def test_execution_summaries_exposed(ray_cluster):
     import ray_tpu.data as rd
 

@@ -115,7 +115,7 @@ def test_paged_decode_kernel_matches_jnp():
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm._internal.paged import (
+    from ray_tpu.ops.paged_attention import (
         paged_attention,
         paged_attention_decode_kernel,
     )
@@ -144,7 +144,7 @@ def test_paged_decode_kernel_one_query_head_per_kv_head():
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm._internal.paged import (
+    from ray_tpu.ops.paged_attention import (
         paged_attention,
         paged_attention_decode_kernel,
     )

@@ -340,18 +340,13 @@ def test_engine_refuses_what_is_not_built_for_state_layers(tiny, what):
         LLMEngine(model, params, EngineConfig(max_seqs=2, **cfg), **kw)
 
 
-def test_llama_cache_is_init_paged_caches():
-    from ray_tpu.llm._internal.paged import PagedCacheConfig, init_paged_cache
+def test_llama_has_no_state_layers_and_keeps_its_prefix_cache():
+    # (the pages' shapes: tests/test_paged_attention.py, both families)
     from ray_tpu.models.llama import LlamaConfig, LlamaModel
 
     cfg = LlamaConfig.tiny()
-    cache_cfg = PagedCacheConfig(num_pages=33, page_size=8, max_seqs=2,
-                                 max_pages_per_seq=16)
     model = LlamaModel(cfg)
-    got = jax.eval_shape(lambda: model.init_cache(cache_cfg))
-    want = jax.eval_shape(lambda: init_paged_cache(
-        cache_cfg, cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.dtype))
-    assert got == want and model.state_layer_ids == ()
+    assert model.state_layer_ids == ()
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     eng = LLMEngine(model, params, EngineConfig(max_seqs=2, page_size=8,

@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerators
-from ray_tpu.llm._internal.paged import paged_attention_decode_kernel
+from ray_tpu.ops.paged_attention import paged_attention_decode_kernel
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 
