@@ -4,8 +4,13 @@ llm/_internal/paged.py; reference: ray.llm delegates paging to vLLM's CUDA
 PagedAttention — here we ARE the engine, SURVEY §7.3).
 
 TPU-first design: everything is static-shaped for XLA —
-- pages:      [kv_heads, num_pages, page_size, head_dim] per layer (kv-head
-  major so Pallas blocks tile the (page_size, head_dim) minor dims),
+- pages:      [num_pages, page_size, kv_heads * head_dim] per layer, token
+  major: a token's K (or V) over all its heads is one contiguous row, a page
+  one contiguous [page_size, kv_heads * head_dim] tile, and kv head g is the
+  lane block [g * head_dim, (g + 1) * head_dim) of a row. The scatter that
+  writes a token, the gather that reads a prefix back and the decode kernel
+  all take this one layout as it lies, so no program copies a pool to call
+  any of them,
 - page_table: [max_seqs, max_pages_per_seq] int32 (host-managed allocator),
 - seq_lens:   [max_seqs] int32.
 Writes are vectorized scatters (`.at[...].set(mode="drop")` — padding lanes
@@ -26,25 +31,30 @@ from jax.experimental import pallas as pl
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 NEG_INF = -1e30
+# VMEM the decode kernel's double-buffered K and V page chunks may take
+# (of the 16 MiB a v5e kernel gets by default; the rest is the compiler's).
+KV_CHUNK_VMEM_BYTES = 2 * 2 ** 20
 
 
-def pages_spec(shape: Tuple[int, ...], mesh: Mesh) -> PartitionSpec:
-    """How pages [HK, P, ps, D] lie on a mesh: split over kv heads, or
-    replicated where the tensor axis does not divide them (tiny test
-    models). The cache and the decode kernel's shard_map both use it."""
+def pages_spec(kv_heads: int, mesh: Mesh) -> PartitionSpec:
+    """How pages [P, ps, HK*D] lie on a mesh: the lanes split over the
+    tensor axis where it divides the KV heads (whole heads a shard), or
+    replicated where it does not (tiny test models). The cache and the
+    decode kernel's shard_map both use it."""
     from ray_tpu.parallel.sharding import spec_for_shape
 
-    return spec_for_shape(("kv_heads", None, None, None), shape, mesh)
+    heads = spec_for_shape(("kv_heads",), (kv_heads,), mesh)
+    return PartitionSpec(None, None, heads[0] if len(heads) else None)
 
 
 def init_kv_pages(cache_cfg, kv_heads: int, head_dim: int,
                   dtype=jnp.bfloat16, mesh: Optional[Mesh] = None):
-    """One layer's (k_pages, v_pages), layout [HK, P, ps, D], for a pool of
+    """One layer's (k_pages, v_pages), layout [P, ps, HK*D], for a pool of
     `cache_cfg.num_pages` pages of `cache_cfg.page_size` tokens (the engine's
     `PagedCacheConfig`); with a mesh, created directly in their sharding (no
     device holds them all)."""
-    shape = (kv_heads, cache_cfg.num_pages, cache_cfg.page_size, head_dim)
-    sharding = (NamedSharding(mesh, pages_spec(shape, mesh))
+    shape = (cache_cfg.num_pages, cache_cfg.page_size, kv_heads * head_dim)
+    sharding = (NamedSharding(mesh, pages_spec(kv_heads, mesh))
                 if mesh is not None else None)
     return (jnp.zeros(shape, dtype, device=sharding),
             jnp.zeros(shape, dtype, device=sharding))
@@ -52,28 +62,25 @@ def init_kv_pages(cache_cfg, kv_heads: int, head_dim: int,
 
 def paged_write(pages: jax.Array, new_kv: jax.Array, page_table: jax.Array,
                 positions: jax.Array, mask: jax.Array) -> jax.Array:
-    """Scatter new_kv [B,S,HK,D] into pages [HK,P,ps,D].
+    """Scatter new_kv [B,S,HK,D] into pages [P,ps,HK*D], a row a token.
 
     positions [B,S]: absolute token index of each entry; mask [B,S]: write
     enable (False lanes scatter out-of-bounds and are dropped)."""
-    ps = pages.shape[2]
+    num_pages, ps, width = pages.shape
     page_idx = jnp.take_along_axis(
         page_table, positions // ps, axis=1)  # [B,S]
     slot_idx = positions % ps
-    page_idx = jnp.where(mask, page_idx, pages.shape[1])  # OOB -> dropped
-    hk, d = new_kv.shape[2], new_kv.shape[3]
-    values = new_kv.reshape(-1, hk, d).swapaxes(0, 1)  # [HK,N,D]
-    return pages.at[:, page_idx.reshape(-1), slot_idx.reshape(-1)].set(
-        values, mode="drop")
+    page_idx = jnp.where(mask, page_idx, num_pages)  # OOB -> dropped
+    return pages.at[page_idx.reshape(-1), slot_idx.reshape(-1)].set(
+        new_kv.reshape(-1, width), mode="drop")
 
 
 def paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[HK,P,ps,D] + [B,MP] -> [B, MP*ps, HK, D] (each row's full context
-    window, garbage beyond seq_len — callers mask)."""
+    """[P,ps,HK*D] + [B,MP] -> [B, MP*ps, HK*D] (each row's full context
+    window in token order, garbage beyond seq_len — callers mask)."""
     b, mp = page_table.shape
-    hk, _, ps, d = pages.shape
-    gathered = jnp.take(pages, page_table, axis=1)  # [HK,B,MP,ps,D]
-    return gathered.reshape(hk, b, mp * ps, d).transpose(1, 2, 0, 3)
+    _, ps, width = pages.shape
+    return jnp.take(pages, page_table, axis=0).reshape(b, mp * ps, width)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -98,9 +105,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             q, k_pages, v_pages, page_table, seq_lens, scale=scale,
             mesh=mesh)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    h, hk = q.shape[2], k_pages.shape[0]
-    k = paged_gather(k_pages, page_table)  # [B,C,HK,D]
-    v = paged_gather(v_pages, page_table)
+    b, _, h, d = q.shape
+    hk = k_pages.shape[2] // d
+    k = paged_gather(k_pages, page_table).reshape(b, -1, hk, d)  # [B,C,HK,D]
+    v = paged_gather(v_pages, page_table).reshape(b, -1, hk, d)
     if hk != h:
         rep = h // hk
         k = jnp.repeat(k, rep, axis=2)
@@ -139,39 +147,46 @@ def paged_write_attend(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                          kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc_scr, *,
-                         page_size: int, pages_per_chunk: int,
-                         max_pages: int, scale: float):
-    """Grid (B, HK). KV pages stay in HBM; the kernel walks the sequence's
-    page list in chunks of C pages, double-buffering the page DMAs against
-    the flash update of the previous chunk (the canonical TPU
-    paged-attention shape — per-page grid steps would be DMA-latency
-    bound)."""
+                         kv_heads: int, scale: float):
+    """Grid (B,): one program a sequence, over all its heads. KV pages stay
+    in HBM; the kernel walks the sequence's page list in chunks of C pages,
+    a page one DMA of [ps, HK*D], double-buffering the page DMAs against the
+    flash update of the previous chunk (the canonical TPU paged-attention
+    shape — per-page grid steps would be DMA-latency bound).
+
+    Heads are told apart by lanes, not by a loop: row h of `q_wide` holds
+    query head h in its kv head's lane block and zeros elsewhere, so one
+    contraction over all HK*D lanes of the keys gives every head's scores,
+    and of p @ v [H, HK*D] each head keeps its own block at the end. Groups
+    of several query heads (GQA) and of one run the same code."""
     from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
-    hki = pl.program_id(1)
-    C = pages_per_chunk
-    ps = page_size
-    seq_len = lens_ref[b]
+    _, C, ps, width = kbuf.shape
+    h, d = q_ref.shape[2:]
+    # Never past the page table: a free slot's length keeps counting while
+    # decode windows chain on the device.
+    seq_len = jnp.minimum(lens_ref[b], pt_ref.shape[1] * ps)
     n_pages = jax.lax.div(seq_len + ps - 1, ps)
     n_chunks = jax.lax.div(n_pages + C - 1, C)
 
+    def page_copies(ci, buf, j):
+        page = pt_ref[b, ci * C + j]
+        return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, j],
+                                      ksem.at[buf, j]),
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, j],
+                                      vsem.at[buf, j]))
+
     def start_chunk(ci, buf):
         for j in range(C):  # static unroll: C independent page DMAs
-            pg = ci * C + j
 
-            @pl.when(pg < n_pages)
-            def _():
-                page = pt_ref[b, pg]
-                pltpu.make_async_copy(
-                    k_hbm.at[hki, page], kbuf.at[buf, j], ksem.at[buf, j],
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[hki, page], vbuf.at[buf, j], vsem.at[buf, j],
-                ).start()
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                for copy in page_copies(ci, buf, j):
+                    copy.start()
 
-            @pl.when(pg >= n_pages)
-            def _zero():
+            @pl.when(ci * C + j >= n_pages)
+            def _zero(j=j):
                 # Unfetched slots must hold zeros, not garbage: their
                 # probability weights are exactly 0, but 0 * NaN = NaN in
                 # the p·v accumulation.
@@ -180,71 +195,70 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def wait_chunk(ci, buf):
         for j in range(C):
-            pg = ci * C + j
 
-            @pl.when(pg < n_pages)
-            def _():
-                page = pt_ref[b, pg]
-                pltpu.make_async_copy(
-                    k_hbm.at[hki, page], kbuf.at[buf, j], ksem.at[buf, j],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[hki, page], vbuf.at[buf, j], vsem.at[buf, j],
-                ).wait()
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                for copy in page_copies(ci, buf, j):
+                    copy.wait()
+
+    # own[h, lane]: the lane lies in the block of query head h's kv head
+    own = (jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // d
+           == jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0)
+           // (h // kv_heads))
+    q_wide = jnp.where(own, jnp.tile(q_ref[0, 0], (1, kv_heads)), 0)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
-    start_chunk(0, 0)
 
-    # Static unroll over the page-table capacity: every buffer index is a
-    # compile-time constant; per-sequence work is guarded by n_chunks.
-    chunks_max = (max_pages + C - 1) // C
-    for ci in range(chunks_max):
-        buf = ci % 2
+    @pl.when(n_chunks > 0)
+    def _first():
+        start_chunk(0, 0)
 
-        @pl.when(ci < n_chunks)
-        def _chunk(ci=ci, buf=buf):
-            if ci + 1 < chunks_max:
-                @pl.when(ci + 1 < n_chunks)
-                def _prefetch():
-                    start_chunk(ci + 1, 1 - buf)
+    def chunk(ci, carry):
+        buf = jax.lax.rem(ci, 2)
 
-            wait_chunk(ci, buf)
-            q = q_ref[0, 0]  # [Hg, D]
-            k = kbuf[buf].reshape(C * ps, -1)  # [C*ps, D]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [Hg, C*ps]
-            pos = ci * C * ps + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(pos < seq_len, s, NEG_INF)
-            m_prev = m_scr[:, 0]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            l_scr[:, 0] = l_scr[:, 0] * alpha + p.sum(axis=-1)
-            m_scr[:, 0] = m_new
-            v = vbuf[buf].reshape(C * ps, -1)
-            acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        @pl.when(ci + 1 < n_chunks)
+        def _prefetch():
+            start_chunk(ci + 1, 1 - buf)
 
-    denom = jnp.maximum(l_scr[:, 0], 1e-30)
-    o_ref[0, 0] = (acc_scr[:] / denom[:, None]).astype(o_ref.dtype)
+        wait_chunk(ci, buf)
+        k = kbuf[buf].reshape(C * ps, width)
+        s = jax.lax.dot_general(
+            q_wide, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, C*ps]
+        pos = ci * C * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < seq_len, s, NEG_INF)
+        m_prev = m_scr[...]  # [H, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        v = vbuf[buf].reshape(C * ps, width)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [H, HK*D]
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+
+    acc = jnp.where(own, acc_scr[...], 0.0)
+    out = sum(acc[:, g * d:(g + 1) * d] for g in range(kv_heads))  # [H, D]
+    o_ref[0, 0] = (out / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_decode_kernel(
         q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         page_table: jax.Array, seq_lens: jax.Array,
         scale: Optional[float] = None,
-        pages_per_chunk: int = 16,
+        pages_per_chunk: Optional[int] = None,
         interpret: Optional[bool] = None,
         mesh: Optional[Mesh] = None) -> jax.Array:
-    """Pallas decode attention: q [B,1,H,D] over paged KV without
-    materializing the gathered context. Grid (B, KV_H); q heads are grouped
-    by kv head (GQA) so one [Hg, C*ps] MXU tile serves all query heads of
-    the group per chunk; see _paged_decode_kernel for the DMA pipeline.
+    """Pallas decode attention: q [B,1,H,D] over paged KV [P,ps,HK*D] without
+    materializing the gathered context. Grid (B,); see _paged_decode_kernel
+    for the DMA pipeline. `pages_per_chunk` defaults to what
+    KV_CHUNK_VMEM_BYTES holds of this pool's pages, twice for K and for V.
 
     With a multi-device `mesh` the kernel runs under shard_map with the KV
     heads (and the query heads grouped under them) split over the tensor
@@ -252,9 +266,13 @@ def paged_attention_decode_kernel(
     replicated."""
     from jax.experimental.pallas import tpu as pltpu
 
+    b, s, h, d = q.shape
+    assert s == 1, "decode kernel expects one query token per sequence"
+    _, ps, width = k_pages.shape
+    hk = width // d
     if mesh is not None and mesh.size > 1:
-        kv_spec = pages_spec(k_pages.shape, mesh)
-        q_spec = PartitionSpec(None, None, *kv_spec[:1])
+        kv_spec = pages_spec(hk, mesh)
+        q_spec = PartitionSpec(None, None, kv_spec[2])
         local = functools.partial(
             paged_attention_decode_kernel, scale=scale,
             pages_per_chunk=pages_per_chunk, interpret=interpret)
@@ -266,46 +284,40 @@ def paged_attention_decode_kernel(
         )(q, k_pages, v_pages, page_table, seq_lens)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    b, s, h, d = q.shape
-    assert s == 1, "decode kernel expects one query token per sequence"
-    hk, num_pages, ps, _ = k_pages.shape
-    hg = h // hk
     mp = page_table.shape[1]
+    if pages_per_chunk is None:
+        page_bytes = ps * width * k_pages.dtype.itemsize
+        pages_per_chunk = max(1, KV_CHUNK_VMEM_BYTES // (4 * page_bytes))
     C = min(pages_per_chunk, mp)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    qg = q.reshape(b, hk, hg, d)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, page_size=ps, pages_per_chunk=C,
-        max_pages=mp, scale=scale)
+    kernel = functools.partial(_paged_decode_kernel, kv_heads=hk, scale=scale)
+    block = pl.BlockSpec((1, 1, h, d), lambda bi, pt, lens: (bi, 0, 0, 0))
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hk),
+            grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, 1, hg, d),
-                             lambda bi, hki, pt, lens: (bi, hki, 0, 0)),
+                block,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, hg, d), lambda bi, hki, pt, lens: (bi, hki, 0, 0)),
+            out_specs=block,
             scratch_shapes=[
-                pltpu.VMEM((2, C, ps, d), k_pages.dtype),
-                pltpu.VMEM((2, C, ps, d), v_pages.dtype),
+                pltpu.VMEM((2, C, ps, width), k_pages.dtype),
+                pltpu.VMEM((2, C, ps, width), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, C)),
                 pltpu.SemaphoreType.DMA((2, C)),
-                pltpu.VMEM((hg, 1), jnp.float32),
-                pltpu.VMEM((hg, 1), jnp.float32),
-                pltpu.VMEM((hg, d), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, width), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hk, hg, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_decode",
-    )(page_table, seq_lens, qg, k_pages, v_pages)
-    return out.reshape(b, 1, h, d)
+    )(page_table, seq_lens, q, k_pages, v_pages)

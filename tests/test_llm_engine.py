@@ -109,54 +109,34 @@ def test_stop_token_and_temperature_paths(tiny_model):
     assert len(got["t"]) == 4
 
 
-def test_paged_decode_kernel_matches_jnp():
-    """Pallas decode kernel (interpret mode on CPU) vs the jnp gather path."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
+@pytest.mark.parametrize("heads,kv_heads,seq_lens", [
+    (8, 2, [5, 17, 31]),
+    # no grouping (H == HK, a [1, D] query tile): the full-attention layers
+    # of models/olmo_hybrid.py. Same kernel, same call as with a group.
+    (4, 4, [1, 18, 32]),
+], ids=["grouped", "one_query_head_per_kv_head"])
+def test_paged_decode_kernel_matches_jnp(heads, kv_heads, seq_lens):
+    """Pallas decode kernel (interpret mode on CPU) vs the jnp gather path,
+    over pools in the shapes `init_kv_pages` makes."""
+    from ray_tpu.llm._internal.paged import PagedCacheConfig
     from ray_tpu.ops.paged_attention import (
+        init_kv_pages,
         paged_attention,
         paged_attention_decode_kernel,
     )
 
-    rng = np.random.default_rng(0)
-    B, H, HK, D, PS, MP, P = 3, 8, 2, 64, 8, 4, 16
-    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-    k_pages = jnp.asarray(rng.standard_normal((HK, P, PS, D)), jnp.float32)
-    v_pages = jnp.asarray(rng.standard_normal((HK, P, PS, D)), jnp.float32)
+    rng = np.random.default_rng(heads)
+    B, D, MP = 3, 64, 4
+    cache_cfg = PagedCacheConfig(num_pages=16, page_size=8, max_seqs=B,
+                                 max_pages_per_seq=MP)
+    q = jnp.asarray(rng.standard_normal((B, 1, heads, D)), jnp.float32)
+    k_pages, v_pages = (
+        jnp.asarray(rng.standard_normal(zeros.shape), jnp.float32)
+        for zeros in init_kv_pages(cache_cfg, kv_heads, D, jnp.float32))
     page_table = jnp.asarray(
-        rng.permutation(P - 1)[: B * MP].reshape(B, MP) % (P - 1),
-        jnp.int32)
-    seq_lens = jnp.asarray([5, 17, 31], jnp.int32)
+        rng.permutation(15)[: B * MP].reshape(B, MP), jnp.int32)
+    seq_lens = jnp.asarray(seq_lens, jnp.int32)
 
-    ref = paged_attention(q, k_pages, v_pages, page_table,
-                          (seq_lens - 1)[:, None], seq_lens)
-    out = paged_attention_decode_kernel(q, k_pages, v_pages, page_table,
-                                        seq_lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_paged_decode_kernel_one_query_head_per_kv_head():
-    """No grouping (H == HK, a [1, D] query tile): the full-attention layers
-    of models/olmo_hybrid.py. Same kernel, same call as with a group."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.ops.paged_attention import (
-        paged_attention,
-        paged_attention_decode_kernel,
-    )
-
-    rng = np.random.default_rng(1)
-    B, H, D, PS, MP, P = 3, 4, 64, 8, 4, 16
-    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-    k_pages = jnp.asarray(rng.standard_normal((H, P, PS, D)), jnp.float32)
-    v_pages = jnp.asarray(rng.standard_normal((H, P, PS, D)), jnp.float32)
-    page_table = jnp.asarray(
-        rng.permutation(P - 1)[: B * MP].reshape(B, MP), jnp.int32)
-    seq_lens = jnp.asarray([1, 18, 32], jnp.int32)
     ref = paged_attention(q, k_pages, v_pages, page_table,
                           (seq_lens - 1)[:, None], seq_lens,
                           use_kernel=False)

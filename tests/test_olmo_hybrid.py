@@ -310,7 +310,7 @@ def test_engine_cache_is_what_the_model_says(tiny):
     eng = _engine(model, params, max_seqs=3)
     assert eng.prefix_cache is None      # whatever enable_prefix_cache says
     assert model.state_layer_ids == (0, 1, 2, 4, 5, 6)
-    pages = (4, 3 * 16 + 1, 8, 32)
+    pages = (3 * 16 + 1, 8, 4 * 32)    # [P, ps, HK * D]
     for i, (a, b) in enumerate(eng.caches):
         if i in model.state_layer_ids:
             assert (a.shape, b.shape) == ((3, 3, 4 * (24 + 24 + 48)),

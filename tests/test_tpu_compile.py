@@ -14,6 +14,8 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +25,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerators
-from ray_tpu.ops.paged_attention import paged_attention_decode_kernel
+from ray_tpu.llm._internal.paged import PagedCacheConfig
+from ray_tpu.ops.paged_attention import (
+    init_kv_pages,
+    paged_attention_decode_kernel,
+    pages_spec,
+)
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 
@@ -115,14 +122,15 @@ def test_flash_fwd_bwd_sharded_2x2(topology):
 
 
 def _decode_args(sharding_for, batch, page_size, pages_per_seq):
-    pages = batch * pages_per_seq + 1
     s = jax.ShapeDtypeStruct
+    k_pages, v_pages = jax.eval_shape(lambda: init_kv_pages(
+        PagedCacheConfig(num_pages=batch * pages_per_seq + 1,
+                         page_size=page_size, max_seqs=batch,
+                         max_pages_per_seq=pages_per_seq), HKV, D))
     return (
         s((batch, 1, H, D), jnp.bfloat16, sharding=sharding_for("q")),
-        s((HKV, pages, page_size, D), jnp.bfloat16,
-          sharding=sharding_for("pages")),
-        s((HKV, pages, page_size, D), jnp.bfloat16,
-          sharding=sharding_for("pages")),
+        s(k_pages.shape, k_pages.dtype, sharding=sharding_for("pages")),
+        s(v_pages.shape, v_pages.dtype, sharding=sharding_for("pages")),
         s((batch, pages_per_seq), jnp.int32, sharding=sharding_for(None)),
         s((batch,), jnp.int32, sharding=sharding_for(None)),
     )
@@ -131,7 +139,7 @@ def _decode_args(sharding_for, batch, page_size, pages_per_seq):
 @pytest.mark.parametrize("batch,page_size,pages_per_seq", [
     (8, 64, 16),    # chip_smoke's engine config
     (32, 16, 64),   # the engine's default page shape
-    (8, 64, 64),    # several chunks of 16 pages
+    (8, 64, 64),    # several chunks of pages
 ])
 def test_paged_decode_one_chip(topology, batch, page_size, pages_per_seq):
     one = SingleDeviceSharding(topology.devices[0])
@@ -146,13 +154,82 @@ def test_paged_decode_sharded_tensor4(topology):
     """KV pages split over kv heads, as the tensor-parallel engine holds
     them; page table and lengths replicated."""
     mesh = _mesh(topology, tensor=4)
-    specs = {"q": P(None, None, "tensor"), "pages": P("tensor"), None: P()}
+    specs = {"q": P(None, None, "tensor"), "pages": pages_spec(HKV, mesh),
+             None: P()}
+    assert specs["pages"] == P(None, None, "tensor")
     args = _decode_args(lambda k: NamedSharding(mesh, specs[k]), 8, 64, 16)
     fn = functools.partial(paged_attention_decode_kernel, interpret=False)
     text = _compiled_text(functools.partial(fn, mesh=mesh), *args)
     assert "tpu_custom_call" in text
     with pytest.raises(Exception, match="shard_map"):
         _compiled_text(fn, *args)
+
+
+# ---------------------------------------------------------------------------
+# The K/V pool is never copied: scatter, gather and kernel take one layout
+# ---------------------------------------------------------------------------
+_LAYOUT_CHANGE = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (.+?) (copy|transpose)\(")
+
+
+def _pool_layout_changes(text, pool_elements):
+    """The `copy` and `transpose` instructions of a compiled program (inside
+    fusions too) whose result has as many elements as one layer's K or V
+    pool: a pool changing its physical layout between two of its users.
+    (`copy-start` is not one: at the cut depth the compiler may move a pool
+    to another memory space, in the layout it has.)"""
+    found = []
+    for line in text.splitlines():
+        m = _LAYOUT_CHANGE.match(line)
+        if m and any(
+                math.prod(int(n) for n in dims.split(",")) == pool_elements
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("cell", ["decode-heavy", "hybrid-decode-heavy"])
+def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
+    """The chip compiler's HLO of a decode window and of a prefill, at the
+    serving widths and engine shapes of Mistral's and of the hybrid's cell
+    (depth cut to two attention layers and to one of each kind): no
+    instruction rewrites a whole pool. With kv-head-major pages the scatter
+    took the pool token major and the kernel as written, so every token
+    step copied each layer's K and V from the one layout to the other (two
+    such copies a layer in the loop, four more at its edges)."""
+    from benchmark import sizing
+    from benchmark.manifest import Manifest
+
+    # the engine takes the Mosaic kernel where the default backend is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    manifest = Manifest(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    made = manifest.cell(cell)
+    cfg = manifest.config(made["config"])
+    family = manifest.family(cfg["family"])
+    kw = family.model_kwargs(cfg)
+    if "layer_types" in kw:
+        kw["layer_types"] = kw["layer_types"][:4]
+        assert "full_attention" in kw["layer_types"]
+    else:
+        kw["num_layers"] = 2
+    model = family.model(kw)
+    ec = manifest.traffic(made["traffic"])["engine_config"]
+    one = SingleDeviceSharding(topology.devices[0])
+    lowered = (sizing.lower_decode(model, ec, one) if program == "decode"
+               else sizing.lower_prefill(model, ec, 128, ec["max_seqs"],
+                                         one))
+    text = lowered.compile().as_text()
+
+    # a layer's K (or V) pool is the largest array of the engine's cache
+    pool = max(jax.tree.leaves(sizing.cache_shapes(model, ec, None)),
+               key=lambda x: math.prod(x.shape))
+    assert pool.shape[:2] == (ec["max_seqs"] * ec["max_pages_per_seq"] + 1,
+                              ec["page_size"])
+    assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    if program == "decode":
+        assert "paged_decode" in _kernel_names(text)
 
 
 # ---------------------------------------------------------------------------
