@@ -2,7 +2,8 @@
 
 Load with ``pytest_plugins = ["ray_tpu._private.pytest_watchdog"]`` (the
 repo's tests/conftest.py does). The plugin heartbeats at every test-phase
-boundary; the external killer SIGKILLs the whole pytest process if a
+boundary (and, in an xdist controller, at every report a worker sends);
+the external killer SIGKILLs the whole pytest process if a
 phase wedges past the stale limit, or if the interpreter fails to exit
 within the exit grace after the session finished (leaked non-daemon
 threads). See watchdog_killer.py for why this must live out-of-process.
@@ -89,6 +90,14 @@ def pytest_runtest_call(item):
 def pytest_runtest_teardown(item):
     _touch()
     yield
+    _touch()
+
+
+def pytest_runtest_logreport(report):
+    # Under xdist the controller runs no test phase of its own: a worker's
+    # report is its sign of life. Without it the controller's heartbeat
+    # was never touched, and a whole run longer than the stale limit was
+    # killed however well its tests went.
     _touch()
 
 

@@ -102,6 +102,12 @@ class Request:
     slot: int = -1
     generated: int = 0
     done: bool = False
+    # Block generation: prompt tokens at the head of the request's first
+    # block; they come back with its first window and are not emitted.
+    skip: int = 0
+    # (cached prompt tokens, prefill batch) of its admission, for the
+    # first-token mark, which block generation makes a window later.
+    prefilled: Tuple[int, int] = (0, 0)
     # Monotonic stamps. Enqueued when the object is made (whoever queues
     # it); admitted and first token by the engine.
     t_enqueued: float = dataclasses.field(default_factory=time.monotonic)
@@ -163,6 +169,14 @@ class LLMEngine:
     from zero and decode updates in place. A model with state layers runs
     without LoRA banks, `param_transform` or prefix sharing (each is built
     for K/V layers only) and, having no sharding rules, without a mesh.
+
+    A model with `block_length` B > 1 generates by diffusion over aligned
+    blocks of B positions (`_block_decode`): prefill only fills the cache
+    with the prompt's whole blocks, a decode window is `decode_steps / B`
+    blocks, and what is carried between windows is a block's ids, not one
+    last token. It runs under the same three limits as a model with state
+    layers (prefix sharing would hold: whole pages are whole blocks; it is
+    off because an admission that finds all its blocks cached is not built).
     """
 
     def __init__(self, model, params, cfg: EngineConfig, mesh=None,
@@ -176,17 +190,26 @@ class LLMEngine:
         # into the consuming matmuls.
         self.param_transform = param_transform
         self._state_layers = len(model.state_layer_ids)
-        if self._state_layers:
+        special = ("has state layers" if self._state_layers else
+                   "generates by blocks" if self._block > 1 else "")
+        if special:
             missing = [what for what, asked in (
                 ("lora_rank > 0: LoRA banks are built for attention "
                  "projections of every layer", cfg.lora_rank > 0),
                 ("param_transform: not checked against state layers' "
-                 "float32 recurrences", param_transform is not None))
+                 "float32 recurrences or a float32 router",
+                 param_transform is not None))
                 if asked]
             if missing:
                 raise NotImplementedError(
-                    f"{type(model).__name__} has state layers and cannot "
+                    f"{type(model).__name__} {special} and cannot "
                     f"run with {'; '.join(missing)}")
+        if self._block > 1 and (max(1, cfg.decode_steps) % self._block
+                                or cfg.page_size % self._block):
+            raise ValueError(
+                f"decode_steps {cfg.decode_steps} and page_size "
+                f"{cfg.page_size} must be multiples of "
+                f"{type(model).__name__}'s block_length {self._block}")
         self.cache_cfg = PagedCacheConfig(
             num_pages=cfg.resolved_num_pages() + 1,  # +1: OOB drop page
             page_size=cfg.page_size, max_seqs=cfg.max_seqs,
@@ -229,7 +252,12 @@ class LLMEngine:
         self.page_table = np.zeros(
             (cfg.max_seqs, cfg.max_pages_per_seq), np.int32)
         self.seq_lens = np.zeros((cfg.max_seqs,), np.int32)
-        self.last_tokens = np.zeros((cfg.max_seqs,), np.int32)
+        # What a row's next decode step is fed: its last token, or (block
+        # generation) the ids of the block it is on, MASK where unrevealed.
+        self.last_tokens = (
+            np.zeros((cfg.max_seqs,), np.int32) if self._block == 1 else
+            np.full((cfg.max_seqs, self._block), model.mask_token_id,
+                    np.int32))
         self.temps = np.zeros((cfg.max_seqs,), np.float32)
         self.top_ps = np.ones((cfg.max_seqs,), np.float32)
         self.top_ks = np.zeros((cfg.max_seqs,), np.int32)
@@ -249,11 +277,11 @@ class LLMEngine:
         self._free_slots = list(range(cfg.max_seqs))
         self.prefix_cache = (PrefixCache(self.allocator)
                              if cfg.enable_prefix_cache else None)
-        if self._state_layers and self.prefix_cache is not None:
+        if special and self.prefix_cache is not None:
             # A shared page carries K/V and no state: a sharer's state
             # layers would start from zero in the middle of its prompt.
-            logger.info("%s has %d state layers: prefix sharing is off",
-                        type(model).__name__, self._state_layers)
+            logger.info("%s %s: prefix sharing is off",
+                        type(model).__name__, special)
             self.prefix_cache = None
         # LoRA banks (slot 0 = zero adapter = base model).
         self.lora_banks: Optional[Dict[str, Any]] = None
@@ -284,6 +312,12 @@ class LLMEngine:
             "ray_tpu_llm_programs_built_total",
             "Prefill/decode programs built or retraced by the engine",
             tag_keys=("kind",))
+
+    @property
+    def _block(self) -> int:
+        """Positions a decode step makes for a row: 1, or the block length
+        of a model that generates by diffusion over blocks."""
+        return int(getattr(self.model, "block_length", 1))
 
     def _describe_params(self) -> Dict[str, int]:
         """Bytes of the parameter tree by dtype, from shapes alone."""
@@ -429,6 +463,11 @@ class LLMEngine:
         fn = self._decode_fns.get((rich, want_lp))
         if fn is not None:
             return fn
+        if self._block > 1:
+            fn = jax.jit(self._block_decode(rich, want_lp),
+                         donate_argnums=(1,))
+            self._decode_fns[(rich, want_lp)] = fn
+            return fn
         model = self.model
         K = max(1, self.cfg.decode_steps)
         L = max(1, self.cfg.max_logprobs)
@@ -488,6 +527,116 @@ class LLMEngine:
         self._decode_fns[(rich, want_lp)] = fn
         return fn
 
+    def _block_decode(self, rich: bool, want_lp: bool):
+        """The decode program of a model that generates by diffusion over
+        blocks of B positions: a window is `decode_steps / B` blocks. A
+        block is `denoising_steps` passes, each one forward over the block's
+        ids [rows, B] (its K/V written in place at lens .. lens+B-1, so all
+        B queries see the earlier blocks and this one), every position
+        sampled, and B / steps of the masked ones revealed by the model's
+        `remasking`; then the commit pass, one more forward over the
+        revealed ids that stores the block's K/V (its logits are not used,
+        so the compiler drops the head), and lens += B. Same arguments and
+        results as `decode`, with `last_tokens` [rows, B] the block a row is
+        on (a prompt's remainder, then MASK), the token of a position
+        reported with the logprobs of the pass that revealed it, and behind
+        the tokens [K, rows], in the one int32 result, [layers, 2] sums over
+        the window's forwards of the experts touched and of the fullest
+        expert's rows (whatever the model sows as `expert_load`)."""
+        model = self.model
+        B, T = self._block, model.denoising_steps
+        blocks = max(1, self.cfg.decode_steps) // B
+        L = max(1, self.cfg.max_logprobs)
+        mask_id = model.mask_token_id
+        by_confidence = model.remasking == "low_confidence_static"
+        sample = self._sampler(rich, want_lp, L)
+
+        def decode(params, caches, last_tokens, page_table, seq_lens,
+                   active, temps, top_ps, top_ks, keys, lora, lora_idx):
+            rows = last_tokens.shape[0]
+            write = jnp.broadcast_to(active[:, None], (rows, B))
+            per_pos = lambda a: jnp.repeat(a, B, axis=0)
+
+            def forward(caches, ids, lens):
+                (logits, caches), sown = model.apply(
+                    {"params": params}, ids,
+                    positions=lens[:, None] + jnp.arange(B)[None, :],
+                    paged_kv=caches, page_table=page_table,
+                    write_mask=write, seq_lens=lens + B,
+                    mutable=["expert_load"])
+                load = jax.tree.leaves(sown)
+                return logits, caches, (jnp.stack(load) if load else
+                                        jnp.zeros((0, 2), jnp.int32))
+
+            def denoise(_, carry):
+                caches, ids, lens, keys, rec, load = carry
+                logits, caches, seen = forward(caches, ids, lens)
+                logits = logits.astype(jnp.float32)  # [rows, B, V]
+                # every position samples from its row's chain, which moves
+                # on once a pass
+                pos_keys = jax.vmap(lambda k: jax.vmap(
+                    lambda j: jax.random.fold_in(k, j))(jnp.arange(B)))(keys)
+                toks, _, lp = sample(
+                    pos_keys.reshape(rows * B, -1),
+                    logits.reshape(rows * B, -1), per_pos(temps),
+                    per_pos(top_ps), per_pos(top_ks))
+                toks = toks.reshape(rows, B)
+                masked = ids == mask_id
+                if by_confidence:
+                    conf = jnp.where(masked, jnp.max(
+                        jax.nn.softmax(logits, axis=-1), axis=-1), -1.0)
+                    left = jnp.tril(jnp.ones((B, B), bool), -1)
+                    ahead = jnp.sum(
+                        (conf[:, None, :] > conf[:, :, None])
+                        | ((conf[:, None, :] == conf[:, :, None]) & left),
+                        axis=-1)
+                else:
+                    ahead = jnp.cumsum(masked, axis=-1) - masked
+                reveal = masked & (ahead < B // T)
+                ids = jnp.where(reveal, toks, ids)
+                if lp is not None:
+                    rec = tuple(
+                        jnp.where(reveal.reshape((rows, B) + (1,) * (
+                            new.ndim - 1)), new.reshape((rows, B)
+                                                        + new.shape[1:]), old)
+                        for old, new in zip(rec, lp))
+                nxt = jax.vmap(lambda k: jax.random.split(k)[1])(keys)
+                keys = jnp.where(active[:, None], nxt, keys)
+                return caches, ids, lens, keys, rec, load + seen
+
+            def block(i, carry):
+                caches, ids, lens, keys, out, out_lp, load = carry
+                rec = (jnp.zeros((rows, B), jnp.float32),
+                       jnp.zeros((rows, B, L), jnp.float32),
+                       jnp.zeros((rows, B, L), jnp.int32))
+                caches, ids, lens, keys, rec, load = jax.lax.fori_loop(
+                    0, T, denoise, (caches, ids, lens, keys, rec, load))
+                _, caches, seen = forward(caches, ids, lens)  # commit
+                at = lambda new, old: jax.lax.dynamic_update_slice(
+                    old, jnp.moveaxis(new, 1, 0),
+                    (i * B,) + (0,) * (old.ndim - 1))
+                out = at(ids, out)
+                if want_lp:
+                    out_lp = tuple(map(at, rec, out_lp))
+                return (caches, jnp.full_like(ids, mask_id), lens + B, keys,
+                        out, out_lp, load + seen)
+
+            K = blocks * B
+            out_lp = (jnp.zeros((K, rows), jnp.float32),
+                      jnp.zeros((K, rows, L), jnp.float32),
+                      jnp.zeros((K, rows, L), jnp.int32))
+            probe = jax.eval_shape(forward, caches, last_tokens, seq_lens)[2]
+            caches, last, lens, keys, out, out_lp, load = jax.lax.fori_loop(
+                0, blocks, block,
+                (caches, last_tokens, seq_lens, keys,
+                 jnp.zeros((K, rows), jnp.int32), out_lp,
+                 jnp.zeros(probe.shape, jnp.int32)))
+            packed = jnp.concatenate([out.reshape(-1), load.reshape(-1)])
+            return (packed, last, lens, caches, keys,
+                    out_lp if want_lp else None)
+
+        return decode
+
     def _prefill_fn(self, bucket: int, nb: int = 1, rich: bool = False,
                     want_lp: bool = False):
         """Batched prefill: `nb` sequences in ONE pass over the weights —
@@ -517,6 +666,10 @@ class LLMEngine:
                 paged_kv=caches, page_table=rows,
                 write_mask=mask, seq_lens=starts + true_lens,
                 lora=lora, lora_idx=lora_idx, slots=slots)
+            if self._block > 1:
+                # Cache fill only: the first block's passes sample its
+                # tokens, and with the logits unused no head is compiled.
+                return None, new_caches, all_keys, None
             last = logits[jnp.arange(nb), true_lens - 1].astype(
                 jnp.float32)  # [nb, V]
             keys = all_keys[slots]
@@ -667,7 +820,7 @@ class LLMEngine:
         # dispatch off the in-flight window's device state. Skip the chain
         # when every request ends inside the in-flight window — the chained
         # window would be pure waste.
-        if all(r.generated + K >= r.max_tokens
+        if all(r.generated + K - r.skip >= r.max_tokens
                for r in self.running.values()):
             self._process_window(self._inflight, out, why="all_finishing")
             self._inflight = None
@@ -707,12 +860,17 @@ class LLMEngine:
     def _dispatch_window(self, last=None, lens=None):
         rich, want_lp = self._sampling_flags(self.running.values())
         key = (rich, want_lp)
+        K, B = max(1, self.cfg.decode_steps), self._block
+        # Forwards this dispatch runs for every row: one a token, or for
+        # each block its denoising passes and the commit pass.
+        denoise = K if B == 1 else K // B * self.model.denoising_steps
         with _fr.span("ray_tpu.engine.dispatch_decode",
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
-                      steps=max(1, self.cfg.decode_steps),
-                      chained=last is not None,
+                      steps=K, chained=last is not None,
                       new_program=key not in self._decode_fns,
-                      state_rows=len(self.running) * self._state_layers):
+                      state_rows=len(self.running) * self._state_layers,
+                      block_length=B, denoise_passes=denoise,
+                      commit_passes=0 if B == 1 else K // B):
             toks, last, lens, self.caches, self._keys_dev, lp = \
                 self._run_program("decode", key, self._decode_fn(*key),
                                   self._decode_args(last, lens))
@@ -740,18 +898,31 @@ class LLMEngine:
             toks = np.asarray(toks)  # [K, B] (blocks here)
             if lp is not None:
                 lp = tuple(np.asarray(a) for a in lp)
+        load = {}
+        if self._block > 1:
+            # Behind the tokens: [layers, 2] sums over the window's forwards.
+            cut = max(1, self.cfg.decode_steps) * self.cfg.max_seqs
+            touched, fullest = toks[cut:].reshape(-1, 2).sum(axis=0)
+            load = {"experts_touched": int(touched),
+                    "expert_load_max": int(fullest)}
+            toks = toks[:cut].reshape(-1, self.cfg.max_seqs)
         if out is None:
             return False
         with _fr.span("ray_tpu.engine.emit") as sp:
             tokens, running = len(out), len(self.running)
-            self._emit_window(toks, lp, slots, out)
+            skipped = self._emit_window(toks, lp, slots, out)
             finished = running - len(self.running)  # one release each
-            sp.set(tokens=len(out) - tokens, finished=finished)
+            sp.set(tokens=len(out) - tokens, finished=finished,
+                   skipped=skipped, **load)
         return finished > 0
 
-    def _emit_window(self, toks, lp, slots, out: List[StepOutput]) -> None:
-        """The host loop over a window's tokens, now on the host."""
+    def _emit_window(self, toks, lp, slots, out: List[StepOutput]) -> int:
+        """The host loop over a window's tokens, now on the host. Returns
+        the positions it passed over as a prompt's remainder (block
+        generation)."""
         K = toks.shape[0]
+        block = self._block
+        skipped = 0
         for slot in slots:
             req = self.running.get(slot)
             if req is None:
@@ -759,10 +930,21 @@ class LLMEngine:
             if req.done:  # aborted externally (e.g. stop-string match)
                 self._release(slot)
                 continue
+            if block > 1:
+                # Every block of the window is committed: the next starts
+                # as MASK.
+                self.last_tokens[slot] = self.model.mask_token_id
             for j in range(K):
                 tok = int(toks[j, slot])
                 self.seq_lens[slot] += 1
-                self.last_tokens[slot] = tok
+                if req.skip:
+                    req.skip -= 1
+                    skipped += 1
+                    continue
+                if block == 1:
+                    self.last_tokens[slot] = tok
+                elif not req.generated:
+                    self._mark_first_token(req, slot, *req.prefilled)
                 req.generated += 1
                 finished = (req.generated >= req.max_tokens
                             or (req.stop_token is not None
@@ -780,6 +962,7 @@ class LLMEngine:
                     # compute (multi-step tradeoff); drop them.
                     self._release(slot)
                     break
+        return skipped
 
     def finish_request(self, request_id: str) -> bool:
         """Finish a request early (serving layer stop-string match /
@@ -809,7 +992,11 @@ class LLMEngine:
                       free_pages=self.allocator.num_free) as sp:
             entries = self._place_waiting()
             pending = self._dispatch_prefills(entries)
-            self._sync_first_tokens(pending, out)
+            if self._block == 1:
+                self._sync_first_tokens(pending, out)
+            else:  # nothing was sampled: the first window brings the tokens
+                for _, req, _, _, _, nb, cached in pending:
+                    req.prefilled = (cached, nb)
             sp.set(admitted=len(entries), waiting_left=len(self.waiting))
         return bool(entries)
 
@@ -872,7 +1059,10 @@ class LLMEngine:
             row = np.zeros((self.cfg.max_pages_per_seq,), np.int32)
             row[:len(pages)] = pages
             self.page_table[slot] = row
-            suffix = req.prompt_ids[cached_len:]
+            # Block generation prefills the prompt's whole blocks alone;
+            # the remainder (none: all MASK) is the first block's start.
+            whole = T - T % self._block
+            suffix = req.prompt_ids[cached_len:whole]
             S = len(suffix)
             bucket = next((b for b in self.cfg.prefill_buckets if b >= S),
                           self.cache_cfg.max_context)
@@ -903,8 +1093,13 @@ class LLMEngine:
                 self.prefix_cache.insert(digests, slot_pages[:n_full])
                 for p in slot_pages[len(shared):n_full]:
                     wave_page_owner[p] = idx
-            self.seq_lens[slot] = T
-            req.generated = 1
+            self.seq_lens[slot] = whole
+            if self._block == 1:
+                req.generated = 1  # prefill samples the first token
+            else:
+                req.skip = T - whole
+                self.last_tokens[slot, :req.skip] = req.prompt_ids[whole:]
+                self.last_tokens[slot, req.skip:] = self.model.mask_token_id
             entries.append((slot, req, suffix, cached_len, S, bucket, deps))
         return entries
 
@@ -919,7 +1114,8 @@ class LLMEngine:
         # deps always point to earlier admissions, so the earliest
         # remaining entry is always dispatchable (no deadlock).
         done: set = set()
-        remaining = list(range(len(entries)))
+        # (a prompt shorter than one block has nothing to prefill)
+        remaining = [j for j, e in enumerate(entries) if e[4] > 0]
         while remaining:
             bucket = entries[remaining[0]][5]
             batch = [j for j in remaining
@@ -982,16 +1178,7 @@ class LLMEngine:
         with _fr.span("ray_tpu.engine.prefill_sync", requests=len(pending)):
             for slot, req, dev_toks, lp, i, nb, cached_len in pending:
                 tok = int(np.asarray(dev_toks)[i])  # blocks on its wave
-                req.t_first_token = now = time.monotonic()
-                queue_s = req.t_admitted - req.t_enqueued
-                prefill_s = now - req.t_admitted
-                self._m_queue_wait.observe(queue_s)
-                self._m_prefill.observe(prefill_s)
-                _fr.mark("ray_tpu.request.first_token",
-                         rid=req.request_id, slot=slot,
-                         queue_ms=queue_s * 1e3, prefill_ms=prefill_s * 1e3,
-                         prompt=len(req.prompt_ids), cached=cached_len,
-                         nb=nb)
+                self._mark_first_token(req, slot, cached_len, nb)
                 self.last_tokens[slot] = tok
                 finished = (req.generated >= req.max_tokens
                             or (req.stop_token is not None
@@ -1006,6 +1193,20 @@ class LLMEngine:
                 out.append(so)
                 if finished:
                     self._release(slot)
+
+    def _mark_first_token(self, req: Request, slot: int, cached_len: int,
+                          nb: int) -> None:
+        """A request's first token has reached the host: stamp it, its queue
+        wait and its prefill time (admission to now)."""
+        req.t_first_token = now = time.monotonic()
+        queue_s = req.t_admitted - req.t_enqueued
+        prefill_s = now - req.t_admitted
+        self._m_queue_wait.observe(queue_s)
+        self._m_prefill.observe(prefill_s)
+        _fr.mark("ray_tpu.request.first_token", rid=req.request_id,
+                 slot=slot, queue_ms=queue_s * 1e3,
+                 prefill_ms=prefill_s * 1e3, prompt=len(req.prompt_ids),
+                 cached=cached_len, nb=nb)
 
     def _ensure_decode_pages(self, k: int = 1) -> None:
         """Each running slot is about to append up to k tokens starting at

@@ -30,6 +30,8 @@ FAMILIES = {
                     "ray_tpu.models.llama:LLAMA_SHARDING"),
     "olmo_hybrid": Family("ray_tpu.models.olmo_hybrid:OlmoHybridConfig",
                           "ray_tpu.models.olmo_hybrid:OlmoHybridModel"),
+    "sdar_moe": Family("ray_tpu.models.sdar_moe:SdarMoeConfig",
+                       "ray_tpu.models.sdar_moe:SdarMoeModel"),
 }
 
 

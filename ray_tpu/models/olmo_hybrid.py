@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.initializers import embed_init, kernel_init
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops.linear_attention import gdn_chunked, gdn_decode
@@ -107,45 +108,6 @@ def _conv_init(key, shape, dtype):
                               bound).astype(dtype)
 
 
-# Elements one compiled random generator fills at once: the TPU compiler's
-# time for a generator grows with its array (the embedding whole: 11 s; in
-# blocks of this size under one small loop: 1 s; compile, PR 29).
-_INIT_BLOCK = 1 << 22
-
-
-def _in_blocks(key, shape, draw):
-    """A 2-D array of `shape` from `draw(key, block_shape)`, block of rows by
-    block of rows under one small loop."""
-    rows, cols = shape
-    fit = max(1, _INIT_BLOCK // cols)
-    n = min((d for d in range(1, rows + 1)
-             if rows % d == 0 and rows // d <= fit), default=rows)
-    return jax.lax.map(lambda k: draw(k, (rows // n, cols)),
-                       jax.random.split(key, n)).reshape(shape)
-
-
-def _kernel_init(key, shape, dtype):
-    """A projection [fan_in, features]: flax's Dense default (lecun normal:
-    truncated at two standard deviations, variance 1 / fan_in), drawn in
-    float32 and rounded to `dtype` as a checkpoint's weights are, in blocks.
-    Not drawn in bf16 itself: that draw's uniform has seven bits and a mean
-    of 127/256, so every matrix gets a mean of -0.018 standard deviations,
-    and 16 layers deep nine tenths of the residual stream is one constant
-    vector whatever the prompt (PERF.md section 6, PR 29)."""
-    std = shape[0] ** -0.5 / 0.87962566103423978
-    return _in_blocks(key, shape, lambda k, block: (
-        std * jax.random.truncated_normal(k, -2.0, 2.0, block, jnp.float32)
-    ).astype(dtype))
-
-
-def _embed_init(key, shape, dtype):
-    """The embedding [vocabulary, hidden]: normal, rows of unit expected
-    norm."""
-    std = shape[1] ** -0.5
-    return _in_blocks(key, shape, lambda k, block: (
-        std * jax.random.normal(k, block, jnp.float32)).astype(dtype))
-
-
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
@@ -153,7 +115,7 @@ def _l2norm(x):
 def _dense(cfg: OlmoHybridConfig, features: int,
            name: Optional[str]) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, kernel_init=_kernel_init,
+                    param_dtype=cfg.param_dtype, kernel_init=kernel_init,
                     name=name)
 
 
@@ -163,7 +125,7 @@ def _norm(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Module:
 
 def _embed(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Embed:
     return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, embedding_init=_embed_init,
+                    param_dtype=cfg.param_dtype, embedding_init=embed_init,
                     name=name)
 
 

@@ -88,7 +88,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     seq_lens: jax.Array,
                     scale: Optional[float] = None,
                     use_kernel: Optional[bool] = None,
-                    mesh: Optional[Mesh] = None) -> jax.Array:
+                    mesh: Optional[Mesh] = None,
+                    block_length: int = 1) -> jax.Array:
     """Attention of q [B,S,H,D] over paged KV (causal by absolute position).
 
     q_positions [B,S]: absolute position of each query token; keys at
@@ -97,10 +98,17 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     decode on a TPU takes the Pallas kernel below instead, which walks the
     pages in HBM (O(actual pages) traffic, not O(max)). `mesh` is the
     engine's tensor-parallel mesh: the kernel needs it (it cannot be
-    partitioned by GSPMD), the gather path does not."""
+    partitioned by GSPMD), the gather path does not.
+
+    `block_length` > 1 (a power of two) is block diffusion's visibility:
+    positions come in aligned blocks of that many, and a query sees the keys
+    up to the end of its own block (and < seq_len). The decode step is then
+    one whole block a row, S = block_length queries that all see the same
+    keys, which the kernel takes as so many more heads."""
     if use_kernel is None:
-        use_kernel = q.shape[1] == 1 and jax.default_backend() == "tpu"
-    if use_kernel and q.shape[1] == 1:
+        use_kernel = (q.shape[1] == block_length
+                      and jax.default_backend() == "tpu")
+    if use_kernel and q.shape[1] == block_length:
         return paged_attention_decode_kernel(
             q, k_pages, v_pages, page_table, seq_lens, scale=scale,
             mesh=mesh)
@@ -117,6 +125,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         k.astype(jnp.float32)) * scale
     ctx = k.shape[1]
     k_pos = jnp.arange(ctx)[None, None, :]  # absolute position within slot
+    if block_length > 1:  # to the end of the query's own block
+        q_positions = q_positions | (block_length - 1)
     visible = (k_pos <= q_positions[:, :, None]) & (
         k_pos < seq_lens[:, None, None])
     logits = jnp.where(visible[:, None, :, :], logits, NEG_INF)
@@ -129,16 +139,17 @@ def paged_write_attend(q: jax.Array, k: jax.Array, v: jax.Array,
                        kv_pages: Tuple[jax.Array, jax.Array],
                        page_table: jax.Array, positions: jax.Array,
                        write_mask: jax.Array, seq_lens: jax.Array,
-                       mesh: Optional[Mesh] = None):
+                       mesh: Optional[Mesh] = None, block_length: int = 1):
     """One attention layer's step over its pool: this call's k and v
     [B,S,HK,D] written at `positions` [B,S] where `write_mask` allows, then
-    q [B,S,H,D] attended over the pool. Returns (out [B,S,H,D], (k_pages,
-    v_pages)): all a model file needs of the pool."""
+    q [B,S,H,D] attended over the pool (`block_length`: see
+    `paged_attention`). Returns (out [B,S,H,D], (k_pages, v_pages)): all a
+    model file needs of the pool."""
     k_pages, v_pages = kv_pages
     k_pages = paged_write(k_pages, k, page_table, positions, write_mask)
     v_pages = paged_write(v_pages, v, page_table, positions, write_mask)
     out = paged_attention(q, k_pages, v_pages, page_table, positions,
-                          seq_lens, mesh=mesh)
+                          seq_lens, mesh=mesh, block_length=block_length)
     return out, (k_pages, v_pages)
 
 
@@ -255,10 +266,15 @@ def paged_attention_decode_kernel(
         pages_per_chunk: Optional[int] = None,
         interpret: Optional[bool] = None,
         mesh: Optional[Mesh] = None) -> jax.Array:
-    """Pallas decode attention: q [B,1,H,D] over paged KV [P,ps,HK*D] without
+    """Pallas decode attention: q [B,S,H,D] over paged KV [P,ps,HK*D] without
     materializing the gathered context. Grid (B,); see _paged_decode_kernel
     for the DMA pipeline. `pages_per_chunk` defaults to what
     KV_CHUNK_VMEM_BYTES holds of this pool's pages, twice for K and for V.
+
+    All S queries of a row see the same keys, those below `seq_lens` (S = 1:
+    the new token; S > 1: one block of block diffusion, already written), so
+    they are folded under their KV heads as S times the query heads, order
+    (kv head, position, head of the group), and the kernel is the same.
 
     With a multi-device `mesh` the kernel runs under shard_map with the KV
     heads (and the query heads grouped under them) split over the tensor
@@ -267,9 +283,16 @@ def paged_attention_decode_kernel(
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
-    assert s == 1, "decode kernel expects one query token per sequence"
     _, ps, width = k_pages.shape
     hk = width // d
+    if s > 1:
+        fold = lambda t, a, c: t.reshape(b, a, hk, c, h // hk, d).transpose(
+            0, 3, 2, 1, 4, 5)
+        out = paged_attention_decode_kernel(
+            fold(q, s, 1).reshape(b, 1, s * h, d), k_pages, v_pages,
+            page_table, seq_lens, scale=scale,
+            pages_per_chunk=pages_per_chunk, interpret=interpret, mesh=mesh)
+        return fold(out, 1, s).reshape(b, s, h, d)
     if mesh is not None and mesh.size > 1:
         kv_spec = pages_spec(hk, mesh)
         q_spec = PartitionSpec(None, None, kv_spec[2])

@@ -29,9 +29,10 @@ ENGINE_SPANS = {
     "ray_tpu.engine.prefill_sync": {"requests"},
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
                                        "chained", "new_program",
-                                       "state_rows"},
+                                       "state_rows", "block_length",
+                                       "denoise_passes", "commit_passes"},
     "ray_tpu.engine.wait_tokens": {"why"},
-    "ray_tpu.engine.emit": {"tokens", "finished"},
+    "ray_tpu.engine.emit": {"tokens", "finished", "skipped"},
 }
 SERVER_SPANS = {
     "ray_tpu.server.deliver": {"outputs"},

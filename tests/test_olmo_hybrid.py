@@ -443,10 +443,10 @@ def test_bf16_weights_have_no_common_sign():
     than a draw made in bf16 itself (128, with a mean of -0.018 standard
     deviations: 16 layers deep the stream was one constant vector and the
     logits hardly depended on the prompt; PERF.md section 6, PR 29)."""
-    from ray_tpu.models import olmo_hybrid as oh
+    from ray_tpu.models.initializers import kernel_init
 
-    w = np.asarray(oh._kernel_init(jax.random.PRNGKey(0), (3840, 512),
-                                   jnp.bfloat16).astype(jnp.float32))
+    w = np.asarray(kernel_init(jax.random.PRNGKey(0), (3840, 512),
+                               jnp.bfloat16).astype(jnp.float32))
     std = 3840 ** -0.5
     assert abs(w.std() / std - 1.0) < 0.01
     assert abs(w.mean()) < 4 * std / np.sqrt(w.size)

@@ -161,6 +161,55 @@ def test_a_float32_use_keeps_a_leaf_float32():
                                    "down_kernel")} == {jnp.dtype(jnp.bfloat16)}
 
 
+@pytest.mark.parametrize("held", ["float32 masters", "bf16 as published"])
+def test_sdar_moe_keeps_its_router_float32_and_one_copy_of_its_experts(held):
+    """The block-diffusion MoE family: the router's kernel multiplies in
+    float32 and the norm scales are consumed in float32, so both stay;
+    every expert stack, projection, the embedding and the head are held in
+    bf16, rounded from float32 masters or, drawn in bf16 as published, the
+    very objects the initializer made (no second copy)."""
+    from ray_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeModel
+
+    param_dtype = jnp.float32 if held == "float32 masters" else jnp.bfloat16
+    model = SdarMoeModel(SdarMoeConfig.tiny(dtype=jnp.bfloat16,
+                                            param_dtype=param_dtype))
+    params = model.init_params(jax.random.PRNGKey(1))
+    after = serving_params(model, params)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(after)[0]:
+        name = path[-1].key
+        assert name in ("kernel", "embedding", "scale", "router", "gate_up",
+                        "down")
+        want = jnp.float32 if name in ("scale", "router") else jnp.bfloat16
+        assert leaf.dtype == want, jax.tree_util.keystr(path)
+    if param_dtype == jnp.bfloat16:
+        assert all(a is b for a, b in zip(jax.tree.leaves(after),
+                                          jax.tree.leaves(params)))
+    else:
+        mlp, was = after["layers_0"]["mlp"], params["layers_0"]["mlp"]
+        assert mlp["router"] is was["router"]
+        np.testing.assert_array_equal(
+            np.asarray(mlp["gate_up"]),
+            np.asarray(was["gate_up"].astype(jnp.bfloat16)))
+
+
+def test_sdar_moe_at_depth_six_is_held_in_8_72e9_bytes_of_bf16():
+    """What `ray_tpu.engine.params_placed` reports for the benchmark's cut,
+    from shapes alone: 4.36B parameters in bf16, the six routers and the
+    norm scales (6.4 MB) in float32."""
+    from ray_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeModel
+
+    model = SdarMoeModel(SdarMoeConfig(num_layers=6))
+    shapes = jax.eval_shape(lambda key: serving_params(model, model.init(
+        key, jnp.zeros((1, 4), jnp.int32))["params"]), jax.random.PRNGKey(0))
+    eng = LLMEngine.__new__(LLMEngine)
+    eng.params = shapes
+    held = eng._describe_params()
+    assert set(held) == {"bfloat16", "float32"}
+    assert 8.71e9 < held["bfloat16"] < 8.73e9
+    assert held["float32"] == 4 * (6 * (2048 * 128 + 2 * 2048 + 2 * 128)
+                                   + 2048)
+
+
 def test_stats_and_mark_say_what_the_replica_holds():
     from ray_tpu._private import flight_recorder as fr
     from ray_tpu.llm._internal.server import LLMServer

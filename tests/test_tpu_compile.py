@@ -188,13 +188,35 @@ def _pool_layout_changes(text, pool_elements):
     return found
 
 
+def _lower_block_decode(model, ec, sharding):
+    """`sizing.lower_decode` for a model that generates by blocks: what is
+    carried between windows is a block's ids [rows, block_length], where
+    `sizing` (the benchmark's, not this PR's to edit) describes one last
+    token a row."""
+    from benchmark import sizing
+
+    eng = sizing._bare_engine(model, ec)
+    b = eng.cfg.max_seqs
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    return eng._decode_fn(False, False).lower(
+        sizing.param_shapes(model, sharding),
+        sizing.cache_shapes(model, ec, sharding),
+        s((b, model.block_length), jnp.int32),
+        s((b, eng.cfg.max_pages_per_seq), jnp.int32), s((b,), jnp.int32),
+        s((b,), jnp.bool_), s((b,), jnp.float32), s((b,), jnp.float32),
+        s((b,), jnp.int32), s((b, 2), jnp.uint32), None, s((b,), jnp.int32))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("cell", ["decode-heavy", "hybrid-decode-heavy"])
+@pytest.mark.parametrize("cell", ["decode-heavy", "hybrid-decode-heavy",
+                                  "sdar-decode-heavy"])
 def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     """The chip compiler's HLO of a decode window and of a prefill, at the
     serving widths and engine shapes of Mistral's and of the hybrid's cell
     (depth cut to two attention layers and to one of each kind): no
-    instruction rewrites a whole pool. With kv-head-major pages the scatter
+    instruction rewrites a whole pool; nor do the repeated in-place writes
+    of block diffusion's denoising and commit passes (SDAR's cell, two
+    layers). With kv-head-major pages the scatter
     took the pool token major and the kernel as written, so every token
     step copied each layer's K and V from the one layout to the other (two
     such copies a layer in the loop, four more at its edges)."""
@@ -217,9 +239,12 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     model = family.model(kw)
     ec = manifest.traffic(made["traffic"])["engine_config"]
     one = SingleDeviceSharding(topology.devices[0])
-    lowered = (sizing.lower_decode(model, ec, one) if program == "decode"
-               else sizing.lower_prefill(model, ec, 128, ec["max_seqs"],
-                                         one))
+    if program == "prefill":
+        lowered = sizing.lower_prefill(model, ec, 128, ec["max_seqs"], one)
+    elif getattr(model, "block_length", 1) == 1:
+        lowered = sizing.lower_decode(model, ec, one)
+    else:
+        lowered = _lower_block_decode(model, ec, one)
     text = lowered.compile().as_text()
 
     # a layer's K (or V) pool is the largest array of the engine's cache

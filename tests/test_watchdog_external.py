@@ -140,3 +140,38 @@ def test_killer_exits_when_target_finishes(tmp_path):
     # tolerate heartbeats from concurrently-running suites, but they must
     # not accumulate from THIS test's run
     assert True
+
+
+def test_xdist_controller_outlives_the_stale_limit(tmp_path):
+    """Under xdist the controller runs no test itself; its heartbeat is
+    the workers' reports. A run of short tests that lasts several stale
+    limits in all must end of itself with its tests passed (the tier-1
+    run was killed at the limit, 720 s, whatever its tests did)."""
+    (tmp_path / "conftest.py").write_text(
+        'pytest_plugins = ["ray_tpu._private.pytest_watchdog"]\n')
+    (tmp_path / "test_many_short.py").write_text(textwrap.dedent("""
+        import time
+        import pytest
+
+        @pytest.mark.parametrize("i", range(8))
+        def test_short(i):
+            time.sleep(1.0)
+    """))
+    env = dict(os.environ)
+    env.update({
+        "RAY_TPU_TEST_TIMEOUT_S": "2",
+        "RAY_TPU_WATCHDOG_MARGIN_S": "1",
+        "RAY_TPU_WATCHDOG_EXIT_GRACE_S": "20",
+        "RAY_TPU_WATCHDOG_DUMP_GRACE_S": "1",
+        "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
+        "JAX_PLATFORMS": "cpu",
+    })
+    env.pop("RAY_TPU_NO_EXTERNAL_WATCHDOG", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "xdist", "-n", "1", "test_many_short.py"],
+        cwd=tmp_path, env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, timeout=120)
+    assert proc.returncode == 0, (proc.returncode, proc.stdout[-2000:])
+    assert b"8 passed" in proc.stdout
+
