@@ -173,10 +173,13 @@ class LLMEngine:
     A model with `block_length` B > 1 generates by diffusion over aligned
     blocks of B positions (`_block_decode`): prefill only fills the cache
     with the prompt's whole blocks, a decode window is `decode_steps / B`
-    blocks, and what is carried between windows is a block's ids, not one
-    last token. It runs under the same three limits as a model with state
-    layers (prefix sharing would hold: whole pages are whole blocks; it is
-    off because an admission that finds all its blocks cached is not built).
+    blocks of `denoising_steps` forwards each, and what is carried between
+    windows is two blocks' ids, not one last token: the block a row is on
+    and the one before it, whose K/V the next window's first forward stores
+    under its revealed ids. It runs under the same three limits as a model
+    with state layers (prefix sharing would hold: whole pages are whole
+    blocks; it is off because an admission that finds all its blocks cached
+    is not built).
     """
 
     def __init__(self, model, params, cfg: EngineConfig, mesh=None,
@@ -253,11 +256,12 @@ class LLMEngine:
             (cfg.max_seqs, cfg.max_pages_per_seq), np.int32)
         self.seq_lens = np.zeros((cfg.max_seqs,), np.int32)
         # What a row's next decode step is fed: its last token, or (block
-        # generation) the ids of the block it is on, MASK where unrevealed.
+        # generation) the ids of the block awaiting its commit, -1 where a
+        # row has none, then of the block it is on, MASK where unrevealed.
         self.last_tokens = (
             np.zeros((cfg.max_seqs,), np.int32) if self._block == 1 else
-            np.full((cfg.max_seqs, self._block), model.mask_token_id,
-                    np.int32))
+            np.tile(np.repeat(np.int32([-1, model.mask_token_id]),
+                              self._block), (cfg.max_seqs, 1)))
         self.temps = np.zeros((cfg.max_seqs,), np.float32)
         self.top_ps = np.ones((cfg.max_seqs,), np.float32)
         self.top_ks = np.zeros((cfg.max_seqs,), np.int32)
@@ -534,15 +538,26 @@ class LLMEngine:
         ids [rows, B] (its K/V written in place at lens .. lens+B-1, so all
         B queries see the earlier blocks and this one), every position
         sampled, and B / steps of the masked ones revealed by the model's
-        `remasking`; then the commit pass, one more forward over the
-        revealed ids that stores the block's K/V (its logits are not used,
-        so the compiler drops the head), and lens += B. Same arguments and
-        results as `decode`, with `last_tokens` [rows, B] the block a row is
-        on (a prompt's remainder, then MASK), the token of a position
-        reported with the logprobs of the pass that revealed it, and behind
-        the tokens [K, rows], in the one int32 result, [layers, 2] sums over
-        the window's forwards of the experts touched and of the fullest
-        expert's rows (whatever the model sows as `expert_load`)."""
+        `remasking`; then lens += B. The K/V its last pass left are those of
+        ids still partly MASK: the block is committed (its K/V stored under
+        its revealed ids) inside the first pass of the block that follows
+        it, which runs 2 * rows rows under the same page-table rows: row
+        (r, 0) the block before, at lens-B .. lens-1 and seeing keys below
+        lens, row (r, 1) the block the sequence is on. Every row's K/V is
+        written before any row attends, so (r, 1) sees what (r, 0) has just
+        stored, the weights are streamed once for both, and only the rows
+        (r, 1) are sampled. A request's last block is never committed:
+        nothing reads it.
+
+        Same arguments and results as `decode`, with `last_tokens`
+        [rows, 2B]: the block awaiting its commit (-1 where there is none: a
+        row just admitted, whose whole prompt blocks prefill has stored) and
+        the block a row is on (a prompt's remainder, then MASK); the token
+        of a position is reported with the logprobs of the pass that
+        revealed it, and behind the tokens [K, rows], in the one int32
+        result, [layers, 2] sums over the window's forwards of the experts
+        touched and of the fullest expert's rows (whatever the model sows as
+        `expert_load`)."""
         model = self.model
         B, T = self._block, model.denoising_steps
         blocks = max(1, self.cfg.decode_steps) // B
@@ -554,23 +569,25 @@ class LLMEngine:
         def decode(params, caches, last_tokens, page_table, seq_lens,
                    active, temps, top_ps, top_ks, keys, lora, lora_idx):
             rows = last_tokens.shape[0]
-            write = jnp.broadcast_to(active[:, None], (rows, B))
             per_pos = lambda a: jnp.repeat(a, B, axis=0)
 
-            def forward(caches, ids, lens):
+            def forward(caches, ids, starts, table, write, logits_from=0):
+                """ids [n, B] at positions starts .. starts+B-1 of the
+                page-table rows `table`, stored where `write` [n]; logits
+                of the rows from `logits_from` on."""
                 (logits, caches), sown = model.apply(
                     {"params": params}, ids,
-                    positions=lens[:, None] + jnp.arange(B)[None, :],
-                    paged_kv=caches, page_table=page_table,
-                    write_mask=write, seq_lens=lens + B,
+                    positions=starts[:, None] + jnp.arange(B)[None, :],
+                    paged_kv=caches, page_table=table,
+                    write_mask=jnp.broadcast_to(write[:, None], ids.shape),
+                    seq_lens=starts + B, logits_from=logits_from,
                     mutable=["expert_load"])
                 load = jax.tree.leaves(sown)
                 return logits, caches, (jnp.stack(load) if load else
                                         jnp.zeros((0, 2), jnp.int32))
 
-            def denoise(_, carry):
-                caches, ids, lens, keys, rec, load = carry
-                logits, caches, seen = forward(caches, ids, lens)
+            def reveal(logits, ids, keys, rec):
+                """A pass's sampling: B / steps more of `ids` revealed."""
                 logits = logits.astype(jnp.float32)  # [rows, B, V]
                 # every position samples from its row's chain, which moves
                 # on once a pass
@@ -592,40 +609,62 @@ class LLMEngine:
                         axis=-1)
                 else:
                     ahead = jnp.cumsum(masked, axis=-1) - masked
-                reveal = masked & (ahead < B // T)
-                ids = jnp.where(reveal, toks, ids)
+                show = masked & (ahead < B // T)
+                ids = jnp.where(show, toks, ids)
                 if lp is not None:
                     rec = tuple(
-                        jnp.where(reveal.reshape((rows, B) + (1,) * (
+                        jnp.where(show.reshape((rows, B) + (1,) * (
                             new.ndim - 1)), new.reshape((rows, B)
                                                         + new.shape[1:]), old)
                         for old, new in zip(rec, lp))
                 nxt = jax.vmap(lambda k: jax.random.split(k)[1])(keys)
-                keys = jnp.where(active[:, None], nxt, keys)
+                return ids, jnp.where(active[:, None], nxt, keys), rec
+
+            def fused(caches, before, ids, lens):
+                """A block's first pass with the commit of the block
+                `before` it riding along: rows (r, 0) then rows (r, 1). A
+                row with none (or under B tokens) runs its first half at
+                clamped positions, stored nowhere, for nothing."""
+                return forward(
+                    caches, jnp.concatenate([jnp.maximum(before, 0), ids]),
+                    jnp.concatenate([jnp.maximum(lens - B, 0), lens]),
+                    jnp.concatenate([page_table, page_table]),
+                    jnp.concatenate([active & (before[:, 0] >= 0), active]),
+                    logits_from=rows)
+
+            def denoise(_, carry):
+                caches, ids, lens, keys, rec, load = carry
+                logits, caches, seen = forward(caches, ids, lens, page_table,
+                                               active)
+                ids, keys, rec = reveal(logits, ids, keys, rec)
                 return caches, ids, lens, keys, rec, load + seen
 
             def block(i, carry):
-                caches, ids, lens, keys, out, out_lp, load = carry
+                caches, pair, lens, keys, out, out_lp, load = carry
                 rec = (jnp.zeros((rows, B), jnp.float32),
                        jnp.zeros((rows, B, L), jnp.float32),
                        jnp.zeros((rows, B, L), jnp.int32))
+                before, ids = pair[:, :B], pair[:, B:]
+                logits, caches, seen = fused(caches, before, ids, lens)
+                ids, keys, rec = reveal(logits, ids, keys, rec)
                 caches, ids, lens, keys, rec, load = jax.lax.fori_loop(
-                    0, T, denoise, (caches, ids, lens, keys, rec, load))
-                _, caches, seen = forward(caches, ids, lens)  # commit
+                    1, T, denoise, (caches, ids, lens, keys, rec,
+                                    load + seen))
                 at = lambda new, old: jax.lax.dynamic_update_slice(
                     old, jnp.moveaxis(new, 1, 0),
                     (i * B,) + (0,) * (old.ndim - 1))
                 out = at(ids, out)
                 if want_lp:
                     out_lp = tuple(map(at, rec, out_lp))
-                return (caches, jnp.full_like(ids, mask_id), lens + B, keys,
-                        out, out_lp, load + seen)
+                pair = jnp.concatenate([ids, jnp.full_like(ids, mask_id)], 1)
+                return caches, pair, lens + B, keys, out, out_lp, load
 
             K = blocks * B
             out_lp = (jnp.zeros((K, rows), jnp.float32),
                       jnp.zeros((K, rows, L), jnp.float32),
                       jnp.zeros((K, rows, L), jnp.int32))
-            probe = jax.eval_shape(forward, caches, last_tokens, seq_lens)[2]
+            probe = jax.eval_shape(fused, caches, last_tokens[:, :B],
+                                   last_tokens[:, B:], seq_lens)[2]
             caches, last, lens, keys, out, out_lp, load = jax.lax.fori_loop(
                 0, blocks, block,
                 (caches, last_tokens, seq_lens, keys,
@@ -862,15 +901,21 @@ class LLMEngine:
         key = (rich, want_lp)
         K, B = max(1, self.cfg.decode_steps), self._block
         # Forwards this dispatch runs for every row: one a token, or for
-        # each block its denoising passes and the commit pass.
+        # each block its denoising passes, the first of which commits the
+        # block before it. A row fresh from admission has none to commit in
+        # its first block (a chained window's rows all have one).
         denoise = K if B == 1 else K // B * self.model.denoising_steps
+        fresh = 0 if B == 1 or last is not None else sum(
+            int(self.last_tokens[slot, 0] < 0) for slot in self.running)
         with _fr.span("ray_tpu.engine.dispatch_decode",
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
                       steps=K, chained=last is not None,
                       new_program=key not in self._decode_fns,
                       state_rows=len(self.running) * self._state_layers,
                       block_length=B, denoise_passes=denoise,
-                      commit_passes=0 if B == 1 else K // B):
+                      commit_passes=0,
+                      fused_commits=0 if B == 1 else K // B,
+                      fresh_rows=fresh):
             toks, last, lens, self.caches, self._keys_dev, lp = \
                 self._run_program("decode", key, self._decode_fn(*key),
                                   self._decode_args(last, lens))
@@ -931,9 +976,10 @@ class LLMEngine:
                 self._release(slot)
                 continue
             if block > 1:
-                # Every block of the window is committed: the next starts
+                # The window's last block awaits its commit; the next starts
                 # as MASK.
-                self.last_tokens[slot] = self.model.mask_token_id
+                self.last_tokens[slot, :block] = toks[K - block:, slot]
+                self.last_tokens[slot, block:] = self.model.mask_token_id
             for j in range(K):
                 tok = int(toks[j, slot])
                 self.seq_lens[slot] += 1
@@ -1097,9 +1143,11 @@ class LLMEngine:
             if self._block == 1:
                 req.generated = 1  # prefill samples the first token
             else:
+                # (nothing awaits a commit: `_release` left the slot so)
                 req.skip = T - whole
-                self.last_tokens[slot, :req.skip] = req.prompt_ids[whole:]
-                self.last_tokens[slot, req.skip:] = self.model.mask_token_id
+                on = self.last_tokens[slot, self._block:]
+                on[:req.skip] = req.prompt_ids[whole:]
+                on[req.skip:] = self.model.mask_token_id
             entries.append((slot, req, suffix, cached_len, S, bucket, deps))
         return entries
 
@@ -1237,6 +1285,8 @@ class LLMEngine:
         self.allocator.release(slot)
         self._free_slots.append(slot)
         self.seq_lens[slot] = 0
+        if self._block > 1:  # its last block is never committed
+            self.last_tokens[slot, :self._block] = -1
         self.lora_idx[slot] = 0
         self.top_ps[slot] = 1.0
         self.top_ks[slot] = 0

@@ -15,10 +15,13 @@ and the mask token.
 Generation is the engine's: a block starts as MASK ids (after what is left
 of the prompt), `denoising_steps` forwards over the block each reveal
 `block_length / denoising_steps` of its masked positions by `remasking`
-(`sequential`: the leftmost; `low_confidence_static`: the most confident),
-a last forward over the revealed ids stores the block's K/V. The logits at a
-position are for that position (no shift), and MASK itself is never
-generated: its logit is minus infinity.
+(`sequential`: the leftmost; `low_confidence_static`: the most confident).
+The K/V the last of them leaves are those of ids still partly MASK, so the
+block's K/V are stored under its revealed ids by one more forward over it,
+which rides as further rows on the next block's first (`logits_from`: rows
+that only store their K/V get no logits). The logits at a position are for
+that position (no shift), and MASK itself is never generated: its logit is
+minus infinity.
 
 Serving cache (`init_cache`): paged K/V on every layer, as `LlamaModel`.
 """
@@ -249,9 +252,11 @@ class SdarMoeModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
                  page_table=None, write_mask=None, seq_lens=None, lora=None,
-                 lora_idx=None, slots=None):
+                 lora_idx=None, slots=None, logits_from: int = 0):
         """The engine's `apply` surface (`LlamaModel`'s). Without
-        `paged_kv`: the whole sequence under the block mask, no cache."""
+        `paged_kv`: the whole sequence under the block mask, no cache. The
+        rows before `logits_from` go through the layers for their K/V alone:
+        the final norm and the head run over the others."""
         cfg = self.cfg
         if lora is not None:
             raise NotImplementedError("SdarMoeModel has no LoRA banks")
@@ -268,7 +273,7 @@ class SdarMoeModel(nn.Module):
             x, kv_pages = SdarMoeLayer(cfg, name=f"layers_{i}")(
                 x, positions, kv_pages, paged)
             new_caches.append(kv_pages)
-        x = _norm(cfg, "norm")(x)
+        x = _norm(cfg, "norm")(x[logits_from:])
         logits = _dense(cfg, cfg.vocab_size, "lm_head")(x)
         logits = jnp.where(jnp.arange(cfg.vocab_size) == cfg.mask_token_id,
                            -jnp.inf, logits)

@@ -30,7 +30,8 @@ ENGINE_SPANS = {
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
                                        "chained", "new_program",
                                        "state_rows", "block_length",
-                                       "denoise_passes", "commit_passes"},
+                                       "denoise_passes", "commit_passes",
+                                       "fused_commits", "fresh_rows"},
     "ray_tpu.engine.wait_tokens": {"why"},
     "ray_tpu.engine.emit": {"tokens", "finished", "skipped"},
 }

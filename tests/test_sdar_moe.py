@@ -1,7 +1,8 @@
 """SDAR-MoE at a tiny size on the CPU (hidden 64, 4 / 2 heads of 32, 16
 experts of 32 routed top-8, two layers, float32, seeded): the block mask in
 `ops/paged_attention.py`, and the engine's block generation (cache-fill
-prefill, denoising and commit passes through pages, the prompt's remainder)
+prefill, denoising passes through pages of which a block's first commits the
+block before it, the prompt's remainder)
 against the plain reference `benchmark/references/sdar_moe.py` (no cache,
 every expert over every token). Logprobs and not tokens: with seeded weights
 the largest logit changes on rounding."""
@@ -19,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 from benchmark.manifest import Manifest  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.llm._internal.paged import PagedCacheConfig  # noqa: E402
+from ray_tpu.models.llama import apply_rope  # noqa: E402
 from ray_tpu.models.sdar_moe import (  # noqa: E402
     SdarMoeConfig,
     SdarMoeModel,
@@ -84,6 +86,42 @@ def _gap(reference, params, kw, prompt, outs):
     ref = np.asarray(reference.logprobs(params, ids, kw))[len(prompt) - 1:]
     return max(abs(float(ref[i, t]) - lp)
                for i, o in enumerate(outs) for t, lp in o.top_logprobs)
+
+
+def _dispatches(run):
+    """`run()`'s result and the arguments of the `dispatch_decode` spans it
+    left in the flight recorder."""
+    from ray_tpu._private import flight_recorder as fr
+
+    before = len(fr.dump_events())
+    got = run()
+    return got, [e["args"] for e in fr.dump_events()[before:]
+                 if e.get("kind") == "span"
+                 and e["name"] == "ray_tpu.engine.dispatch_decode"]
+
+
+def _pool_gap(eng, params, slot, ids):
+    """Largest gap, over every layer's K and V, between what the pool holds
+    at positions 0 .. len(ids)-1 of `slot`'s pages and what the model's
+    whole-sequence form (no cache, block mask) computes on `ids`: the
+    outputs of `k_norm` (then rotated) and of `v_proj`."""
+    n, ps, cfg = len(ids), eng.cfg.page_size, eng.model.cfg
+    _, state = eng.model.apply(
+        {"params": params}, jnp.asarray(ids, jnp.int32)[None],
+        capture_intermediates=lambda m, _: m.name in ("k_norm", "v_proj"),
+        mutable=["intermediates"])
+    pages = eng.page_table[slot, :-(-n // ps)]
+    gap = 0.0
+    for i, pool in enumerate(eng.caches):
+        seen = state["intermediates"][f"layers_{i}"]["self_attn"]
+        want = (apply_rope(seen["k_norm"]["__call__"][0],
+                           jnp.arange(n)[None], cfg.rope_theta),
+                seen["v_proj"]["__call__"][0])
+        for held, w in zip(pool, want):
+            held = np.asarray(held)[pages].reshape(-1, held.shape[-1])[:n]
+            gap = max(gap, float(np.abs(
+                held - np.asarray(w).reshape(n, -1)).max()))
+    return gap
 
 
 # -- the block mask in ops/paged_attention.py --------------------------------
@@ -177,7 +215,7 @@ def test_models_whole_sequence_form_is_the_references_clean_stream(tiny):
 def test_engine_matches_the_reference_for_every_prompt_remainder(
         tiny, prompt_len):
     """Prefill of the prompt's whole blocks, then four blocks of four
-    denoising passes and a commit pass through pages, for a prompt that
+    denoising passes through pages, for a prompt that
     leaves 0, 1, 2 and 3 tokens to its first block; 13 tokens, so the last
     block is cut by `max_tokens`."""
     model, params, kw, reference = tiny
@@ -251,11 +289,63 @@ def test_chained_windows_and_a_second_request_through_the_slot(tiny, pipeline):
     model, params, kw, reference = tiny
     eng = _engine(model, params, max_seqs=1, pipeline_dispatch=pipeline)
     first, second = _ids(17, seed=3), _ids(6, seed=4)
-    got = _run(eng, Request("a", first, max_tokens=27, logprobs=3),
-               Request("b", second, max_tokens=5, logprobs=3))
+    got, decode = _dispatches(lambda: _run(
+        eng, Request("a", first, max_tokens=27, logprobs=3),
+        Request("b", second, max_tokens=5, logprobs=3)))
     assert [len(got[r]) for r in "ab"] == [27, 5]
     assert _gap(reference, params, kw, first, got["a"]) < TOL
     assert _gap(reference, params, kw, second, got["b"]) < TOL
+    # The slot forgot the first request's last block: the second's first
+    # window had nothing to commit, and its pages hold its own blocks (a
+    # stale commit would have gone to positions 0-3, its prefilled prompt).
+    assert [d["fresh_rows"] for d in decode] == [1, 0, 0, 0, 1]
+    assert [d["chained"] for d in decode] == [False] + [pipeline] * 3 + [False]
+    assert (eng.last_tokens[0, :B] == -1).all()
+    ids = second + [o.token for o in got["b"]]
+    assert _pool_gap(eng, params, 0, ids[:8]) < 1e-5
+
+
+@pytest.mark.parametrize("prompt_len,pipeline", [
+    (12, True), (13, False), (14, True), (15, False)])
+def test_commit_inside_the_next_blocks_first_pass_stores_the_blocks_kv(
+        tiny, prompt_len, pipeline):
+    """Three windows, chained on the device or dispatched from the host's
+    mirrors, that end on position 35: every block but the last was committed
+    by the first pass of the block after it, inside a window and across two,
+    so the pool holds at positions 0-31 the K/V of the revealed ids, what a
+    forward over the whole sequence computes. The last block was never
+    committed: it holds what its last pass wrote, the K/V of ids of which
+    one was still MASK."""
+    model, params, _, _ = tiny
+    eng = _engine(model, params, max_seqs=1, pipeline_dispatch=pipeline)
+    prompt = _ids(prompt_len, seed=prompt_len)
+    got, decode = _dispatches(lambda: _run(
+        eng, Request("a", prompt, max_tokens=36 - prompt_len)))
+    assert [(d["chained"], d["fresh_rows"]) for d in decode] == [
+        (False, 1), (pipeline, 0), (pipeline, 0)]
+    ids = prompt + [o.token for o in got["a"]]
+    assert len(ids) == 36
+    assert _pool_gap(eng, params, 0, ids[:32]) < 1e-5
+    assert _pool_gap(eng, params, 0, ids) > 1e-3
+
+
+@pytest.mark.parametrize("prompt_len,max_tokens", [(3, 5), (14, 3)])
+def test_request_that_ends_in_its_first_window_commits_nothing(
+        tiny, prompt_len, max_tokens):
+    """A prompt under one block (nothing prefilled, length 0: the first
+    half of the fused pass runs at clamped positions and is stored nowhere)
+    and a request that stops inside its first window: one dispatch, every
+    row of it fresh, and the reference's logprobs."""
+    model, params, kw, reference = tiny
+    eng = _engine(model, params)
+    prompt = _ids(prompt_len, seed=prompt_len)
+    got, decode = _dispatches(lambda: _run(
+        eng, Request("a", prompt, max_tokens=max_tokens, logprobs=3)))
+    assert [(d["active"], d["fresh_rows"], d["fused_commits"])
+            for d in decode] == [(1, 1, 2)]
+    assert len(got["a"]) == max_tokens
+    assert _gap(reference, params, kw, prompt, got["a"]) < TOL
+    assert not eng.running and (eng.last_tokens[:, :B] == -1).all()
 
 
 def test_low_confidence_rule_matches_the_reference_pass_by_pass():
@@ -386,15 +476,16 @@ def test_server_serves_the_family_and_its_spans_count_the_passes():
     decode = args("ray_tpu.engine.dispatch_decode")
     assert decode and all(
         (d["block_length"], d["denoise_passes"], d["commit_passes"],
-         d["steps"]) == (4, 8, 2, 8) for d in decode)
+         d["fused_commits"], d["steps"]) == (4, 8, 0, 2, 8) for d in decode)
+    # ten prompt tokens and seven more: two windows, the first fresh
+    assert [d["fresh_rows"] for d in decode] == [1, 0]
     emit = [e for e in args("ray_tpu.engine.emit") if "skipped" in e]
     # ten prompt tokens: eight prefilled, two at the head of the first block
     assert sum(e["skipped"] for e in emit) == 2
     assert sum(e["tokens"] for e in emit) == 7
-    # a window is 2 blocks x 5 forwards x 2 layers of 16 experts, of which
-    # one row's four tokens touch at most 8 each
+    # a window is 2 blocks x 4 forwards x 2 layers of 16 experts
     for e in emit:
-        assert 0 < e["experts_touched"] <= 2 * 5 * 2 * 16
+        assert 0 < e["experts_touched"] <= 2 * 4 * 2 * 16
         assert e["experts_touched"] <= e["expert_load_max"] * 16
     first = args("ray_tpu.request.first_token")
     assert len(first) == 1 and first[0]["prompt"] == 10
@@ -416,8 +507,9 @@ def test_other_families_report_one_pass_a_token():
     decode = [e["args"] for e in events
               if e["name"] == "ray_tpu.engine.dispatch_decode"]
     assert decode and all(
-        (d["block_length"], d["denoise_passes"], d["commit_passes"])
-        == (1, 4, 0) for d in decode)
+        (d["block_length"], d["denoise_passes"], d["commit_passes"],
+         d["fused_commits"], d["fresh_rows"]) == (1, 4, 0, 0, 0)
+        for d in decode)
     emit = [e["args"] for e in events if e["name"] == "ray_tpu.engine.emit"]
     assert emit and all(e["skipped"] == 0 and "experts_touched" not in e
                         for e in emit)

@@ -190,9 +190,9 @@ def _pool_layout_changes(text, pool_elements):
 
 def _lower_block_decode(model, ec, sharding):
     """`sizing.lower_decode` for a model that generates by blocks: what is
-    carried between windows is a block's ids [rows, block_length], where
-    `sizing` (the benchmark's, not this PR's to edit) describes one last
-    token a row."""
+    carried between windows is two blocks' ids [rows, 2 * block_length] (the
+    one awaiting its commit and the one a row is on), where `sizing` (the
+    benchmark's, not this PR's to edit) describes one last token a row."""
     from benchmark import sizing
 
     eng = sizing._bare_engine(model, ec)
@@ -201,7 +201,7 @@ def _lower_block_decode(model, ec, sharding):
     return eng._decode_fn(False, False).lower(
         sizing.param_shapes(model, sharding),
         sizing.cache_shapes(model, ec, sharding),
-        s((b, model.block_length), jnp.int32),
+        s((b, 2 * model.block_length), jnp.int32),
         s((b, eng.cfg.max_pages_per_seq), jnp.int32), s((b,), jnp.int32),
         s((b,), jnp.bool_), s((b,), jnp.float32), s((b,), jnp.float32),
         s((b,), jnp.int32), s((b, 2), jnp.uint32), None, s((b,), jnp.int32))
@@ -215,8 +215,8 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     serving widths and engine shapes of Mistral's and of the hybrid's cell
     (depth cut to two attention layers and to one of each kind): no
     instruction rewrites a whole pool; nor do the repeated in-place writes
-    of block diffusion's denoising and commit passes (SDAR's cell, two
-    layers). With kv-head-major pages the scatter
+    of block diffusion's denoising passes, the first of a block scattering
+    two blocks a row (SDAR's cell, two layers). With kv-head-major pages the scatter
     took the pool token major and the kernel as written, so every token
     step copied each layer's K and V from the one layout to the other (two
     such copies a layer in the loop, four more at its edges)."""
