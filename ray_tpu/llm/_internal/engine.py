@@ -131,6 +131,13 @@ def _leaf_bytes(x) -> int:
     return int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
 
 
+def _laid_out_bytes(x) -> int:
+    """Bytes of an array on the device, whose tiles are 128 lanes wide: the
+    minor axis is padded to a multiple of 128."""
+    lanes = -(-x.shape[-1] // 128) * 128
+    return _leaf_bytes(x) // x.shape[-1] * lanes
+
+
 def _signature(args) -> Dict[str, tuple]:
     """What a jitted function's cache tells calls apart by, per argument
     leaf: shape, dtype, weak type, sharding, committed to it or not."""
@@ -169,6 +176,11 @@ class LLMEngine:
     from zero and decode updates in place. A model with state layers runs
     without LoRA banks, `param_transform` or prefix sharing (each is built
     for K/V layers only) and, having no sharding rules, without a mesh.
+
+    A model that says `num_logits_to_keep = 1` gets its prefill's final norm
+    and head on each row's last prompt position only (`logits_at`): logits
+    [nb, 1, V] where the other families compute [nb, bucket, V] and keep a
+    row.
 
     A model with `block_length` B > 1 generates by diffusion over aligned
     blocks of B positions (`_block_decode`): prefill only fills the cache
@@ -323,6 +335,13 @@ class LLMEngine:
         of a model that generates by diffusion over blocks."""
         return int(getattr(self.model, "block_length", 1))
 
+    @property
+    def _head_last(self) -> bool:
+        """Whether a prefill runs the head on each row's last prompt
+        position only: the model says so (`num_logits_to_keep`), as it says
+        `state_layer_ids`, and takes `logits_at`."""
+        return getattr(self.model, "num_logits_to_keep", 0) == 1
+
     def _describe_params(self) -> Dict[str, int]:
         """Bytes of the parameter tree by dtype, from shapes alone."""
         held: Dict[str, int] = {}
@@ -332,9 +351,11 @@ class LLMEngine:
         return held
 
     def _describe_cache(self) -> Dict[str, int]:
-        """Layers and bytes of the cache by kind, from shapes alone."""
+        """Layers and bytes of the cache by kind, from shapes alone and as
+        the device lays them out: a minor axis fills whole lanes."""
         state = set(self.model.state_layer_ids)
-        size = lambda layer: sum(map(_leaf_bytes, jax.tree.leaves(layer)))
+        size = lambda layer: sum(map(_laid_out_bytes,
+                                     jax.tree.leaves(layer)))
         return {
             "kv_layers": len(self.caches) - len(state),
             "state_layers": len(state),
@@ -700,16 +721,20 @@ class LLMEngine:
             # into its page-table row); causal within each sequence.
             positions = starts[:, None] + jnp.arange(bucket)[None, :]
             mask = jnp.arange(bucket)[None, :] < true_lens[:, None]
+            # The head on one position a row where the model takes it so:
+            # logits [nb, 1, V], not [nb, bucket, V] of which one row is kept.
+            at = {"logits_at": true_lens - 1} if self._head_last else {}
             logits, new_caches = model.apply(
                 {"params": params}, ids, positions=positions,
                 paged_kv=caches, page_table=rows,
                 write_mask=mask, seq_lens=starts + true_lens,
-                lora=lora, lora_idx=lora_idx, slots=slots)
+                lora=lora, lora_idx=lora_idx, slots=slots, **at)
             if self._block > 1:
                 # Cache fill only: the first block's passes sample its
                 # tokens, and with the logits unused no head is compiled.
                 return None, new_caches, all_keys, None
-            last = logits[jnp.arange(nb), true_lens - 1].astype(
+            last = (logits[:, 0] if at else
+                    logits[jnp.arange(nb), true_lens - 1]).astype(
                 jnp.float32)  # [nb, V]
             keys = all_keys[slots]
             toks, nxt, lp = sample(keys, last, temps, top_ps, top_ks)
@@ -1178,7 +1203,10 @@ class LLMEngine:
                           cached_tokens=sum(w[3] for w in wave), rich=rich,
                           want_lp=want_lp,
                           new_program=key not in self._prefill_fns,
-                          state_rows=nb * self._state_layers):
+                          state_rows=nb * self._state_layers,
+                          scan_positions=nb * bucket * self._state_layers,
+                          head_rows=(0 if self._block > 1 else nb
+                                     if self._head_last else nb * bucket)):
                 dev_toks, lp = self._prefill_wave(key, wave)
             for i, (slot, req, _, cached_len, _) in enumerate(wave):
                 pending.append((slot, req, dev_toks, lp, i, nb, cached_len))

@@ -32,6 +32,8 @@ FAMILIES = {
                           "ray_tpu.models.olmo_hybrid:OlmoHybridModel"),
     "sdar_moe": Family("ray_tpu.models.sdar_moe:SdarMoeConfig",
                        "ray_tpu.models.sdar_moe:SdarMoeModel"),
+    "jamba": Family("ray_tpu.models.jamba:JambaConfig",
+                    "ray_tpu.models.jamba:JambaModel"),
 }
 
 

@@ -59,6 +59,14 @@ REPLICA_NAME_PREFIX = "SERVE_REPLICA::"
 # A replica still booting (worker spawn + model load) gets this long
 # before an unhealthy check means "replace".
 STARTUP_GRACE_S = 180.0
+# A booted replica that does not answer a health check in time (10 s) is
+# taken out of routing at once and replaced only when this many checks in a
+# row time out (some 30 s of silence; the reference's default health check
+# timeout is 30 s): its process may hold the interpreter for seconds at a
+# stretch (reducing a profiler trace of 800k events took 11 s; my chip run,
+# PR 37) and answer again. A dead actor, or a check that raises, is replaced
+# at the first.
+HEALTH_TIMEOUTS_TO_REPLACE = 3
 
 
 class ServeController:
@@ -442,6 +450,7 @@ class ServeController:
                                 float(result.get("shed_delta", 0) or 0),
                                 now)
                 info.healthy = True
+                info.health_timeouts = 0
                 if not getattr(info, "booted", False):
                     info.booted = True
                     self._note_boot_success(name)
@@ -455,11 +464,16 @@ class ServeController:
                 # host. Only replace once it EXCEEDS the grace
                 # window or is definitively dead. While in grace
                 # it is marked unhealthy so routing skips it.
-                from ray_tpu.exceptions import ActorDiedError
+                from ray_tpu.exceptions import ActorDiedError, GetTimeoutError
 
                 age = time.monotonic() - info.created_at
                 dead = isinstance(e, ActorDiedError)
-                if not dead and age < STARTUP_GRACE_S:
+                silent = 0
+                if isinstance(e, GetTimeoutError):
+                    silent = info.health_timeouts = getattr(
+                        info, "health_timeouts", 0) + 1
+                if not dead and (age < STARTUP_GRACE_S or 0 < silent
+                                 < HEALTH_TIMEOUTS_TO_REPLACE):
                     info.healthy = False
                     if was_healthy:
                         # Routing filters on healthy: push the

@@ -25,7 +25,8 @@ ENGINE_SPANS = {
                              "free_pages"},
     "ray_tpu.engine.prefill_dispatch": {"bucket", "nb", "tokens",
                                         "cached_tokens", "rich", "want_lp",
-                                        "new_program", "state_rows"},
+                                        "new_program", "state_rows",
+                                        "scan_positions", "head_rows"},
     "ray_tpu.engine.prefill_sync": {"requests"},
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
                                        "chained", "new_program",

@@ -75,7 +75,8 @@ def _kernel_names(text):
         if "tpu_custom_call" in line and " = " in line:
             instruction = line.split(" = ", 1)[0]
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv") if k in instruction}
+                                 "flash_bwd_dkv", "ssm_scan")
+                     if k in instruction}
     return held
 
 
@@ -255,6 +256,79 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     assert _pool_layout_changes(text, math.prod(pool.shape)) == []
     if program == "decode":
         assert "paged_decode" in _kernel_names(text)
+
+
+def _jamba_at_depth(layers=None):
+    """(`jamba-prompt-heavy`'s family, its model at `layers` layers or all,
+    its engine shapes)."""
+    from benchmark.manifest import Manifest
+
+    manifest = Manifest(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    made = manifest.cell("jamba-prompt-heavy")
+    cfg = manifest.config(made["config"])
+    family = manifest.family(cfg["family"])
+    kw = family.model_kwargs(cfg)
+    if layers is not None:
+        kw["num_layers"] = layers
+    return family.model(kw), manifest.traffic(made["traffic"])[
+        "engine_config"]
+
+
+def _program_bytes(model, ec):
+    from benchmark import sizing
+
+    return sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+               for tree in (sizing.param_shapes(model, None),
+                            sizing.cache_shapes(model, ec, None))
+               for x in jax.tree.leaves(tree))
+
+
+def test_jamba_decode_updates_the_state_pool_in_place(topology, monkeypatch):
+    """The chip compiler's HLO of `jamba-prompt-heavy`'s decode window at the
+    published widths (eight layers: seven Mamba layers and the attention
+    layer after them): the one-token step is plain `jax.numpy` on the donated
+    pool, and no instruction rewrites a layer's float32 state [8, 16, 5120]
+    or a K/V pool; the attention layer's 20 query heads share one K/V head in
+    the paged kernel."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _jamba_at_depth(8)
+    one = SingleDeviceSharding(topology.devices[0])
+    text = sizing.lower_decode(model, ec, one).compile().as_text()
+    caches = sizing.cache_shapes(model, ec, None)
+    state, pages = caches[0][1], caches[7][0]
+    assert (state.shape, state.dtype) == ((8, 16, 5120), jnp.float32)
+    assert pages.shape == (8 * 36 + 1, 64, 128)
+    assert f"f32[{','.join(map(str, state.shape))}]" in text
+    for pool in (state, pages):
+        assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert "paged_decode" in _kernel_names(text)
+
+
+def test_jamba_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch):
+    """Prefill of 8 prompts in the 2,048 bucket, the largest program of
+    `jamba-prompt-heavy`: compiled at eight layers (the temporaries are a
+    layer's: the attention layer's float32 scores [8, 20, 2048, 2304], 3.0
+    GB, are the largest) with the other twenty layers' weights and cache
+    added from their shapes, it peaks under 14.75 GiB of a v5e's 15.75; the
+    scan is the Pallas kernel, and no logits of every position are made."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _jamba_at_depth(8)
+    whole, _ = _jamba_at_depth()
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = sizing.lower_prefill(model, ec, 2048, 8, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    rest = (_program_bytes(whole, ec) - _program_bytes(model, ec)) / sizing.GIB
+    assert 3.5 < rest < 4.2            # 20 of 28 layers' weights and state
+    assert parts["temp"] > 2.8         # the scores of a wave are there
+    assert peak + rest < 14.75
+    text = compiled.as_text()
+    assert "ssm_scan" in _kernel_names(text)
+    assert "[8,2048,65536]" not in text and "f32[8,65536]" in text
 
 
 # ---------------------------------------------------------------------------
