@@ -24,7 +24,7 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +56,9 @@ class EngineConfig:
     prefill_buckets: Tuple[int, ...] = (32, 128, 512, 2048)
     # Decode iterations per jitted dispatch (multi-step scheduling, like
     # vLLM's num_scheduler_steps): amortizes host dispatch over K tokens at
-    # the cost of up to K-1 wasted tokens past a stop condition.
+    # the cost of up to K-1 wasted tokens past a stop condition. The longest
+    # a window runs: `_window_steps` halves it while a request could be
+    # admitted at the window's end.
     decode_steps: int = 8
     # Static width of the per-token top-logprob report (requests may ask
     # for fewer; more than this raises at add_request).
@@ -124,6 +126,18 @@ class StepOutput:
     # (id, logprob) alternatives — populated when the request asked.
     logprob: Optional[float] = None
     top_logprobs: Optional[List[Tuple[int, float]]] = None
+
+
+class _Window(NamedTuple):
+    """A dispatched decode window: its device results (tokens [K, B] of
+    which the first `steps` rows are filled, final last_tokens and seq_lens,
+    logprobs or None), the slots it was dispatched for and its token steps."""
+    toks: Any
+    last: Any
+    lens: Any
+    lp: Any
+    slots: frozenset
+    steps: int
 
 
 def _leaf_bytes(x) -> int:
@@ -305,10 +319,8 @@ class LLMEngine:
         self.lora_idx = np.zeros((cfg.max_seqs,), np.int32)
         if cfg.lora_rank > 0:
             self.lora_banks = self._init_lora_banks()
-        # Pipelined dispatch state: the in-flight window's device arrays
-        # (tokens [K,B], final last_tokens [B], final seq_lens [B]) plus
-        # the slot set it was dispatched for.
-        self._inflight: Optional[Tuple[Any, Any, Any, frozenset]] = None
+        # Pipelined dispatch state: the window in flight.
+        self._inflight: Optional[_Window] = None
         # Compile record: (kind, key) -> [jit cache size after the last
         # call, argument signature it was last traced for], and the last
         # records of programs built and retraced.
@@ -517,7 +529,11 @@ class LLMEngine:
             return toks, new_caches, nxt, lp
 
         def decode(params, caches, last_tokens, page_table, seq_lens,
-                   active, temps, top_ps, top_ks, keys, lora, lora_idx):
+                   active, temps, top_ps, top_ks, keys, lora, lora_idx,
+                   steps=None):
+            """`steps` (traced, at most K) token steps for every row; the
+            results keep K rows, of which the first `steps` are filled. Left
+            out (whoever lowers the program from shapes alone), K."""
             B = last_tokens.shape[0]
             out = jnp.zeros((K, B), jnp.int32)
             out_lp = jnp.zeros((K, B), jnp.float32)
@@ -540,7 +556,7 @@ class LLMEngine:
 
             (caches, last, lens, keys, out, out_lp, out_tv, out_ti) = \
                 jax.lax.fori_loop(
-                    0, K, body,
+                    0, K if steps is None else steps, body,
                     (caches, last_tokens, seq_lens, keys, out, out_lp,
                      out_tv, out_ti))
             # Final last_tokens/seq_lens feed the NEXT window's dispatch
@@ -880,17 +896,17 @@ class LLMEngine:
                 self._process_window(self._inflight, out)
                 self._inflight = None
             return
-        # Pipelined: cover the NEXT window's writes too, then chain the
-        # dispatch off the in-flight window's device state. Skip the chain
-        # when every request ends inside the in-flight window — the chained
-        # window would be pure waste.
-        if all(r.generated + K - r.skip >= r.max_tokens
+        # Pipelined: cover the NEXT window's writes too (both at their
+        # longest), then chain the dispatch off the in-flight window's
+        # device state. Skip the chain when every request ends inside the
+        # in-flight window — the chained window would be pure waste.
+        if all(r.generated + self._inflight.steps - r.skip >= r.max_tokens
                for r in self.running.values()):
             self._process_window(self._inflight, out, why="all_finishing")
             self._inflight = None
             return
         self._ensure_decode_pages(2 * K)
-        nxt = self._dispatch_window(*self._inflight[1:3])
+        nxt = self._dispatch_window(self._inflight.last, self._inflight.lens)
         finished = self._process_window(self._inflight, out, why="chained")
         if finished:
             # The chained window ran with pre-finish control state. Its
@@ -905,14 +921,33 @@ class LLMEngine:
         else:
             self._inflight = nxt
 
-    def _decode_args(self, last=None, lens=None) -> tuple:
+    def _window_steps(self) -> int:
+        """Token steps of the window about to be dispatched: `decode_steps`
+        when nobody can be admitted at its end (no slot is free, or the
+        head of the queue finds too few pages: `_admit` has just left it
+        there), half of that while a slot is free, since a request that
+        arrives meanwhile waits for all that is on the device: this window
+        and the rest of the one before it. Block generation keeps
+        `decode_steps`: its program's two blocks a window are a static count
+        (the carry and the fused commit were measured at that chain), and
+        `experts_touched_pct` reads every window as the same forwards."""
+        full = max(1, self.cfg.decode_steps)
+        if self._block > 1 or not self._free_slots:
+            return full
+        if self.waiting and not self.allocator.can_allocate(
+                len(self.waiting[0].prompt_ids) + 1):
+            return full
+        return max(1, full // 2)
+
+    def _decode_args(self, last=None, lens=None, steps=None) -> tuple:
         """Arguments of the decode program: control state from the host
         mirrors, except `last`/`lens` when chaining off a window that is
-        still on the device."""
+        still on the device, then the window's token steps (the whole
+        `decode_steps` if not given)."""
         active = np.zeros((self.cfg.max_seqs,), bool)
         for slot in self.running:
             active[slot] = True
-        return (
+        args = (
             self.params, self.caches,
             self._dev(self.last_tokens) if last is None else last,
             self._dev(self.page_table),
@@ -920,11 +955,15 @@ class LLMEngine:
             self._dev(active), self._dev(self.temps),
             self._dev(self.top_ps), self._dev(self.top_ks),
             self._keys_dev, self.lora_banks, self._dev(self.lora_idx))
+        if self._block > 1:  # the block program's count of blocks is static
+            return args
+        return args + (self._dev(
+            np.int32(steps or max(1, self.cfg.decode_steps))),)
 
-    def _dispatch_window(self, last=None, lens=None):
+    def _dispatch_window(self, last=None, lens=None) -> _Window:
         rich, want_lp = self._sampling_flags(self.running.values())
         key = (rich, want_lp)
-        K, B = max(1, self.cfg.decode_steps), self._block
+        K, B = self._window_steps(), self._block
         # Forwards this dispatch runs for every row: one a token, or for
         # each block its denoising passes, the first of which commits the
         # block before it. A row fresh from admission has none to commit in
@@ -934,7 +973,8 @@ class LLMEngine:
             int(self.last_tokens[slot, 0] < 0) for slot in self.running)
         with _fr.span("ray_tpu.engine.dispatch_decode",
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
-                      steps=K, chained=last is not None,
+                      steps=K, free_slots=len(self._free_slots),
+                      chained=last is not None,
                       new_program=key not in self._decode_fns,
                       state_rows=len(self.running) * self._state_layers,
                       block_length=B, denoise_passes=denoise,
@@ -943,8 +983,8 @@ class LLMEngine:
                       fresh_rows=fresh):
             toks, last, lens, self.caches, self._keys_dev, lp = \
                 self._run_program("decode", key, self._decode_fn(*key),
-                                  self._decode_args(last, lens))
-        return (toks, last, lens, lp, frozenset(self.running))
+                                  self._decode_args(last, lens, K))
+        return _Window(toks, last, lens, lp, frozenset(self.running), K)
 
     def lowered_decode_text(self) -> str:
         """StableHLO of the greedy decode program as this process lowers
@@ -963,7 +1003,7 @@ class LLMEngine:
         outputs. out=None discards (pipeline drain). `why` names what made
         the caller wait for this window (the span's argument). Returns True
         if any slot finished."""
-        toks, _, _, lp, slots = window
+        toks, _, _, lp, slots, steps = window
         with _fr.span("ray_tpu.engine.wait_tokens", why=why):
             toks = np.asarray(toks)  # [K, B] (blocks here)
             if lp is not None:
@@ -978,6 +1018,7 @@ class LLMEngine:
             toks = toks[:cut].reshape(-1, self.cfg.max_seqs)
         if out is None:
             return False
+        toks = toks[:steps]  # the rows the window filled
         with _fr.span("ray_tpu.engine.emit") as sp:
             tokens, running = len(out), len(self.running)
             skipped = self._emit_window(toks, lp, slots, out)

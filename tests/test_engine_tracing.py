@@ -29,7 +29,8 @@ ENGINE_SPANS = {
                                         "scan_positions", "head_rows"},
     "ray_tpu.engine.prefill_sync": {"requests"},
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
-                                       "chained", "new_program",
+                                       "free_slots", "chained",
+                                       "new_program",
                                        "state_rows", "block_length",
                                        "denoise_passes", "commit_passes",
                                        "fused_commits", "fresh_rows"},
@@ -225,8 +226,11 @@ def test_wait_tokens_says_why(traced):
 
 def test_decode_rows_and_admission_counters_are_exact(traced):
     for e in _named(traced, "ray_tpu.engine.dispatch_decode"):
-        assert e["stats"]["max_seqs"] == 2 and e["stats"]["steps"] == 2
+        assert e["stats"]["max_seqs"] == 2
         assert 1 <= e["stats"]["active"] <= 2
+        # half a window of two while one of the two slots is free
+        assert e["stats"]["free_slots"] == 2 - e["stats"]["active"]
+        assert e["stats"]["steps"] == (1 if e["stats"]["free_slots"] else 2)
     assert {e["stats"]["active"] for e in
             _named(traced, "ray_tpu.engine.dispatch_decode")} == {1, 2}
     pre = _named(traced, "ray_tpu.engine.prefill_dispatch")

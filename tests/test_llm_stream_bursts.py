@@ -15,7 +15,10 @@ import pytest
 
 from ray_tpu._private import flight_recorder as fr
 
-DECODE_STEPS = 8
+DECODE_STEPS = 16
+# These requests run one at a time in two slots: a slot is free, so the
+# engine runs half windows.
+WINDOW = DECODE_STEPS // 2
 CONFIG = {"model": "tiny", "model_id": "tiny-bursts", "seed": 7,
           "model_config": {"vocab_size": 300},
           "engine_config": {"max_seqs": 2, "page_size": 4,
@@ -150,14 +153,14 @@ def test_body_is_the_per_token_sequence_of_frames(app, api):
 @pytest.mark.parametrize("n", [1, 9, 32])
 def test_one_item_per_engine_step(app, api, n):
     strs, items = _stream(app, api, max_tokens=n)
-    assert len(items) <= 1 + math.ceil((n - 1) / DECODE_STEPS) + 3
+    assert len(items) <= 1 + math.ceil((n - 1) / WINDOW) + 3
     tokens = app.server.generate_all(_prompt_ids(app, api),
                                      max_tokens=n)["tokens"]
     # The first token is a step of its own (the prefill's), and so an item
     # of its own; the frames of a window travel together, a step that made
     # no frame makes no item, and the closing frames ride with the last.
-    steps = [tokens[:1]] + [tokens[i:i + DECODE_STEPS]
-                            for i in range(1, n, DECODE_STEPS)]
+    steps = [tokens[:1]] + [tokens[i:i + WINDOW]
+                            for i in range(1, n, WINDOW)]
     want = [_visible(app, tokens[:1])]
     seen = 1
     for step in steps[1:]:
@@ -188,8 +191,8 @@ def test_stop_string_inside_a_burst(app, api):
     # make, and that the text does not hold earlier: the match falls inside
     # a burst, with tokens of the same step behind it.
     at = next(
-        i for i in range(1 + DECODE_STEPS, n - DECODE_STEPS)
-        if 1 <= (i - 1) % DECODE_STEPS <= DECODE_STEPS - 4
+        i for i in range(1 + WINDOW, n - WINDOW)
+        if 1 <= (i - 1) % WINDOW <= WINDOW - 4
         and len(deltas[i]) == 1 and len(deltas[i + 1]) == 1
         and text.find(deltas[i] + deltas[i + 1]) == len("".join(deltas[:i])))
     stop = deltas[at] + deltas[at + 1]
@@ -210,7 +213,7 @@ def test_stop_string_inside_a_burst(app, api):
 
 
 def test_generate_yields_one_dict_per_token(app):
-    n = 1 + 2 * DECODE_STEPS + 3
+    n = 1 + 2 * WINDOW + 3
     items = list(app.server.generate([5, 17, 42], max_tokens=n))
     assert len(items) == n
     assert all(isinstance(i["token"], int) for i in items)
@@ -219,7 +222,7 @@ def test_generate_yields_one_dict_per_token(app):
     # `more` counts down inside a step: the prefill's token alone, then
     # whole windows, then what was left of the last one
     assert [i["more"] for i in items] == (
-        [0] + 2 * list(range(DECODE_STEPS - 1, -1, -1)) + [2, 1, 0])
+        [0] + 2 * list(range(WINDOW - 1, -1, -1)) + [2, 1, 0])
     assert "delivered_s" in items[-1]
     assert not any("delivered_s" in i for i in items[:-1])
     assert [i["token"] for i in items] == app.server.generate_all(

@@ -189,23 +189,28 @@ def _pool_layout_changes(text, pool_elements):
     return found
 
 
-def _lower_block_decode(model, ec, sharding):
-    """`sizing.lower_decode` for a model that generates by blocks: what is
-    carried between windows is two blocks' ids [rows, 2 * block_length] (the
-    one awaiting its commit and the one a row is on), where `sizing` (the
-    benchmark's, not this PR's to edit) describes one last token a row."""
+def _lower_decode(model, ec, sharding):
+    """The decode program as the engine calls it, where `sizing.lower_decode`
+    (the benchmark's, not this PR's to edit) describes one last token a row
+    and a window of a static `decode_steps`: a window's token steps are a
+    traced argument, the loop's trip count; for a model that generates by
+    blocks the count stays static and what is carried between windows is two
+    blocks' ids [rows, 2 * block_length] (the one awaiting its commit and
+    the one a row is on)."""
     from benchmark import sizing
 
     eng = sizing._bare_engine(model, ec)
-    b = eng.cfg.max_seqs
+    b, block = eng.cfg.max_seqs, getattr(model, "block_length", 1)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    steps = (s((), jnp.int32),) if block == 1 else ()
     return eng._decode_fn(False, False).lower(
         sizing.param_shapes(model, sharding),
         sizing.cache_shapes(model, ec, sharding),
-        s((b, 2 * model.block_length), jnp.int32),
+        s((b, 2 * block) if block > 1 else (b,), jnp.int32),
         s((b, eng.cfg.max_pages_per_seq), jnp.int32), s((b,), jnp.int32),
         s((b,), jnp.bool_), s((b,), jnp.float32), s((b,), jnp.float32),
-        s((b,), jnp.int32), s((b, 2), jnp.uint32), None, s((b,), jnp.int32))
+        s((b,), jnp.int32), s((b, 2), jnp.uint32), None, s((b,), jnp.int32),
+        *steps)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -215,7 +220,8 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     """The chip compiler's HLO of a decode window and of a prefill, at the
     serving widths and engine shapes of Mistral's and of the hybrid's cell
     (depth cut to two attention layers and to one of each kind): no
-    instruction rewrites a whole pool; nor do the repeated in-place writes
+    instruction rewrites a whole pool, with the window's length the loop's
+    traced trip count; nor do the repeated in-place writes
     of block diffusion's denoising passes, the first of a block scattering
     two blocks a row (SDAR's cell, two layers). With kv-head-major pages the scatter
     took the pool token major and the kernel as written, so every token
@@ -242,10 +248,8 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     one = SingleDeviceSharding(topology.devices[0])
     if program == "prefill":
         lowered = sizing.lower_prefill(model, ec, 128, ec["max_seqs"], one)
-    elif getattr(model, "block_length", 1) == 1:
-        lowered = sizing.lower_decode(model, ec, one)
     else:
-        lowered = _lower_block_decode(model, ec, one)
+        lowered = _lower_decode(model, ec, one)
     text = lowered.compile().as_text()
 
     # a layer's K (or V) pool is the largest array of the engine's cache
@@ -296,7 +300,7 @@ def test_jamba_decode_updates_the_state_pool_in_place(topology, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, ec = _jamba_at_depth(8)
     one = SingleDeviceSharding(topology.devices[0])
-    text = sizing.lower_decode(model, ec, one).compile().as_text()
+    text = _lower_decode(model, ec, one).compile().as_text()
     caches = sizing.cache_shapes(model, ec, None)
     state, pages = caches[0][1], caches[7][0]
     assert (state.shape, state.dtype) == ((8, 16, 5120), jnp.float32)
