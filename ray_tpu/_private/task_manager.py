@@ -28,7 +28,10 @@ class TaskManager:
     def __init__(self, put_result: Callable[[ObjectID, Any], None]):
         self._pending: Dict[TaskID, PendingTask] = {}
         self._lineage: Dict[ObjectID, TaskSpec] = {}
-        self._lock = threading.Lock()
+        # Reentrant, as MemoryStore's: a GC pass inside a critical section
+        # can free an ObjectRef, whose ref-zero path ends in `drop_lineage`
+        # on the same thread.
+        self._lock = threading.RLock()
         self._put_result = put_result
 
     def add_pending(self, spec: TaskSpec) -> List[ObjectID]:
@@ -73,8 +76,9 @@ class TaskManager:
         with self._lock:
             spec = self._lineage.pop(object_id, None)
         # The spec's destruction can cascade (its ObjectRef args drop their
-        # local refs -> _on_owned_ref_zero -> drop_lineage again). That MUST
-        # happen outside the lock — destroying it inside self-deadlocks.
+        # local refs -> _on_owned_ref_zero -> drop_lineage again). Outside
+        # the lock, so that other threads do not wait for the whole cascade
+        # (the lock is reentrant: inside it the cascade would not deadlock).
         del spec
 
     def fail_or_retry(self, task_id: TaskID) -> Optional[TaskSpec]:

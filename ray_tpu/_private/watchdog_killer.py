@@ -1,6 +1,6 @@
 """Out-of-process test watchdog: SIGKILLs a wedged pytest process.
 
-The in-process SIGALRM watchdog (tests/conftest.py) covers armed test
+The in-process SIGALRM watchdog (pytest_watchdog.py) covers armed test
 phases, but cannot save a process that hangs during collection, inside a
 session fixture, or at interpreter exit (leaked non-daemon threads keep
 the interpreter alive after pytest_sessionfinish) — and a main thread
@@ -12,7 +12,9 @@ every test-phase boundary and writes ``done`` into it at sessionfinish.
 If the heartbeat goes stale for longer than ``stale_limit`` seconds
 (or ``exit_grace`` seconds after ``done``), the killer sends SIGUSR1
 (faulthandler stack dump for forensics), waits ``dump_grace``, then
-SIGKILLs the pid. It exits on its own when the target dies.
+SIGKILLs the pid. It exits on its own when the target dies, or when the
+heartbeat file is removed: that is how an xdist worker, which waits for
+its controller after its own session, stands its killer down.
 
 Usage: ``python -m ray_tpu._private.watchdog_killer <pid> <heartbeat>
 <stale_limit_s> <exit_grace_s> [dump_grace_s]``
@@ -29,11 +31,17 @@ import time
 
 
 def _alive(pid: int) -> bool:
+    """False once the process has exited, reaped or not. A parent that
+    reads its child's output to the end before it reaps the child waits
+    for this killer too, which holds the same pipe: the zombie must count
+    as dead, or the two wait for each other until the exit grace. Read
+    from /proc, so Linux only, as the cluster's own process handling is:
+    without /proc every pid reads as gone and the killer exits at once."""
     try:
-        os.kill(pid, 0)
-        return True
-    except OSError:
-        return False
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rpartition(")")[2].split()[0] != "Z"
+    except (FileNotFoundError, ProcessLookupError):
+        return False  # gone, before the open or between it and the read
 
 
 def main() -> None:
@@ -52,7 +60,7 @@ def main() -> None:
             with open(hb) as f:
                 done = f.read().strip() == "done"
         except OSError:
-            break  # heartbeat file removed: monitored run cleaned up
+            break  # heartbeat file removed: the monitored run stood us down
         age = time.time() - st.st_mtime
         if age <= (exit_grace if done else stale_limit):
             continue

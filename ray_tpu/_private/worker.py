@@ -861,6 +861,10 @@ class Worker:
         # get_actor -> None means PENDING, not "was never created".
         self._registering_actors: set = set()
         self._log_sub_started = False
+        # Where each GCS pub/sub subscription of this process stands
+        # (channel -> last sequence taken). For observation only:
+        # `_subscribe` writes it and holds its own cursor; a test reads it.
+        self._pubsub_cursors: Dict[str, int] = {}
         # Task-event buffer (timeline/profiling floor).
         self._task_events: List[Dict[str, Any]] = []
         self._task_events_lock = threading.Lock()
@@ -2055,6 +2059,27 @@ class Worker:
         self.loop.call_soon_threadsafe(
             lambda: self.loop.create_task(self._log_sub_loop()))
 
+    async def _subscribe(self, channel: str, cursor: int, retry_s: float):
+        """Long-poll one GCS pub/sub channel from `cursor`; yields each
+        answer's messages. The cursor follows the answer's last sequence,
+        never the highest seen: a restarted GCS counts from 1 again and
+        replays its backlog once to a cursor from its previous incarnation
+        (`PubsubChannels.poll`), and a subscriber that held the old, higher
+        number would be answered at once with that whole backlog on every
+        poll until the new count passed the old."""
+        while not self._shutdown:
+            self._pubsub_cursors[channel] = cursor
+            try:
+                out = await self.gcs_client.call(
+                    "pubsub_poll", cursors={channel: cursor}, timeout=40.0)
+            except Exception:
+                await asyncio.sleep(retry_s)
+                continue
+            msgs = (out or {}).get(channel)
+            if msgs:
+                cursor = msgs[-1][0]
+                yield [m for _, m in msgs]
+
     async def _log_sub_loop(self) -> None:
         import sys
 
@@ -2064,15 +2089,8 @@ class Worker:
             cursor = await self.gcs_client.call("pubsub_seq", channel="logs")
         except Exception:
             cursor = 0
-        while not self._shutdown:
-            try:
-                out = await self.gcs_client.call(
-                    "pubsub_poll", cursors={"logs": cursor}, timeout=40.0)
-            except Exception:
-                await asyncio.sleep(1.0)
-                continue
-            for seq, batches in (out or {}).get("logs", []):
-                cursor = max(cursor, seq)
+        async for msgs in self._subscribe("logs", cursor, retry_s=1.0):
+            for batches in msgs:
                 for b in batches:
                     prefix = f"({b.get('source')}, node={b.get('node')})"
                     for line in b.get("lines", []):
@@ -2082,23 +2100,14 @@ class Worker:
         """Long-poll the GCS 'actors' channel (reference: the reference's
         pubsub had zero subscribers in round 1 — this makes actor-state
         discovery push-based)."""
-        cursor = 0
-        while not self._shutdown:
-            try:
-                out = await self.gcs_client.call(
-                    "pubsub_poll", cursors={"actors": cursor}, timeout=40.0)
-            except Exception:
-                await asyncio.sleep(0.5)
-                continue
-            for seq, msg in (out or {}).get("actors", []):
-                cursor = max(cursor, seq)
+        async for msgs in self._subscribe("actors", 0, retry_s=0.5):
+            for msg in msgs:
                 view = msg.get("actor") or {}
                 aid = view.get("actor_id")
                 if aid:
                     self._actor_states[aid] = view
-            if (out or {}).get("actors"):
-                pulse, self._actor_pulse = self._actor_pulse, asyncio.Event()
-                pulse.set()
+            pulse, self._actor_pulse = self._actor_pulse, asyncio.Event()
+            pulse.set()
 
     def unresolved_owned_deps(self, spec: TaskSpec) -> List[ObjectID]:
         """Top-level ref args owned by this process whose values are not yet

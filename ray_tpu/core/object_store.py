@@ -406,7 +406,12 @@ class MemoryStore:
         self._objects: Dict[ObjectID, SerializedObject] = {}
         self._events: Dict[ObjectID, "MemoryStore._Waiter"] = {}
         self._thread_events: Dict[ObjectID, list] = {}
-        self._lock = threading.Lock()
+        # Reentrant: the ids' `__hash__` is Python, so the cyclic GC can
+        # run inside any critical section here, and an ObjectRef it frees
+        # comes back through `_on_owned_ref_zero` -> `pop` on the same
+        # thread. With a plain lock that thread (the IO loop's, in `put`)
+        # waited for itself, and every `get` of the process with it.
+        self._lock = threading.RLock()
 
     def put(self, object_id: ObjectID, obj: SerializedObject) -> None:
         with self._lock:
@@ -488,7 +493,8 @@ class MemoryStore:
         with self._lock:
             obj = self._objects.pop(object_id, None)
         # Destroy outside the lock: a value holding ObjectRefs cascades into
-        # ref-count callbacks that may re-enter this store.
+        # ref-count callbacks that re-enter this store (the lock is
+        # reentrant; other threads need not wait for the cascade).
         del obj
 
     def pop(self, object_id: ObjectID, default=None):

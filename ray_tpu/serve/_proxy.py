@@ -65,6 +65,7 @@ class ProxyActor:
         self._routes: Dict[str, str] = {}  # prefix -> deployment name
         self._deployments: Dict[str, Any] = {}  # name -> routing info
         self._handles: Dict[str, Any] = {}
+        self._controller = None
         self._version = -1
         self._max_concurrent = int(max_concurrent_requests)
         self._max_body = int(max_body_bytes)
@@ -130,21 +131,24 @@ class ProxyActor:
             self._shed_accum[name] = (self._shed_accum.get(name, 0)
                                       + rep["shed_delta"])
 
+    async def _resolve_controller(self):
+        """The controller's handle, looked up by name when there is none:
+        before the first answer, and again after any failure (the old loop
+        resolved once and then polled a dead handle forever, so a
+        controller restart left every proxy blind until ITS restart)."""
+        if self._controller is None:
+            # get_actor is a blocking driver-style call — it must run on
+            # an executor thread, never on this event loop (it would
+            # deadlock the proxy's accept loop).
+            self._controller = await asyncio.get_running_loop(
+            ).run_in_executor(
+                None, lambda: ray_tpu.get_actor(CONTROLLER_NAME))
+        return self._controller
+
     async def _route_refresh_loop(self) -> None:
-        loop = asyncio.get_running_loop()
-        # The controller handle is RE-resolved after any failure: the old
-        # loop resolved once and then polled a dead handle forever, so a
-        # controller restart left every proxy blind until ITS restart.
-        controller = None
         while True:
             try:
-                if controller is None:
-                    # get_actor is a blocking driver-style call — it must
-                    # run on an executor thread, never on this event loop
-                    # (it would deadlock the proxy's accept loop).
-                    controller = await loop.run_in_executor(
-                        None, lambda: ray_tpu.get_actor(CONTROLLER_NAME))
-                    self._controller = controller
+                controller = await self._resolve_controller()
                 report = self._take_ingress_report()
                 try:
                     routing = await controller.get_routing.remote(
@@ -154,10 +158,10 @@ class ProxyActor:
                     raise
                 self._apply_routing(routing)
             except Exception:
-                if controller is not None:
+                if self._controller is not None:
                     logger.warning("route refresh failed; will re-resolve "
                                    "controller", exc_info=True)
-                controller = None
+                self._controller = None
             await asyncio.sleep(1.0)
 
     def _apply_routing(self, routing) -> None:
@@ -180,9 +184,14 @@ class ProxyActor:
         self._ready = True
 
     async def _force_refresh(self) -> None:
-        controller = getattr(self, "_controller", None)
-        if controller is None:
-            return
+        # A request can arrive before the refresh loop has found the
+        # controller (a proxy just started, on a loaded machine): look for
+        # it here too, or a route that `serve.run` has returned for is
+        # answered 404.
+        try:
+            controller = await self._resolve_controller()
+        except Exception:
+            return  # none to ask: the refresh loop keeps looking
         try:
             self._apply_routing(await controller.get_routing.remote(-1))
         except Exception:

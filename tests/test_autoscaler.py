@@ -31,6 +31,11 @@ def test_autoscaler_scales_up_for_pending_pg():
             remove_placement_group(pg)
         finally:
             scaler.stop()
+            # the nodes it launched are the provider's, not the cluster's:
+            # left up, their nodelets outlive the GCS until this process
+            # exits (and for good when it is killed)
+            for node in provider.nodes():
+                provider.terminate_node(node)
     finally:
         ray_tpu.shutdown()
         c.shutdown()

@@ -12,6 +12,8 @@ import pytest
 from ray_tpu._private.chaos import ChaosEngine, ChaosInjectedError, set_chaos
 from ray_tpu.utils.config import RayTpuConfig
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture
 def chaos_reset():
@@ -145,7 +147,7 @@ print(e.schedule_digest())
 def test_chaos_seed_env_reproduces_schedule_across_runs():
     """Acceptance: RAY_TPU_CHAOS_SEED=<n> reproduces an identical fault
     schedule across two separate runs (processes)."""
-    env = dict(os.environ, PYTHONPATH="/root/repo")
+    env = dict(os.environ, PYTHONPATH=REPO)
     outs = [
         subprocess.run([sys.executable, "-c", SEED_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=120)
@@ -327,7 +329,7 @@ ray_tpu.shutdown()
 
 
 def test_lease_and_actor_paths_under_seeded_delay_chaos():
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", DELAY_CLUSTER_SCRIPT],
                          env=env, capture_output=True, text=True,
                          timeout=420)
@@ -367,7 +369,7 @@ def test_one_way_heartbeat_partition_tolerated():
     """Regression for the heartbeat hardening: before bounding the beat's
     RPC timeout to ~2x the interval, a dropped ack stalled the beat loop
     for gcs_rpc_timeout_s (30s) and the GCS declared a healthy node dead."""
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", HEARTBEAT_PARTITION_SCRIPT],
                          env=env, capture_output=True, text=True,
                          timeout=300)

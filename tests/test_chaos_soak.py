@@ -10,6 +10,8 @@ import sys
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SOAK_SCRIPT = """
 import os, time
 
@@ -114,7 +116,7 @@ ray_tpu.shutdown()
 
 @pytest.mark.slow
 def test_chaos_soak_completes_without_watchdog():
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", SOAK_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=540)
     assert "SOAK_OK" in out.stdout, \

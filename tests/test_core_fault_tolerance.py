@@ -8,6 +8,8 @@ import pytest
 
 import ray_tpu
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_task_retry_on_worker_crash(ray_start_regular, tmp_path):
     marker = str(tmp_path / "flaky_marker")
@@ -127,7 +129,7 @@ def test_rpc_chaos_injection_absorbed_by_retries():
     import subprocess
     import sys
 
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", CHAOS_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=420)
     assert "CHAOS_OK" in out.stdout, out.stdout[-800:] + out.stderr[-2000:]
@@ -167,7 +169,7 @@ def test_memory_monitor_kills_leased_worker():
     import subprocess
     import sys
 
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", OOM_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=240)
     assert "OOM_KILLED" in out.stdout, out.stdout[-500:] + out.stderr[-1500:]

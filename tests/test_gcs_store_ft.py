@@ -18,6 +18,8 @@ import pytest
 
 import ray_tpu
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 CHAOS_FT_SCRIPT = """
 import os, threading, time
@@ -95,11 +97,78 @@ ray_tpu.shutdown()
 
 
 def test_gcs_sqlite_store_survives_kill_under_chaos():
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", CHAOS_FT_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=420)
     assert "GCS_FT_OK" in out.stdout, \
         out.stdout[-800:] + out.stderr[-2000:]
+
+
+LOG_CURSOR_SCRIPT = """
+import time
+import ray_tpu
+from ray_tpu import cluster_utils
+from ray_tpu._private import worker as worker_mod
+
+cluster = cluster_utils.Cluster(initialize_head=True,
+                                head_node_args=dict(num_cpus=2,
+                                object_store_memory=128 * 1024 * 1024))
+ray_tpu.init(address=cluster.address)      # log_to_driver is the default
+w = worker_mod.global_worker()
+
+@ray_tpu.remote
+def say(line):
+    print(line, flush=True)
+    return 1
+
+# Raise the old GCS's 'logs' sequence well past what the new one will
+# reach: the nodelet publishes one batch a tail (0.5 s), so one print a
+# round and a wait.
+deadline = time.monotonic() + 60
+n = 0
+while w._pubsub_cursors.get("logs", 0) < 8:
+    assert time.monotonic() < deadline, w._pubsub_cursors
+    ray_tpu.get(say.remote("BEFORE-%d" % n), timeout=60)
+    n += 1
+    time.sleep(0.7)
+old = w._pubsub_cursors["logs"]
+
+cluster.head_node.restart_gcs()
+time.sleep(2.0)                            # nodes re-register via heartbeat
+assert ray_tpu.get(say.remote("AFTER-RESTART-marker"), timeout=60) == 1
+time.sleep(4.0)                            # tail, publish, poll, print
+
+new = w._pubsub_cursors["logs"]
+try:       # the GCS holds a poll for 30 s; the call gives up after 1.5
+    out = w.loop_thread.run(w.gcs_client.call(
+        "pubsub_poll", cursors={"logs": new}, timeout=1.5), timeout=30)
+except TimeoutError:
+    out = None
+print("CURSOR old=%d new=%d poll=%s"
+      % (old, new, "blocks" if out is None else "answers"), flush=True)
+ray_tpu.shutdown()
+cluster.shutdown()
+"""
+
+
+def test_log_subscription_counts_with_the_restarted_gcs():
+    """A restarted GCS counts its channels from 1 again. The driver's log
+    subscription must take up the new count: a line printed after the
+    restart reaches the driver's stderr once (with the old, higher cursor
+    kept, every poll was answered at once with the whole new backlog, and
+    the driver printed it again and again), and a poll from the driver's
+    cursor then blocks."""
+    import re
+
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", LOG_CURSOR_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=200)
+    tail = out.stdout[-800:] + out.stderr[-2000:]
+    m = re.search(r"CURSOR old=(\d+) new=(\d+) poll=(\w+)", out.stdout)
+    assert m, tail
+    assert out.stderr.count("AFTER-RESTART-marker") == 1, tail
+    assert 0 < int(m[2]) < int(m[1]), tail  # it counts with the new GCS
+    assert m[3] == "blocks", tail
 
 
 def test_sqlite_store_incremental_and_roundtrip(tmp_path):

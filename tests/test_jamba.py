@@ -16,6 +16,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark.manifest import Manifest  # noqa: E402
+from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.models.jamba import MAMBA, JambaConfig, JambaModel  # noqa: E402
 from ray_tpu.ops import ssm  # noqa: E402
@@ -41,10 +42,13 @@ def _ids(n, seed=2):
 
 
 def _engine(model, params, **kw):
+    """A new engine, whose decode programs are compiled once for each
+    (model, config) of the module (`engine_sharing`)."""
     cfg = dict(max_seqs=2, page_size=8, max_pages_per_seq=20,
                prefill_buckets=(32, 128), decode_steps=4, max_logprobs=5)
     cfg.update(kw)
-    return LLMEngine(model, params, EngineConfig(**cfg))
+    return share_decode_programs(
+        LLMEngine(model, params, EngineConfig(**cfg)))
 
 
 def _run(eng, *requests):
@@ -66,22 +70,11 @@ def _gap(reference, params, kw, prompt, outs):
     and the reference's full forward over prompt + tokens."""
     toks = [o.token for o in outs]
     ids = list(prompt) + toks[:-1]
-    # one compiled reference for every request: ids padded to 128 at the
-    # end, which a causal model's earlier positions do not see
-    padded = jnp.asarray(ids + [0] * (-len(ids) % 128), jnp.int32)
-    ref = np.asarray(_jitted(reference, kw)(params, padded))[len(prompt) - 1:]
+    # padded to 128 at the end, which a causal model's earlier positions do
+    # not see
+    ref = reference_logprobs(reference, params, kw, ids, 128)[len(prompt) - 1:]
     return max(abs(float(ref[i, t]) - lp)
                for i, o in enumerate(outs) for t, lp in o.top_logprobs)
-
-
-_JITTED = {}
-
-
-def _jitted(reference, kw):
-    if "logprobs" not in _JITTED:
-        _JITTED["logprobs"] = jax.jit(
-            lambda p, x: reference.logprobs(p, x, kw))
-    return _JITTED["logprobs"]
 
 
 def _scan_inputs(b, length, d=256, n=16, seed=0):

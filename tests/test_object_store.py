@@ -159,3 +159,38 @@ def test_overflow_spilling_roundtrip(tmp_path):
             assert ray_tpu.get(r, timeout=120)[0] == 100 + i
     finally:
         ray_tpu.shutdown()
+
+
+@pytest.mark.parametrize("which", ["memory_store", "task_manager"])
+def test_ref_zero_inside_a_critical_section_does_not_wait_for_itself(which):
+    """An id's `__hash__` is Python: the cyclic GC can run under the memory
+    store's or the task manager's lock and free an ObjectRef, whose
+    ref-zero path (`_on_owned_ref_zero`: `MemoryStore.pop`, then
+    `TaskManager.drop_lineage`) takes the same lock on the same thread.
+    Here the hash itself plays the collector. With plain locks the IO
+    loop's thread waited for itself in `put` and every `get` of the process
+    hung (test_serve_overload.py under load, PR 40)."""
+    import asyncio
+    import threading
+
+    from ray_tpu._private.task_manager import TaskManager
+    from ray_tpu.core.object_store import MemoryStore
+
+    store = MemoryStore(asyncio.new_event_loop())
+    tasks = TaskManager(lambda oid, result: None)
+    store.put(_oid(1), "freed by the collector")
+
+    class Collecting(ObjectID):
+        def __hash__(self):
+            store.pop(_oid(1), None)
+            tasks.drop_lineage(_oid(1))
+            return super().__hash__()
+
+    oid = Collecting(_oid(2).binary())
+    call = {"memory_store": lambda: store.put(oid, "value"),
+            "task_manager": lambda: tasks.lineage_spec(oid)}[which]
+    t = threading.Thread(target=call, daemon=True)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive(), f"{which}: the thread waits for its own lock"
+    assert store.size() == (1 if which == "memory_store" else 0)

@@ -15,6 +15,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark.manifest import Manifest  # noqa: E402
+from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.models.olmo_hybrid import (  # noqa: E402
     LINEAR,
@@ -44,10 +45,13 @@ def _ids(n, seed=2):
 
 
 def _engine(model, params, **kw):
+    """A new engine, whose decode programs are compiled once for each
+    (model, config) of the module (`engine_sharing`)."""
     cfg = dict(max_seqs=2, page_size=8, max_pages_per_seq=16,
                prefill_buckets=(32, 128), decode_steps=4, max_logprobs=5)
     cfg.update(kw)
-    return LLMEngine(model, params, EngineConfig(**cfg))
+    return share_decode_programs(
+        LLMEngine(model, params, EngineConfig(**cfg)))
 
 
 def _run(eng, *requests):
@@ -68,8 +72,10 @@ def _gap(reference, params, kw, prompt, outs):
     """Largest logprob gap between an engine request's reported top tokens
     and the reference's full forward over prompt + tokens."""
     toks = [o.token for o in outs]
-    ids = jnp.asarray(list(prompt) + toks[:-1], jnp.int32)
-    ref = np.asarray(reference.logprobs(params, ids, kw))[len(prompt) - 1:]
+    ids = list(prompt) + toks[:-1]
+    # padded to 128 at the end, which a causal model's earlier positions do
+    # not see
+    ref = reference_logprobs(reference, params, kw, ids, 128)[len(prompt) - 1:]
     return max(abs(float(ref[i, t]) - lp)
                for i, o in enumerate(outs) for t, lp in o.top_logprobs)
 

@@ -6,6 +6,8 @@ import subprocess
 import sys
 import textwrap
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_remote_driver_subprocess(ray_start_regular):
     from ray_tpu.util.client import serve_client
@@ -75,7 +77,7 @@ def test_remote_driver_subprocess(ray_start_regular):
         print("CLIENT-OK")
     """)
     env = dict(os.environ)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     # The client process must work WITHOUT joining the cluster: no store
     # path, no GCS bootstrap — only the proxy address.
     out = subprocess.run([sys.executable, "-c", script], env=env,

@@ -10,6 +10,8 @@ import sys
 
 import ray_tpu
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 # ---------------------------------------------------------------------------
 # worker.py _ensure_client: get_actor -> None while our register_actor is
@@ -39,7 +41,7 @@ ray_tpu.shutdown()
 
 
 def test_anonymous_actor_survives_delayed_registration():
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", REGISTRATION_RACE_SCRIPT],
                          env=env, capture_output=True, text=True,
                          timeout=300)

@@ -8,6 +8,7 @@ every expert over every token). Logprobs and not tokens: with seeded weights
 the largest logit changes on rounding."""
 
 import dataclasses
+import functools
 import math
 import os
 
@@ -18,6 +19,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark.manifest import Manifest  # noqa: E402
+from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.llm._internal.paged import PagedCacheConfig  # noqa: E402
 from ray_tpu.models.llama import apply_rope  # noqa: E402
@@ -56,10 +58,13 @@ def _ids(n, seed=2):
 
 
 def _engine(model, params, **kw):
+    """A new engine, whose decode programs are compiled once for each
+    (model, config) of the module (`engine_sharing`)."""
     cfg = dict(max_seqs=2, page_size=8, max_pages_per_seq=8,
                prefill_buckets=(16, 32), decode_steps=8, max_logprobs=3)
     cfg.update(kw)
-    return LLMEngine(model, params, EngineConfig(**cfg))
+    return share_decode_programs(
+        LLMEngine(model, params, EngineConfig(**cfg)))
 
 
 def _run(eng, *requests):
@@ -82,8 +87,10 @@ def _gap(reference, params, kw, prompt, outs):
     under MASK from r+1 to its block's end, what the pass that revealed
     position r+1 left to right computed."""
     toks = [o.token for o in outs]
-    ids = jnp.asarray(list(prompt) + toks[:-1], jnp.int32)
-    ref = np.asarray(reference.logprobs(params, ids, kw))[len(prompt) - 1:]
+    ids = list(prompt) + toks[:-1]
+    # padded to 64 at the end, which no row before the padding sees (a row
+    # reads the blocks before its own and its own block's head)
+    ref = reference_logprobs(reference, params, kw, ids, 64)[len(prompt) - 1:]
     return max(abs(float(ref[i, t]) - lp)
                for i, o in enumerate(outs) for t, lp in o.top_logprobs)
 
@@ -100,16 +107,23 @@ def _dispatches(run):
                  and e["name"] == "ray_tpu.engine.dispatch_decode"]
 
 
+@functools.lru_cache(maxsize=None)
+def _whole_sequence_kv(model):
+    """The model's whole-sequence form on ids [S], keeping what `k_norm`
+    and `v_proj` return; compiled once a length, not run op by op a call."""
+    return jax.jit(lambda params, ids: model.apply(
+        {"params": params}, ids[None],
+        capture_intermediates=lambda m, _: m.name in ("k_norm", "v_proj"),
+        mutable=["intermediates"])[1])
+
+
 def _pool_gap(eng, params, slot, ids):
     """Largest gap, over every layer's K and V, between what the pool holds
     at positions 0 .. len(ids)-1 of `slot`'s pages and what the model's
     whole-sequence form (no cache, block mask) computes on `ids`: the
     outputs of `k_norm` (then rotated) and of `v_proj`."""
     n, ps, cfg = len(ids), eng.cfg.page_size, eng.model.cfg
-    _, state = eng.model.apply(
-        {"params": params}, jnp.asarray(ids, jnp.int32)[None],
-        capture_intermediates=lambda m, _: m.name in ("k_norm", "v_proj"),
-        mutable=["intermediates"])
+    state = _whole_sequence_kv(eng.model)(params, jnp.asarray(ids, jnp.int32))
     pages = eng.page_table[slot, :-(-n // ps)]
     gap = 0.0
     for i, pool in enumerate(eng.caches):

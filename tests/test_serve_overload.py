@@ -398,13 +398,15 @@ def test_graceful_drain_zero_errors_on_downscale(serve_instance):
     assert status["Napper"]["target"] == 1
 
 
-def test_proxy_drain_rejects_new_accepts_inflight(serve_instance):
+def test_proxy_drain_rejects_new_accepts_inflight(serve_instance, tmp_path):
     """serve.shutdown() drains the proxy: listener closes first so no new
     connection lands, while accepted requests run to completion."""
+    started = str(tmp_path / "request_started")
 
     @serve.deployment(max_ongoing_requests=8,
                       graceful_shutdown_timeout_s=5.0)
     def slowish(request):
+        open(started, "w").close()
         time.sleep(1.0)
         return {"ok": True}
 
@@ -414,7 +416,12 @@ def test_proxy_drain_rejects_new_accepts_inflight(serve_instance):
     t = threading.Thread(
         target=lambda: out.append(_post(port, "/slowish", {}, timeout=30)))
     t.start()
-    time.sleep(0.3)
+    # Shut down once the request is in flight, however long a loaded
+    # machine takes to route it (0.3 s was not always enough).
+    deadline = time.time() + 30
+    while not os.path.exists(started) and time.time() < deadline:
+        time.sleep(0.02)
+    assert os.path.exists(started), out
     serve.shutdown()
     t.join(timeout=30)
     # The in-flight request was NOT cut off by the shutdown.

@@ -13,6 +13,8 @@ import pytest
 import ray_tpu
 from ray_tpu import tune
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_random_and_grid_search(ray_start_regular, tmp_path):
     def trainable(config):
@@ -126,7 +128,8 @@ def trainable(config):
         tune.report({"it": step + 1}, checkpoint=tune.Checkpoint(d))
         time.sleep(%(sleep)s)
 
-ray_tpu.init(num_cpus=8, object_store_memory=256 * 1024 * 1024)
+info = ray_tpu.init(num_cpus=8, object_store_memory=256 * 1024 * 1024)
+print("SESSION", info["session_dir"], flush=True)
 tuner = %(tuner)s
 grid = tuner.fit()
 assert not grid.errors, grid.errors
@@ -136,11 +139,27 @@ ray_tpu.shutdown()
 """
 
 
+def _terminate_cluster_of(session_dir):
+    """A driver's GCS and nodelet run in sessions of their own and outlive
+    a driver that is SIGKILLed: end them, or they keep their workers, cores
+    and ports from every test after this one. Their command lines name the
+    session's directory; an empty name would be in every command line."""
+    assert (os.path.basename(session_dir).startswith("session_")
+            and os.path.isdir(session_dir)), session_dir
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                if session_dir.encode() in f.read():
+                    os.kill(int(pid), signal.SIGTERM)
+        except OSError:
+            continue
+
+
 def test_experiment_resume_after_kill(tmp_path):
     """Kill a running experiment; Tuner.restore finishes it from
     checkpoints (reference: experiment_state resume)."""
     exp = str(tmp_path / "exp1")
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
 
     first = RESUME_SCRIPT % {
         "sleep": "0.8",
@@ -153,6 +172,11 @@ def test_experiment_resume_after_kill(tmp_path):
     p = subprocess.Popen([sys.executable, "-c", first], env=env,
                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
                          start_new_session=True)
+    session_dir = ""
+    for line in p.stdout:       # the log lines of init come first
+        if line.startswith(b"SESSION "):
+            session_dir = line.split()[1].decode()
+            break
     state = os.path.join(exp, "e", "experiment_state.json")
     deadline = time.time() + 90
     # Wait until both trials have checkpointed at least once, then kill.
@@ -169,6 +193,7 @@ def test_experiment_resume_after_kill(tmp_path):
     assert _progressed(), "experiment never made progress"
     os.killpg(p.pid, signal.SIGKILL)
     p.wait()
+    _terminate_cluster_of(session_dir)
 
     second = RESUME_SCRIPT % {
         "sleep": "0.05",

@@ -1,12 +1,15 @@
 """util components: ActorPool, Queue, CLI (reference: ray.util)."""
 
 import json
+import os
 import subprocess
 import sys
 
 import ray_tpu
 from ray_tpu.util.actor_pool import ActorPool
 from ray_tpu.util.queue import Empty, Queue
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_actor_pool_ordered_and_unordered(ray_start_regular):
@@ -43,12 +46,10 @@ def test_distributed_queue(ray_start_regular):
 
 
 def test_cli_status(ray_start_regular):
-    import os
-
     from ray_tpu._private import worker as wm
 
     addr = "%s:%d" % wm.global_worker().gcs_address
-    env = dict(os.environ, PYTHONPATH="/root/repo", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.cli", "--address", addr,
          "status"],
