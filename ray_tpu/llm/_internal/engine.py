@@ -131,12 +131,14 @@ class StepOutput:
 class _Window(NamedTuple):
     """A dispatched decode window: its device results (tokens [K, B] of
     which the first `steps` rows are filled, final last_tokens and seq_lens,
-    logprobs or None), the slots it was dispatched for and its token steps."""
+    logprobs or None), the request each of its rows was dispatched for, by
+    slot (a slot may hold another by the time the tokens are read), and its
+    token steps."""
     toks: Any
     last: Any
     lens: Any
     lp: Any
-    slots: frozenset
+    slots: Dict[int, "Request"]
     steps: int
 
 
@@ -319,8 +321,12 @@ class LLMEngine:
         self.lora_idx = np.zeros((cfg.max_seqs,), np.int32)
         if cfg.lora_rank > 0:
             self.lora_banks = self._init_lora_banks()
-        # Pipelined dispatch state: the window in flight.
+        # Pipelined dispatch state: the window in flight, whether a slot
+        # was freed since the last dispatch, and the dispatches counted.
         self._inflight: Optional[_Window] = None
+        self._freed = False
+        self._windows = {"unchained": 0, "none": 0, "finish": 0,
+                         "admission": 0}
         # Compile record: (kind, key) -> [jit cache size after the last
         # call, argument signature it was last traced for], and the last
         # records of programs built and retraced.
@@ -535,6 +541,10 @@ class LLMEngine:
             results keep K rows, of which the first `steps` are filled. Left
             out (whoever lowers the program from shapes alone), K."""
             B = last_tokens.shape[0]
+            # A free slot's row is stale while windows chain on the device
+            # (the host's mirror says 0, the device's what its last request
+            # left): it attends over nothing, whoever dispatched the window.
+            seq_lens = jnp.where(active, seq_lens, 0)
             out = jnp.zeros((K, B), jnp.int32)
             out_lp = jnp.zeros((K, B), jnp.float32)
             out_tv = jnp.zeros((K, B, L), jnp.float32)
@@ -606,6 +616,7 @@ class LLMEngine:
         def decode(params, caches, last_tokens, page_table, seq_lens,
                    active, temps, top_ps, top_ks, keys, lora, lora_idx):
             rows = last_tokens.shape[0]
+            seq_lens = jnp.where(active, seq_lens, 0)  # as in `decode`
             per_pos = lambda a: jnp.repeat(a, B, axis=0)
 
             def forward(caches, ids, starts, table, write, logits_from=0):
@@ -729,7 +740,12 @@ class LLMEngine:
 
         def prefill(params, caches, ids, rows, starts, true_lens,
                     temps, top_ps, top_ks, all_keys, slots, lora,
-                    lora_idx):
+                    lora_idx, last_tokens=None, seq_lens=None):
+            """`last_tokens`/`seq_lens` [max_seqs]: what the decode window
+            behind this prefill is fed, returned with the wave's rows
+            scattered in (each first token and prompt length), as
+            `all_keys` is. Left out (whoever lowers the program from shapes
+            alone, block generation), None comes back."""
             if transform is not None:
                 params = transform(params)
             # ids [nb, bucket] = each prompt's SUFFIX from absolute
@@ -748,7 +764,7 @@ class LLMEngine:
             if self._block > 1:
                 # Cache fill only: the first block's passes sample its
                 # tokens, and with the logits unused no head is compiled.
-                return None, new_caches, all_keys, None
+                return None, new_caches, all_keys, None, None, None
             last = (logits[:, 0] if at else
                     logits[jnp.arange(nb), true_lens - 1]).astype(
                 jnp.float32)  # [nb, V]
@@ -756,7 +772,10 @@ class LLMEngine:
             toks, nxt, lp = sample(keys, last, temps, top_ps, top_ks)
             # write the advanced chains back into the [B,2] key table
             all_keys = all_keys.at[slots].set(nxt)
-            return toks, new_caches, all_keys, lp
+            if last_tokens is not None:
+                last_tokens = last_tokens.at[slots].set(toks)
+                seq_lens = seq_lens.at[slots].set(starts + true_lens)
+            return toks, new_caches, all_keys, lp, last_tokens, seq_lens
 
         fn = jax.jit(prefill, donate_argnums=(1,))
         self._prefill_fns[(bucket, nb, rich, want_lp)] = fn
@@ -864,9 +883,15 @@ class LLMEngine:
         in-flight window's DEVICE outputs before its tokens reach the
         host, so host-side stop/stream handling overlaps device compute
         (the "enqueue N+1 before N returns" scheme; reference analog:
-        vLLM async scheduling). The pipeline drains to a sync point when
-        the slot set changes (admit/finish) — the next dispatch then
-        rebuilds control state from the host mirrors."""
+        vLLM async scheduling). The chain outlives a change of the slot
+        set: after a finish the next window takes `active` and the rest
+        from the host mirrors as every dispatch does, and the freed row,
+        stale on the device, is inactive; at an admission the window behind
+        the wave's prefills is queued before the host reads a first token,
+        with the new rows merged in on the device (`_admit`). A window is
+        read with none queued behind it only when nothing runs, when every
+        request ends inside it, and at a block-generating model's
+        admissions."""
         out: List[StepOutput] = []
         with _fr.span("ray_tpu.engine.step", running=len(self.running),
                       waiting=len(self.waiting),
@@ -875,19 +900,29 @@ class LLMEngine:
         return out
 
     def _step(self, out: List[StepOutput]) -> None:
+        inflight = self._inflight
         admitted = self._admit(out)
-        if not self.running:
-            if self._inflight is not None:
-                self._process_window(self._inflight, out, why="idle")
+        if self._inflight is not inflight:
+            # `_admit` queued the next window behind its prefills: the one
+            # that was in flight is this step's.
+            self._process_window(inflight, out, why="chained")
+        else:
+            if admitted and inflight is not None:
+                # Block generation: the first block's ids are in the host
+                # mirror alone, so the window in flight is drained and the
+                # next dispatched from the host.
+                self._process_window(inflight, out, why="admitted")
                 self._inflight = None
-            return
-        if admitted and self._inflight is not None:
-            # Admission changed active/temps/last_tokens: the in-flight
-            # window predates it — drain before dispatching from host.
-            self._process_window(self._inflight, out, why="admitted")
+            if self.running:
+                self._decode(out)
+        if not self.running and self._inflight is not None:
+            # Nothing runs: every row of the window in flight is stale.
+            self._process_window(self._inflight, out, why="idle")
             self._inflight = None
-            if not self.running:
-                return
+
+    def _decode(self, out: List[StepOutput]) -> None:
+        """One decode window for the running requests: dispatch the next
+        and, pipelined, read the one before it."""
         K = max(1, self.cfg.decode_steps)
         if self._inflight is None:
             self._ensure_decode_pages(K)
@@ -907,19 +942,14 @@ class LLMEngine:
             return
         self._ensure_decode_pages(2 * K)
         nxt = self._dispatch_window(self._inflight.last, self._inflight.lens)
-        finished = self._process_window(self._inflight, out, why="chained")
-        if finished:
-            # The chained window ran with pre-finish control state. Its
-            # tokens are still VALID for surviving slots (their device
-            # last/lens were correct); finished slots are skipped by the
-            # processing loop, and their stale page writes are harmless:
-            # released pages get re-prefilled by strictly later programs
-            # on the ordered device stream. Process it now and resync from
-            # host state on the next step.
-            self._process_window(nxt, out, why="finished_in_chain")
-            self._inflight = None
-        else:
-            self._inflight = nxt
+        # A request that ends inside the window read here is a row of `nxt`
+        # too. Its tokens there are passed over (`_emit_window`), and its
+        # stale page and state writes are harmless: released pages and the
+        # slot's state row get re-prefilled by strictly later programs on
+        # the ordered device stream. The surviving rows' device last/lens
+        # are right, so the chain goes on.
+        self._process_window(self._inflight, out, why="chained")
+        self._inflight = nxt
 
     def _window_steps(self) -> int:
         """Token steps of the window about to be dispatched: `decode_steps`
@@ -960,7 +990,17 @@ class LLMEngine:
         return args + (self._dev(
             np.int32(steps or max(1, self.cfg.decode_steps))),)
 
-    def _dispatch_window(self, last=None, lens=None) -> _Window:
+    def _dispatch_window(self, last=None, lens=None,
+                         admission: bool = False) -> _Window:
+        """Dispatch a window for the running requests, chained off `last`
+        and `lens` on the device where given. `across` says what a chained
+        dispatch's chain outlived since the dispatch before it: an
+        `admission` (`last`/`lens` hold the wave's rows), a `finish`, or
+        `none`."""
+        across = ("none" if last is None else "admission" if admission
+                  else "finish" if self._freed else "none")
+        self._freed = False
+        self._windows[across if last is not None else "unchained"] += 1
         rich, want_lp = self._sampling_flags(self.running.values())
         key = (rich, want_lp)
         K, B = self._window_steps(), self._block
@@ -974,7 +1014,7 @@ class LLMEngine:
         with _fr.span("ray_tpu.engine.dispatch_decode",
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
                       steps=K, free_slots=len(self._free_slots),
-                      chained=last is not None,
+                      chained=last is not None, across=across,
                       new_program=key not in self._decode_fns,
                       state_rows=len(self.running) * self._state_layers,
                       block_length=B, denoise_passes=denoise,
@@ -984,7 +1024,13 @@ class LLMEngine:
             toks, last, lens, self.caches, self._keys_dev, lp = \
                 self._run_program("decode", key, self._decode_fn(*key),
                                   self._decode_args(last, lens, K))
-        return _Window(toks, last, lens, lp, frozenset(self.running), K)
+        return _Window(toks, last, lens, lp, dict(self.running), K)
+
+    def windows_report(self) -> Dict[str, int]:
+        """Decode windows dispatched: `unchained` from the host mirrors, and
+        chained off the window before by what the chain outlived (`none`,
+        `finish`, `admission`)."""
+        return dict(self._windows)
 
     def lowered_decode_text(self) -> str:
         """StableHLO of the greedy decode program as this process lowers
@@ -1027,16 +1073,19 @@ class LLMEngine:
                    skipped=skipped, **load)
         return finished > 0
 
-    def _emit_window(self, toks, lp, slots, out: List[StepOutput]) -> int:
+    def _emit_window(self, toks, lp, slots: Dict[int, Request],
+                     out: List[StepOutput]) -> int:
         """The host loop over a window's tokens, now on the host. Returns
         the positions it passed over as a prompt's remainder (block
         generation)."""
         K = toks.shape[0]
         block = self._block
         skipped = 0
-        for slot in slots:
-            req = self.running.get(slot)
-            if req is None:
+        for slot, req in slots.items():
+            if self.running.get(slot) is not req:
+                # It ended before this window was read (inside the one
+                # before, or on its first token); the slot may hold the next
+                # request by now, whose tokens these are not.
                 continue
             if req.done:  # aborted externally (e.g. stop-string match)
                 self._release(slot)
@@ -1096,19 +1145,42 @@ class LLMEngine:
         BATCHED per bucket — one pass over the (dequantized) weights for
         the whole admission wave, not one per request — and the first
         tokens stay on device until every batch is in flight, so TTFT for
-        N admissions is ~one weight stream + one host sync."""
+        N admissions is ~one weight stream + one host sync.
+
+        With a window in flight, the next is queued behind the prefills
+        before the host reads a first token, chained off the in-flight
+        window's last tokens and lengths with the wave's rows scattered in by
+        the prefill programs: it becomes `_inflight`, and the caller reads
+        the one that was. Block generation samples nothing in its prefill
+        and leaves the window in flight to its caller's drain."""
         if not (self.waiting and self._free_slots):
             return False
         with _fr.span("ray_tpu.engine.admit",
                       free_slots=len(self._free_slots),
                       free_pages=self.allocator.num_free) as sp:
             entries = self._place_waiting()
-            pending = self._dispatch_prefills(entries)
-            if self._block == 1:
-                self._sync_first_tokens(pending, out)
-            else:  # nothing was sampled: the first window brings the tokens
-                for _, req, _, _, _, nb, cached in pending:
+            if self._block > 1:
+                # nothing was sampled: the first window brings the tokens
+                for _, req, _, _, _, nb, cached in self._dispatch_prefills(
+                        entries)[0]:
                     req.prefilled = (cached, nb)
+            else:
+                pending: List[tuple] = []
+                if entries:
+                    # The programs take last tokens and lengths whether a
+                    # window waits for them or not: one signature, so one
+                    # program a shape.
+                    behind = self._inflight
+                    carry = ((self._dev(self.last_tokens),
+                              self._dev(self.seq_lens)) if behind is None
+                             else (behind.last, behind.lens))
+                    pending, carry = self._dispatch_prefills(entries, carry)
+                    if behind is not None:
+                        self._ensure_decode_pages(
+                            2 * max(1, self.cfg.decode_steps))
+                        self._inflight = self._dispatch_window(
+                            *carry, admission=True)
+                self._sync_first_tokens(pending, out)
             sp.set(admitted=len(entries), waiting_left=len(self.waiting))
         return bool(entries)
 
@@ -1217,10 +1289,13 @@ class LLMEngine:
             entries.append((slot, req, suffix, cached_len, S, bucket, deps))
         return entries
 
-    def _dispatch_prefills(self, entries: List[tuple]) -> List[tuple]:
+    def _dispatch_prefills(self, entries: List[tuple], carry=(None, None)
+                           ) -> Tuple[List[tuple], tuple]:
         """Dispatch the wave's prefills; the first tokens stay on the
         device. Returns (slot, req, tokens on device, logprobs, row, batch
-        size, cached prompt tokens) per admission."""
+        size, cached prompt tokens) per admission, and `carry`: every slot's
+        last token and length on the device, threaded through the
+        sub-batches as `self.caches` is, each scattering its rows in."""
         pending: List[tuple] = []
         # Dispatch in dependency-respecting sub-batches: repeatedly take
         # the earliest undispatched admission, batch it with every other
@@ -1248,15 +1323,15 @@ class LLMEngine:
                           scan_positions=nb * bucket * self._state_layers,
                           head_rows=(0 if self._block > 1 else nb
                                      if self._head_last else nb * bucket)):
-                dev_toks, lp = self._prefill_wave(key, wave)
+                dev_toks, lp, carry = self._prefill_wave(key, wave, carry)
             for i, (slot, req, _, cached_len, _) in enumerate(wave):
                 pending.append((slot, req, dev_toks, lp, i, nb, cached_len))
             done.update(batch)
             remaining = [j for j in remaining if j not in done]
-        return pending
+        return pending, carry
 
     def _prefill_wave(self, key: Tuple[int, int, bool, bool],
-                      wave: List[tuple]):
+                      wave: List[tuple], carry: tuple):
         """One batched prefill: the host arrays, their transfers and the
         program call."""
         bucket, nb = key[:2]
@@ -1279,14 +1354,15 @@ class LLMEngine:
             tks[i] = req.top_k
             slot_ids[i] = slot
             lidx[i] = self.lora_idx[slot]
-        dev_toks, self.caches, self._keys_dev, lp = self._run_program(
-            "prefill", key, self._prefill_fn(*key), (
-                self.params, self.caches, self._dev(ids),
-                self._dev(rows), self._dev(starts), self._dev(lens),
-                self._dev(temps), self._dev(tps), self._dev(tks),
-                self._keys_dev, self._dev(slot_ids), self.lora_banks,
-                self._dev(lidx)))
-        return dev_toks, lp
+        dev_toks, self.caches, self._keys_dev, lp, *carry = \
+            self._run_program(
+                "prefill", key, self._prefill_fn(*key), (
+                    self.params, self.caches, self._dev(ids),
+                    self._dev(rows), self._dev(starts), self._dev(lens),
+                    self._dev(temps), self._dev(tps), self._dev(tks),
+                    self._keys_dev, self._dev(slot_ids), self.lora_banks,
+                    self._dev(lidx), *carry))
+        return dev_toks, lp, tuple(carry)
 
     def _sync_first_tokens(self, pending: List[tuple],
                            out: List[StepOutput]) -> None:
@@ -1353,6 +1429,7 @@ class LLMEngine:
                          time.monotonic() - req.t_first_token) * 1e3)
         self.allocator.release(slot)
         self._free_slots.append(slot)
+        self._freed = True
         self.seq_lens[slot] = 0
         if self._block > 1:  # its last block is never committed
             self.last_tokens[slot, :self._block] = -1

@@ -320,6 +320,10 @@ class LLMServer:
             "tokens_out": self._tokens_out,
             # Compile record: programs built and retraced, last records.
             "programs": self.engine.programs_report(),
+            # Decode windows dispatched: `unchained` from the host mirrors
+            # (the chip waited for that dispatch), and chained off the window
+            # before by what the chain outlived: none, finish, admission.
+            "decode_windows": self.engine.windows_report(),
             # Which device answers: the devices this engine computes on, as
             # JAX reports them in this process, and the chips it was leased.
             "pid": os.getpid(),

@@ -175,8 +175,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     b = pl.program_id(0)
     _, C, ps, width = kbuf.shape
     h, d = q_ref.shape[2:]
-    # Never past the page table: a free slot's length keeps counting while
-    # decode windows chain on the device.
+    # Never past the page table, whatever length a caller hands a row it
+    # does not use (the engine's decode programs start a free slot's row at
+    # 0 every window, so it walks one page).
     seq_len = jnp.minimum(lens_ref[b], pt_ref.shape[1] * ps)
     n_pages = jax.lax.div(seq_len + ps - 1, ps)
     n_chunks = jax.lax.div(n_pages + C - 1, C)

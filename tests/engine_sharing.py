@@ -1,6 +1,9 @@
 """What the model families' engine tests (`test_sdar_moe.py`,
 `test_olmo_hybrid.py`, `test_jamba.py`) compile once a module and not once a
-test: an engine's decode programs and the plain reference's forward."""
+test: an engine's decode programs and the plain reference's forward. And the
+four families' tiny models, for the tests of the engine's scheduler, which
+run the same schedule through each (`test_engine_window.py`,
+`test_engine_rechain.py`)."""
 
 import dataclasses
 import functools
@@ -49,3 +52,38 @@ def reference_logprobs(reference, params, kw, ids, multiple):
     padded = jnp.asarray(list(ids) + [0] * (-len(ids) % multiple), jnp.int32)
     fn = _compiled_logprobs(reference, tuple(sorted(kw.items())))
     return np.asarray(fn(params, padded))[:len(ids)]
+
+
+@functools.lru_cache(maxsize=None)
+def tiny_family(name):
+    """(model, params) of a family's tiny configuration, float32 on the CPU:
+    `llama`, `olmo_hybrid`, `jamba` (a token a forward) or `sdar_moe` (a
+    block of four). One of each a process, so that `share_decode_programs`
+    finds the same model again."""
+    if name == "llama":
+        from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+        model = LlamaModel(LlamaConfig.tiny())
+        return model, model.init(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))["params"]
+    if name == "olmo_hybrid":
+        from ray_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                OlmoHybridModel)
+
+        model = OlmoHybridModel(OlmoHybridConfig.tiny())
+    elif name == "jamba":
+        from ray_tpu.models.jamba import JambaConfig, JambaModel
+
+        model = JambaModel(JambaConfig.tiny())
+    else:
+        from ray_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeModel
+
+        assert name == "sdar_moe", name
+        model = SdarMoeModel(SdarMoeConfig.tiny())
+    return model, model.init_params(jax.random.PRNGKey(1))
+
+
+def prompt_ids(n, seed=2):
+    """`n` seeded token ids under 500 (never SDAR's MASK id, 511)."""
+    return [int(t) for t in jax.random.randint(
+        jax.random.PRNGKey(seed), (n,), 0, 500)]

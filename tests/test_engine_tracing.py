@@ -29,7 +29,7 @@ ENGINE_SPANS = {
                                         "scan_positions", "head_rows"},
     "ray_tpu.engine.prefill_sync": {"requests"},
     "ray_tpu.engine.dispatch_decode": {"active", "max_seqs", "steps",
-                                       "free_slots", "chained",
+                                       "free_slots", "chained", "across",
                                        "new_program",
                                        "state_rows", "block_length",
                                        "denoise_passes", "commit_passes",
@@ -46,7 +46,7 @@ MARKS = {
                                     "prompt", "cached", "nb"},
     "ray_tpu.request.finished": {"rid", "slot", "decode_ms", "tokens"},
 }
-WHYS = {"admitted", "idle", "all_finishing", "chained", "finished_in_chain",
+WHYS = {"admitted", "idle", "all_finishing", "chained",
         "unpipelined"}
 
 
@@ -78,9 +78,9 @@ def _profiler_events(log_dir):
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """Three requests through two slots under the profiler: A runs alone,
-    the second is admitted while A's decode window is in flight (the
-    pipeline drains because of an admission), the third has to wait for a
-    slot; after an idle stretch a fourth ends it."""
+    the second is admitted while A's decode window is in flight (the next
+    window is queued behind its prefill, inside the admission), the third
+    has to wait for a slot; after an idle stretch a fourth ends it."""
     log_dir = str(tmp_path_factory.mktemp("trace"))
     srv = _server()
     srv.generate_all([5, 6, 7], max_tokens=3)       # build the programs
@@ -209,19 +209,36 @@ def test_wait_tokens_says_why(traced):
     whys = [e["stats"]["why"] for e in waits]
     assert set(whys) <= WHYS
     assert "chained" in whys            # the pipelined steady state
-    # B's admission found A's window in flight and drained it: the wait
-    # lies inside the step that holds that admission
+    # No admission and no finish drained a window: a Llama's chain of
+    # windows outlives both.
+    assert "admitted" not in whys and "finished_in_chain" not in whys
+    # B's admission found A's window in flight and queued the next one
+    # behind B's prefill, inside its `admit` span and before the first token
+    # was read; the step then waited for A's window with that one behind it
     admits = [e for e in _named(traced, "ray_tpu.engine.admit")
               if e["stats"]["admitted"] >= 1]
-    drained = [w for w in waits if w["stats"]["why"] == "admitted"]
-    assert drained
+    inside = lambda e, outer: (outer["start"] <= e["start"]
+                               and e["end"] <= outer["end"])
+    decode = _named(traced, "ray_tpu.engine.dispatch_decode")
+    across = [d for d in decode if d["stats"]["across"] == "admission"]
+    assert across and all(d["stats"]["chained"] for d in across)
     steps = _named(traced, "ray_tpu.engine.step")
-    for w in drained:
-        step = next(s for s in steps
-                    if s["start"] <= w["start"] and w["end"] <= s["end"])
+    syncs = _named(traced, "ray_tpu.engine.prefill_sync")
+    for d in across:
+        admit = next(a for a in admits if inside(d, a))
+        sync = next(s for s in syncs if inside(s, admit))
+        assert d["end"] <= sync["start"]
+        step = next(s for s in steps if inside(admit, s))
         assert step["stats"]["inflight"] == 1
-        assert any(step["start"] <= a["start"] and a["end"] <= w["start"]
+        (wait,) = [w for w in waits if inside(w, step)]
+        assert wait["stats"]["why"] == "chained"
+        assert admit["end"] <= wait["start"]
+    # every other dispatch lies outside every admission
+    assert not any(inside(d, a) for d in decode if d not in across
                    for a in admits)
+    # C took the slot B or A left while a window with that row was in
+    # flight: the chain outlived the finish too
+    assert "finish" in {d["stats"]["across"] for d in decode}
 
 
 def test_decode_rows_and_admission_counters_are_exact(traced):

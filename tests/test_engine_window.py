@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
+from engine_sharing import prompt_ids as _ids  # noqa: E402
+from engine_sharing import tiny_family  # noqa: E402
 from ray_tpu._private import flight_recorder as fr  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 
@@ -23,54 +24,11 @@ FULL = 8
 CUTS = {"whole": [8], "halves": [4], "mixed": [3, 5, 1, 7, 2, 6]}
 
 
-def _llama():
-    from ray_tpu.models.llama import LlamaConfig, LlamaModel
-
-    model = LlamaModel(LlamaConfig.tiny())
-    return model, model.init(jax.random.PRNGKey(0),
-                             jnp.zeros((1, 8), jnp.int32))["params"]
-
-
-def _olmo_hybrid():
-    from ray_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridModel
-
-    model = OlmoHybridModel(OlmoHybridConfig.tiny())
-    return model, model.init_params(jax.random.PRNGKey(1))
-
-
-def _jamba():
-    from ray_tpu.models.jamba import JambaConfig, JambaModel
-
-    model = JambaModel(JambaConfig.tiny())
-    return model, model.init_params(jax.random.PRNGKey(1))
-
-
-def _sdar_moe():
-    from ray_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeModel
-
-    model = SdarMoeModel(SdarMoeConfig.tiny())
-    return model, model.init_params(jax.random.PRNGKey(1))
-
-
-_FAMILIES = {"llama": _llama, "olmo_hybrid": _olmo_hybrid, "jamba": _jamba,
-             "sdar_moe": _sdar_moe}
-
-
-@functools.lru_cache(maxsize=None)
-def _family(name):
-    return _FAMILIES[name]()
-
-
-def _ids(n, seed=2):
-    return [int(t) for t in jax.random.randint(
-        jax.random.PRNGKey(seed), (n,), 0, 500)]
-
-
 def _engine(family, **kw):
     cfg = dict(max_seqs=2, page_size=8, max_pages_per_seq=16,
                prefill_buckets=(32,), decode_steps=FULL, max_logprobs=3)
     cfg.update(kw)
-    return LLMEngine(*_family(family), EngineConfig(**cfg))
+    return LLMEngine(*tiny_family(family), EngineConfig(**cfg))
 
 
 def _run(eng, *requests):
@@ -189,7 +147,7 @@ def test_full_window_while_the_queue_waits_for_pages():
 
 
 # -- (c) a finish inside a short chained window -----------------------------
-def test_finish_inside_a_short_chained_window_resyncs_from_the_host():
+def test_finish_inside_a_short_chained_window_leaves_the_chain_whole():
     alone, _ = _run(_engine("llama", max_seqs=3),
                     Request("a", _ids(13), max_tokens=30, logprobs=2))
     eng = _engine("llama", max_seqs=3)
@@ -198,13 +156,14 @@ def test_finish_inside_a_short_chained_window_resyncs_from_the_host():
                       Request("b", _ids(9, seed=5), max_tokens=7))
     assert [s["steps"] for s in spans] == [4] * len(spans)
     # the window chained behind the one "b" ends in is on the device by the
-    # time the host sees the finish: it is read, and the chain broken
-    assert [(s["chained"], s["active"]) for s in spans[:5]] == [
-        (False, 2), (True, 2), (True, 2), (False, 1), (True, 1)]
+    # time the host sees the finish, with b's row: the next is chained off
+    # it all the same, without that row
+    assert [(s["chained"], s["active"], s["across"]) for s in spans[:5]] == [
+        (False, 2, "none"), (True, 2, "none"), (True, 2, "none"),
+        (True, 1, "finish"), (True, 1, "none")]
     assert len(got["b"]) == 7 and got["b"][-1].finished
     assert sorted(eng._free_slots) == [0, 1, 2]
-    # "a" went on from the host mirrors, which hold what the chained
-    # window made of it
+    # "a" went on from its row on the device, which b's end did not touch
     assert [o.token for o in got["a"]] == [o.token for o in alone["a"]]
     assert [o.logprob for o in got["a"]] == pytest.approx(
         [o.logprob for o in alone["a"]], abs=1e-5)
@@ -222,9 +181,9 @@ def test_pages_cover_the_window_in_flight_and_the_one_chained(max_seqs,
     seen = []
     dispatch = eng._dispatch_window
 
-    def checked(last=None, lens=None):
+    def checked(last=None, lens=None, **kw):
         ahead = 0 if eng._inflight is None else eng._inflight.steps
-        window = dispatch(last, lens)
+        window = dispatch(last, lens, **kw)
         for slot in window.slots:
             room = len(eng.allocator.slot_pages[slot]) * 4
             written = int(eng.seq_lens[slot]) + ahead + window.steps

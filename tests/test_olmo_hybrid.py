@@ -279,7 +279,12 @@ def test_request_finishing_inside_a_chained_window(tiny):
         if "C" not in seen and len(seen.get("B", [])) == 6 \
                 and not any(r.request_id == "C" for r in eng.waiting):
             eng.add_request(Request("C", third, max_tokens=12, logprobs=5))
-    assert "finished_in_chain" in whys
+    # no window was read at once for B's finish, and none drained for C's
+    # admission: B's stale row rode in the window C's prefill ran behind
+    assert set(whys) <= {"chained", "all_finishing", "idle"}
+    assert whys.count("chained") >= 8
+    assert eng.windows_report()["finish"] + eng.windows_report()[
+        "admission"] >= 2
     assert len(seen["A"]) == 40 and len(seen["C"]) == 12
     _same(seen["C"], _alone(model, params, third, 12))
 

@@ -314,6 +314,13 @@ def test_chained_windows_and_a_second_request_through_the_slot(tiny, pipeline):
     # stale commit would have gone to positions 0-3, its prefilled prompt).
     assert [d["fresh_rows"] for d in decode] == [1, 0, 0, 0, 1]
     assert [d["chained"] for d in decode] == [False] + [pipeline] * 3 + [False]
+    # one slot: "a" ends in a window with none queued behind it
+    # (`all_finishing`), so "b" is admitted with nothing in flight and the
+    # chain had no finish and no admission to outlive
+    assert {d["across"] for d in decode} == {"none"}
+    assert eng.windows_report() == {
+        "unchained": 2 if pipeline else 5, "none": 3 if pipeline else 0,
+        "finish": 0, "admission": 0}
     assert (eng.last_tokens[0, :B] == -1).all()
     ids = second + [o.token for o in got["b"]]
     assert _pool_gap(eng, params, 0, ids[:8]) < 1e-5
@@ -335,8 +342,8 @@ def test_commit_inside_the_next_blocks_first_pass_stores_the_blocks_kv(
     prompt = _ids(prompt_len, seed=prompt_len)
     got, decode = _dispatches(lambda: _run(
         eng, Request("a", prompt, max_tokens=36 - prompt_len)))
-    assert [(d["chained"], d["fresh_rows"]) for d in decode] == [
-        (False, 1), (pipeline, 0), (pipeline, 0)]
+    assert [(d["chained"], d["fresh_rows"], d["across"]) for d in decode] == [
+        (False, 1, "none"), (pipeline, 0, "none"), (pipeline, 0, "none")]
     ids = prompt + [o.token for o in got["a"]]
     assert len(ids) == 36
     assert _pool_gap(eng, params, 0, ids[:32]) < 1e-5

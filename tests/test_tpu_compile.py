@@ -213,6 +213,29 @@ def _lower_decode(model, ec, sharding):
         *steps)
 
 
+def _lower_prefill(model, ec, bucket, nb, sharding):
+    """The prefill program as the engine calls it, where
+    `sizing.lower_prefill` describes the arguments it had before a decode
+    window was chained behind it: every slot's last token and length ride
+    through it [max_seqs] and come back with the wave's rows scattered in,
+    as the key table does. Block generation samples nothing in its prefill
+    and passes none."""
+    from benchmark import sizing
+
+    eng = sizing._bare_engine(model, ec)
+    b, mp = eng.cfg.max_seqs, eng.cfg.max_pages_per_seq
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    carry = ((None, None) if getattr(model, "block_length", 1) > 1
+             else (s((b,), jnp.int32), s((b,), jnp.int32)))
+    return eng._prefill_fn(bucket, nb, False, False).lower(
+        sizing.param_shapes(model, sharding),
+        sizing.cache_shapes(model, ec, sharding),
+        s((nb, bucket), jnp.int32), s((nb, mp), jnp.int32),
+        s((nb,), jnp.int32), s((nb,), jnp.int32), s((nb,), jnp.float32),
+        s((nb,), jnp.float32), s((nb,), jnp.int32), s((b, 2), jnp.uint32),
+        s((nb,), jnp.int32), None, s((nb,), jnp.int32), *carry)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("cell", ["decode-heavy", "hybrid-decode-heavy",
                                   "sdar-decode-heavy"])
@@ -247,7 +270,10 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     ec = manifest.traffic(made["traffic"])["engine_config"]
     one = SingleDeviceSharding(topology.devices[0])
     if program == "prefill":
-        lowered = sizing.lower_prefill(model, ec, 128, ec["max_seqs"], one)
+        lowered = _lower_prefill(model, ec, 128, ec["max_seqs"], one)
+        carried = [x and x.shape for x in lowered.out_info[4:]]
+        assert carried == ([None] * 2 if getattr(model, "block_length", 1) > 1
+                           else [(ec["max_seqs"],)] * 2)
     else:
         lowered = _lower_decode(model, ec, one)
     text = lowered.compile().as_text()
@@ -324,7 +350,7 @@ def test_jamba_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch):
     model, ec = _jamba_at_depth(8)
     whole, _ = _jamba_at_depth()
     one = SingleDeviceSharding(topology.devices[0])
-    compiled = sizing.lower_prefill(model, ec, 2048, 8, one).compile()
+    compiled = _lower_prefill(model, ec, 2048, 8, one).compile()
     peak, parts = sizing.peak_gib(compiled)
     rest = (_program_bytes(whole, ec) - _program_bytes(model, ec)) / sizing.GIB
     assert 3.5 < rest < 4.2            # 20 of 28 layers' weights and state
