@@ -142,6 +142,27 @@ class _Window(NamedTuple):
     steps: int
 
 
+# What a layer of experts sows as `expert_load` in a forward (`ops.moe.Load`),
+# under the names the spans carry it by.
+_EXPERT_LOAD = ("experts_touched", "expert_load_max", "expert_rows_held",
+                "expert_rows_routed")
+
+
+def _sown_load(sown):
+    """[layers, 4] int32: the `expert_load` collection of one forward, a row
+    a layer that routes; [0, 4] of a model that sows none."""
+    load = jax.tree.leaves(sown)
+    return (jnp.stack(load) if load
+            else jnp.zeros((0, len(_EXPERT_LOAD)), jnp.int32))
+
+
+def _load_args(load) -> Dict[str, int]:
+    """A program's expert load (sums over its forwards, whatever its shape)
+    as a span's arguments: sums over the layers."""
+    sums = np.asarray(load).reshape(-1, len(_EXPERT_LOAD)).sum(axis=0)
+    return dict(zip(_EXPERT_LOAD, map(int, sums)))
+
+
 def _leaf_bytes(x) -> int:
     """Bytes of an array (or a tracer, or a shape) from its shape alone."""
     return int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
@@ -197,6 +218,11 @@ class LLMEngine:
     and head on each row's last prompt position only (`logits_at`): logits
     [nb, 1, V] where the other families compute [nb, bucket, V] and keep a
     row.
+
+    A model whose layers in `model.expert_layer_ids` sow an `expert_load`
+    (`ops.moe.Load`, one a layer and forward) has it summed over a decode
+    window's steps and carried behind the window's tokens, and returned by
+    its prefills: the `emit` and `prefill_dispatch` spans report it.
 
     A model with `block_length` B > 1 generates by diffusion over aligned
     blocks of B positions (`_block_decode`): prefill only fills the cache
@@ -523,23 +549,27 @@ class LLMEngine:
                 params = transform(params)
             # positions of the NEW token = current length (before write).
             positions = seq_lens[:, None]
-            logits, new_caches = model.apply(
+            (logits, new_caches), sown = model.apply(
                 {"params": params}, last_tokens[:, None],
                 positions=positions, paged_kv=caches,
                 page_table=page_table, write_mask=active[:, None],
-                seq_lens=seq_lens + 1, lora=lora, lora_idx=lora_idx)
+                seq_lens=seq_lens + 1, lora=lora, lora_idx=lora_idx,
+                mutable=["expert_load"])
             logits = logits[:, 0].astype(jnp.float32)  # [B, V]
             toks, nxt, lp = sample(keys, logits, temps, top_ps, top_ks)
             # inactive slots keep their chain position
             nxt = jnp.where(active[:, None], nxt, keys)
-            return toks, new_caches, nxt, lp
+            return toks, new_caches, nxt, lp, _sown_load(sown)
 
         def decode(params, caches, last_tokens, page_table, seq_lens,
                    active, temps, top_ps, top_ks, keys, lora, lora_idx,
                    steps=None):
             """`steps` (traced, at most K) token steps for every row; the
             results keep K rows, of which the first `steps` are filled. Left
-            out (whoever lowers the program from shapes alone), K."""
+            out (whoever lowers the program from shapes alone), K. Where the
+            model sows an `expert_load`, the tokens come flat with its sums
+            over the window's steps [layers, 4] behind them, in the one
+            int32 result (as `_block_decode`'s)."""
             B = last_tokens.shape[0]
             # A free slot's row is stale while windows chain on the device
             # (the host's mirror says 0, the device's what its last request
@@ -549,11 +579,15 @@ class LLMEngine:
             out_lp = jnp.zeros((K, B), jnp.float32)
             out_tv = jnp.zeros((K, B, L), jnp.float32)
             out_ti = jnp.zeros((K, B, L), jnp.int32)
+            # (the model says which layers sow: a trace of the forward to
+            # find out would cost every family seconds a program)
+            load = jnp.zeros((len(getattr(model, "expert_layer_ids", ())),
+                              len(_EXPERT_LOAD)), jnp.int32)
 
             def body(j, carry):
                 (caches, toks, lens, keys, out, out_lp, out_tv,
-                 out_ti) = carry
-                toks, caches, keys, lp = one(
+                 out_ti, load) = carry
+                toks, caches, keys, lp, seen = one(
                     params, caches, toks, page_table, lens, active,
                     temps, top_ps, top_ks, keys, lora, lora_idx)
                 out = out.at[j].set(toks)
@@ -562,13 +596,15 @@ class LLMEngine:
                     out_tv = out_tv.at[j].set(lp[1])
                     out_ti = out_ti.at[j].set(lp[2])
                 return (caches, toks, lens + 1, keys, out, out_lp,
-                        out_tv, out_ti)
+                        out_tv, out_ti, load + seen)
 
-            (caches, last, lens, keys, out, out_lp, out_tv, out_ti) = \
-                jax.lax.fori_loop(
+            (caches, last, lens, keys, out, out_lp, out_tv, out_ti,
+             load) = jax.lax.fori_loop(
                     0, K if steps is None else steps, body,
                     (caches, last_tokens, seq_lens, keys, out, out_lp,
-                     out_tv, out_ti))
+                     out_tv, out_ti, load))
+            if load.size:
+                out = jnp.concatenate([out.reshape(-1), load.reshape(-1)])
             # Final last_tokens/seq_lens feed the NEXT window's dispatch
             # without a host round trip (pipeline_dispatch).
             lp_out = (out_lp, out_tv, out_ti) if want_lp else None
@@ -602,9 +638,8 @@ class LLMEngine:
         the block a row is on (a prompt's remainder, then MASK); the token
         of a position is reported with the logprobs of the pass that
         revealed it, and behind the tokens [K, rows], in the one int32
-        result, [layers, 2] sums over the window's forwards of the experts
-        touched and of the fullest expert's rows (whatever the model sows as
-        `expert_load`)."""
+        result, [layers, 4] sums over the window's forwards of what the model
+        sows as `expert_load` (`ops.moe.Load`)."""
         model = self.model
         B, T = self._block, model.denoising_steps
         blocks = max(1, self.cfg.decode_steps) // B
@@ -630,9 +665,7 @@ class LLMEngine:
                     write_mask=jnp.broadcast_to(write[:, None], ids.shape),
                     seq_lens=starts + B, logits_from=logits_from,
                     mutable=["expert_load"])
-                load = jax.tree.leaves(sown)
-                return logits, caches, (jnp.stack(load) if load else
-                                        jnp.zeros((0, 2), jnp.int32))
+                return logits, caches, _sown_load(sown)
 
             def reveal(logits, ids, keys, rec):
                 """A pass's sampling: B / steps more of `ids` revealed."""
@@ -756,15 +789,20 @@ class LLMEngine:
             # The head on one position a row where the model takes it so:
             # logits [nb, 1, V], not [nb, bucket, V] of which one row is kept.
             at = {"logits_at": true_lens - 1} if self._head_last else {}
-            logits, new_caches = model.apply(
+            (logits, new_caches), sown = model.apply(
                 {"params": params}, ids, positions=positions,
                 paged_kv=caches, page_table=rows,
                 write_mask=mask, seq_lens=starts + true_lens,
-                lora=lora, lora_idx=lora_idx, slots=slots, **at)
+                lora=lora, lora_idx=lora_idx, slots=slots,
+                mutable=["expert_load"], **at)
             if self._block > 1:
                 # Cache fill only: the first block's passes sample its
-                # tokens, and with the logits unused no head is compiled.
-                return None, new_caches, all_keys, None, None, None
+                # tokens, and with the logits unused no head is compiled
+                # (nor the load counted: the host reads nothing of this
+                # program).
+                return None, new_caches, all_keys, None, None, None, None
+            # [layers, 4] of this forward, or None of a model without experts
+            load = _sown_load(sown) if sown else None
             last = (logits[:, 0] if at else
                     logits[jnp.arange(nb), true_lens - 1]).astype(
                 jnp.float32)  # [nb, V]
@@ -775,7 +813,8 @@ class LLMEngine:
             if last_tokens is not None:
                 last_tokens = last_tokens.at[slots].set(toks)
                 seq_lens = seq_lens.at[slots].set(starts + true_lens)
-            return toks, new_caches, all_keys, lp, last_tokens, seq_lens
+            return (toks, new_caches, all_keys, lp, load, last_tokens,
+                    seq_lens)
 
         fn = jax.jit(prefill, donate_argnums=(1,))
         self._prefill_fns[(bucket, nb, rich, want_lp)] = fn
@@ -1055,12 +1094,11 @@ class LLMEngine:
             if lp is not None:
                 lp = tuple(np.asarray(a) for a in lp)
         load = {}
-        if self._block > 1:
-            # Behind the tokens: [layers, 2] sums over the window's forwards.
+        if toks.ndim == 1:
+            # Behind the tokens: the expert load summed over the window's
+            # forwards (a model that routes).
             cut = max(1, self.cfg.decode_steps) * self.cfg.max_seqs
-            touched, fullest = toks[cut:].reshape(-1, 2).sum(axis=0)
-            load = {"experts_touched": int(touched),
-                    "expert_load_max": int(fullest)}
+            load = _load_args(toks[cut:])
             toks = toks[:cut].reshape(-1, self.cfg.max_seqs)
         if out is None:
             return False
@@ -1322,8 +1360,15 @@ class LLMEngine:
                           state_rows=nb * self._state_layers,
                           scan_positions=nb * bucket * self._state_layers,
                           head_rows=(0 if self._block > 1 else nb
-                                     if self._head_last else nb * bucket)):
-                dev_toks, lp, carry = self._prefill_wave(key, wave, carry)
+                                     if self._head_last else nb * bucket)
+                          ) as sp:
+                dev_toks, lp, load, carry = self._prefill_wave(key, wave,
+                                                               carry)
+                if load is not None:
+                    # A model that routes: the span waits for its prefill's
+                    # counts (the device then idles for the host's next
+                    # dispatch, once a wave).
+                    sp.set(**_load_args(load))
             for i, (slot, req, _, cached_len, _) in enumerate(wave):
                 pending.append((slot, req, dev_toks, lp, i, nb, cached_len))
             done.update(batch)
@@ -1354,7 +1399,7 @@ class LLMEngine:
             tks[i] = req.top_k
             slot_ids[i] = slot
             lidx[i] = self.lora_idx[slot]
-        dev_toks, self.caches, self._keys_dev, lp, *carry = \
+        dev_toks, self.caches, self._keys_dev, lp, load, *carry = \
             self._run_program(
                 "prefill", key, self._prefill_fn(*key), (
                     self.params, self.caches, self._dev(ids),
@@ -1362,7 +1407,7 @@ class LLMEngine:
                     self._dev(temps), self._dev(tps), self._dev(tks),
                     self._keys_dev, self._dev(slot_ids), self.lora_banks,
                     self._dev(lidx), *carry))
-        return dev_toks, lp, tuple(carry)
+        return dev_toks, lp, load, tuple(carry)
 
     def _sync_first_tokens(self, pending: List[tuple],
                            out: List[StepOutput]) -> None:

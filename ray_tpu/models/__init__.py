@@ -34,6 +34,9 @@ FAMILIES = {
                        "ray_tpu.models.sdar_moe:SdarMoeModel"),
     "jamba": Family("ray_tpu.models.jamba:JambaConfig",
                     "ray_tpu.models.jamba:JambaModel"),
+    "granite_hybrid": Family(
+        "ray_tpu.models.granite_hybrid:GraniteHybridConfig",
+        "ray_tpu.models.granite_hybrid:GraniteHybridModel"),
 }
 
 

@@ -15,6 +15,11 @@ owns no tile, so its weights are never read; one that got up to `tm` rows is
 read once. At decode (a few rows an expert) the kernel is bound by streaming
 the experts' weights, so its tiles are short (16 rows) and its weight blocks
 large (3 MiB).
+
+A chip that shares a layer with others by expert parallelism holds some of
+the router's columns (`held = (first, count)`, static): routing stays over
+all of them, an assignment to an expert that is held elsewhere gets no row
+and no tile here, and the layer returns its own experts' part of the sum.
 """
 
 from __future__ import annotations
@@ -29,6 +34,9 @@ from jax.experimental import pallas as pl
 # Bytes of one weight block the kernel streams (double-buffered in VMEM).
 RHS_BLOCK_BYTES = 3 * 2 ** 20
 MIN_TILE_ROWS, MAX_TILE_ROWS = 16, 128
+# Tokens whose chosen rows are gathered and weighted at a time: [tokens,
+# top_k, H] float32 of a prefill's 16,384 tokens would be gigabytes.
+COMBINE_TOKENS = 1024
 
 
 def route(x: jax.Array, router: jax.Array, top_k: int,
@@ -52,7 +60,15 @@ class Plan(NamedTuple):
     dest: jax.Array         # [T,k] the row of token t's j-th assignment
     tile_expert: jax.Array  # [M/tm] the expert whose weights tile i takes
     tiles_used: jax.Array   # [1] tiles that hold a row; the rest are skipped
-    sizes: jax.Array        # [E] rows an expert got
+    sizes: jax.Array        # [E] rows an expert (of those held) got
+
+
+class Load(NamedTuple):
+    """What one call of the layer routed, int32 scalars."""
+    touched: jax.Array      # experts held here that some token chose
+    fullest: jax.Array      # rows of the fullest of them
+    rows_held: jax.Array    # assignments that fell on experts held here
+    rows_routed: jax.Array  # all assignments: tokens * top_k
 
 
 def tile_rows(assignments: int, num_experts: int) -> int:
@@ -64,19 +80,31 @@ def tile_rows(assignments: int, num_experts: int) -> int:
     return tm
 
 
-def plan(experts: jax.Array, num_experts: int,
-         tm: Optional[int] = None) -> Plan:
+def plan(experts: jax.Array, num_experts: int, tm: Optional[int] = None,
+         held: Optional[Tuple[int, int]] = None) -> Plan:
     """experts [T,k] -> the tile-aligned layout. Its length is static: every
-    expert's rows rounded up to whole tiles cannot pass `T*k + E*(tm-1)`."""
+    expert's rows rounded up to whole tiles cannot pass `T*k + E*(tm-1)`.
+    `held = (first, count)`: only the assignments to experts first ..
+    first+count-1 of the `num_experts` get a row (`dest` of another is -1);
+    tiles and `sizes` are over those `count`, numbered from 0 as the weight
+    stacks hold them."""
     t, k = experts.shape
     a = t * k
-    tm = tm or tile_rows(a, num_experts)
-    tiles = (a + min(a, num_experts) * (tm - 1) + tm - 1) // tm
     flat = experts.reshape(a)
+    # (the mean rows of an expert are those over all the router's columns)
+    tm = tm or tile_rows(a, num_experts)
+    bins = num_experts
+    if held is not None:
+        first, num_experts = held
+        local = flat - first
+        here = (local >= 0) & (local < num_experts)
+        # An absent expert's assignments sort behind every held one's.
+        flat, bins = jnp.where(here, local, num_experts), num_experts + 1
+    tiles = (a + min(a, num_experts) * (tm - 1) + tm - 1) // tm
     order = jnp.argsort(flat, stable=True)  # sorted place -> assignment
     place = jnp.zeros((a,), jnp.int32).at[order].set(
         jnp.arange(a, dtype=jnp.int32))     # assignment -> sorted place
-    sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((bins,), jnp.int32).at[flat].add(1)[:num_experts]
     starts = jnp.cumsum(sizes) - sizes
     padded = (sizes + tm - 1) // tm * tm
     ends = jnp.cumsum(padded)
@@ -91,8 +119,11 @@ def plan(experts: jax.Array, num_experts: int,
     e = tile_expert[row // tm]
     within = jnp.minimum(row - pstarts[e], sizes[e] - 1)  # padding repeats
     row_token = order[jnp.clip(starts[e] + within, 0, a - 1)] // k
-    dest = (pstarts[flat] + place - starts[flat]).reshape(t, k)
-    return Plan(tm, row_token, dest, tile_expert, tiles_used, sizes)
+    dest = pstarts[flat] + place - starts[flat]
+    if held is not None:  # (an absent expert's index was clamped)
+        dest = jnp.where(here, dest, -1)
+    return Plan(tm, row_token, dest.reshape(t, k), tile_expert, tiles_used,
+                sizes)
 
 
 def _gmm_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, rhs_ref, out_ref):
@@ -141,7 +172,9 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
         """(tile, column block) whose blocks step (t, j) takes: its own, or
         for a skipped tile those of the last step that did any work."""
         skip = t >= used[0]
-        return (jnp.where(skip, used[0] - 1, t), jnp.where(skip, last, j))
+        # (no tile at all in use: a share none of whose experts was chosen)
+        return (jnp.where(skip, jnp.maximum(used[0] - 1, 0), t),
+                jnp.where(skip, last, j))
 
     def lhs_map(t, j, tile_expert, used):
         return (held(t, j, used)[0], 0)
@@ -173,20 +206,43 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
 def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
               down: jax.Array, top_k: int,
               use_kernel: Optional[bool] = None,
-              interpret: Optional[bool] = None):
+              interpret: Optional[bool] = None,
+              held: Optional[Tuple[int, int]] = None):
     """The layer over x [T,H]: router [H,E] float32, gate_up [E,H,2I] (an
     expert's gate columns, then its up columns), down [E,I,H]. Returns
-    (y [T,H], (experts touched, rows of the fullest expert)), the pair as
-    int32 scalars of this call."""
-    num_experts, _, two_i = gate_up.shape
+    (y [T,H], `Load` of this call). With `held = (first, count)` the stacks
+    are [count, ...], the experts of the router's columns first ..
+    first+count-1, and y is their part of the sum: what a token's other
+    chosen experts would add is computed where they are held."""
+    num_experts, two_i = router.shape[1], gate_up.shape[2]
+    count = num_experts if held is None else held[1]
+    if gate_up.shape[0] != count or down.shape[0] != count:
+        raise ValueError(f"moe_layer: stacks of {gate_up.shape[0]} and "
+                         f"{down.shape[0]} experts where {count} are held")
     weights, experts = route(x, router, top_k)
-    p = plan(experts, num_experts)
+    p = plan(experts, num_experts, held=held)
     run = functools.partial(gmm, p=p, use_kernel=use_kernel,
                             interpret=interpret)
     gu = run(jnp.take(x, p.row_token, axis=0), gate_up).astype(jnp.float32)
     act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
     y = run(act.astype(x.dtype), down)                      # [M,H]
-    picked = jnp.take(y, p.dest, axis=0).astype(jnp.float32)  # [T,k,H]
-    out = jnp.einsum("tk,tkh->th", weights, picked).astype(x.dtype)
-    return out, (jnp.sum(p.sizes > 0).astype(jnp.int32),
-                 jnp.max(p.sizes).astype(jnp.int32))
+
+    def combine(w, dest):
+        picked = jnp.take(y, dest, axis=0).astype(jnp.float32)  # [t,k,H]
+        if held is not None:
+            # An absent expert's assignment has no row: whatever the row its
+            # -1 names holds (an unused tile's is never written) is not read
+            # into the sum.
+            picked = jnp.where((dest >= 0)[..., None], picked, 0.0)
+        return jnp.einsum("tk,tkh->th", w, picked).astype(x.dtype)
+
+    t = x.shape[0]
+    if t > COMBINE_TOKENS and t % COMBINE_TOKENS == 0:
+        blocks = lambda a: a.reshape(t // COMBINE_TOKENS, COMBINE_TOKENS, -1)
+        out = jax.lax.map(lambda b: combine(*b),
+                          (blocks(weights), blocks(p.dest))).reshape(x.shape)
+    else:
+        out = combine(weights, p.dest)
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    return out, Load(i32(jnp.sum(p.sizes > 0)), i32(jnp.max(p.sizes)),
+                     i32(jnp.sum(p.sizes)), i32(t * top_k))

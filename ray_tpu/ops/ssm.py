@@ -18,6 +18,20 @@ pad 16 to 128 lanes and move eight times the bytes).
 `causal_conv` the depthwise convolution in front of both with the tail it
 leaves for the next token. Plain `jax.numpy` forms of the same run on the
 CPU.
+
+Mamba-2 (SSD, arXiv:2405.21060) is another recurrence: the channels come in
+heads of P, a head has ONE scalar decay a token, and B and C are shared by
+all heads,
+
+    S_t[h] = exp(dt_t[h] A[h]) . S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t   [P, N]
+    y_t[h] = S_t[h] C_t + D[h] . x_t[h]
+
+so that a chunk of Q positions is matrix products (`ssd_scan`): with c_i the
+running sum of dt A inside the chunk, Y = ((C B^T) o L) (dt x) + exp(c) .
+(C S_0^T), L_ij = exp(c_i - c_j) for j <= i, and the chunk hands on S_Q =
+exp(c_Q) S_0 + (exp(c_Q - c) dt x)^T B. A state is [H, P, N] float32, the N
+states on the lanes. `ssd_step` is its one-token update, `ssd_scan_plain`
+the recurrence token by token.
 """
 
 from __future__ import annotations
@@ -34,6 +48,10 @@ from jax.experimental import pallas as pl
 # float32 is 8 vector registers.
 SCAN_CHUNK = 128
 SCAN_CHANNELS = 512
+# Mamba-2: positions of a chunk (the published `mamba_chunk_size`) and heads a
+# grid step of `ssd_scan` takes (its loop over them is unrolled).
+SSD_CHUNK = 256
+SSD_HEADS = 8
 
 
 def causal_conv(x: jax.Array, taps: jax.Array, bias: jax.Array,
@@ -210,3 +228,192 @@ def ssm_step(x, dt, b, c, z, a, d, h, active):
     y = jnp.sum(new * c[:, :, None], axis=1) + d * x
     return (y * jax.nn.silu(z),
             jnp.where(active[:, None, None], new, h))
+
+
+def ssd_scan_plain(x, dt, b, c, a, d, s0=None):
+    """Mamba-2's recurrence token by token (`lax.scan`), float32: x
+    [B, L, H, P], dt [B, L, H] (after softplus; 0 at a position that must
+    change nothing), b, c [B, L, N], a [H] = -exp(A_log), d [H], s0
+    [B, H, P, N] or zero. Returns (y [B, L, H, P] float32, S_L [B, H, P, N]):
+    y before the gate and its norm, which are the model's."""
+    f32 = lambda t: t.astype(jnp.float32)
+    x, dt, b, c = map(f32, (x, dt, b, c))
+    if s0 is None:
+        s0 = jnp.zeros(x.shape[:1] + x.shape[2:] + b.shape[-1:], jnp.float32)
+
+    def token(s, xs):
+        xt, dtt, bt, ct = xs  # [B, H, P], [B, H], [B, N], [B, N]
+        s = (jnp.exp(dtt * a)[:, :, None, None] * s
+             + (dtt[:, :, None] * xt)[..., None] * bt[:, None, None, :])
+        return s, jnp.sum(s * ct[:, None, None, :], axis=-1)
+
+    time_major = lambda t: jnp.swapaxes(t, 0, 1)
+    s, y = jax.lax.scan(token, s0, tuple(map(time_major, (x, dt, b, c))))
+    return time_major(y) + d[:, None] * x, s
+
+
+def ssd_step(x, dt, b, c, a, d, s, active):
+    """One token a row on the state pool: x [B, H, P], dt [B, H], b, c
+    [B, N], s [B, H, P, N] float32 (a row per engine slot), active [B] bool.
+    Returns (y [B, H, P] float32, s): an inactive row's state is left as it
+    was. Plain `jax.numpy`, in place on the donated pool (as `ssm_step`)."""
+    f32 = lambda t: t.astype(jnp.float32)
+    x, dt, b, c = map(f32, (x, dt, b, c))
+    new = (jnp.exp(dt * a)[:, :, None, None] * s
+           + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
+    y = jnp.sum(new * c[:, None, None, :], axis=-1) + d[:, None] * x
+    return y, jnp.where(active[:, None, None, None], new, s)
+
+
+def _ssd_scan_kernel(lens_ref, x_ref, cols_ref, rows_ref, b_ref, c_ref,
+                     d_ref, y_ref, s_ref, g_scr, dx_scr, *, chunk: int,
+                     heads: int, width: int):
+    """Grid (rows, chunks, head blocks), the head blocks innermost: the
+    row's whole state [H*P, N] is the output block, which stays in VMEM from
+    the row's first chunk to its last, and C B^T of a chunk (lower triangle)
+    is made once, at its first head block. cols [chunk, 2*heads]: per head
+    the running sum c of dt A inside the chunk, then dt, down the sublanes;
+    rows [heads, chunk]: c along the lanes. A chunk past the row's length is
+    skipped (index maps stop at the last chunk in use)."""
+    r, t, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    block = heads * width
+
+    @pl.when((t == 0) & (g == 0))
+    def _zero():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(t * chunk >= lens_ref[r])
+    def _skip():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(t * chunk < lens_ref[r])
+    def _chunk():
+        mm = x_ref.dtype
+        bm, cm = b_ref[0].astype(mm), c_ref[0].astype(mm)    # [chunk, N]
+
+        @pl.when(g == 0)
+        def _cb():
+            cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            i = jax.lax.broadcasted_iota(jnp.int32, cb.shape, 0)
+            j = jax.lax.broadcasted_iota(jnp.int32, cb.shape, 1)
+            g_scr[...] = jnp.where(j <= i, cb, 0.0)
+
+        at = pl.ds(pl.multiple_of(g * block, block), block)
+        s = s_ref[0, at, :]                                  # [block, N]
+        carried = jax.lax.dot_general(                       # C S^T
+            cm, s.astype(mm), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [chunk, block]
+        ends = []
+        for h in range(heads):  # static: every slice is a constant
+            ch = slice(h * width, (h + 1) * width)
+            cum = cols_ref[0, 0, :, h:h + 1]                 # [chunk, 1]
+            dt = cols_ref[0, 0, :, heads + h:heads + h + 1]
+            along = rows_ref[0, 0, h:h + 1, :]               # [1, chunk]
+            # The chunk's last (dt A <= 0: its least), as a reduction: a
+            # slice [1, 1] is not broadcast over sublanes and lanes at once.
+            end = jnp.min(cum, axis=0, keepdims=True)        # [1, 1]
+            # j <= i: c_i - c_j <= 0 (A < 0); above the diagonal C B^T is 0.
+            decay = jnp.exp(jnp.minimum(cum - along, 0.0))
+            xh = x_ref[0, :, ch].astype(jnp.float32)         # [chunk, P]
+            dx = dt * xh
+            inside = jnp.dot((g_scr[...] * decay).astype(mm), dx.astype(mm),
+                             preferred_element_type=jnp.float32)
+            y_ref[0, :, ch] = (inside + jnp.exp(cum) * carried[:, ch]
+                               + d_ref[:, ch] * xh).astype(y_ref.dtype)
+            dx_scr[:, ch] = (dx * jnp.exp(end - cum)).astype(mm)
+            ends.append(jnp.exp(end))
+        gain = jax.lax.dot_general(                          # (w dt x)^T B
+            dx_scr[...], bm, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [block, N]
+        for h in range(heads):
+            ch = slice(h * width, (h + 1) * width)
+            s_ref[0, pl.ds(pl.multiple_of(g * block + h * width, width),
+                           width), :] = ends[h] * s[ch] + gain[ch]
+
+
+def ssd_scan_kernel(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
+                    heads: int = SSD_HEADS,
+                    interpret: Optional[bool] = None):
+    """Shapes as `ssd_scan`. x is read once and y written once, B and C once
+    a chunk, the state written once a row; the matrix products take x's
+    dtype (float32 sums), the decays and the state are float32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    rows, length, h, p = x.shape
+    n = b.shape[-1]
+    chunk = min(chunk, length)
+    heads = min(heads, h)
+    if length % chunk or h % heads:
+        raise ValueError(f"ssd_scan: {length} positions in chunks of {chunk},"
+                         f" {h} heads in blocks of {heads}")
+    chunks, blocks, block = length // chunk, h // heads, heads * p
+    dt = dt.astype(jnp.float32)
+    # The running sum of dt A from each chunk's first position on.
+    cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(
+        rows, chunks, chunk, h), axis=2).reshape(rows, length, blocks, heads)
+    by_block = lambda m: m.reshape(rows, length, blocks, heads)
+    cols = jnp.concatenate([cum, by_block(dt)], axis=-1).transpose(0, 2, 1, 3)
+    along = cum.transpose(0, 2, 3, 1)       # [rows, blocks, heads, length]
+
+    def at(r, t, lens):  # chunk t, or the last one the row uses if past it
+        last = jnp.maximum(jax.lax.div(lens[r] + chunk - 1, chunk) - 1, 0)
+        return jnp.minimum(t, last)
+
+    tile = lambda r, t, g, lens: (r, at(r, t, lens), g)
+    shared = pl.BlockSpec((1, chunk, n),
+                          lambda r, t, g, lens: (r, at(r, t, lens), 0))
+    y, s = pl.pallas_call(
+        functools.partial(_ssd_scan_kernel, chunk=chunk, heads=heads,
+                          width=p),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, chunks, blocks),
+            in_specs=[
+                pl.BlockSpec((1, chunk, block), tile),
+                pl.BlockSpec((1, 1, chunk, 2 * heads),
+                             lambda r, t, g, lens: (r, g, at(r, t, lens), 0)),
+                pl.BlockSpec((1, 1, heads, chunk),
+                             lambda r, t, g, lens: (r, g, 0, at(r, t, lens))),
+                shared, shared,
+                pl.BlockSpec((1, block), lambda r, t, g, lens: (0, g)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, block),
+                             lambda r, t, g, lens: (r, t, g)),
+                pl.BlockSpec((1, h * p, n), lambda r, t, g, lens: (r, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((chunk, chunk), jnp.float32),
+                            pltpu.VMEM((chunk, block), x.dtype)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((rows, length, h * p), x.dtype),
+                   jax.ShapeDtypeStruct((rows, h * p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 2 ** 20),
+        interpret=interpret,
+        name="ssd_scan",
+    )(lens.astype(jnp.int32), x.reshape(rows, length, h * p), cols, along,
+      b, c, jnp.repeat(d.astype(jnp.float32), p).reshape(1, h * p))
+    return y.reshape(x.shape), s.reshape(rows, h, p, n)
+
+
+def ssd_scan(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
+             use_kernel: Optional[bool] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """A prefill's positions from a zero state: x [B, L, H, P] and b, c
+    [B, L, N] (the compute dtype), dt [B, L, H] float32, a [H] = -exp(A_log)
+    and d [H] float32, lens [B] the rows' true lengths. A position at or past
+    its row's length must come with dt = 0 and a finite x: it then changes
+    nothing, and the kernel does not walk a chunk (of `chunk` positions)
+    that holds only such. Returns (y [B, L, H, P] in x's dtype, the state
+    after the last true position [B, H, P, N] float32). The Pallas kernel on
+    a TPU, the recurrence token by token elsewhere (as `ssm_scan`)."""
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if use_kernel:
+        return ssd_scan_kernel(x, dt, b, c, a, d, lens, chunk)
+    y, s = ssd_scan_plain(x, dt, b, c, a, d)
+    return y.astype(x.dtype), s

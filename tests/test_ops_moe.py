@@ -60,7 +60,7 @@ def _skewed(tokens=24, seed=1):
 
 def test_layer_matches_the_token_by_token_oracle_under_skewed_routing():
     x, router, gate_up, down = _skewed()
-    y, (touched, fullest) = moe.moe_layer(x, router, gate_up, down, K)
+    y, (touched, fullest, *_) = moe.moe_layer(x, router, gate_up, down, K)
     want, load = _oracle(x, router, gate_up, down, K)
     assert load[0] == 24 and load[1] == 12 and not load[13:].any()
     assert load.sum() == 24 * K                      # nothing dropped
@@ -73,7 +73,7 @@ def test_layer_matches_the_token_by_token_oracle_under_skewed_routing():
 def test_layer_matches_the_oracle_on_random_routing(tokens):
     x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, H))
     router, gate_up, down = _weights(seed=3)
-    y, (touched, _) = moe.moe_layer(x, router, gate_up, down, K)
+    y, (touched, *_) = moe.moe_layer(x, router, gate_up, down, K)
     want, load = _oracle(x, router, gate_up, down, K)
     np.testing.assert_allclose(np.asarray(y), want, atol=TOL, rtol=TOL)
     assert int(touched) == (load > 0).sum()

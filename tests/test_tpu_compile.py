@@ -75,7 +75,8 @@ def _kernel_names(text):
         if "tpu_custom_call" in line and " = " in line:
             instruction = line.split(" = ", 1)[0]
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv", "ssm_scan")
+                                 "flash_bwd_dkv", "ssm_scan", "ssd_scan",
+                                 "moe_gmm")
                      if k in instruction}
     return held
 
@@ -271,7 +272,8 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     one = SingleDeviceSharding(topology.devices[0])
     if program == "prefill":
         lowered = _lower_prefill(model, ec, 128, ec["max_seqs"], one)
-        carried = [x and x.shape for x in lowered.out_info[4:]]
+        # (behind the tokens, caches, keys, logprobs and the expert load)
+        carried = [x and x.shape for x in lowered.out_info[5:]]
         assert carried == ([None] * 2 if getattr(model, "block_length", 1) > 1
                            else [(ec["max_seqs"],)] * 2)
     else:
@@ -288,21 +290,27 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
         assert "paged_decode" in _kernel_names(text)
 
 
-def _jamba_at_depth(layers=None):
-    """(`jamba-prompt-heavy`'s family, its model at `layers` layers or all,
-    its engine shapes)."""
+def _cell_at_depth(cell, layers=None):
+    """(the cell's model at its first `layers` layers or all, its engine
+    shapes)."""
     from benchmark.manifest import Manifest
 
     manifest = Manifest(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    made = manifest.cell("jamba-prompt-heavy")
+    made = manifest.cell(cell)
     cfg = manifest.config(made["config"])
     family = manifest.family(cfg["family"])
     kw = family.model_kwargs(cfg)
-    if layers is not None:
+    if layers is not None and "layer_types" in kw:
+        kw["layer_types"] = kw["layer_types"][:layers]
+    elif layers is not None:
         kw["num_layers"] = layers
     return family.model(kw), manifest.traffic(made["traffic"])[
         "engine_config"]
+
+
+def _jamba_at_depth(layers=None):
+    return _cell_at_depth("jamba-prompt-heavy", layers)
 
 
 def _program_bytes(model, ec):
@@ -359,6 +367,76 @@ def test_jamba_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch):
     text = compiled.as_text()
     assert "ssm_scan" in _kernel_names(text)
     assert "[8,2048,65536]" not in text and "f32[8,65536]" in text
+
+
+def test_granite_decode_streams_its_share_and_updates_the_pool_in_place(
+        topology, monkeypatch):
+    """The chip compiler's HLO of `granite-prompt-heavy`'s decode window at
+    the published widths (six layers: five Mamba-2 layers and the attention
+    layer after them, 36 of 72 experts held): the one-token step is plain
+    `jax.numpy` on the donated pool, and no instruction rewrites a layer's
+    float32 state [8, 128, 64, 128] or a K/V pool; the experts are the
+    grouped-matmul kernel over stacks of 36, the attention layer the paged
+    kernel."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("granite-prompt-heavy", 6)
+    one = SingleDeviceSharding(topology.devices[0])
+    text = _lower_decode(model, ec, one).compile().as_text()
+    caches = sizing.cache_shapes(model, ec, None)
+    state, pages = caches[0][1], caches[5][0]
+    assert (state.shape, state.dtype) == ((8, 128, 64, 128), jnp.float32)
+    assert pages.shape == (8 * 36 + 1, 64, 8 * 128)
+    assert f"f32[{','.join(map(str, state.shape))}]" in text
+    for pool in (state, pages):
+        assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert {"paged_decode", "moe_gmm"} <= _kernel_names(text)
+    assert "bf16[36,4096,1536]" in text and "bf16[72," not in text
+
+
+def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch):
+    """Prefill of 8 prompts in the 2,048 bucket, the largest program of
+    `granite-prompt-heavy`: compiled at six layers (the temporaries are a
+    layer's: the expert layer's rows laid out by expert are the largest, and
+    attention keeps one row's scores) with the other four layers' weights and
+    state added from their shapes, it peaks under 14.75 GiB of a v5e's 15.75
+    with the whole vocabulary held; the scan is the Pallas kernel `ssd_scan`,
+    the experts `moe_gmm`, and no logits of every position are made."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("granite-prompt-heavy", 6)
+    whole, _ = _cell_at_depth("granite-prompt-heavy")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_prefill(model, ec, 2048, 8, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    rest = (_program_bytes(whole, ec) - _program_bytes(model, ec)) / sizing.GIB
+    assert 3.4 < rest < 3.6            # 4 of 10 layers' weights and state
+    assert 1.0 < parts["temp"] < 3.5   # not the 4.5 GiB of a wave's scores
+    assert peak + rest < 14.75
+    text = compiled.as_text()
+    assert {"ssd_scan", "moe_gmm"} <= _kernel_names(text)
+    assert "[8,2048,100352]" not in text and "f32[8,100352]" in text
+    assert "f32[8,32,2048,2304]" not in text
+
+
+def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
+    """`ssd_scan` alone for a wave of 8 x 2,048 positions at Granite's 128
+    heads of 64 with 128 states: the Mosaic compiler takes its blocks, its
+    dynamic slices of the resident state and its 48 MiB of VMEM."""
+    from ray_tpu.ops.ssm import ssd_scan_kernel
+
+    one = SingleDeviceSharding(topology.devices[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    b, length, h, p, n = 8, 2048, 128, 64, 128
+    text = _compiled_text(
+        functools.partial(ssd_scan_kernel, interpret=False),
+        s((b, length, h, p), jnp.bfloat16), s((b, length, h), jnp.float32),
+        s((b, length, n), jnp.bfloat16), s((b, length, n), jnp.bfloat16),
+        s((h,), jnp.float32), s((h,), jnp.float32), s((b,), jnp.int32))
+    assert "ssd_scan" in _kernel_names(text)
+    assert f"f32[{b},{h * p},{n}]" in text     # the state, N on the lanes
 
 
 # ---------------------------------------------------------------------------
