@@ -145,22 +145,15 @@ class _Window(NamedTuple):
 # What a layer of experts sows as `expert_load` in a forward (`ops.moe.Load`),
 # under the names the spans carry it by.
 _EXPERT_LOAD = ("experts_touched", "expert_load_max", "expert_rows_held",
-                "expert_rows_routed")
+                "expert_rows_routed", "expert_tiles")
 
 
 def _sown_load(sown):
-    """[layers, 4] int32: the `expert_load` collection of one forward, a row
-    a layer that routes; [0, 4] of a model that sows none."""
+    """[layers, 5] int32: the `expert_load` collection of one forward, a row
+    a layer that routes; [0, 5] of a model that sows none."""
     load = jax.tree.leaves(sown)
     return (jnp.stack(load) if load
             else jnp.zeros((0, len(_EXPERT_LOAD)), jnp.int32))
-
-
-def _load_args(load) -> Dict[str, int]:
-    """A program's expert load (sums over its forwards, whatever its shape)
-    as a span's arguments: sums over the layers."""
-    sums = np.asarray(load).reshape(-1, len(_EXPERT_LOAD)).sum(axis=0)
-    return dict(zip(_EXPERT_LOAD, map(int, sums)))
 
 
 def _leaf_bytes(x) -> int:
@@ -353,6 +346,8 @@ class LLMEngine:
         self._freed = False
         self._windows = {"unchained": 0, "none": 0, "finish": 0,
                          "admission": 0}
+        # What the prefills and decode windows read so far have routed.
+        self._expert_load = dict.fromkeys(_EXPERT_LOAD, 0)
         # Compile record: (kind, key) -> [jit cache size after the last
         # call, argument signature it was last traced for], and the last
         # records of programs built and retraced.
@@ -568,7 +563,7 @@ class LLMEngine:
             results keep K rows, of which the first `steps` are filled. Left
             out (whoever lowers the program from shapes alone), K. Where the
             model sows an `expert_load`, the tokens come flat with its sums
-            over the window's steps [layers, 4] behind them, in the one
+            over the window's steps [layers, 5] behind them, in the one
             int32 result (as `_block_decode`'s)."""
             B = last_tokens.shape[0]
             # A free slot's row is stale while windows chain on the device
@@ -638,7 +633,7 @@ class LLMEngine:
         the block a row is on (a prompt's remainder, then MASK); the token
         of a position is reported with the logprobs of the pass that
         revealed it, and behind the tokens [K, rows], in the one int32
-        result, [layers, 4] sums over the window's forwards of what the model
+        result, [layers, 5] sums over the window's forwards of what the model
         sows as `expert_load` (`ops.moe.Load`)."""
         model = self.model
         B, T = self._block, model.denoising_steps
@@ -801,7 +796,7 @@ class LLMEngine:
                 # (nor the load counted: the host reads nothing of this
                 # program).
                 return None, new_caches, all_keys, None, None, None, None
-            # [layers, 4] of this forward, or None of a model without experts
+            # [layers, 5] of this forward, or None of a model without experts
             load = _sown_load(sown) if sown else None
             last = (logits[:, 0] if at else
                     logits[jnp.arange(nb), true_lens - 1]).astype(
@@ -1065,6 +1060,23 @@ class LLMEngine:
                                   self._decode_args(last, lens, K))
         return _Window(toks, last, lens, lp, dict(self.running), K)
 
+    def _count_load(self, load) -> Dict[str, int]:
+        """A program's expert load (sums over its forwards, whatever its
+        shape) as its span's arguments, sums over the layers; added to the
+        sums `expert_load_report` gives."""
+        sums = np.asarray(load).reshape(-1, len(_EXPERT_LOAD)).sum(axis=0)
+        args = dict(zip(_EXPERT_LOAD, map(int, sums)))
+        for name, n in args.items():
+            self._expert_load[name] += n
+        return args
+
+    def expert_load_report(self) -> Dict[str, int]:
+        """`ops.moe.Load` summed over every prefill and decode window read so
+        far, layers and forwards (all 0 for a model without experts):
+        `expert_tiles` over `experts_touched` is how many tiles shared one
+        read of an expert's weights."""
+        return dict(self._expert_load)
+
     def windows_report(self) -> Dict[str, int]:
         """Decode windows dispatched: `unchained` from the host mirrors, and
         chained off the window before by what the chain outlived (`none`,
@@ -1098,7 +1110,7 @@ class LLMEngine:
             # Behind the tokens: the expert load summed over the window's
             # forwards (a model that routes).
             cut = max(1, self.cfg.decode_steps) * self.cfg.max_seqs
-            load = _load_args(toks[cut:])
+            load = self._count_load(toks[cut:])
             toks = toks[:cut].reshape(-1, self.cfg.max_seqs)
         if out is None:
             return False
@@ -1368,7 +1380,7 @@ class LLMEngine:
                     # A model that routes: the span waits for its prefill's
                     # counts (the device then idles for the host's next
                     # dispatch, once a wave).
-                    sp.set(**_load_args(load))
+                    sp.set(**self._count_load(load))
             for i, (slot, req, _, cached_len, _) in enumerate(wave):
                 pending.append((slot, req, dev_toks, lp, i, nb, cached_len))
             done.update(batch)

@@ -324,6 +324,9 @@ class LLMServer:
             # (the chip waited for that dispatch), and chained off the window
             # before by what the chain outlived: none, finish, admission.
             "decode_windows": self.engine.windows_report(),
+            # What the experts' layers routed, summed since the engine began
+            # (all 0 for a model without experts).
+            "expert_load": self.engine.expert_load_report(),
             # Which device answers: the devices this engine computes on, as
             # JAX reports them in this process, and the chips it was leased.
             "pid": os.getpid(),

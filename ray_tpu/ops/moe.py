@@ -11,10 +11,15 @@ are sorted by expert and laid out so that every expert's rows start on a tile
 of `tm` rows (`Plan`): a tile then belongs to one expert, and the grouped
 matmul (`gmm`, on the TPU the Pallas kernel `moe_gmm`) is a grid over tiles
 whose weight block is picked by the tile's expert. An expert no token chose
-owns no tile, so its weights are never read; one that got up to `tm` rows is
-read once. At decode (a few rows an expert) the kernel is bound by streaming
-the experts' weights, so its tiles are short (16 rows) and its weight blocks
-large (3 MiB).
+owns no tile, so its weights are never read; any other's are read once a
+call, however many tiles it owns: the tiles are walked innermost under a
+fixed block of columns, an expert's tiles lie side by side, and a block whose
+index does not change from one step to the next is not fetched again. The
+block takes as many columns as VMEM holds (all of them at the widths served
+so far). At decode (a few rows an expert) the kernel is bound by streaming
+the experts' weights, so its tiles are short (16 rows); at prefill (hundreds
+of rows an expert, tiles of 128) by the arithmetic of the tiles that share a
+block.
 
 A chip that shares a layer with others by expert parallelism holds some of
 the router's columns (`held = (first, count)`, static): routing stays over
@@ -31,8 +36,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Bytes of one weight block the kernel streams (double-buffered in VMEM).
-RHS_BLOCK_BYTES = 3 * 2 ** 20
+# VMEM the kernel may take (a v5e core has 128 MiB), and the share of it that
+# a grid step's blocks may fill.
+VMEM_LIMIT_BYTES = 48 * 2 ** 20
+VMEM_BLOCKS_SHARE = 0.75
 MIN_TILE_ROWS, MAX_TILE_ROWS = 16, 128
 # Tokens whose chosen rows are gathered and weighted at a time: [tokens,
 # top_k, H] float32 of a prefill's 16,384 tokens would be gigabytes.
@@ -69,6 +76,7 @@ class Load(NamedTuple):
     fullest: jax.Array      # rows of the fullest of them
     rows_held: jax.Array    # assignments that fell on experts held here
     rows_routed: jax.Array  # all assignments: tokens * top_k
+    tiles: jax.Array        # tiles in use (over `touched`: tiles a weight read)
 
 
 def tile_rows(assignments: int, num_experts: int) -> int:
@@ -127,20 +135,53 @@ def plan(experts: jax.Array, num_experts: int, tm: Optional[int] = None,
 
 
 def _gmm_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, rhs_ref, out_ref):
-    @pl.when(pl.program_id(0) < tiles_used_ref[0])
+    @pl.when(pl.program_id(1) < tiles_used_ref[0])
     def _():
         out_ref[...] = jnp.dot(
             lhs_ref[...], rhs_ref[0],
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _rhs_columns(k: int, n: int, itemsize: int) -> int:
+def _rhs_columns(tm: int, k: int, n: int, itemsize: int) -> int:
     """Columns of a weight block [k, tn]: the most that divide `n`, are whole
-    lanes and keep the block within RHS_BLOCK_BYTES (all of `n` where it is
-    small)."""
-    fit = [tn for tn in range(128, n + 1, 128)
-           if n % tn == 0 and k * tn * itemsize <= RHS_BLOCK_BYTES]
-    return max(fit, default=n)
+    lanes and leave a grid step's blocks (the weights, the tile's rows and
+    its output, each double-buffered, and the float32 product) within the
+    kernel's share of VMEM; all of `n` where it has no whole lanes. The more
+    columns, the fewer times the rows are read: once a block of columns."""
+    def step_bytes(tn):
+        return 2 * (k * tn + tm * k + tm * tn) * itemsize + 4 * tm * tn
+
+    whole = [tn for tn in range(128, n + 1, 128) if n % tn == 0] or [n]
+    fit = [tn for tn in whole
+           if step_bytes(tn) <= VMEM_BLOCKS_SHARE * VMEM_LIMIT_BYTES]
+    return max(fit, default=whole[0])
+
+
+# The block each array takes at grid step (column block j, tile t), the tiles
+# innermost: `Plan.tile_expert` does not decrease, so under one column block
+# the weight block's index changes only where the expert does, and every
+# expert some token chose is fetched once a column block.
+def _held(j, t, used):
+    """(tile, column block) whose blocks step (j, t) takes: its own, or for a
+    skipped tile (t >= tiles in use) those of the last tile in use, which the
+    step before it left in VMEM: it fetches nothing and writes nothing new."""
+    # (no tile at all in use, a share none of whose experts was chosen: every
+    # step takes one block, fetched once)
+    return (jnp.clip(t, 0, jnp.maximum(used[0] - 1, 0)),
+            jnp.where(used[0] > 0, j, 0))
+
+
+def _lhs_map(j, t, tile_expert, used):
+    return (_held(j, t, used)[0], 0)
+
+
+def _rhs_map(j, t, tile_expert, used):
+    tile, col = _held(j, t, used)
+    return (tile_expert[tile], 0, col)
+
+
+def _out_map(j, t, tile_expert, used):
+    return _held(j, t, used)
 
 
 def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
@@ -149,7 +190,8 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
     """Grouped matmul: lhs [M,K] (rows as `p` lays them), rhs [E,K,N] ->
     [M,N], row r times the weights of its tile's expert. Rows of unused tiles
     are left as they are (nothing reads them). On the TPU a Pallas kernel,
-    grid (tiles, column blocks); elsewhere one batched einsum over tiles."""
+    grid (column blocks, tiles), one `dot` of a tile's rows [tm, K] with a
+    block [K, tn] a step; elsewhere one batched einsum over tiles."""
     m, k = lhs.shape
     _, _, n = rhs.shape
     tm = p.tm
@@ -165,39 +207,19 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    tn = _rhs_columns(k, n, rhs.dtype.itemsize)
-    last = n // tn - 1
-
-    def held(t, j, used):
-        """(tile, column block) whose blocks step (t, j) takes: its own, or
-        for a skipped tile those of the last step that did any work."""
-        skip = t >= used[0]
-        # (no tile at all in use: a share none of whose experts was chosen)
-        return (jnp.where(skip, jnp.maximum(used[0] - 1, 0), t),
-                jnp.where(skip, last, j))
-
-    def lhs_map(t, j, tile_expert, used):
-        return (held(t, j, used)[0], 0)
-
-    def rhs_map(t, j, tile_expert, used):
-        tile, col = held(t, j, used)
-        return (tile_expert[tile], 0, col)
-
-    def out_map(t, j, tile_expert, used):
-        return held(t, j, used)
-
+    tn = _rhs_columns(tm, k, n, rhs.dtype.itemsize)
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(tiles, n // tn),
-            in_specs=[pl.BlockSpec((tm, k), lhs_map),
-                      pl.BlockSpec((1, k, tn), rhs_map)],
-            out_specs=pl.BlockSpec((tm, tn), out_map)),
+            grid=(n // tn, tiles),
+            in_specs=[pl.BlockSpec((tm, k), _lhs_map),
+                      pl.BlockSpec((1, k, tn), _rhs_map)],
+            out_specs=pl.BlockSpec((tm, tn), _out_map)),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=32 * 2 ** 20),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="moe_gmm",
     )(p.tile_expert, p.tiles_used, lhs, rhs)
@@ -245,4 +267,5 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
         out = combine(weights, p.dest)
     i32 = lambda v: jnp.asarray(v, jnp.int32)
     return out, Load(i32(jnp.sum(p.sizes > 0)), i32(jnp.max(p.sizes)),
-                     i32(jnp.sum(p.sizes)), i32(t * top_k))
+                     i32(jnp.sum(p.sizes)), i32(t * top_k),
+                     i32(p.tiles_used[0]))

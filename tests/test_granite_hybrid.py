@@ -10,6 +10,7 @@ changes on rounding."""
 
 import dataclasses
 import os
+import time
 
 import numpy as np
 import pytest
@@ -259,6 +260,22 @@ def _expert_layer(t=64, h=32, inter=16, e=8, seed=0):
             0.2 * jax.random.normal(ks[3], (e, inter, h)))
 
 
+def test_load_counts_the_tiles_that_share_one_read_of_an_expert():
+    """Granite's routing at a small width, top-10 of 72 columns with the
+    first 36 held: a 2,048-token prompt lays some 284 rows an expert on tiles
+    of 128, about three tiles to a read of an expert's weights; a decode
+    step's 8 rows give an expert at most 8, one tile of 16 each."""
+    x, router, gate_up, down = _expert_layer(2048, e=72)
+    gate_up, down = gate_up[:36], down[:36]
+    _, load = moe.moe_layer(x, router, gate_up, down, 10, held=(0, 36))
+    assert int(load.rows_routed) == 20480 and int(load.touched) == 36
+    assert 2.5 < int(load.tiles) / int(load.touched) < 3.5
+    assert int(load.tiles) >= -(-int(load.rows_held) // 128)
+    _, load = moe.moe_layer(x[:8], router, gate_up, down, 10, held=(0, 36))
+    assert 0 < int(load.touched) <= 36
+    assert int(load.tiles) == int(load.touched)
+
+
 def _layer_before_shares(x, router, gate_up, down, top_k):
     """`ops.moe.moe_layer` as it stood before it could hold a share."""
     weights, experts = moe.route(x, router, top_k)
@@ -417,6 +434,7 @@ def test_spans_carry_the_expert_load_of_prefill_and_decode():
     from ray_tpu._private import flight_recorder as fr
     from ray_tpu.llm._internal.server import LLMServer, load_model_and_params
 
+    began = time.time()
     srv = LLMServer({"family": "granite_hybrid", "model": "tiny",
                      "engine_config": {"max_seqs": 2, "page_size": 8,
                                        "max_pages_per_seq": 16,
@@ -426,11 +444,14 @@ def test_spans_carry_the_expert_load_of_prefill_and_decode():
         assert isinstance(srv.engine.model, GraniteHybridModel)
         out = srv.generate_all(_ids(10), max_tokens=5)
         assert len(out["tokens"]) == 5
-        cache = srv.stats()["cache"]
+        stats = srv.stats()
     finally:
         srv._running = False
+    cache, summed = stats["cache"], stats["expert_load"]
     assert (cache["kv_layers"], cache["state_layers"]) == (1, 2)
-    spans = [e for e in fr.dump_events() if e.get("kind") == "span"]
+    # (the ring is the process's: this server's spans are those since then)
+    spans = [e for e in fr.dump_events()
+             if e.get("kind") == "span" and e["ts"] >= began]
     prefill = [e["args"] for e in spans
                if e["name"] == "ray_tpu.engine.prefill_dispatch"][-1]
     emits = [e["args"] for e in spans if e["name"] == "ray_tpu.engine.emit"
@@ -444,6 +465,14 @@ def test_spans_carry_the_expert_load_of_prefill_and_decode():
         e["expert_rows_routed"] % (3 * 2 * 3) == 0   # layers x slots x k
         and 0 < e["expert_load_max"] <= e["expert_rows_held"]
         for e in emits)
+    # tiles in use: a prefill's experts own one or more of 16 rows, a decode
+    # step's 6 rows a layer one tile an expert; `stats()` sums the spans'
+    # counts (and a window drained unread, which has no `emit`)
+    assert prefill["experts_touched"] <= prefill["expert_tiles"] <= 3 * 12
+    assert all(e["expert_tiles"] == e["experts_touched"] for e in emits)
+    for name in ("experts_touched", "expert_rows_routed", "expert_tiles"):
+        assert summed[name] >= prefill[name] + sum(e[name] for e in emits)
+    assert summed["expert_rows_routed"] % (3 * 2 * 3) == 0
     model, _ = load_model_and_params(
         {"family": "granite_hybrid", "model": "tiny", "seed": 3})
     assert models.sharding_rules(model) is None

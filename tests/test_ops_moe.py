@@ -119,7 +119,8 @@ def test_plan_lays_every_assignment_on_a_tile_of_its_expert(tokens, tm):
 
 
 @pytest.mark.parametrize("tokens,n", [(24, 2 * I), (24, 256), (130, H)])
-def test_gmm_kernel_in_interpret_mode_matches_the_einsum(tokens, n):
+def test_gmm_kernel_in_interpret_mode_matches_the_einsum(tokens, n,
+                                                         monkeypatch):
     """The Pallas kernel over the tiles some expert owns (column blocks of
     128 where `n` allows more than one); rows of the skipped tiles are
     nobody's."""
@@ -128,12 +129,9 @@ def test_gmm_kernel_in_interpret_mode_matches_the_einsum(tokens, n):
     p = moe.plan(experts, E)
     rhs = jax.random.normal(jax.random.PRNGKey(7), (E, H, n)) * H ** -0.5
     lhs = jnp.take(x, p.row_token, axis=0)
-    old = moe.RHS_BLOCK_BYTES
-    moe.RHS_BLOCK_BYTES = H * 128 * 4   # several column blocks at n = 256
-    try:
-        got = moe.gmm(lhs, rhs, p, use_kernel=True, interpret=True)
-    finally:
-        moe.RHS_BLOCK_BYTES = old
+    # several column blocks at n = 256
+    monkeypatch.setattr(moe, "_rhs_columns", lambda tm, k, n, _: min(n, 128))
+    got = moe.gmm(lhs, rhs, p, use_kernel=True, interpret=True)
     want = moe.gmm(lhs, rhs, p, use_kernel=False)
     live = int(p.tiles_used[0]) * p.tm
     np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live],
@@ -160,7 +158,145 @@ def test_tiles_are_short_at_decode_and_long_at_prefill():
     # its largest prefill: 16 prompts of 128 tokens
     assert moe.tile_rows(16 * 128 * 8, 128) == 128
     assert moe.tile_rows(10 ** 6, 128) == moe.MAX_TILE_ROWS
-    # a weight block [2048, 768] bf16 is 3 MiB: gate and up are a block each
-    assert moe._rhs_columns(2048, 1536, 2) == 768
-    assert moe._rhs_columns(768, 2048, 2) == 2048
-    assert moe._rhs_columns(H, 2 * I, 4) == 2 * I
+    # a weight block takes every column at the widths served: SDAR's gate
+    # and up [2048, 1536] and down [768, 2048], Granite's [4096, 1536] (12
+    # MiB, 27.5 MiB a step of 128 rows with its double buffers) and [768,
+    # 4096]; wider stacks are cut into whole lanes that divide them
+    for tm in (16, 128):
+        assert moe._rhs_columns(tm, 2048, 1536, 2) == 1536
+        assert moe._rhs_columns(tm, 768, 2048, 2) == 2048
+        assert moe._rhs_columns(tm, 4096, 1536, 2) == 1536
+        assert moe._rhs_columns(tm, 768, 4096, 2) == 4096
+    assert moe._rhs_columns(128, 4096, 28672, 2) == 2048
+    assert moe._rhs_columns(16, H, 2 * I, 4) == 2 * I
+
+
+# ---------------------------------------------------------------------------
+# The schedule: the regimes the cells run, bit for bit and fetch by fetch
+# ---------------------------------------------------------------------------
+def _round_robin(tokens, k, first, count):
+    """[tokens, k] experts: token t's j-th choice is first + (t + j) % count,
+    so each of `count` experts gets tokens * k / count rows."""
+    return (first + (jnp.arange(tokens)[:, None] + jnp.arange(k)[None])
+            % count).astype(jnp.int32)
+
+
+def _granite_share(tokens):
+    """Top-10 of 72 seeded router columns, the first 36 held."""
+    logits = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, 72))
+    return jax.lax.top_k(logits, 10)[1].astype(jnp.int32), 72, (0, 36)
+
+
+# name -> (experts [T, k], router columns, held, tm, tiles an expert)
+REGIMES = {
+    # a decode step: tiles of 16, every expert one tile
+    "decode_one_tile_an_expert": lambda: (_round_robin(8, 4, 0, 8), 8, None,
+                                          16, 1),
+    # a one-prompt prefill: tiles of 128, 300 rows an expert
+    "prefill_3_tiles_an_expert": lambda: (_round_robin(300, 4, 0, 4), 4,
+                                          None, 128, 3),
+    # a wave's prefill: 2,200 rows an expert
+    "prefill_18_tiles_an_expert": lambda: (_round_robin(2200, 2, 0, 2), 2,
+                                           None, 128, 18),
+    # a chip's share: half of the assignments have no row here
+    "held_share_with_absent_experts": lambda: (*_granite_share(64), None,
+                                               None),
+    # a share none of whose experts was chosen: no tile in use
+    "no_tile_in_use": lambda: (_round_robin(8, 4, 0, 8), 16, (8, 8), None,
+                               None),
+    # two experts of sixteen take everything: fourteen tiles are skipped
+    "tail_of_skipped_tiles": lambda: (_round_robin(24, 2, 5, 2), 16, None,
+                                      16, 2),
+}
+
+
+@pytest.mark.parametrize("regime", list(REGIMES))
+def test_kernel_is_the_einsum_bit_for_bit_in_every_regime(regime,
+                                                          monkeypatch):
+    """The interpreted kernel, two column blocks wide so that the order of
+    the grid matters, against the batched einsum: every row some assignment
+    owns is the same float, whatever the tiles an expert owns."""
+    experts, columns, held, tm, tiles_each = REGIMES[regime]()
+    p = moe.plan(experts, columns, tm, held=held)
+    count = columns if held is None else held[1]
+    used, sizes = int(p.tiles_used[0]), np.asarray(p.sizes)
+    if tiles_each is not None:
+        assert p.tm == tm
+        assert (-(-sizes[sizes > 0] // p.tm) == tiles_each).all()
+    if regime == "no_tile_in_use":
+        assert used == 0 and not sizes.any()
+    if regime == "tail_of_skipped_tiles":
+        assert len(p.tile_expert) - used >= 14
+    if regime == "held_share_with_absent_experts":
+        assert 0 < sizes.sum() < experts.size       # some rows elsewhere
+    k, n = 64, 256
+    ks = jax.random.split(jax.random.PRNGKey(len(regime)), 2)
+    lhs = jax.random.normal(ks[0], (len(p.row_token), k), jnp.bfloat16)
+    rhs = jax.random.normal(ks[1], (count, k, n), jnp.bfloat16) * k ** -0.5
+    monkeypatch.setattr(moe, "_rhs_columns", lambda *_: 128)
+    got = moe.gmm(lhs, rhs, p, use_kernel=True, interpret=True)
+    want = moe.gmm(lhs, rhs, p, use_kernel=False)
+    live = used * p.tm
+    assert got.shape == want.shape == (len(p.row_token), n)
+    assert bool((got[:live] == want[:live]).all())
+
+
+def _fetches(index_map, grid, tile_expert, used):
+    """Walk `grid` in the order Pallas does (last axis innermost) through one
+    of the kernel's index maps: (steps at which the block's index differs
+    from the step before, the first step among them; the indices)."""
+    outer, inner = np.meshgrid(np.arange(grid[0]), np.arange(grid[1]),
+                               indexing="ij")
+    index = index_map(outer.reshape(-1), inner.reshape(-1),
+                      jnp.asarray(tile_expert), jnp.asarray(used))
+    index = np.stack([np.broadcast_to(np.asarray(i), outer.size)
+                      for i in index], axis=1)
+    changed = np.ones(outer.size, bool)
+    changed[1:] = (index[1:] != index[:-1]).any(axis=1)
+    return changed, index
+
+
+def _parent_rhs_map(last):
+    """The weight block's index as the parent's grid (tiles, column blocks)
+    took it."""
+    def rhs_map(t, j, tile_expert, used):
+        skip = t >= used[0]
+        tile = jnp.where(skip, jnp.maximum(used[0] - 1, 0), t)
+        return (tile_expert[tile], 0, jnp.where(skip, last, j))
+    return rhs_map
+
+
+@pytest.mark.parametrize("regime", list(REGIMES))
+@pytest.mark.parametrize("cols", [1, 4])
+def test_an_expert_is_fetched_once_a_column_block(regime, cols):
+    """The grid walked through the kernel's own index maps, no chip: a
+    weight block is fetched where its index differs from the step before.
+    Fetches = experts touched x column blocks, however many tiles an expert
+    owns; a skipped step fetches no weights and no rows and writes no block
+    of its own; and where every expert owns one tile the parent's order
+    fetched as many."""
+    experts, columns, held, tm, tiles_each = REGIMES[regime]()
+    p = moe.plan(experts, columns, tm, held=held)
+    tile_expert, used = np.asarray(p.tile_expert), np.asarray(p.tiles_used)
+    tiles, touched = len(tile_expert), int((np.asarray(p.sizes) > 0).sum())
+    grid = (cols, tiles)
+    rhs, rhs_index = _fetches(moe._rhs_map, grid, tile_expert, used)
+    lhs, _ = _fetches(moe._lhs_map, grid, tile_expert, used)
+    out, out_index = _fetches(moe._out_map, grid, tile_expert, used)
+    skipped = np.tile(np.arange(tiles) >= used[0], cols)
+    skipped[0] = False            # (the first step fetches, whatever it is)
+    assert not (rhs | lhs | out)[skipped].any()
+    # (a share with no tile in use: one block, fetched at the first step)
+    assert rhs.sum() == max(touched * cols, 1)
+    assert lhs.sum() == out.sum() == max(int(used[0]) * cols, 1)
+    work = ~np.tile(np.arange(tiles) >= used[0], cols)
+    # every step that works takes its own tile's expert and its own column
+    assert (rhs_index[work, 0] == np.tile(tile_expert, cols)[work]).all()
+    assert (rhs_index[work, 2] == np.repeat(np.arange(cols), tiles)[work]
+            ).all()
+    assert len({tuple(i) for i in out_index[work]}) == work.sum()
+    parent, _ = _fetches(_parent_rhs_map(cols - 1), (tiles, cols),
+                         tile_expert, used)
+    assert rhs.sum() <= parent.sum()
+    if tiles_each is not None and tiles_each > 1 and cols > 1:
+        assert parent.sum() == max(int(used[0]), 1) * cols > rhs.sum()

@@ -395,30 +395,66 @@ def test_granite_decode_streams_its_share_and_updates_the_pool_in_place(
     assert "bf16[36,4096,1536]" in text and "bf16[72," not in text
 
 
-def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch):
-    """Prefill of 8 prompts in the 2,048 bucket, the largest program of
+@pytest.mark.parametrize("nb", [1, 8])
+def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
+                                                      nb):
+    """Prefill of one prompt (what the closed loop admits after its first
+    wave) and of 8 in the 2,048 bucket, the largest program of
     `granite-prompt-heavy`: compiled at six layers (the temporaries are a
     layer's: the expert layer's rows laid out by expert are the largest, and
     attention keeps one row's scores) with the other four layers' weights and
-    state added from their shapes, it peaks under 14.75 GiB of a v5e's 15.75
-    with the whole vocabulary held; the scan is the Pallas kernel `ssd_scan`,
-    the experts `moe_gmm`, and no logits of every position are made."""
+    state added from their shapes, it peaks no higher than before `moe_gmm`
+    held an expert's whole weight block in VMEM (11.855 GiB of a v5e's
+    15.75, the whole vocabulary held); the scan is the Pallas kernel
+    `ssd_scan`, the experts `moe_gmm` inside its `vmem_limit_bytes` (the
+    chip's compiler refuses a kernel that is not), and no logits of every
+    position are made."""
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, ec = _cell_at_depth("granite-prompt-heavy", 6)
     whole, _ = _cell_at_depth("granite-prompt-heavy")
     one = SingleDeviceSharding(topology.devices[0])
-    compiled = _lower_prefill(model, ec, 2048, 8, one).compile()
+    compiled = _lower_prefill(model, ec, 2048, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
     rest = (_program_bytes(whole, ec) - _program_bytes(model, ec)) / sizing.GIB
     assert 3.4 < rest < 3.6            # 4 of 10 layers' weights and state
-    assert 1.0 < parts["temp"] < 3.5   # not the 4.5 GiB of a wave's scores
-    assert peak + rest < 14.75
+    # not the 4.5 GiB of a wave's scores
+    assert parts["temp"] < (3.5 if nb == 8 else 1.0)
+    assert peak + rest <= 11.855
     text = compiled.as_text()
     assert {"ssd_scan", "moe_gmm"} <= _kernel_names(text)
-    assert "[8,2048,100352]" not in text and "f32[8,100352]" in text
-    assert "f32[8,32,2048,2304]" not in text
+    assert f"[{nb},2048,100352]" not in text and f"f32[{nb},100352]" in text
+    assert f"f32[{nb},32,2048,2304]" not in text
+
+
+@pytest.mark.parametrize("tm,tiles,experts,k,n", [
+    (128, 196, 36, 4096, 1536),    # Granite, one prompt of 2,048: gate and up
+    (128, 1316, 36, 768, 4096),    # a wave of 8: down
+    (16, 39, 36, 4096, 1536),      # its decode step of 8 rows
+    (16, 152, 128, 2048, 1536),    # SDAR's forward over 64 tokens
+    (128, 64, 8, 4096, 28672),     # a stack too wide for one block: 14
+])
+def test_moe_gmm_one_chip_holds_its_blocks_in_vmem(topology, tm, tiles,
+                                                   experts, k, n):
+    """`moe_gmm` alone at the cells' shapes: the Mosaic compiler takes the
+    weight block `_rhs_columns` chose (every column of an expert at the
+    widths served, 12 MiB double-buffered at Granite's gate and up) within
+    `VMEM_LIMIT_BYTES`."""
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(topology.devices[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def run(lhs, rhs, tile_expert, tiles_used):
+        p = moe.Plan(tm, None, None, tile_expert, tiles_used, None)
+        return moe.gmm(lhs, rhs, p, use_kernel=True, interpret=False)
+
+    text = _compiled_text(run, s((tiles * tm, k), jnp.bfloat16),
+                          s((experts, k, n), jnp.bfloat16),
+                          s((tiles,), jnp.int32), s((1,), jnp.int32))
+    assert "moe_gmm" in _kernel_names(text)
+    assert f"bf16[{tiles * tm},{n}]" in text
 
 
 def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
