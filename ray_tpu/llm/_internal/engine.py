@@ -207,6 +207,14 @@ class LLMEngine:
     without LoRA banks, `param_transform` or prefix sharing (each is built
     for K/V layers only) and, having no sharding rules, without a mesh.
 
+    The layers in `model.ring_layer_ids` (sliding-window attention) hold a
+    ring of `sliding_window / page_size + 1` pages a slot, owned by the slot
+    as a state is and addressed as pages are: the allocator, the page table
+    and `max_pages_per_seq` are the other layers' alone, and releasing a slot
+    needs nothing for a ring (the next prefill overwrites what its decode
+    steps read). Such a model runs under the same limits as one with state
+    layers: a shared page run has no ring to restore.
+
     A model that says `num_logits_to_keep = 1` gets its prefill's final norm
     and head on each row's last prompt position only (`logits_at`): logits
     [nb, 1, V] where the other families compute [nb, bucket, V] and keep a
@@ -240,8 +248,13 @@ class LLMEngine:
         # into the consuming matmuls.
         self.param_transform = param_transform
         self._state_layers = len(model.state_layer_ids)
+        # The window of a model with ring layers (0: none); `init_cache`
+        # refuses one that is not whole pages.
+        self._ring_window = (int(model.sliding_window)
+                             if getattr(model, "ring_layer_ids", ()) else 0)
         special = ("has state layers" if self._state_layers else
-                   "generates by blocks" if self._block > 1 else "")
+                   "generates by blocks" if self._block > 1 else
+                   "has ring layers" if self._ring_window else "")
         if special:
             missing = [what for what, asked in (
                 ("lora_rank > 0: LoRA banks are built for attention "
@@ -329,8 +342,9 @@ class LLMEngine:
         self.prefix_cache = (PrefixCache(self.allocator)
                              if cfg.enable_prefix_cache else None)
         if special and self.prefix_cache is not None:
-            # A shared page carries K/V and no state: a sharer's state
-            # layers would start from zero in the middle of its prompt.
+            # A shared page carries K/V and no state (and no ring): a
+            # sharer's state layers would start from zero in the middle of
+            # its prompt.
             logger.info("%s %s: prefix sharing is off",
                         type(model).__name__, special)
             self.prefix_cache = None
@@ -391,16 +405,22 @@ class LLMEngine:
 
     def _describe_cache(self) -> Dict[str, int]:
         """Layers and bytes of the cache by kind, from shapes alone and as
-        the device lays them out: a minor axis fills whole lanes."""
+        the device lays them out: a minor axis fills whole lanes. A model
+        with ring layers reports them apart from the allocator's pages."""
         state = set(self.model.state_layer_ids)
+        ring = set(getattr(self.model, "ring_layer_ids", ()))
         size = lambda layer: sum(map(_laid_out_bytes,
                                      jax.tree.leaves(layer)))
-        return {
-            "kv_layers": len(self.caches) - len(state),
+        report = {
+            "kv_layers": len(self.caches) - len(state) - len(ring),
             "state_layers": len(state),
             "kv_bytes": sum(size(c) for i, c in enumerate(self.caches)
-                            if i not in state),
+                            if i not in state | ring),
             "state_bytes": sum(size(self.caches[i]) for i in state)}
+        if ring:
+            report.update(ring_layers=len(ring), ring_bytes=sum(
+                size(self.caches[i]) for i in ring))
+        return report
 
     # ------------------------------------------------------------------
     # LoRA multiplexing
@@ -1045,7 +1065,15 @@ class LLMEngine:
         denoise = K if B == 1 else K // B * self.model.denoising_steps
         fresh = 0 if B == 1 or last is not None else sum(
             int(self.last_tokens[slot, 0] < 0) for slot in self.running)
-        with _fr.span("ray_tpu.engine.dispatch_decode",
+        # What the window's first step attends over, from the host mirrors
+        # (a chained window's rows are up to a window further on): every
+        # active row's length, and what of it lies inside the window.
+        reach = {}
+        if self._ring_window:
+            held = self.seq_lens[list(self.running)]
+            reach = {"context_tokens": int(held.sum()), "window_tokens": int(
+                np.minimum(held, self._ring_window).sum())}
+        with _fr.span("ray_tpu.engine.dispatch_decode", **reach,
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
                       steps=K, free_slots=len(self._free_slots),
                       chained=last is not None, across=across,

@@ -37,6 +37,8 @@ FAMILIES = {
     "granite_hybrid": Family(
         "ray_tpu.models.granite_hybrid:GraniteHybridConfig",
         "ray_tpu.models.granite_hybrid:GraniteHybridModel"),
+    "mellum": Family("ray_tpu.models.mellum:MellumConfig",
+                     "ray_tpu.models.mellum:MellumModel"),
 }
 
 

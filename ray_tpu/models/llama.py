@@ -16,6 +16,7 @@ config 2/4 uses Llama-3-8B).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -100,19 +101,44 @@ class RMSNorm(nn.Module):
         return (x32 * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def rope_freqs(head_dim: int, theta: float,
+               yarn: Optional[Tuple[float, int, float, float]] = None
+               ) -> jax.Array:
+    """The rotary inverse frequencies of one kind of layer, [head_dim / 2].
+    `yarn` = (factor, original_max_position_embeddings, beta_fast, beta_slow)
+    gives YaRN's: pair j keeps its frequency below the dimension that turns
+    `beta_fast` times over the original context, takes it over `factor` above
+    the one that turns `beta_slow` times, and a linear ramp between (the
+    range's ends rounded outwards: HF's `truncate` default)."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn is None:
+        return freqs
+    factor, original, beta_fast, beta_slow = yarn
+    turns_at = lambda turns: (head_dim * math.log(
+        original / (turns * 2 * math.pi))) / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D]; positions: [B, S] or [S]."""
-    freqs = rope_freqs(x.shape[-1], theta)
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               factor: float = 1.0) -> jax.Array:
+    """x: [B, S, H, D]; positions: [B, S] or [S]. `freqs` [D/2]: a layer
+    kind's own inverse frequencies in place of `rope_freqs(D, theta)`;
+    `factor` multiplies cos and sin (YaRN's `attention_factor`)."""
+    if freqs is None:
+        freqs = rope_freqs(x.shape[-1], theta)
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -106,18 +106,18 @@ def _stack_init(key, shape, dtype):
                        fan_in).reshape(shape)
 
 
-def _dense(cfg: SdarMoeConfig, features: int,
+def _dense(cfg: Any, features: int,
            name: Optional[str]) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, kernel_init=kernel_init,
                     name=name)
 
 
-def _norm(cfg: SdarMoeConfig, name: Optional[str]) -> nn.Module:
+def _norm(cfg: Any, name: Optional[str]) -> nn.Module:
     return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
 
 
-def _embed(cfg: SdarMoeConfig, name: Optional[str]) -> nn.Embed:
+def _embed(cfg: Any, name: Optional[str]) -> nn.Embed:
     return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, embedding_init=embed_init,
                     name=name)
@@ -164,9 +164,12 @@ class Attention(nn.Module):
 
 
 class SparseMoe(nn.Module):
-    """All of a layer's experts (`ops/moe.py`): the router's kernel float32,
-    the experts two stacks in the compute dtype."""
-    cfg: SdarMoeConfig
+    """All of a layer's experts (`ops/moe.py`): the router's kernel float32
+    (seeded with logits of standard deviation `router_std`), the experts two
+    stacks in the compute dtype. `cfg`: any family's config with this block's
+    fields (models/mellum.py)."""
+    cfg: Any
+    router_std: float = ROUTER_LOGIT_STD
 
     @nn.compact
     def __call__(self, x):
@@ -174,7 +177,7 @@ class SparseMoe(nn.Module):
         e, hid, inter = (cfg.num_experts, cfg.hidden_size,
                          cfg.moe_intermediate_size)
         router = self.param("router", nn.initializers.variance_scaling(
-            ROUTER_LOGIT_STD ** 2, "fan_in", "truncated_normal"),
+            self.router_std ** 2, "fan_in", "truncated_normal"),
             (hid, e), jnp.float32)
         gate_up = self.param("gate_up", _stack_init, (e, hid, 2 * inter),
                              cfg.param_dtype)

@@ -41,8 +41,10 @@ def attention_reference(
     causal: bool = True,
     scale: Optional[float] = None,
     q_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Plain softmax attention (test oracle)."""
+    """Plain softmax attention (test oracle). `window`: a query sees the
+    `window` keys up to and including its own position."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     k, v = _gqa_expand(k, v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -51,7 +53,10 @@ def attention_reference(
     if causal:
         q_ids = jnp.arange(q.shape[1])[:, None] + q_offset
         k_ids = jnp.arange(k.shape[1])[None, :]
-        logits = jnp.where(k_ids <= q_ids, logits, NEG_INF)
+        visible = k_ids <= q_ids
+        if window is not None:
+            visible &= q_ids - k_ids < window
+        logits = jnp.where(visible, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32),
                       precision=jax.lax.Precision.HIGHEST).astype(q.dtype)
@@ -438,6 +443,105 @@ def flash_attention(
     vt = v.transpose(0, 2, 1, 3)
     cfg = (causal, scale, block_q, block_k, interpret)
     out = _flash_core(qt, kt, vt, cfg)
+    return out.transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Sliding-window (banded causal) forward: a prefill over the call's own keys
+# ---------------------------------------------------------------------------
+def _swa_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                      scale: float, window: int, block: int, band: int):
+    """Grid (B, H, q blocks, band): step j of q block qi holds key block
+    qi - (band - 1) + j, the band's blocks left to right and the diagonal
+    block last; a block before the sequence's start is passed over (its
+    index map repeats block 0, so nothing is fetched for it). A row whose
+    keys in a block are all outside the band adds weights at m = NEG_INF
+    that the diagonal block, where every row sees its own key, scales to 0."""
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    ki = qi - (band - 1) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki >= 0)
+    def _compute():
+        q = q_ref[0, 0]                            # [block, d]
+        k = k_ref[0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        q_ids = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        k_ids = ki * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        s = jnp.where((k_ids <= q_ids) & (q_ids - k_ids < window), s,
+                      NEG_INF)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])
+        l_scr[:, 0] = l_scr[:, 0] * alpha + p.sum(axis=-1)
+        m_scr[:, 0] = m_new
+        v = v_ref[0, 0]
+        acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == band - 1)
+    def _finish():
+        denom = jnp.maximum(l_scr[:, 0], 1e-30)
+        o_ref[0, 0] = (acc_scr[:] / denom[:, None]).astype(o_ref.dtype)
+
+
+def sliding_window_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    window: int,
+    scale: Optional[float] = None,
+    block: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal attention in which query i sees keys i - window < j <= i, over
+    the call's own q [B,S,H,D] and k/v [B,S,Hkv,D] (the Pallas kernel
+    `swa_flash`; forward only). Key blocks wholly outside the band are not
+    visited: a q block walks ceil((window - 1) / block) + 1 key blocks
+    whatever S is, so the work grows with S x window and there is no scores
+    tensor. K and V keep their Hkv heads: a query head's index map reads its
+    group's."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, s, h, d = q.shape
+    rep = h // k.shape[2]
+    block = min(block, s)
+    while block > 1 and s % block:
+        block //= 2
+    band = -(-(window - 1) // block) + 1
+    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    q_spec = pl.BlockSpec((1, 1, block, d),
+                          lambda bi, hi, qi, j: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block, d), lambda bi, hi, qi, j: (
+            bi, hi // rep, jnp.maximum(qi - (band - 1) + j, 0), 0))
+    out = pl.pallas_call(
+        functools.partial(_swa_flash_kernel, scale=scale, window=window,
+                          block=block, band=band),
+        grid=(b, h, s // block, band),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        scratch_shapes=[_vmem((block, 1)), _vmem((block, 1)),
+                        _vmem((block, d))],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="swa_flash",
+    )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -17,6 +17,14 @@ Writes are vectorized scatters (`.at[...].set(mode="drop")` — padding lanes
 are sent out-of-bounds and dropped, so no dynamic shapes anywhere). The
 decode gather reads each sequence's pages back as a contiguous view. A model
 file calls `init_kv_pages` and `paged_write_attend` and knows no layout.
+
+A sliding-window layer keeps no pages from the allocator but a RING a slot
+(`init_ring_pages`, `ring_write`, `ring_attention`): `ring_pages(window, ps)`
+= window / ps + 1 pages of the same layout, slot s owning pages s R .. s R +
+R - 1, position p living in ring page (p // ps) % R. The keys a query may
+still see (the last `window` positions) span at most R pages, so a write
+only ever lands on a page whose keys are all dead, and the layer's bytes do
+not grow with the context.
 """
 
 from __future__ import annotations
@@ -61,14 +69,18 @@ def init_kv_pages(cache_cfg, kv_heads: int, head_dim: int,
 
 
 def paged_write(pages: jax.Array, new_kv: jax.Array, page_table: jax.Array,
-                positions: jax.Array, mask: jax.Array) -> jax.Array:
+                positions: jax.Array, mask: jax.Array,
+                wrap: bool = False) -> jax.Array:
     """Scatter new_kv [B,S,HK,D] into pages [P,ps,HK*D], a row a token.
 
     positions [B,S]: absolute token index of each entry; mask [B,S]: write
-    enable (False lanes scatter out-of-bounds and are dropped)."""
+    enable (False lanes scatter out-of-bounds and are dropped). `wrap`: the
+    table's columns are a ring, page n of a row is column n % columns."""
     num_pages, ps, width = pages.shape
-    page_idx = jnp.take_along_axis(
-        page_table, positions // ps, axis=1)  # [B,S]
+    cols = positions // ps
+    if wrap:
+        cols = cols % page_table.shape[1]
+    page_idx = jnp.take_along_axis(page_table, cols, axis=1)  # [B,S]
     slot_idx = positions % ps
     page_idx = jnp.where(mask, page_idx, num_pages)  # OOB -> dropped
     return pages.at[page_idx.reshape(-1), slot_idx.reshape(-1)].set(
@@ -154,11 +166,94 @@ def paged_write_attend(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# A ring of pages a slot: the cache of a sliding-window layer
+# ---------------------------------------------------------------------------
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a slot's ring: the last `window` positions of any length
+    span at most this many pages of `page_size` (which divides `window`)."""
+    return window // page_size + 1
+
+
+def init_ring_pages(cache_cfg, window: int, kv_heads: int, head_dim: int,
+                    dtype=jnp.bfloat16):
+    """One sliding-window layer's (k_pages, v_pages): `max_seqs` rings of
+    `ring_pages` pages, layout as `init_kv_pages`. Nothing of it depends on
+    `max_pages_per_seq` or the allocator's pool."""
+    if window % cache_cfg.page_size:
+        raise ValueError(f"sliding_window {window} is not a multiple of "
+                         f"page_size {cache_cfg.page_size}")
+    shape = (cache_cfg.max_seqs * ring_pages(window, cache_cfg.page_size),
+             cache_cfg.page_size, kv_heads * head_dim)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def ring_table(slots: jax.Array, ring: int) -> jax.Array:
+    """[B] slots -> [B, ring]: the pages of each slot's ring, in ring
+    order."""
+    return slots[:, None] * ring + jnp.arange(ring, dtype=slots.dtype)
+
+
+def ring_write(pages: jax.Array, new_kv: jax.Array, slots: jax.Array,
+               positions: jax.Array, mask: jax.Array, seq_lens: jax.Array,
+               window: int) -> jax.Array:
+    """`paged_write` into the rings of `slots` [B]: new_kv [B,S,HK,D] at
+    `positions` [B,S] of rows that end at `seq_lens` [B] with this call. Only
+    the positions of a row's last `ring_pages` pages are written: an earlier
+    one shares its ring page with a later one of the same call (a scatter
+    with two writers to one row is not ordered), and no later query sees
+    it."""
+    ps = pages.shape[1]
+    ring = ring_pages(window, ps)
+    alive = positions // ps + ring > (seq_lens[:, None] - 1) // ps
+    return paged_write(pages, new_kv, ring_table(slots, ring), positions,
+                       mask & alive, wrap=True)
+
+
+def ring_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                   slots: jax.Array, seq_lens: jax.Array, window: int,
+                   scale: Optional[float] = None,
+                   use_kernel: Optional[bool] = None) -> jax.Array:
+    """One decode step of a sliding-window layer: q [B,1,H,D], the query at
+    position `seq_lens` - 1 (already written), over the keys at positions
+    `seq_lens` - `window` .. `seq_lens` - 1 of the rings of `slots` [B]. On a
+    TPU the decode kernel walks the window's pages (`swa_decode`); elsewhere
+    the rings are gathered whole and every cell's position worked out from
+    the row's length."""
+    ps = k_pages.shape[1]
+    ring = ring_pages(window, ps)
+    table = ring_table(slots, ring)
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if use_kernel:
+        return paged_attention_decode_kernel(
+            q, k_pages, v_pages, table, seq_lens, scale=scale, window=window)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, _, h, d = q.shape
+    hk = k_pages.shape[2] // d
+    k, v = (jnp.repeat(paged_gather(pages, table).reshape(b, -1, hk, d),
+                       h // hk, axis=2) for pages in (k_pages, v_pages))
+    # Column c of a ring holds the newest page n <= the row's last page with
+    # n % ring == c (a page before the row's start: nothing).
+    last_page = ((seq_lens - 1) // ps)[:, None]
+    page = last_page - (last_page - jnp.arange(ring)) % ring     # [B, ring]
+    pos = (page[:, :, None] * ps + jnp.arange(ps)).reshape(b, -1)
+    lens = seq_lens[:, None]
+    visible = (pos >= 0) & (pos < lens) & (pos >= lens - window)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * scale
+    logits = jnp.where(visible[:, None, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                      v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Pallas TPU paged-attention decode kernel
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                          kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc_scr, *,
-                         kv_heads: int, scale: float):
+                         kv_heads: int, scale: float,
+                         window: Optional[int] = None):
     """Grid (B,): one program a sequence, over all its heads. KV pages stay
     in HBM; the kernel walks the sequence's page list in chunks of C pages,
     a page one DMA of [ps, HK*D], double-buffering the page DMAs against the
@@ -178,12 +273,22 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     # Never past the page table, whatever length a caller hands a row it
     # does not use (the engine's decode programs start a free slot's row at
     # 0 every window, so it walks one page).
-    seq_len = jnp.minimum(lens_ref[b], pt_ref.shape[1] * ps)
-    n_pages = jax.lax.div(seq_len + ps - 1, ps)
+    if window is None:
+        seq_len = jnp.minimum(lens_ref[b], pt_ref.shape[1] * ps)
+        n_pages = jax.lax.div(seq_len + ps - 1, ps)
+    else:
+        # (a ring bounds no length; the walk is R pages at most)
+        seq_len = lens_ref[b]
+        first = jax.lax.div(jnp.maximum(seq_len - window, 0), ps)
+        n_pages = jax.lax.div(seq_len + ps - 1, ps) - first
     n_chunks = jax.lax.div(n_pages + C - 1, C)
 
     def page_copies(ci, buf, j):
-        page = pt_ref[b, ci * C + j]
+        if window is None:
+            page = pt_ref[b, ci * C + j]
+        else:
+            page = pt_ref[b, jax.lax.rem(first + ci * C + j,
+                                         pt_ref.shape[1])]
         return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, j],
                                       ksem.at[buf, j]),
                 pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, j],
@@ -240,7 +345,12 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             q_wide, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [H, C*ps]
         pos = ci * C * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
+        if window is None:
+            s = jnp.where(pos < seq_len, s, NEG_INF)
+        else:
+            pos = pos + first * ps
+            s = jnp.where((pos < seq_len) & (pos >= seq_len - window), s,
+                          NEG_INF)
         m_prev = m_scr[...]  # [H, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -266,7 +376,8 @@ def paged_attention_decode_kernel(
         scale: Optional[float] = None,
         pages_per_chunk: Optional[int] = None,
         interpret: Optional[bool] = None,
-        mesh: Optional[Mesh] = None) -> jax.Array:
+        mesh: Optional[Mesh] = None,
+        window: Optional[int] = None) -> jax.Array:
     """Pallas decode attention: q [B,S,H,D] over paged KV [P,ps,HK*D] without
     materializing the gathered context. Grid (B,); see _paged_decode_kernel
     for the DMA pipeline. `pages_per_chunk` defaults to what
@@ -280,12 +391,19 @@ def paged_attention_decode_kernel(
     With a multi-device `mesh` the kernel runs under shard_map with the KV
     heads (and the query heads grouped under them) split over the tensor
     axis, as the engine shards the pages; page table and lengths ride along
-    replicated."""
+    replicated.
+
+    `window`: a sliding-window layer's step over its rings (`page_table` a
+    `ring_table`; S = 1, no mesh). The call is then named `swa_decode`, so a
+    trace tells the two apart."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
     _, ps, width = k_pages.shape
     hk = width // d
+    if window is not None and (s > 1 or mesh is not None):
+        raise NotImplementedError("a windowed decode step is one query a "
+                                  "row on one device")
     if s > 1:
         fold = lambda t, a, c: t.reshape(b, a, hk, c, h // hk, d).transpose(
             0, 3, 2, 1, 4, 5)
@@ -315,7 +433,8 @@ def paged_attention_decode_kernel(
     C = min(pages_per_chunk, mp)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
-    kernel = functools.partial(_paged_decode_kernel, kv_heads=hk, scale=scale)
+    kernel = functools.partial(_paged_decode_kernel, kv_heads=hk, scale=scale,
+                               window=window)
     block = pl.BlockSpec((1, 1, h, d), lambda bi, pt, lens: (bi, 0, 0, 0))
 
     return pl.pallas_call(
@@ -343,5 +462,5 @@ def paged_attention_decode_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "swa_decode",
     )(page_table, seq_lens, q, k_pages, v_pages)

@@ -76,7 +76,7 @@ def _kernel_names(text):
             instruction = line.split(" = ", 1)[0]
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "ssm_scan", "ssd_scan",
-                                 "moe_gmm")
+                                 "moe_gmm", "swa_decode", "swa_flash")
                      if k in instruction}
     return held
 
@@ -426,6 +426,59 @@ def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     assert {"ssd_scan", "moe_gmm"} <= _kernel_names(text)
     assert f"[{nb},2048,100352]" not in text and f"f32[{nb},100352]" in text
     assert f"f32[{nb},32,2048,2304]" not in text
+
+
+def test_mellum_decode_walks_rings_and_pages_in_place(topology, monkeypatch):
+    """The chip compiler's HLO of `mellum-code-context`'s decode window at the
+    published widths and all eight layers: the six sliding layers' step is
+    the windowed kernel over rings of 17 pages a slot, the two full layers'
+    the paged kernel over the allocator's pool, the experts `moe_gmm` over
+    all 64; no instruction rewrites a ring or a pool; it peaks at 7.47 GiB
+    of a v5e's 15.75 (47%)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("mellum-code-context")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_decode(model, ec, one).compile()
+    peak, _ = sizing.peak_gib(compiled)
+    assert 0.25 * sizing.USABLE_GIB < peak <= 7.466 + 0.05
+    text = compiled.as_text()
+    caches = sizing.cache_shapes(model, ec, None)
+    ring, pages = caches[0][0], caches[3][0]
+    assert ring.shape == (8 * 17, 64, 4 * 128)
+    assert pages.shape == (8 * 72 + 1, 64, 4 * 128)
+    for pool in (ring, pages):
+        assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert {"swa_decode", "paged_decode", "moe_gmm"} <= _kernel_names(text)
+    assert "bf16[64,2304,1792]" in text
+
+
+@pytest.mark.parametrize("nb", [1, 8])
+def test_mellum_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
+                                                     nb):
+    """Prefill of one prompt and of 8 in the 4,096 bucket, the largest
+    program of `mellum-code-context`, at all eight layers: both kinds of
+    layer attend over the call's own keys through a flash forward
+    (`flash_fwd` causal, `swa_flash` banded), so no scores of 4,096 queries
+    are made; the head runs on one position a row; the wave peaks at 10.95
+    GiB of a v5e's 15.75 (compile, PR 44)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("mellum-code-context")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    assert peak <= (10.953 if nb == 8 else 8.05) + 0.05 < sizing.USABLE_GIB
+    assert parts["temp"] < (3.7 if nb == 8 else 0.8)
+    text = compiled.as_text()
+    assert {"flash_fwd", "swa_flash", "moe_gmm"} <= _kernel_names(text)
+    assert not {"swa_decode", "paged_decode"} & _kernel_names(text)
+    assert f"[{nb},4096,98304]" not in text and f"f32[{nb},98304]" in text
+    for keys in (4096, 4608):                   # no [rows, heads, q, keys]
+        assert f"[{nb},32,4096,{keys}]" not in text
+        assert f"[32,4096,{keys}]" not in text
 
 
 @pytest.mark.parametrize("tm,tiles,experts,k,n", [
