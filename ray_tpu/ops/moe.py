@@ -21,6 +21,14 @@ the experts' weights, so its tiles are short (16 rows); at prefill (hundreds
 of rows an expert, tiles of 128) by the arithmetic of the tiles that share a
 block.
 
+The layout is built a tile at a time, from sorts, comparisons and selects:
+the padded rows (`tiles * tm`, sixteen times the assignments at a decode step
+of 8 rows) index nothing. On the TPU a gather of scalars costs 20 us for any
+1,024 indices or fewer, a scatter runs an index at a time, and a gather of
+windows becomes a loop of a step a window, where a sort of 32,768 pairs is
+under 20 us: so `plan` has no scalar gather, no scatter and no loop, at any
+shape.
+
 A chip that shares a layer with others by expert parallelism holds some of
 the router's columns (`held = (first, count)`, static): routing stays over
 all of them, an assignment to an expert that is held elsewhere gets no row
@@ -95,41 +103,57 @@ def plan(experts: jax.Array, num_experts: int, tm: Optional[int] = None,
     `held = (first, count)`: only the assignments to experts first ..
     first+count-1 of the `num_experts` get a row (`dest` of another is -1);
     tiles and `sizes` are over those `count`, numbered from 0 as the weight
-    stacks hold them."""
+    stacks hold them. A tile's rows are `tm` assignments in sorted order from
+    its first, so a padding row (one no `dest` names; nothing reads its
+    product) holds the token of an assignment that follows its expert's last
+    (token 0 behind the last of all): always some token's activations."""
     t, k = experts.shape
     a = t * k
     flat = experts.reshape(a)
     # (the mean rows of an expert are those over all the router's columns)
     tm = tm or tile_rows(a, num_experts)
-    bins = num_experts
     if held is not None:
         first, num_experts = held
         local = flat - first
         here = (local >= 0) & (local < num_experts)
         # An absent expert's assignments sort behind every held one's.
-        flat, bins = jnp.where(here, local, num_experts), num_experts + 1
+        flat = jnp.where(here, local, num_experts)
     tiles = (a + min(a, num_experts) * (tm - 1) + tm - 1) // tm
     order = jnp.argsort(flat, stable=True)  # sorted place -> assignment
-    place = jnp.zeros((a,), jnp.int32).at[order].set(
-        jnp.arange(a, dtype=jnp.int32))     # assignment -> sorted place
-    sizes = jnp.zeros((bins,), jnp.int32).at[flat].add(1)[:num_experts]
-    starts = jnp.cumsum(sizes) - sizes
+    place = jnp.argsort(order)              # assignment -> sorted place
+    chose = flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)  # [a,E]
+    sizes = jnp.sum(chose, axis=0, dtype=jnp.int32)
     padded = (sizes + tm - 1) // tm * tm
     ends = jnp.cumsum(padded)
-    pstarts = ends - padded
+    # Rows of padding before an expert's first row: what a row's index is
+    # ahead of its assignment's sorted place.
+    ahead = ends - padded - (jnp.cumsum(sizes) - sizes)
+    dest = place + jnp.sum(jnp.where(chose, ahead, 0), axis=1)
+    if held is not None:
+        dest = jnp.where(here, dest, -1)
     tiles_used = ends[-1:] // tm
     # A tile past the last used one repeats it: the kernel skips it, and its
     # blocks are the ones already in VMEM.
     tile = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32), tiles_used - 1)
-    tile_expert = jnp.searchsorted(ends, tile * tm, side="right").astype(
-        jnp.int32)
-    row = jnp.arange(tiles * tm, dtype=jnp.int32)
-    e = tile_expert[row // tm]
-    within = jnp.minimum(row - pstarts[e], sizes[e] - 1)  # padding repeats
-    row_token = order[jnp.clip(starts[e] + within, 0, a - 1)] // k
-    dest = pstarts[flat] + place - starts[flat]
-    if held is not None:  # (an absent expert's index was clamped)
-        dest = jnp.where(here, dest, -1)
+    # The experts whose rows end at or before a tile's first are those before
+    # its own, and their padding is what the tile's first row is ahead by.
+    before = ends <= (tile * tm)[:, None]                         # [tiles,E]
+    tile_expert = jnp.sum(before, axis=1, dtype=jnp.int32)
+    start = jnp.clip(
+        tile * tm - jnp.sum(jnp.where(before, padded - sizes, 0), axis=1),
+        0, a - 1)                          # the tile's first sorted place
+    # A tile's rows are the `tm` sorted assignments from its start: a window
+    # that lies in two neighbouring blocks of `tm`. One lookup a tile takes
+    # the pair, and the window is shifted to its front by the bits of its
+    # offset, a select each.
+    token = jnp.pad(order // k, (0, -a % tm + tm)).reshape(-1, tm)
+    window = jnp.concatenate([token[:-1], token[1:]], axis=1)[start // tm]
+    offset, shift = (start % tm)[:, None], 1
+    while shift < tm:
+        window = jnp.where((offset & shift) != 0,
+                           jnp.roll(window, -shift, axis=1), window)
+        shift *= 2
+    row_token = window[:, :tm].reshape(tiles * tm)
     return Plan(tm, row_token, dest.reshape(t, k), tile_expert, tiles_used,
                 sizes)
 

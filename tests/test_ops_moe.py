@@ -3,6 +3,8 @@ dropless top-k layer against an oracle that walks token by token and expert
 by expert, the tile-aligned layout's invariants, and the Pallas grouped
 matmul (interpret mode) against the einsum it stands in for."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -300,3 +302,197 @@ def test_an_expert_is_fetched_once_a_column_block(regime, cols):
     assert rhs.sum() <= parent.sum()
     if tiles_each is not None and tiles_each > 1 and cols > 1:
         assert parent.sum() == max(int(used[0]), 1) * cols > rhs.sum()
+
+
+# ---------------------------------------------------------------------------
+# The layout a tile at a time, against the layout a row at a time
+# ---------------------------------------------------------------------------
+def _plan_per_row(experts, num_experts, tm=None, held=None):
+    """The plain oracle: `plan` as it stood before it was built a tile at a
+    time. Every one of the padded rows looks up its tile's expert, that
+    expert's first row, size and first sorted place, and its own assignment:
+    five gathers indexed by the `tiles * tm` rows. A padding row repeats its
+    expert's last row."""
+    t, k = experts.shape
+    a = t * k
+    flat = experts.reshape(a)
+    tm = tm or moe.tile_rows(a, num_experts)
+    bins = num_experts
+    if held is not None:
+        first, num_experts = held
+        local = flat - first
+        here = (local >= 0) & (local < num_experts)
+        flat, bins = jnp.where(here, local, num_experts), num_experts + 1
+    tiles = (a + min(a, num_experts) * (tm - 1) + tm - 1) // tm
+    order = jnp.argsort(flat, stable=True)
+    place = jnp.zeros((a,), jnp.int32).at[order].set(
+        jnp.arange(a, dtype=jnp.int32))
+    sizes = jnp.zeros((bins,), jnp.int32).at[flat].add(1)[:num_experts]
+    starts = jnp.cumsum(sizes) - sizes
+    padded = (sizes + tm - 1) // tm * tm
+    ends = jnp.cumsum(padded)
+    pstarts = ends - padded
+    tiles_used = ends[-1:] // tm
+    tile = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32), tiles_used - 1)
+    tile_expert = jnp.searchsorted(ends, tile * tm, side="right").astype(
+        jnp.int32)
+    row = jnp.arange(tiles * tm, dtype=jnp.int32)
+    e = tile_expert[row // tm]
+    within = jnp.minimum(row - pstarts[e], sizes[e] - 1)
+    row_token = order[jnp.clip(starts[e] + within, 0, a - 1)] // k
+    dest = pstarts[flat] + place - starts[flat]
+    if held is not None:
+        dest = jnp.where(here, dest, -1)
+    return moe.Plan(tm, row_token, dest.reshape(t, k), tile_expert,
+                    tiles_used, sizes)
+
+
+def _routed(tokens, k, columns, skewed, seed=0):
+    """[tokens, k] distinct experts a token. Uniform: the top k of seeded
+    logits. Skewed: the logits fall with the expert's number (the first few
+    take most rows) and experts 5 and `columns - 1` are chosen by nobody."""
+    logits = jax.random.normal(jax.random.PRNGKey(seed + tokens),
+                               (tokens, columns))
+    if skewed:
+        logits = logits - 2.0 * jnp.log1p(
+            jnp.arange(columns, dtype=jnp.float32))
+        logits = logits.at[:, 5].set(-jnp.inf).at[:, -1].set(-jnp.inf)
+    return jax.lax.top_k(logits, k)[1].astype(jnp.int32)
+
+
+def _share(columns, held):
+    """The middle half of the router's columns, or all of them."""
+    return (columns // 4, columns // 2) if held else None
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["uniform", "skewed"])
+@pytest.mark.parametrize("held", [False, True], ids=["all", "held"])
+@pytest.mark.parametrize("columns", [36, 64, 72, 128])
+@pytest.mark.parametrize("k", [1, 8, 10])
+@pytest.mark.parametrize("tokens", [1, 8, 64, 300, 2048])
+def test_plan_a_tile_at_a_time_is_the_plan_a_row_at_a_time(tokens, k, columns,
+                                                           held, skewed):
+    """`dest`, `tile_expert`, `tiles_used` and `sizes` element for element;
+    `row_token` at every row some `dest` names, and some token's number at
+    every other (nothing reads a padding row's product, but it is computed:
+    it must be of real activations)."""
+    experts = _routed(tokens, k, columns, skewed)
+    share = _share(columns, held)
+    # (jitted: one compilation a layout, not one an operation)
+    got, want = (jax.jit(functools.partial(fn, num_experts=columns,
+                                           held=share))(experts)
+                 for fn in (moe.plan, _plan_per_row))
+    assert got.tm == want.tm
+    for name in ("dest", "tile_expert", "tiles_used", "sizes"):
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert (a == b).all(), name
+    rows, dest = np.asarray(got.row_token), np.asarray(want.dest)
+    assert got.row_token.dtype == want.row_token.dtype
+    assert rows.shape == want.row_token.shape
+    named = dest[dest >= 0]
+    assert (rows[named] == np.asarray(want.row_token)[named]).all()
+    assert rows.min() >= 0 and rows.max() < tokens
+    if skewed and columns > k + 2:
+        sizes = np.asarray(got.sizes)
+        first = share[0] if held else 0
+        # the experts nobody chose, where this share holds them, own no tile
+        for e in (5 - first, columns - 1 - first):
+            if 0 <= e < len(sizes):
+                assert sizes[e] == 0
+                assert e not in set(np.asarray(got.tile_expert))
+
+
+# (tokens, k, router columns, held): Mellum's and SDAR's decode, Granite's
+# decode and a short prefill on a share, a prefill combined in blocks
+LAYERS = [(8, 8, 64, False), (64, 8, 128, False), (8, 10, 72, True),
+          (300, 10, 72, True), (2048, 8, 64, False), (2048, 10, 36, True)]
+
+
+@pytest.mark.parametrize("through", ["einsum", "kernel"])
+@pytest.mark.parametrize("tokens,k,columns,held", LAYERS)
+def test_layer_is_the_per_row_planned_layer_bit_for_bit(tokens, k, columns,
+                                                        held, through,
+                                                        monkeypatch):
+    """A row's product depends on its own row and its tile's expert alone, so
+    what a padding row holds reaches no output: the layer over the new layout
+    is the layer over the old one, every float, through the einsum and
+    through the kernel (interpret mode)."""
+    share = _share(columns, held)
+    count = share[1] if held else columns
+    ks = jax.random.split(jax.random.PRNGKey(tokens + columns), 4)
+    x = jax.random.normal(ks[0], (tokens, H), jnp.bfloat16)
+    router = jax.random.normal(ks[1], (H, columns)) * 4 * H ** -0.5
+    gate_up = (jax.random.normal(ks[2], (count, H, 2 * I)) * H ** -0.5
+               ).astype(jnp.bfloat16)
+    down = (jax.random.normal(ks[3], (count, I, H)) * I ** -0.5
+            ).astype(jnp.bfloat16)
+    run = lambda: moe.moe_layer(x, router, gate_up, down, k,
+                                use_kernel=through == "kernel",
+                                interpret=True, held=share)
+    got, load = run()
+    monkeypatch.setattr(moe, "plan", _plan_per_row)
+    want, load_want = run()
+    assert got.dtype == want.dtype and got.shape == want.shape == x.shape
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    assert bool((got == want).all())
+    assert [int(v) for v in load] == [int(v) for v in load_want]
+
+
+# ---------------------------------------------------------------------------
+# The census: what `plan` asks of the compiler at the shapes served
+# ---------------------------------------------------------------------------
+# name -> (tokens, k, router columns, held, the padded rows)
+SERVED = {
+    "mellum_decode": (8, 8, 64, None, 1024),
+    "sdar_forward": (64, 8, 128, None, 2432),
+    "granite_decode": (8, 10, 72, (0, 36), 624),
+    "mellum_prefill": (4096, 8, 64, None, 40960),
+}
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _per_row_work(fn, experts, rows):
+    """The gathers and scatters of `fn`'s jaxpr that look up `rows` or more
+    indices, and its loops that carry an array of `rows` or more elements."""
+    found = []
+    for eqn in _equations(jax.make_jaxpr(fn)(experts).jaxpr):
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            lookups = int(np.prod(eqn.invars[1].aval.shape[:-1],
+                                  dtype=np.int64))
+            if lookups >= rows:
+                found.append((name, lookups))
+        elif name in ("while", "scan"):
+            carried = max(int(np.prod(v.aval.shape, dtype=np.int64))
+                          for v in [*eqn.invars, *eqn.outvars])
+            if carried >= rows:
+                found.append((name, carried))
+    return found
+
+
+@pytest.mark.parametrize("shape", list(SERVED))
+def test_plan_asks_for_no_gather_scatter_or_loop_over_the_padded_rows(shape):
+    """The layout describes `tokens * k` assignments on `tiles` tiles: no
+    index array as long as the padded rows (`tiles * tm`, sixteen times the
+    assignments at Mellum's decode) may come back. The per-row oracle trips
+    the same census five times, so the census sees what it is for."""
+    tokens, k, columns, held, rows = SERVED[shape]
+    experts = jnp.zeros((tokens, k), jnp.int32)
+    new = functools.partial(moe.plan, num_experts=columns, held=held)
+    old = functools.partial(_plan_per_row, num_experts=columns, held=held)
+    assert jax.eval_shape(new, experts).row_token.shape == (rows,)
+    assert rows > tokens * k
+    assert _per_row_work(new, experts, rows) == []
+    assert _per_row_work(old, experts, rows) == [("gather", rows)] * 5

@@ -510,6 +510,33 @@ def test_moe_gmm_one_chip_holds_its_blocks_in_vmem(topology, tm, tiles,
     assert f"bf16[{tiles * tm},{n}]" in text
 
 
+@pytest.mark.parametrize("tokens,k,columns,held", [
+    (8, 8, 64, None),              # Mellum's decode step
+    (64, 8, 128, None),            # SDAR's forward over 64 tokens
+    (8, 10, 72, (0, 36)),          # Granite's decode step on its share
+    (4096, 8, 64, None),           # Mellum's one-prompt prefill
+])
+def test_moe_plan_compiles_to_no_loop_gather_of_scalars_or_scatter(
+        topology, tokens, k, columns, held):
+    """What the chip's compiler makes of `ops/moe.py` `plan`, which the
+    jaxpr cannot show: it turns a gather of windows into a `while` of a step
+    a window, pads a gather of scalars to 1,024 indices (20 us, whatever
+    their number) and runs a scatter an index at a time (builder's chip runs,
+    PR 45). The layout has none of the three at the shapes served; the one
+    lookup left takes a block of `2 * tm` tokens a tile."""
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(topology.devices[0])
+    experts = jax.ShapeDtypeStruct((tokens, k), jnp.int32, sharding=one)
+    text = _compiled_text(
+        lambda e: tuple(moe.plan(e, columns, held=held))[1:], experts)
+    assert " while(" not in text
+    assert " scatter(" not in text
+    gathers = re.findall(r" gather\(.*slice_sizes=\{([\d,]+)\}", text)
+    tm = moe.tile_rows(tokens * k, columns)
+    assert gathers == [f"1,{2 * tm}"]
+
+
 def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
     """`ssd_scan` alone for a wave of 8 x 2,048 positions at Granite's 128
     heads of 64 with 128 states: the Mosaic compiler takes its blocks, its
