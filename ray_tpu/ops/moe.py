@@ -29,6 +29,24 @@ windows becomes a loop of a step a window, where a sort of 32,768 pairs is
 under 20 us: so `plan` has no scalar gather, no scatter and no loop, at any
 shape.
 
+Outside the two grouped matmuls the layer moves the layout's rows once each
+way: the tokens' activations are gathered onto the rows by indices the
+gather knows to be in bounds (a gather that does not is followed by a pass
+that reads and rewrites all `[M, H]` rows to put NaN where an index would be
+out: two thirds of its time), and the chosen rows come back through
+`combine`: gathered `k`-major, a block of tokens at a time, into `k` whole
+`[tokens, H]` slices that one fusion sums in float32 (a `[tokens, k, H]`
+array, `k` rows on a bf16 tile's sixteen sublanes, costs a relayout). A
+Pallas kernel that fetched a token's rows by DMA is not on offer: `y` lies in
+HBM in tiles of eight rows, two rows a 32-bit word, and the chip's compiler
+takes no slice of it that is not whole tiles. Between the two matmuls the
+gate-and-up product is still converted to float32 whole before the
+activation reads it: sliced first it is one fusion and a third of the
+traffic, but the activation's output then lives beside the product it reads
+and beside the second matmul's output, and the compiler's buffer assignment
+puts it on top of both (a wave's prefill 0.26-0.47 GiB higher, compile, PR
+46), where the float32 copy dies in the gathered rows' place.
+
 A chip that shares a layer with others by expert parallelism holds some of
 the router's columns (`held = (first, count)`, static): routing stays over
 all of them, an assignment to an expert that is held elsewhere gets no row
@@ -49,9 +67,12 @@ from jax.experimental import pallas as pl
 VMEM_LIMIT_BYTES = 48 * 2 ** 20
 VMEM_BLOCKS_SHARE = 0.75
 MIN_TILE_ROWS, MAX_TILE_ROWS = 16, 128
-# Tokens whose chosen rows are gathered and weighted at a time: [tokens,
-# top_k, H] float32 of a prefill's 16,384 tokens would be gigabytes.
-COMBINE_TOKENS = 1024
+# Tokens whose chosen rows are gathered and summed at a time. Measured, not
+# derived: a block of 256 tokens of Granite's (ten rows of 4,096 each: 21 MB
+# gathered) runs in 0.40 ms a 1,024 tokens, where blocks of 512 and 1,024 (42
+# and 84 MB) take 0.59 and 0.60 and blocks of 128 0.41 (builder's chip runs,
+# PR 46); a wave's 16,384 tokens at once would be gigabytes.
+COMBINE_TOKENS = 256
 
 
 def route(x: jax.Array, router: jax.Array, top_k: int,
@@ -249,6 +270,48 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
     )(p.tile_expert, p.tiles_used, lhs, rhs)
 
 
+@functools.partial(jax.jit, static_argnames="masked")
+def combine(y: jax.Array, weights: jax.Array, dest: jax.Array,
+            masked: bool = False) -> jax.Array:
+    """y [M,H] (the rows as `Plan` lays them), weights [T,k] float32, dest
+    [T,k] -> [T,H] in y's dtype: token t's sum over j of weights[t,j] *
+    y[dest[t,j]], in float32 in the order j = 0 .. k-1, rounded once.
+
+    The chosen rows are gathered `k`-major, every token's j-th row side by
+    side: [k * tokens, H] is then `k` whole arrays of [tokens, H] (a view,
+    no relayout: where [tokens, k, H] puts a token's `k` rows on a bf16
+    tile's sixteen sublanes, ten of them do not fill it), each converted
+    inside the one fusion that sums them. One gather a block whatever `k`
+    (at a decode step's 8 tokens ten gathers would cost ten launches).
+    `masked`: a `dest` of -1 (an assignment whose expert is held elsewhere)
+    names no row and nothing is read into the sum for it, whatever the row
+    its clamped index names holds (an unused tile's is never written).
+    Every other index lies in [0, M) by construction: the gather says so
+    (`clip`), and no second pass over its rows fills in for an index out
+    of bounds. Jitted, so that a program's layers trace it once (its `k`
+    slices a layer, traced ten times over, were 0.6 s of a program's
+    set-up)."""
+    def block(w, dest):
+        t, k = dest.shape
+        index = jnp.maximum(dest, 0) if masked else dest
+        picked = jnp.take(y, index.T.reshape(k * t), axis=0, mode="clip")
+        out = None
+        for j in range(k):
+            row = picked[j * t:(j + 1) * t].astype(jnp.float32)
+            if masked:
+                row = jnp.where((dest[:, j] >= 0)[:, None], row, 0.0)
+            term = w[:, j, None] * row
+            out = term if out is None else out + term
+        return out.astype(y.dtype)
+
+    t = dest.shape[0]
+    if t > COMBINE_TOKENS and t % COMBINE_TOKENS == 0:
+        blocks = lambda a: a.reshape(t // COMBINE_TOKENS, COMBINE_TOKENS, -1)
+        return jax.lax.map(lambda b: block(*b),
+                           (blocks(weights), blocks(dest))).reshape(t, -1)
+    return block(weights, dest)
+
+
 def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
               down: jax.Array, top_k: int,
               use_kernel: Optional[bool] = None,
@@ -269,26 +332,14 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
     p = plan(experts, num_experts, held=held)
     run = functools.partial(gmm, p=p, use_kernel=use_kernel,
                             interpret=interpret)
-    gu = run(jnp.take(x, p.row_token, axis=0), gate_up).astype(jnp.float32)
+    # (`row_token` lies in [0, T) by construction: the gather says so, and no
+    # pass over the rows it took puts NaN where an index would be out)
+    gu = run(jnp.take(x, p.row_token, axis=0, mode="clip"),
+             gate_up).astype(jnp.float32)
     act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
     y = run(act.astype(x.dtype), down)                      # [M,H]
-
-    def combine(w, dest):
-        picked = jnp.take(y, dest, axis=0).astype(jnp.float32)  # [t,k,H]
-        if held is not None:
-            # An absent expert's assignment has no row: whatever the row its
-            # -1 names holds (an unused tile's is never written) is not read
-            # into the sum.
-            picked = jnp.where((dest >= 0)[..., None], picked, 0.0)
-        return jnp.einsum("tk,tkh->th", w, picked).astype(x.dtype)
-
+    out = combine(y, weights, p.dest, masked=held is not None)
     t = x.shape[0]
-    if t > COMBINE_TOKENS and t % COMBINE_TOKENS == 0:
-        blocks = lambda a: a.reshape(t // COMBINE_TOKENS, COMBINE_TOKENS, -1)
-        out = jax.lax.map(lambda b: combine(*b),
-                          (blocks(weights), blocks(p.dest))).reshape(x.shape)
-    else:
-        out = combine(weights, p.dest)
     i32 = lambda v: jnp.asarray(v, jnp.int32)
     return out, Load(i32(jnp.sum(p.sizes > 0)), i32(jnp.max(p.sizes)),
                      i32(jnp.sum(p.sizes)), i32(t * top_k),

@@ -409,15 +409,9 @@ LAYERS = [(8, 8, 64, False), (64, 8, 128, False), (8, 10, 72, True),
           (300, 10, 72, True), (2048, 8, 64, False), (2048, 10, 36, True)]
 
 
-@pytest.mark.parametrize("through", ["einsum", "kernel"])
-@pytest.mark.parametrize("tokens,k,columns,held", LAYERS)
-def test_layer_is_the_per_row_planned_layer_bit_for_bit(tokens, k, columns,
-                                                        held, through,
-                                                        monkeypatch):
-    """A row's product depends on its own row and its tile's expert alone, so
-    what a padding row holds reaches no output: the layer over the new layout
-    is the layer over the old one, every float, through the einsum and
-    through the kernel (interpret mode)."""
+def _layer_inputs(tokens, k, columns, held):
+    """Seeded bf16 activations and stacks of a layer, its float32 router and
+    the share of the columns it holds."""
     share = _share(columns, held)
     count = share[1] if held else columns
     ks = jax.random.split(jax.random.PRNGKey(tokens + columns), 4)
@@ -427,6 +421,19 @@ def test_layer_is_the_per_row_planned_layer_bit_for_bit(tokens, k, columns,
                ).astype(jnp.bfloat16)
     down = (jax.random.normal(ks[3], (count, I, H)) * I ** -0.5
             ).astype(jnp.bfloat16)
+    return x, router, gate_up, down, share
+
+
+@pytest.mark.parametrize("through", ["einsum", "kernel"])
+@pytest.mark.parametrize("tokens,k,columns,held", LAYERS)
+def test_layer_is_the_per_row_planned_layer_bit_for_bit(tokens, k, columns,
+                                                        held, through,
+                                                        monkeypatch):
+    """A row's product depends on its own row and its tile's expert alone, so
+    what a padding row holds reaches no output: the layer over the new layout
+    is the layer over the old one, every float, through the einsum and
+    through the kernel (interpret mode)."""
+    x, router, gate_up, down, share = _layer_inputs(tokens, k, columns, held)
     run = lambda: moe.moe_layer(x, router, gate_up, down, k,
                                 use_kernel=through == "kernel",
                                 interpret=True, held=share)
@@ -496,3 +503,137 @@ def test_plan_asks_for_no_gather_scatter_or_loop_over_the_padded_rows(shape):
     assert rows > tokens * k
     assert _per_row_work(new, experts, rows) == []
     assert _per_row_work(old, experts, rows) == [("gather", rows)] * 5
+
+
+# ---------------------------------------------------------------------------
+# The rows moved once each way: the combine, and the census of the layer
+# ---------------------------------------------------------------------------
+def _combine_per_token(y, weights, dest):
+    """The plain reference: token by token, its `k` rows in the order j = 0
+    .. k-1, every product and sum rounded to float32; a -1 names no row."""
+    y, weights, dest = (np.asarray(y, np.float32),
+                        np.asarray(weights, np.float32), np.asarray(dest))
+    out = np.zeros((dest.shape[0], y.shape[1]), np.float32)
+    for t in range(dest.shape[0]):
+        for j in range(dest.shape[1]):
+            if dest[t, j] >= 0:
+                out[t] = out[t] + weights[t, j] * y[dest[t, j]]
+    return out
+
+
+def _bf16_ulp(v):
+    """The distance between neighbouring bfloat16 values at |v| (8 bits of
+    significand; the smallest normal's below it)."""
+    v = np.maximum(np.abs(np.asarray(v, np.float32)), 2.0 ** -126)
+    return np.exp2(np.floor(np.log2(v)) - 7)
+
+
+# LAYERS and a blocked prefill on a share: 2 * COMBINE_TOKENS tokens
+COMBINED = LAYERS + [(2 * moe.COMBINE_TOKENS, 10, 72, True)]
+
+
+@pytest.mark.parametrize("tokens,k,columns,held", COMBINED)
+def test_combine_is_the_per_token_sum_within_one_bf16_ulp(tokens, k, columns,
+                                                          held):
+    """`combine` over a layout of `plan`'s, the rows no `dest` names NaN (an
+    unused tile's row may hold anything): every output is the float32 sum
+    of the token's own rows in the order of its choices, rounded once."""
+    share = _share(columns, held)
+    experts = _routed(tokens, k, columns, skewed=False)
+    p = jax.jit(functools.partial(moe.plan, num_experts=columns,
+                                  held=share))(experts)
+    dest = np.asarray(p.dest)
+    assert (dest < 0).any() == held
+    rows = len(p.row_token)
+    ks = jax.random.split(jax.random.PRNGKey(tokens + k), 2)
+    y = np.array(jax.random.normal(ks[0], (rows, H)), np.float32)
+    named = np.zeros(rows, bool)
+    named[dest[dest >= 0]] = True
+    y[~named] = np.nan
+    y = jnp.asarray(y, jnp.bfloat16)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], (tokens, k)), axis=-1)
+    got = jax.jit(functools.partial(moe.combine, masked=held))(
+        y, weights, p.dest)
+    assert got.dtype == y.dtype and got.shape == (tokens, H)
+    got = np.asarray(got, np.float32)
+    want = _combine_per_token(y, weights, dest)
+    assert np.isfinite(got).all()
+    assert (np.abs(got - want) <= _bf16_ulp(want)).all()
+
+
+def _layer_as_it_was(x, router, gate_up, down, top_k, use_kernel=None,
+                     interpret=None, held=None):
+    """The oracle of the census: `moe_layer`'s glue before the rows were
+    moved once each way. Both gathers in `fill` mode, the chosen rows as
+    [tokens, k, H] under an einsum."""
+    num_experts, two_i = router.shape[1], gate_up.shape[2]
+    weights, experts = moe.route(x, router, top_k)
+    p = moe.plan(experts, num_experts, held=held)
+    run = functools.partial(moe.gmm, p=p, use_kernel=use_kernel,
+                            interpret=interpret)
+    gu = run(jnp.take(x, p.row_token, axis=0), gate_up).astype(jnp.float32)
+    act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
+    y = run(act.astype(x.dtype), down)
+
+    def combine(w, dest):
+        picked = jnp.take(y, dest, axis=0).astype(jnp.float32)
+        if held is not None:
+            picked = jnp.where((dest >= 0)[..., None], picked, 0.0)
+        return jnp.einsum("tk,tkh->th", w, picked).astype(x.dtype)
+
+    t, block = x.shape[0], 1024                 # (its blocks were of 1,024)
+    if t > block and t % block == 0:
+        blocks = lambda a: a.reshape(t // block, block, -1)
+        return jax.lax.map(lambda b: combine(*b),
+                           (blocks(weights), blocks(p.dest))).reshape(x.shape)
+    return combine(weights, p.dest)
+
+
+@pytest.mark.parametrize("tokens,k,columns,held", COMBINED)
+def test_layer_is_the_layer_as_it_was_within_one_bf16_ulp(tokens, k, columns,
+                                                          held):
+    """Same routing, same rows, same precision at every step: the sum of a
+    token's rows is taken in the order of its choices where the einsum took
+    its own, so an output may move by its last bit and no further."""
+    x, router, gate_up, down, share = _layer_inputs(tokens, k, columns, held)
+    got, _ = jax.jit(functools.partial(moe.moe_layer, top_k=k, held=share)
+                     )(x, router, gate_up, down)
+    want = jax.jit(functools.partial(_layer_as_it_was, top_k=k, held=share)
+                   )(x, router, gate_up, down)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert (np.abs(got - want) <= _bf16_ulp(want)).all()
+
+
+def _row_traffic(fn, shape):
+    """What a layer's jaxpr at a served shape asks for that moves the
+    layout's rows more than once: gathers in `fill` mode (a second pass over
+    their rows puts NaN where an index is out of bounds) and values of shape
+    [tokens, k, H] (a block's tokens where the combine runs in blocks)."""
+    tokens, k, columns, held, rows = SERVED[shape]
+    count = columns if held is None else held[1]
+    s = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        fn, top_k=k, held=held, use_kernel=True, interpret=True))(
+            s((tokens, H), jnp.bfloat16), s((H, columns), jnp.float32),
+            s((count, H, 2 * I), jnp.bfloat16),
+            s((count, I, H), jnp.bfloat16))
+    blocks = {tokens, min(tokens, 1024), min(tokens, moe.COMBINE_TOKENS)}
+    found, gathered = set(), set()
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "gather":
+            gathered.add(eqn.outvars[0].aval.shape)
+            if eqn.params["mode"] == jax.lax.GatherScatterMode.FILL_OR_DROP:
+                found.add("fill")
+        for v in eqn.outvars:
+            if v.aval.shape in {(b, k, H) for b in blocks}:
+                found.add("[tokens, k, H]")
+    assert (rows, H) in gathered                # (the rows' gather is there)
+    return found
+
+
+@pytest.mark.parametrize("shape", list(SERVED))
+def test_layer_moves_its_rows_once_each_way(shape):
+    """The census of `moe_layer` at the shapes served; the layer as it was
+    trips both, so the census sees what it is for."""
+    assert _row_traffic(moe.moe_layer, shape) == set()
+    assert _row_traffic(_layer_as_it_was, shape) == {"fill", "[tokens, k, H]"}

@@ -537,6 +537,48 @@ def test_moe_plan_compiles_to_no_loop_gather_of_scalars_or_scatter(
     assert gathers == [f"1,{2 * tm}"]
 
 
+@pytest.mark.parametrize("tokens,k,columns,held,h,inter", [
+    (2048, 10, 72, (0, 36), 4096, 768),   # Granite's one-prompt prefill
+    (8, 8, 64, None, 2304, 896),          # Mellum's decode step
+])
+def test_moe_layer_compiles_to_rows_moved_once_each_way(
+        topology, tokens, k, columns, held, h, inter):
+    """What the chip's compiler makes of `moe_layer`'s glue around its two
+    grouped matmuls: a gather in `fill` mode brings a `broadcast_select`
+    pass over all `[M, H]` rows behind it (626 us of 940 at Granite's
+    prefill), rows gathered token-major a relayout `[tokens, k, H]` (0.2 ms
+    a 1,024 tokens; builder's chip runs, PR 45 and 46). Neither is left; the
+    kernels are the two `moe_gmm` calls and no other."""
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(topology.devices[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    count = columns if held is None else held[1]
+    text = _compiled_text(
+        lambda *a: moe.moe_layer(*a, k, use_kernel=True, interpret=False,
+                                 held=held)[0],
+        s((tokens, h), jnp.bfloat16), s((h, columns), jnp.float32),
+        s((count, h, 2 * inter), jnp.bfloat16),
+        s((count, inter, h), jnp.bfloat16))
+    rows = jax.eval_shape(
+        lambda e: moe.plan(e, columns, held=held).row_token,
+        jax.ShapeDtypeStruct((tokens, k), jnp.int32)).shape[0]
+    block = min(tokens, moe.COMBINE_TOKENS)
+    # (instruction name, what it is) of every instruction; its result's type
+    defs = [line.split(" = ", 1) for line in text.splitlines()
+            if " = " in line]
+    results = [d.split("{", 1)[0].split("(", 1)[0] for _, d in defs]
+    assert f"bf16[{rows},{h}]" in results             # (the rows are there)
+    assert not [n for n, d in defs if "broadcast_select_fusion" in n
+                and d.startswith(f"bf16[{rows},{h}]")]
+    for b in {block, min(tokens, 1024)}:    # (the blocks were of 1,024)
+        assert f"bf16[{b},{k},{h}]" not in results
+        assert f"f32[{b},{k},{h}]" not in results
+    assert len([n for n, d in defs
+                if "tpu_custom_call" in d and "moe_gmm" in n]) == 2
+    assert _kernel_names(text) == {"moe_gmm"}
+
+
 def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
     """`ssd_scan` alone for a wave of 8 x 2,048 positions at Granite's 128
     heads of 64 with 128 states: the Mosaic compiler takes its blocks, its
