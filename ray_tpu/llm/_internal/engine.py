@@ -200,12 +200,15 @@ class LLMEngine:
     (reference passes tensor_parallel_size into vLLM,
     serve/deployments/llm/vllm/vllm_models.py:125; here TP is native).
 
+    What the engine reads off a model is stated once, with the value of a
+    model that does not say otherwise, on `models/layers.py` `Decoder`.
+
     The cache is what the model says each layer holds
     (`model.init_cache`): K/V pages per token, or for the layers in
     `model.state_layer_ids` a fixed state per slot, which prefill overwrites
     from zero and decode updates in place. A model with state layers runs
-    without LoRA banks, `param_transform` or prefix sharing (each is built
-    for K/V layers only) and, having no sharding rules, without a mesh.
+    without LoRA banks or prefix sharing (each is built for K/V layers only)
+    and, having no sharding rules, without a mesh.
 
     The layers in `model.ring_layer_ids` (sliding-window attention) hold a
     ring of `sliding_window / page_size + 1` pages a slot, owned by the slot
@@ -231,42 +234,29 @@ class LLMEngine:
     blocks of `denoising_steps` forwards each, and what is carried between
     windows is two blocks' ids, not one last token: the block a row is on
     and the one before it, whose K/V the next window's first forward stores
-    under its revealed ids. It runs under the same three limits as a model
+    under its revealed ids. It runs under the same limits as a model
     with state layers (prefix sharing would hold: whole pages are whole
     blocks; it is off because an admission that finds all its blocks cached
     is not built).
     """
 
-    def __init__(self, model, params, cfg: EngineConfig, mesh=None,
-                 param_transform=None):
+    def __init__(self, model, params, cfg: EngineConfig, mesh=None):
         self.model = model
         self.cfg = cfg
         self.mesh = mesh
-        # In-jit params hook (e.g. models/quant.py dequantize_tree): HBM
-        # holds the transformed-INPUT tree (int8), the jitted step
-        # reconstructs compute-dtype weights where XLA fuses the converts
-        # into the consuming matmuls.
-        self.param_transform = param_transform
         self._state_layers = len(model.state_layer_ids)
         # The window of a model with ring layers (0: none); `init_cache`
         # refuses one that is not whole pages.
         self._ring_window = (int(model.sliding_window)
-                             if getattr(model, "ring_layer_ids", ()) else 0)
+                             if model.ring_layer_ids else 0)
         special = ("has state layers" if self._state_layers else
                    "generates by blocks" if self._block > 1 else
                    "has ring layers" if self._ring_window else "")
-        if special:
-            missing = [what for what, asked in (
-                ("lora_rank > 0: LoRA banks are built for attention "
-                 "projections of every layer", cfg.lora_rank > 0),
-                ("param_transform: not checked against state layers' "
-                 "float32 recurrences or a float32 router",
-                 param_transform is not None))
-                if asked]
-            if missing:
-                raise NotImplementedError(
-                    f"{type(model).__name__} {special} and cannot "
-                    f"run with {'; '.join(missing)}")
+        if special and cfg.lora_rank > 0:
+            raise NotImplementedError(
+                f"{type(model).__name__} {special} and cannot run with "
+                "lora_rank > 0: LoRA banks are built for attention "
+                "projections of every layer")
         if self._block > 1 and (max(1, cfg.decode_steps) % self._block
                                 or cfg.page_size % self._block):
             raise ValueError(
@@ -386,14 +376,14 @@ class LLMEngine:
     def _block(self) -> int:
         """Positions a decode step makes for a row: 1, or the block length
         of a model that generates by diffusion over blocks."""
-        return int(getattr(self.model, "block_length", 1))
+        return int(self.model.block_length)
 
     @property
     def _head_last(self) -> bool:
         """Whether a prefill runs the head on each row's last prompt
         position only: the model says so (`num_logits_to_keep`), as it says
         `state_layer_ids`, and takes `logits_at`."""
-        return getattr(self.model, "num_logits_to_keep", 0) == 1
+        return self.model.num_logits_to_keep == 1
 
     def _describe_params(self) -> Dict[str, int]:
         """Bytes of the parameter tree by dtype, from shapes alone."""
@@ -408,7 +398,7 @@ class LLMEngine:
         the device lays them out: a minor axis fills whole lanes. A model
         with ring layers reports them apart from the allocator's pages."""
         state = set(self.model.state_layer_ids)
-        ring = set(getattr(self.model, "ring_layer_ids", ()))
+        ring = set(self.model.ring_layer_ids)
         size = lambda layer: sum(map(_laid_out_bytes,
                                      jax.tree.leaves(layer)))
         report = {
@@ -555,13 +545,10 @@ class LLMEngine:
         model = self.model
         K = max(1, self.cfg.decode_steps)
         L = max(1, self.cfg.max_logprobs)
-        transform = self.param_transform
         sample = self._sampler(rich, want_lp, L)
 
         def one(params, caches, last_tokens, page_table, seq_lens, active,
                 temps, top_ps, top_ks, keys, lora, lora_idx):
-            if transform is not None:
-                params = transform(params)
             # positions of the NEW token = current length (before write).
             positions = seq_lens[:, None]
             (logits, new_caches), sown = model.apply(
@@ -596,7 +583,7 @@ class LLMEngine:
             out_ti = jnp.zeros((K, B, L), jnp.int32)
             # (the model says which layers sow: a trace of the forward to
             # find out would cost every family seconds a program)
-            load = jnp.zeros((len(getattr(model, "expert_layer_ids", ())),
+            load = jnp.zeros((len(model.expert_layer_ids),
                               len(_EXPERT_LOAD)), jnp.int32)
 
             def body(j, carry):
@@ -775,7 +762,7 @@ class LLMEngine:
     def _prefill_fn(self, bucket: int, nb: int = 1, rich: bool = False,
                     want_lp: bool = False):
         """Batched prefill: `nb` sequences in ONE pass over the weights —
-        a wave of admissions streams the (dequantized) parameters once
+        a wave of admissions streams the parameters once
         instead of once per request, the dominant term in TTFT for
         HBM-bound models."""
         fn = self._prefill_fns.get((bucket, nb, rich, want_lp))
@@ -783,7 +770,6 @@ class LLMEngine:
             return fn
         model = self.model
         L = max(1, self.cfg.max_logprobs)
-        transform = self.param_transform
         sample = self._sampler(rich, want_lp, L)
 
         def prefill(params, caches, ids, rows, starts, true_lens,
@@ -794,8 +780,6 @@ class LLMEngine:
             scattered in (each first token and prompt length), as
             `all_keys` is. Left out (whoever lowers the program from shapes
             alone, block generation), None comes back."""
-            if transform is not None:
-                params = transform(params)
             # ids [nb, bucket] = each prompt's SUFFIX from absolute
             # position starts[i] (>0 when a cached prefix run was shared
             # into its page-table row); causal within each sequence.
@@ -1220,7 +1204,7 @@ class LLMEngine:
 
     def _admit(self, out: List[StepOutput]) -> bool:
         """Admit as many waiting requests as fit. The wave's prefills run
-        BATCHED per bucket — one pass over the (dequantized) weights for
+        BATCHED per bucket — one pass over the weights for
         the whole admission wave, not one per request — and the first
         tokens stay on device until every batch is in flight, so TTFT for
         N admissions is ~one weight stream + one host sync.

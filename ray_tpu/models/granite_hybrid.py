@@ -38,20 +38,17 @@ every active row in place.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.initializers import embed_init, kernel_init
-from ray_tpu.models.jamba import _conv_init, _dt_bias_init
-from ray_tpu.models.llama import RMSNorm
-from ray_tpu.models.sdar_moe import _stack_init
+from ray_tpu.models.layers import (Decoder, SparseMoe, batch_positions,
+                                   conv_init, dense, dt_bias_init, embed,
+                                   init_params, no_lora, norm)
 from ray_tpu.ops.attention import attention_reference
-from ray_tpu.ops.moe import moe_layer
-from ray_tpu.ops.paged_attention import (init_kv_pages, paged_attention,
-                                         paged_write)
+from ray_tpu.ops.paged_attention import paged_attention, paged_write
 from ray_tpu.ops.ssm import causal_conv, ssd_scan, ssd_scan_plain, ssd_step
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -144,23 +141,6 @@ def _a_log_init(key, shape, dtype):
         dtype)
 
 
-def _dense(cfg: GraniteHybridConfig, features: int,
-           name: Optional[str]) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, kernel_init=kernel_init,
-                    name=name)
-
-
-def _norm(cfg: GraniteHybridConfig, name: Optional[str]) -> nn.Module:
-    return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-
-
-def _embed(cfg: GraniteHybridConfig, name: Optional[str]) -> nn.Embed:
-    return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, embedding_init=embed_init,
-                    name=name)
-
-
 class Mamba2Mixer(nn.Module):
     """`state` is None (no cache: the recurrence token by token over the
     whole sequence) or the layer's (conv_tail, S) pool, with `rows` = the pool
@@ -178,11 +158,11 @@ class Mamba2Mixer(nn.Module):
         if mask is None:
             mask = jnp.ones((b, s), bool)
         z, xbc, dt = jnp.split(
-            _dense(cfg, 2 * d + 2 * n + heads, "in_proj")(u),
+            dense(cfg, 2 * d + 2 * n + heads, "in_proj")(u),
             [d, d + cfg.conv_dim], axis=-1)
-        taps = self.param("conv1d_weight", _conv_init, (width, cfg.conv_dim),
+        taps = self.param("conv1d_weight", conv_init, (width, cfg.conv_dim),
                           cfg.param_dtype)
-        bias = self.param("conv1d_bias", _conv_init, (cfg.conv_dim,),
+        bias = self.param("conv1d_bias", conv_init, (cfg.conv_dim,),
                           cfg.param_dtype)
         decode = state is not None and rows is None
         conv, window = causal_conv(xbc, taps, bias,
@@ -201,7 +181,7 @@ class Mamba2Mixer(nn.Module):
             cfg.dtype)
         x, bm, cm = jnp.split(xbc, [d, d + n], axis=-1)
         x = x.reshape(b, s, heads, cfg.mamba_d_head)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (heads,), jnp.float32)
+        dt_bias = self.param("dt_bias", dt_bias_init, (heads,), jnp.float32)
         dt = jnp.where(mask[:, :, None],
                        jax.nn.softplus(f32(dt) + dt_bias), 0.0)
         a = -jnp.exp(f32(self.param("A_log", _a_log_init, (heads,),
@@ -226,7 +206,7 @@ class Mamba2Mixer(nn.Module):
                               + cfg.rms_norm_eps)
         g = g * f32(self.param("norm", nn.initializers.ones, (d,),
                                jnp.float32))
-        return _dense(cfg, cfg.hidden_size, "out_proj")(
+        return dense(cfg, cfg.hidden_size, "out_proj")(
             g.astype(cfg.dtype)), new_state
 
 
@@ -245,9 +225,9 @@ class Attention(nn.Module):
         b, s, _ = x.shape
         h, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         scale = cfg.attention_multiplier
-        q = _dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
-        k = _dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
-        v = _dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
+        q = dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
+        k = dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
+        v = dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
         if kv_pages is None:
             out = attention_reference(q, k, v, causal=True, scale=scale)
         else:
@@ -267,37 +247,8 @@ class Attention(nn.Module):
                 out = jax.lax.map(
                     lambda row: attend(*(t[None] for t in row))[0],
                     (q, page_table, positions, seq_lens))
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, s, h * d)), kv_pages
-
-
-class SparseMoe(nn.Module):
-    """The routed experts this chip holds (`ops/moe.py`): the router's kernel
-    float32 over all `num_experts`, the held experts two stacks in the
-    compute dtype."""
-    cfg: GraniteHybridConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        hid, inter = cfg.hidden_size, cfg.intermediate_size
-        first, count = cfg.experts_held
-        router = self.param("router", nn.initializers.variance_scaling(
-            ROUTER_LOGIT_STD ** 2, "fan_in", "truncated_normal"),
-            (hid, cfg.num_experts), jnp.float32)
-        gate_up = self.param("gate_up", _stack_init, (count, hid, 2 * inter),
-                             cfg.param_dtype)
-        down = self.param("down", _stack_init, (count, inter, hid),
-                          cfg.param_dtype)
-        b, s, _ = x.shape
-        y, load = moe_layer(
-            x.reshape(b * s, hid), router, gate_up.astype(cfg.dtype),
-            down.astype(cfg.dtype), cfg.num_experts_per_tok,
-            held=None if count == cfg.num_experts else (first, count))
-        # `ops.moe.Load` of this call, for whoever asks for the collection
-        # (the engine's programs).
-        self.sow("expert_load", "load", jnp.stack(load))
-        return y.reshape(b, s, hid)
 
 
 class SharedMlp(nn.Module):
@@ -307,9 +258,9 @@ class SharedMlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate, up = jnp.split(_dense(cfg, 2 * cfg.shared_intermediate_size,
+        gate, up = jnp.split(dense(cfg, 2 * cfg.shared_intermediate_size,
                                     "input_linear")(x), 2, axis=-1)
-        return _dense(cfg, cfg.hidden_size, "output_linear")(
+        return dense(cfg, cfg.hidden_size, "output_linear")(
             nn.silu(gate) * up)
 
 
@@ -320,10 +271,11 @@ class GraniteHybridLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, positions, mask, cache, paged, rows):
+    def __call__(self, x, positions, mask=None, cache=None, paged=None,
+                 rows=None):
         cfg = self.cfg
         r = cfg.residual_multiplier
-        normed = _norm(cfg, "input_layernorm")(x)
+        normed = norm(cfg, "input_layernorm")(x)
         if self.kind == MAMBA:
             mixed, new_cache = Mamba2Mixer(cfg, name="mamba")(
                 normed, mask, cache, rows)
@@ -331,73 +283,50 @@ class GraniteHybridLayer(nn.Module):
             mixed, new_cache = Attention(cfg, name="self_attn")(
                 normed, positions, cache, paged)
         x = x + (r * mixed).astype(cfg.dtype)
-        u = _norm(cfg, "post_attention_layernorm")(x)
-        ff = (SparseMoe(cfg, name="block_sparse_moe")(u)
-              + SharedMlp(cfg, name="shared_mlp")(u))
+        u = norm(cfg, "post_attention_layernorm")(x)
+        held = cfg.experts_held
+        routed = SparseMoe(
+            cfg, num_experts=cfg.num_experts,
+            intermediate=cfg.intermediate_size,
+            top_k=cfg.num_experts_per_tok, router_std=ROUTER_LOGIT_STD,
+            held=None if held[1] == cfg.num_experts else held,
+            name="block_sparse_moe")
+        ff = routed(u) + SharedMlp(cfg, name="shared_mlp")(u)
         return x + (r * ff).astype(cfg.dtype), new_cache
 
 
-class GraniteHybridModel(nn.Module):
+class GraniteHybridModel(Decoder):
     cfg: GraniteHybridConfig
 
-    # What the engine reads off a model (as `state_layer_ids`): a prefill
-    # wants the head on this many of a row's last positions, not on all (the
-    # logits of a wave's 16,384 positions over 100,352 ids would be 6.6 GB).
+    # A prefill wants the head on a row's last position only (the logits of
+    # a wave's 16,384 positions over 100,352 ids would be 6.6 GB).
     num_logits_to_keep = 1
 
     @property
     def state_layer_ids(self) -> Tuple[int, ...]:
-        """Layers whose cache entry is a state per slot, not K/V pages."""
         return tuple(i for i, kind in enumerate(self.cfg.layer_types)
                      if kind == MAMBA)
 
     @property
     def expert_layer_ids(self) -> Tuple[int, ...]:
-        """Layers that sow an `expert_load` (`ops.moe.Load`) a forward, for
-        the engine's token-at-a-time programs to sum and report: all."""
         return tuple(range(self.cfg.num_layers))
 
     def init_cache(self, cache_cfg, mesh=None):
         """Per layer: (k_pages, v_pages) on an attention layer; (conv_tail
         [max_seqs, 3, d_inner + 2 N], S [max_seqs, heads, d_head, N] float32)
         on a Mamba layer, a row per engine slot, the states minor."""
-        if mesh is not None:
-            raise NotImplementedError(
-                "GraniteHybridModel: state layers and expert stacks have no "
-                "sharding under a mesh (tensor parallelism is not built for "
-                "this family)")
         cfg = self.cfg
-        tail = (cache_cfg.max_seqs, cfg.mamba_d_conv - 1, cfg.conv_dim)
-        state = (cache_cfg.max_seqs, cfg.mamba_n_heads, cfg.mamba_d_head,
-                 cfg.mamba_d_state)
-        return [(jnp.zeros(tail, cfg.dtype), jnp.zeros(state, jnp.float32))
-                if kind == MAMBA else
-                init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
-                              cfg.dtype)
-                for kind in cfg.layer_types]
+        return super().init_cache(
+            cache_cfg, mesh, tail=(cfg.mamba_d_conv - 1, cfg.conv_dim),
+            state=(cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state))
 
     @nn.nowrap
     def init_params(self, rng):
-        """The tree `self.init(rng, ids)["params"]` holds, made layer by
-        layer: one compiled initializer per kind of layer, run once for each
-        layer of the kind (a constructor has 60 s, and the TPU compiler's
-        time for one program over every layer grows with the depth:
-        models/olmo_hybrid.py)."""
         cfg = self.cfg
-        ids = jnp.zeros((1, 8), jnp.int32)
-        x = jnp.zeros((1, 8, cfg.hidden_size), cfg.dtype)
-
-        def of(module, *args):
-            return jax.jit(lambda key: module.init(key, *args)["params"])
-
-        layer = {kind: of(GraniteHybridLayer(cfg, kind), x, ids, None, None,
-                          None, None) for kind in set(cfg.layer_types)}
-        keys = jax.random.split(rng, cfg.num_layers + 2)
-        params = {f"layers_{i}": layer[kind](keys[i])
-                  for i, kind in enumerate(cfg.layer_types)}
-        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-2])
-        params["norm"] = of(_norm(cfg, None), x)(keys[-1])
-        return params
+        return init_params(
+            rng, cfg,
+            [GraniteHybridLayer(cfg, kind) for kind in cfg.layer_types],
+            {"norm": norm(cfg, None)})
 
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
@@ -410,15 +339,10 @@ class GraniteHybridModel(nn.Module):
         head run on (logits [B, 1, V]); None: every position. Without
         `paged_kv`: the whole sequence, no cache."""
         cfg = self.cfg
-        if lora is not None:
-            raise NotImplementedError("GraniteHybridModel has no LoRA banks")
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        if positions.ndim == 1:
-            positions = jnp.broadcast_to(positions[None, :], (b, s))
-        embed = _embed(cfg, "embed_tokens")
-        x = (cfg.embedding_multiplier * embed(input_ids)).astype(cfg.dtype)
+        no_lora(self, lora)
+        positions = batch_positions(input_ids, positions)
+        table = embed(cfg, "embed_tokens")
+        x = (cfg.embedding_multiplier * table(input_ids)).astype(cfg.dtype)
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i, kind in enumerate(cfg.layer_types):
@@ -428,7 +352,7 @@ class GraniteHybridModel(nn.Module):
             new_caches.append(new_cache)
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        logits = embed.attend(_norm(cfg, "norm")(x)) / cfg.logits_scaling
+        logits = table.attend(norm(cfg, "norm")(x)) / cfg.logits_scaling
         if paged_kv is not None:
             return logits, new_caches
         return logits

@@ -29,17 +29,17 @@ the rows it is given from zero, decode updates every active row in place.
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.initializers import embed_init, kernel_init
-from ray_tpu.models.llama import RMSNorm
+from ray_tpu.models.layers import (Decoder, Mlp, batch_positions, conv_init,
+                                   dense, dt_bias_init, embed, init_params,
+                                   no_lora, norm)
 from ray_tpu.ops.attention import attention_reference
-from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
+from ray_tpu.ops.paged_attention import paged_write_attend
 from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_scan_plain, ssm_step
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -93,37 +93,6 @@ def _a_log_init(key, shape, dtype):
         (n, d)).astype(dtype)
 
 
-def _dt_bias_init(key, shape, dtype):
-    """Mamba: a step log-uniform in [1e-3, 0.1], held through the inverse of
-    softplus."""
-    u = jax.random.uniform(key, shape, jnp.float32)
-    dt = jnp.exp(u * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-    dt = jnp.maximum(dt, 1e-4)
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
-def _conv_init(key, shape, dtype):
-    """torch's Conv1d default for a depthwise kernel (and its bias) of width
-    4: uniform in +-1/sqrt(4)."""
-    return jax.random.uniform(key, shape, jnp.float32, -0.5, 0.5).astype(dtype)
-
-
-def _dense(cfg: JambaConfig, features: int, name: Optional[str]) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, kernel_init=kernel_init,
-                    name=name)
-
-
-def _norm(cfg: JambaConfig, name: Optional[str]) -> nn.Module:
-    return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-
-
-def _embed(cfg: JambaConfig, name: Optional[str]) -> nn.Embed:
-    return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, embedding_init=embed_init,
-                    name=name)
-
-
 class MambaMixer(nn.Module):
     """`state` is None (no cache: the recurrence token by token over the
     whole sequence) or the layer's (conv_tail, h) pool, with `rows` = the pool
@@ -140,10 +109,10 @@ class MambaMixer(nn.Module):
         f32 = lambda t: t.astype(jnp.float32)
         if mask is None:
             mask = jnp.ones((b, s), bool)
-        x, z = jnp.split(_dense(cfg, 2 * d, "in_proj")(u), 2, axis=-1)
-        taps = self.param("conv1d_weight", _conv_init, (width, d),
+        x, z = jnp.split(dense(cfg, 2 * d, "in_proj")(u), 2, axis=-1)
+        taps = self.param("conv1d_weight", conv_init, (width, d),
                           cfg.param_dtype)
-        bias = self.param("conv1d_bias", _conv_init, (d,), cfg.param_dtype)
+        bias = self.param("conv1d_bias", conv_init, (d,), cfg.param_dtype)
         decode = state is not None and rows is None
         conv, window = causal_conv(x, taps, bias,
                                    state[0] if decode else None)
@@ -159,13 +128,13 @@ class MambaMixer(nn.Module):
         # and what a skipped chunk of the scan leaves there is never read.
         x = jnp.where(mask[:, :, None], jax.nn.silu(conv), 0.0).astype(
             cfg.dtype)
-        dt, bm, cm = jnp.split(_dense(cfg, rank + 2 * n, "x_proj")(x),
+        dt, bm, cm = jnp.split(dense(cfg, rank + 2 * n, "x_proj")(x),
                                [rank, rank + n], axis=-1)
-        dt = _norm(cfg, "dt_layernorm")(dt)
-        bm = _norm(cfg, "b_layernorm")(bm)
-        cm = _norm(cfg, "c_layernorm")(cm)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (d,), jnp.float32)
-        dt = jax.nn.softplus(f32(_dense(cfg, d, "dt_proj")(dt)) + dt_bias)
+        dt = norm(cfg, "dt_layernorm")(dt)
+        bm = norm(cfg, "b_layernorm")(bm)
+        cm = norm(cfg, "c_layernorm")(cm)
+        dt_bias = self.param("dt_bias", dt_bias_init, (d,), jnp.float32)
+        dt = jax.nn.softplus(f32(dense(cfg, d, "dt_proj")(dt)) + dt_bias)
         dt = jnp.where(mask[:, :, None], dt, 0.0)
         a = -jnp.exp(f32(self.param("A_log", _a_log_init, (n, d),
                                     jnp.float32)))
@@ -181,7 +150,7 @@ class MambaMixer(nn.Module):
             y, h = ssm_scan(x, dt, bm, cm, z, a, skip, true_len)
             new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
                          state[1].at[rows].set(h))
-        return _dense(cfg, cfg.hidden_size, "out_proj")(
+        return dense(cfg, cfg.hidden_size, "out_proj")(
             y.astype(cfg.dtype)), new_state
 
 
@@ -198,9 +167,9 @@ class Attention(nn.Module):
         cfg = self.cfg
         b, s, _ = x.shape
         h, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        q = _dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
-        k = _dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
-        v = _dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
+        q = dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
+        k = dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
+        v = dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
         if kv_pages is None:
             out = attention_reference(q, k, v, causal=True)
         else:
@@ -208,19 +177,8 @@ class Attention(nn.Module):
             out, kv_pages = paged_write_attend(
                 q, k, v, kv_pages, page_table, positions, write_mask,
                 seq_lens)
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, s, h * d)), kv_pages
-
-
-class Mlp(nn.Module):
-    cfg: JambaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        gate = _dense(cfg, cfg.intermediate_size, "gate_proj")(x)
-        up = _dense(cfg, cfg.intermediate_size, "up_proj")(x)
-        return _dense(cfg, cfg.hidden_size, "down_proj")(nn.silu(gate) * up)
 
 
 class JambaLayer(nn.Module):
@@ -230,9 +188,10 @@ class JambaLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, positions, mask, cache, paged, rows):
+    def __call__(self, x, positions, mask=None, cache=None, paged=None,
+                 rows=None):
         cfg = self.cfg
-        normed = _norm(cfg, "input_layernorm")(x)
+        normed = norm(cfg, "input_layernorm")(x)
         if self.kind == MAMBA:
             mixed, new_cache = MambaMixer(cfg, name="mamba")(
                 normed, mask, cache, rows)
@@ -241,21 +200,18 @@ class JambaLayer(nn.Module):
                 normed, positions, cache, paged)
         x = x + mixed
         x = x + Mlp(cfg, name="feed_forward")(
-            _norm(cfg, "pre_ff_layernorm")(x))
+            norm(cfg, "pre_ff_layernorm")(x))
         return x, new_cache
 
 
-class JambaModel(nn.Module):
+class JambaModel(Decoder):
     cfg: JambaConfig
 
-    # What the engine reads off a model (as `state_layer_ids`): a prefill
-    # wants the head on this many of a row's last positions, not on all
-    # (the published config's own `num_logits_to_keep`).
+    # The published config's own `num_logits_to_keep`.
     num_logits_to_keep = 1
 
     @property
     def state_layer_ids(self) -> Tuple[int, ...]:
-        """Layers whose cache entry is a state per slot, not K/V pages."""
         return tuple(i for i, kind in enumerate(self.cfg.layer_kinds)
                      if kind == MAMBA)
 
@@ -263,41 +219,17 @@ class JambaModel(nn.Module):
         """Per layer: (k_pages, v_pages) on an attention layer; (conv_tail
         [max_seqs, 3, d_inner], h [max_seqs, d_state, d_inner] float32) on a
         Mamba layer, a row per engine slot, the channels minor."""
-        if mesh is not None:
-            raise NotImplementedError(
-                "JambaModel: state layers have no sharding under a mesh "
-                "(tensor parallelism is not built for this family)")
         cfg = self.cfg
-        tail = (cache_cfg.max_seqs, cfg.mamba_d_conv - 1, cfg.d_inner)
-        state = (cache_cfg.max_seqs, cfg.mamba_d_state, cfg.d_inner)
-        return [(jnp.zeros(tail, cfg.dtype), jnp.zeros(state, jnp.float32))
-                if kind == MAMBA else
-                init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
-                              cfg.dtype)
-                for kind in cfg.layer_kinds]
+        return super().init_cache(
+            cache_cfg, mesh, tail=(cfg.mamba_d_conv - 1, cfg.d_inner),
+            state=(cfg.mamba_d_state, cfg.d_inner))
 
     @nn.nowrap
     def init_params(self, rng):
-        """The tree `self.init(rng, ids)["params"]` holds, made layer by
-        layer: one compiled initializer per kind of layer, run once for each
-        layer of the kind (a constructor has 60 s, and the TPU compiler's
-        time for one program over every layer grows with the depth:
-        models/olmo_hybrid.py)."""
         cfg = self.cfg
-        ids = jnp.zeros((1, 8), jnp.int32)
-        x = jnp.zeros((1, 8, cfg.hidden_size), cfg.dtype)
-
-        def of(module, *args):
-            return jax.jit(lambda key: module.init(key, *args)["params"])
-
-        layer = {kind: of(JambaLayer(cfg, kind), x, ids, None, None, None,
-                          None) for kind in set(cfg.layer_kinds)}
-        keys = jax.random.split(rng, cfg.num_layers + 2)
-        params = {f"layers_{i}": layer[kind](keys[i])
-                  for i, kind in enumerate(cfg.layer_kinds)}
-        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-2])
-        params["final_layernorm"] = of(_norm(cfg, None), x)(keys[-1])
-        return params
+        return init_params(
+            rng, cfg, [JambaLayer(cfg, kind) for kind in cfg.layer_kinds],
+            {"final_layernorm": norm(cfg, None)})
 
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
@@ -310,15 +242,10 @@ class JambaModel(nn.Module):
         head run on (logits [B, 1, V]); None: every position. Without
         `paged_kv`: the whole sequence, no cache."""
         cfg = self.cfg
-        if lora is not None:
-            raise NotImplementedError("JambaModel has no LoRA banks")
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        if positions.ndim == 1:
-            positions = jnp.broadcast_to(positions[None, :], (b, s))
-        embed = _embed(cfg, "embed_tokens")
-        x = embed(input_ids)
+        no_lora(self, lora)
+        positions = batch_positions(input_ids, positions)
+        table = embed(cfg, "embed_tokens")
+        x = table(input_ids)
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i, kind in enumerate(cfg.layer_kinds):
@@ -328,7 +255,7 @@ class JambaModel(nn.Module):
             new_caches.append(new_cache)
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        logits = embed.attend(_norm(cfg, "final_layernorm")(x))
+        logits = table.attend(norm(cfg, "final_layernorm")(x))
         if paged_kv is not None:
             return logits, new_caches
         return logits

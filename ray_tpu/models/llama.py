@@ -16,14 +16,14 @@ config 2/4 uses Llama-3-8B).
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Any, ClassVar, Dict, Optional, Tuple
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from ray_tpu.models.layers import Decoder, RMSNorm, apply_rope
 from ray_tpu.ops.attention import attention_reference, flash_attention
 from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
 from ray_tpu.parallel.sharding import ParamShardingRules
@@ -86,62 +86,6 @@ LLAMA_SHARDING = ParamShardingRules([
     (r"lm_head/kernel", ("embed_fsdp", "vocab")),
     (r"norm|input_layernorm|post_attention_layernorm", ("embed",)),
 ])
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (x32 * scale.astype(jnp.float32)).astype(self.dtype)
-
-
-def rope_freqs(head_dim: int, theta: float,
-               yarn: Optional[Tuple[float, int, float, float]] = None
-               ) -> jax.Array:
-    """The rotary inverse frequencies of one kind of layer, [head_dim / 2].
-    `yarn` = (factor, original_max_position_embeddings, beta_fast, beta_slow)
-    gives YaRN's: pair j keeps its frequency below the dimension that turns
-    `beta_fast` times over the original context, takes it over `factor` above
-    the one that turns `beta_slow` times, and a linear ramp between (the
-    range's ends rounded outwards: HF's `truncate` default)."""
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                             / head_dim))
-    if yarn is None:
-        return freqs
-    factor, original, beta_fast, beta_slow = yarn
-    turns_at = lambda turns: (head_dim * math.log(
-        original / (turns * 2 * math.pi))) / (2 * math.log(theta))
-    low = max(math.floor(turns_at(beta_fast)), 0)
-    high = min(math.ceil(turns_at(beta_slow)), head_dim - 1)
-    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 0.001), 0.0, 1.0)
-    return freqs / factor * ramp + freqs * (1.0 - ramp)
-
-
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               freqs: Optional[jax.Array] = None,
-               factor: float = 1.0) -> jax.Array:
-    """x: [B, S, H, D]; positions: [B, S] or [S]. `freqs` [D/2]: a layer
-    kind's own inverse frequencies in place of `rope_freqs(D, theta)`;
-    `factor` multiplies cos and sin (YaRN's `attention_factor`)."""
-    if freqs is None:
-        freqs = rope_freqs(x.shape[-1], theta)
-    if positions.ndim == 1:
-        positions = positions[None, :]
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
 
 
 def lora_delta(x, bank, idx):
@@ -266,12 +210,9 @@ class DecoderLayer(nn.Module):
         return x, new_cache
 
 
-class LlamaModel(nn.Module):
+class LlamaModel(Decoder):
     cfg: LlamaConfig
     mesh: Optional[Mesh] = None
-
-    # Layers whose serving cache is a state per slot: none, all hold K/V.
-    state_layer_ids: ClassVar[Tuple[int, ...]] = ()
 
     def init_cache(self, cache_cfg, mesh=None):
         """The serving engine's cache, per layer: (k_pages, v_pages)."""

@@ -17,8 +17,8 @@ window counting the query's own position, the MTP head left out).
     full      key j visible iff j <= i; inv_freq YaRN's (`rope_freqs`), cos
               and sin times `yarn_attention_factor`
 
-Serving cache, per layer (`init_cache`): a full layer holds paged K/V from
-the engine's allocator like `LlamaModel`; a sliding layer holds a ring of
+Serving cache, per layer (`Decoder.init_cache`): a full layer holds paged
+K/V from the engine's allocator like `LlamaModel`; a sliding layer a ring of
 `sliding_window / page_size + 1` pages a slot (`ops/paged_attention.py`
 `ring_*`), whose bytes do not grow with the context. A prefill attends over
 the call's own q, k, v (the flash forward kernel, causal or banded: no
@@ -32,18 +32,18 @@ of the ring (`swa_decode`).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, ClassVar, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import apply_rope, rope_freqs
-from ray_tpu.models.sdar_moe import SparseMoe, _dense, _embed, _norm
+from ray_tpu.models.layers import (Decoder, SparseMoe, apply_rope,
+                                   batch_positions, dense, embed, init_params,
+                                   no_lora, norm, rope_freqs)
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    sliding_window_attention)
-from ray_tpu.ops.paged_attention import (init_kv_pages, init_ring_pages,
-                                         paged_write, paged_write_attend,
+from ray_tpu.ops.paged_attention import (paged_write, paged_write_attend,
                                          ring_attention, ring_write)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -141,16 +141,16 @@ class Attention(nn.Module):
         freqs, factor = cfg.rope(self.kind)
         rope = lambda t: apply_rope(t, positions, cfg.rope_theta, freqs,
                                     factor)
-        q = _dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
-        k = _dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
-        v = _dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
-        q, k = rope(_norm(cfg, "q_norm")(q)), rope(_norm(cfg, "k_norm")(k))
+        q = dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
+        k = dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
+        v = dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
+        q, k = rope(norm(cfg, "q_norm")(q)), rope(norm(cfg, "k_norm")(k))
         if kv_pages is None:
             out = attention_reference(q, k, v, causal=True, window=window)
         else:
             out, kv_pages = self._serve(q, k, v, kv_pages, positions, paged,
                                         slots, window)
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, s, h * d)), kv_pages
 
     def _serve(self, q, k, v, kv_pages, positions, paged, slots, window):
@@ -183,76 +183,46 @@ class MellumLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, positions, kv_pages, paged, slots):
+    def __call__(self, x, positions, kv_pages=None, paged=None, slots=None):
         cfg = self.cfg
         mixed, kv_pages = Attention(cfg, self.kind, name="self_attn")(
-            _norm(cfg, "input_layernorm")(x), positions, kv_pages, paged,
+            norm(cfg, "input_layernorm")(x), positions, kv_pages, paged,
             slots)
         x = x + mixed
-        x = x + SparseMoe(cfg, ROUTER_LOGIT_STD, name="mlp")(
-            _norm(cfg, "post_attention_layernorm")(x))
+        x = x + SparseMoe(
+            cfg, num_experts=cfg.num_experts,
+            intermediate=cfg.moe_intermediate_size,
+            top_k=cfg.num_experts_per_tok, router_std=ROUTER_LOGIT_STD,
+            name="mlp")(norm(cfg, "post_attention_layernorm")(x))
         return x, kv_pages
 
 
-class MellumModel(nn.Module):
+class MellumModel(Decoder):
     cfg: MellumConfig
 
-    # What the engine reads off a model. No layer's cache is a state per
-    # slot; a prefill wants the head on a row's last position only (the
-    # logits of a wave's 32,768 positions over 98,304 ids would be 12.9 GB).
-    state_layer_ids: ClassVar[Tuple[int, ...]] = ()
+    # A prefill wants the head on a row's last position only (the logits of
+    # a wave's 32,768 positions over 98,304 ids would be 12.9 GB).
     num_logits_to_keep = 1
     sliding_window = property(lambda self: self.cfg.sliding_window)
 
     @property
     def ring_layer_ids(self) -> Tuple[int, ...]:
-        """Layers whose cache entry is a ring of pages a slot, not pages from
-        the allocator."""
         return tuple(i for i, kind in enumerate(self.cfg.layer_types)
                      if kind == SLIDING)
 
     @property
     def expert_layer_ids(self) -> Tuple[int, ...]:
-        """Layers that sow an `expert_load` (`ops.moe.Load`) a forward: all."""
         return tuple(range(self.cfg.num_layers))
-
-    def init_cache(self, cache_cfg, mesh=None):
-        """Per layer (k_pages, v_pages): the allocator's pool on a full
-        layer, `max_seqs` rings on a sliding layer."""
-        if mesh is not None:
-            raise NotImplementedError(
-                "MellumModel: ring layers and expert stacks have no sharding "
-                "under a mesh (tensor parallelism is not built for this "
-                "family)")
-        cfg = self.cfg
-        return [init_ring_pages(cache_cfg, cfg.sliding_window,
-                                cfg.num_kv_heads, cfg.head_dim, cfg.dtype)
-                if kind == SLIDING else
-                init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
-                              cfg.dtype)
-                for kind in cfg.layer_types]
 
     @nn.nowrap
     def init_params(self, rng):
-        """The tree `self.init(rng, ids)["params"]` holds, made layer by
-        layer (a constructor has 60 s, and the TPU compiler's time for one
-        program over every layer grows with the depth: models/olmo_hybrid.py).
-        Both kinds of layer hold the same tensors: one compiled initializer."""
+        """Both kinds of layer hold the same tensors: one compiled
+        initializer."""
         cfg = self.cfg
-        ids = jnp.zeros((1, 8), jnp.int32)
-        x = jnp.zeros((1, 8, cfg.hidden_size), cfg.dtype)
-
-        def of(module, *args):
-            return jax.jit(lambda key: module.init(key, *args)["params"])
-
-        layer = of(MellumLayer(cfg, FULL), x, ids, None, None, None)
-        keys = jax.random.split(rng, cfg.num_layers + 3)
-        params = {f"layers_{i}": layer(keys[i])
-                  for i in range(cfg.num_layers)}
-        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-3])
-        params["norm"] = of(_norm(cfg, None), x)(keys[-2])
-        params["lm_head"] = of(_dense(cfg, cfg.vocab_size, None), x)(keys[-1])
-        return params
+        return init_params(
+            rng, cfg, [MellumLayer(cfg, FULL)] * cfg.num_layers,
+            {"norm": norm(cfg, None),
+             "lm_head": dense(cfg, cfg.vocab_size, None)})
 
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
@@ -266,14 +236,9 @@ class MellumModel(nn.Module):
         None: every position. Without `paged_kv`: the whole sequence, no
         cache."""
         cfg = self.cfg
-        if lora is not None:
-            raise NotImplementedError("MellumModel has no LoRA banks")
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        if positions.ndim == 1:
-            positions = jnp.broadcast_to(positions[None, :], (b, s))
-        x = _embed(cfg, "embed_tokens")(input_ids)
+        no_lora(self, lora)
+        positions = batch_positions(input_ids, positions)
+        x = embed(cfg, "embed_tokens")(input_ids)
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i, kind in enumerate(cfg.layer_types):
@@ -283,7 +248,7 @@ class MellumModel(nn.Module):
             new_caches.append(kv_pages)
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        logits = _dense(cfg, cfg.vocab_size, "lm_head")(_norm(cfg, "norm")(x))
+        logits = dense(cfg, cfg.vocab_size, "lm_head")(norm(cfg, "norm")(x))
         if paged_kv is not None:
             return logits, new_caches
         return logits

@@ -20,17 +20,18 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.initializers import embed_init, kernel_init
-from ray_tpu.models.llama import RMSNorm
+from ray_tpu.models.layers import (Decoder, Mlp, RMSNorm, batch_positions,
+                                   dense, dt_bias_init, embed, init_params,
+                                   no_lora, norm)
 from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops.linear_attention import gdn_chunked, gdn_decode
-from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
+from ray_tpu.ops.paged_attention import paged_write_attend
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -92,15 +93,6 @@ def _a_log_init(key, shape, dtype):
     return jnp.log(a).astype(dtype)
 
 
-def _dt_bias_init(key, shape, dtype):
-    """GatedDeltaNet: dt log-uniform in [1e-3, 0.1], held through the
-    inverse of softplus."""
-    u = jax.random.uniform(key, shape, jnp.float32)
-    dt = jnp.exp(u * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-    dt = jnp.maximum(dt, 1e-4)
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
 def _conv_init(key, shape, dtype):
     """torch's Conv1d default for a depthwise kernel of width 4."""
     bound = 1.0 / math.sqrt(shape[0])
@@ -110,23 +102,6 @@ def _conv_init(key, shape, dtype):
 
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
-def _dense(cfg: OlmoHybridConfig, features: int,
-           name: Optional[str]) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, kernel_init=kernel_init,
-                    name=name)
-
-
-def _norm(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Module:
-    return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-
-
-def _embed(cfg: OlmoHybridConfig, name: Optional[str]) -> nn.Embed:
-    return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, embedding_init=embed_init,
-                    name=name)
 
 
 class GatedDeltaNet(nn.Module):
@@ -146,8 +121,8 @@ class GatedDeltaNet(nn.Module):
         if mask is None:
             mask = jnp.ones((b, s), bool)
         proj = jnp.concatenate(
-            [_dense(cfg, h * dk, "q_proj")(x), _dense(cfg, h * dk, "k_proj")(x),
-             _dense(cfg, h * dv, "v_proj")(x)], axis=-1)  # [B,S,C]
+            [dense(cfg, h * dk, "q_proj")(x), dense(cfg, h * dk, "k_proj")(x),
+             dense(cfg, h * dv, "v_proj")(x)], axis=-1)  # [B,S,C]
         taps = jnp.concatenate(
             [self.param(f"conv_{n}", _conv_init, (width, c), cfg.param_dtype)
              for n, c in (("q", h * dk), ("k", h * dk), ("v", h * dv))],
@@ -171,11 +146,11 @@ class GatedDeltaNet(nn.Module):
         k = _l2norm(k.reshape(b, s, h, dk))
         v = v.reshape(b, s, h, dv)
         a_log = self.param("A_log", _a_log_init, (h,), cfg.param_dtype)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (h,), cfg.param_dtype)
+        dt_bias = self.param("dt_bias", dt_bias_init, (h,), cfg.param_dtype)
         f32 = lambda t: t.astype(jnp.float32)
         g = -jnp.exp(f32(a_log)) * jax.nn.softplus(
-            f32(_dense(cfg, h, "a_proj")(x)) + f32(dt_bias))
-        beta = jax.nn.sigmoid(f32(_dense(cfg, h, "b_proj")(x)))
+            f32(dense(cfg, h, "a_proj")(x)) + f32(dt_bias))
+        beta = jax.nn.sigmoid(f32(dense(cfg, h, "b_proj")(x)))
         if cfg.linear_allow_neg_eigval:
             beta = 2.0 * beta
         if decode:
@@ -194,9 +169,9 @@ class GatedDeltaNet(nn.Module):
             new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
                          state[1].at[rows].set(new_s))
         o = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="o_norm")(o)
-        gate = _dense(cfg, h * dv, "g_proj")(x).reshape(b, s, h, dv)
+        gate = dense(cfg, h * dv, "g_proj")(x).reshape(b, s, h, dv)
         y = (o * jax.nn.silu(f32(gate))).astype(cfg.dtype)
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             y.reshape(b, s, h * dv)), new_state
 
 
@@ -213,9 +188,9 @@ class FullAttention(nn.Module):
         cfg = self.cfg
         b, s, _ = x.shape
         h, d = cfg.num_heads, cfg.head_dim
-        q = _norm(cfg, "q_norm")(_dense(cfg, h * d, "q_proj")(x))
-        k = _norm(cfg, "k_norm")(_dense(cfg, h * d, "k_proj")(x))
-        v = _dense(cfg, h * d, "v_proj")(x)
+        q = norm(cfg, "q_norm")(dense(cfg, h * d, "q_proj")(x))
+        k = norm(cfg, "k_norm")(dense(cfg, h * d, "k_proj")(x))
+        v = dense(cfg, h * d, "v_proj")(x)
         q, k, v = (t.reshape(b, s, h, d) for t in (q, k, v))
         if kv_pages is None:
             out = attention_reference(q, k, v, causal=True)
@@ -224,19 +199,8 @@ class FullAttention(nn.Module):
             out, kv_pages = paged_write_attend(
                 q, k, v, kv_pages, page_table, positions, write_mask,
                 seq_lens)
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, s, h * d)), kv_pages
-
-
-class Mlp(nn.Module):
-    cfg: OlmoHybridConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        gate = _dense(cfg, cfg.intermediate_size, "gate_proj")(x)
-        up = _dense(cfg, cfg.intermediate_size, "up_proj")(x)
-        return _dense(cfg, cfg.hidden_size, "down_proj")(nn.silu(gate) * up)
 
 
 class HybridLayer(nn.Module):
@@ -246,7 +210,8 @@ class HybridLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, positions, mask, cache, paged, rows):
+    def __call__(self, x, positions, mask=None, cache=None, paged=None,
+                 rows=None):
         cfg = self.cfg
         if self.kind == LINEAR:
             mixed, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
@@ -254,18 +219,17 @@ class HybridLayer(nn.Module):
         else:
             mixed, new_cache = FullAttention(cfg, name="self_attn")(
                 x, positions, cache, paged)
-        x = x + _norm(cfg, "post_attention_layernorm")(mixed)
-        x = x + _norm(cfg, "post_feedforward_layernorm")(
+        x = x + norm(cfg, "post_attention_layernorm")(mixed)
+        x = x + norm(cfg, "post_feedforward_layernorm")(
             Mlp(cfg, name="mlp")(x))
         return x, new_cache
 
 
-class OlmoHybridModel(nn.Module):
+class OlmoHybridModel(Decoder):
     cfg: OlmoHybridConfig
 
     @property
     def state_layer_ids(self) -> Tuple[int, ...]:
-        """Layers whose cache entry is a state per slot, not K/V pages."""
         return tuple(i for i, kind in enumerate(self.cfg.layer_types)
                      if kind == LINEAR)
 
@@ -273,44 +237,20 @@ class OlmoHybridModel(nn.Module):
         """Per layer: (k_pages, v_pages) on a full layer; (conv_tail
         [max_seqs, 3, C], S [max_seqs, H, dk, dv] float32) on a linear one, a
         row per engine slot."""
-        if mesh is not None:
-            raise NotImplementedError(
-                "OlmoHybridModel: state layers have no sharding under a "
-                "mesh (tensor parallelism is not built for this family)")
         cfg = self.cfg
-        tail = (cache_cfg.max_seqs, cfg.linear_conv_kernel_dim - 1,
-                cfg.conv_channels)
-        state = (cache_cfg.max_seqs, cfg.linear_num_key_heads,
-                 cfg.linear_key_head_dim, cfg.linear_value_head_dim)
-        return [(jnp.zeros(tail, cfg.dtype), jnp.zeros(state, jnp.float32))
-                if kind == LINEAR else
-                init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
-                              cfg.dtype)
-                for kind in cfg.layer_types]
+        return super().init_cache(
+            cache_cfg, mesh,
+            tail=(cfg.linear_conv_kernel_dim - 1, cfg.conv_channels),
+            state=(cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                   cfg.linear_value_head_dim))
 
     @nn.nowrap
     def init_params(self, rng):
-        """The tree `self.init(rng, ids)["params"]` holds, made layer by
-        layer: one compiled initializer per kind of layer, run once for each
-        layer of the kind. One program over all 16 layers took the TPU's
-        compiler 88 s on the chip's host (my chip run, PR 29), longer than an
-        actor's constructor may take."""
         cfg = self.cfg
-        ids = jnp.zeros((1, 8), jnp.int32)
-        x = jnp.zeros((1, 8, cfg.hidden_size), cfg.dtype)
-
-        def of(module, *args):
-            return jax.jit(lambda key: module.init(key, *args)["params"])
-
-        layer = {kind: of(HybridLayer(cfg, kind), x, ids, None, None, None,
-                          None) for kind in set(cfg.layer_types)}
-        keys = jax.random.split(rng, cfg.num_layers + 3)
-        params = {f"layers_{i}": layer[kind](keys[i])
-                  for i, kind in enumerate(cfg.layer_types)}
-        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-3])
-        params["norm"] = of(_norm(cfg, None), x)(keys[-2])
-        params["lm_head"] = of(_dense(cfg, cfg.vocab_size, None), x)(keys[-1])
-        return params
+        return init_params(
+            rng, cfg, [HybridLayer(cfg, kind) for kind in cfg.layer_types],
+            {"norm": norm(cfg, None),
+             "lm_head": dense(cfg, cfg.vocab_size, None)})
 
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
@@ -321,14 +261,9 @@ class OlmoHybridModel(nn.Module):
         writes (state from zero), None when decoding one token for every row.
         Without `paged_kv`: the whole sequence, no cache."""
         cfg = self.cfg
-        if lora is not None:
-            raise NotImplementedError("OlmoHybridModel has no LoRA banks")
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        if positions.ndim == 1:
-            positions = jnp.broadcast_to(positions[None, :], (b, s))
-        x = _embed(cfg, "embed_tokens")(input_ids)
+        no_lora(self, lora)
+        positions = batch_positions(input_ids, positions)
+        x = embed(cfg, "embed_tokens")(input_ids)
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i, kind in enumerate(cfg.layer_types):
@@ -336,8 +271,8 @@ class OlmoHybridModel(nn.Module):
             x, new_cache = HybridLayer(cfg, kind, name=f"layers_{i}")(
                 x, positions, write_mask, cache, paged, slots)
             new_caches.append(new_cache)
-        x = _norm(cfg, "norm")(x)
-        logits = _dense(cfg, cfg.vocab_size, "lm_head")(x)
+        x = norm(cfg, "norm")(x)
+        logits = dense(cfg, cfg.vocab_size, "lm_head")(x)
         if paged_kv is not None:
             return logits, new_caches
         return logits

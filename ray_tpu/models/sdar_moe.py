@@ -23,23 +23,24 @@ that only store their K/V get no logits). The logits at a position are for
 that position (no shift), and MASK itself is never generated: its logit is
 minus infinity.
 
-Serving cache (`init_cache`): paged K/V on every layer, as `LlamaModel`.
+Serving cache (`Decoder.init_cache`): paged K/V on every layer, as
+`LlamaModel`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, ClassVar, Optional, Tuple
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.initializers import embed_init, kernel_init
-from ray_tpu.models.llama import RMSNorm, apply_rope
-from ray_tpu.ops.moe import moe_layer
-from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
+from ray_tpu.models.layers import (Decoder, SparseMoe, apply_rope,
+                                   batch_positions, dense, embed, init_params,
+                                   no_lora, norm)
+from ray_tpu.ops.paged_attention import paged_write_attend
 
 REMASKING = ("sequential", "low_confidence_static")
 
@@ -98,31 +99,6 @@ class SdarMoeConfig:
             param_dtype=jnp.float32), **kw})
 
 
-def _stack_init(key, shape, dtype):
-    """An expert stack [E, fan_in, features]: each expert's kernel as
-    `kernel_init` draws a projection (float32, rounded, in blocks)."""
-    e, fan_in, features = shape
-    return kernel_init(key, (e * fan_in, features), dtype,
-                       fan_in).reshape(shape)
-
-
-def _dense(cfg: Any, features: int,
-           name: Optional[str]) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, kernel_init=kernel_init,
-                    name=name)
-
-
-def _norm(cfg: Any, name: Optional[str]) -> nn.Module:
-    return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-
-
-def _embed(cfg: Any, name: Optional[str]) -> nn.Embed:
-    return nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, embedding_init=embed_init,
-                    name=name)
-
-
 def block_attention(q, k, v, block_length: int):
     """Dense attention over a whole sequence under the block mask, without
     a cache: q [B,S,H,D], k and v [B,S,HK,D]."""
@@ -147,11 +123,11 @@ class Attention(nn.Module):
         cfg = self.cfg
         b, s, _ = x.shape
         h, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        q = _dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
-        k = _dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
-        v = _dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
-        q = apply_rope(_norm(cfg, "q_norm")(q), positions, cfg.rope_theta)
-        k = apply_rope(_norm(cfg, "k_norm")(k), positions, cfg.rope_theta)
+        q = dense(cfg, h * d, "q_proj")(x).reshape(b, s, h, d)
+        k = dense(cfg, hk * d, "k_proj")(x).reshape(b, s, hk, d)
+        v = dense(cfg, hk * d, "v_proj")(x).reshape(b, s, hk, d)
+        q = apply_rope(norm(cfg, "q_norm")(q), positions, cfg.rope_theta)
+        k = apply_rope(norm(cfg, "k_norm")(k), positions, cfg.rope_theta)
         if kv_pages is None:
             out = block_attention(q, k, v, cfg.block_length)
         else:
@@ -159,98 +135,44 @@ class Attention(nn.Module):
             out, kv_pages = paged_write_attend(
                 q, k, v, kv_pages, page_table, positions, write_mask,
                 seq_lens, block_length=cfg.block_length)
-        return _dense(cfg, cfg.hidden_size, "o_proj")(
+        return dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, s, h * d)), kv_pages
-
-
-class SparseMoe(nn.Module):
-    """All of a layer's experts (`ops/moe.py`): the router's kernel float32
-    (seeded with logits of standard deviation `router_std`), the experts two
-    stacks in the compute dtype. `cfg`: any family's config with this block's
-    fields (models/mellum.py)."""
-    cfg: Any
-    router_std: float = ROUTER_LOGIT_STD
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        e, hid, inter = (cfg.num_experts, cfg.hidden_size,
-                         cfg.moe_intermediate_size)
-        router = self.param("router", nn.initializers.variance_scaling(
-            self.router_std ** 2, "fan_in", "truncated_normal"),
-            (hid, e), jnp.float32)
-        gate_up = self.param("gate_up", _stack_init, (e, hid, 2 * inter),
-                             cfg.param_dtype)
-        down = self.param("down", _stack_init, (e, inter, hid),
-                          cfg.param_dtype)
-        b, s, _ = x.shape
-        y, load = moe_layer(x.reshape(b * s, hid), router,
-                            gate_up.astype(cfg.dtype), down.astype(cfg.dtype),
-                            cfg.num_experts_per_tok)
-        # [experts touched, rows of the fullest] of this call, for whoever
-        # asks for the collection (the engine's decode program).
-        self.sow("expert_load", "load", jnp.stack(load))
-        return y.reshape(b, s, hid)
 
 
 class SdarMoeLayer(nn.Module):
     cfg: SdarMoeConfig
 
     @nn.compact
-    def __call__(self, x, positions, kv_pages, paged):
+    def __call__(self, x, positions, kv_pages=None, paged=None):
         cfg = self.cfg
         mixed, kv_pages = Attention(cfg, name="self_attn")(
-            _norm(cfg, "input_layernorm")(x), positions, kv_pages, paged)
+            norm(cfg, "input_layernorm")(x), positions, kv_pages, paged)
         x = x + mixed
-        x = x + SparseMoe(cfg, name="mlp")(
-            _norm(cfg, "post_attention_layernorm")(x))
+        x = x + SparseMoe(
+            cfg, num_experts=cfg.num_experts,
+            intermediate=cfg.moe_intermediate_size,
+            top_k=cfg.num_experts_per_tok, router_std=ROUTER_LOGIT_STD,
+            name="mlp")(norm(cfg, "post_attention_layernorm")(x))
         return x, kv_pages
 
 
-class SdarMoeModel(nn.Module):
+class SdarMoeModel(Decoder):
     cfg: SdarMoeConfig
 
-    # Layers whose serving cache is a state per slot: none, all hold K/V.
-    state_layer_ids: ClassVar[Tuple[int, ...]] = ()
-
-    # What the engine reads to generate by blocks (as `state_layer_ids`).
+    # What the engine reads to generate by blocks.
     block_length = property(lambda self: self.cfg.block_length)
     denoising_steps = property(lambda self: self.cfg.denoising_steps)
     remasking = property(lambda self: self.cfg.remasking)
     mask_token_id = property(lambda self: self.cfg.mask_token_id)
 
-    def init_cache(self, cache_cfg, mesh=None):
-        """The serving engine's cache, per layer: (k_pages, v_pages)."""
-        if mesh is not None:
-            raise NotImplementedError(
-                "SdarMoeModel: the expert stacks have no sharding under a "
-                "mesh (tensor parallelism is not built for this family)")
-        cfg = self.cfg
-        return [init_kv_pages(cache_cfg, cfg.num_kv_heads, cfg.head_dim,
-                              cfg.dtype) for _ in range(cfg.num_layers)]
-
     @nn.nowrap
     def init_params(self, rng):
-        """The tree `self.init(rng, ids)["params"]` holds, one compiled
-        initializer per kind of tensor group (a layer, the embedding, the
-        head), the layer's run once a layer: a constructor has 60 s, and the
-        TPU compiler's time for one program over every layer grows with the
-        depth (models/olmo_hybrid.py)."""
         cfg = self.cfg
-        ids = jnp.zeros((1, cfg.block_length), jnp.int32)
-        x = jnp.zeros((1, cfg.block_length, cfg.hidden_size), cfg.dtype)
-
-        def of(module, *args):
-            return jax.jit(lambda key: module.init(key, *args)["params"])
-
-        layer = of(SdarMoeLayer(cfg), x, ids, None, None)
-        keys = jax.random.split(rng, cfg.num_layers + 3)
-        params = {f"layers_{i}": layer(keys[i])
-                  for i in range(cfg.num_layers)}
-        params["embed_tokens"] = of(_embed(cfg, None), ids)(keys[-3])
-        params["norm"] = of(_norm(cfg, None), x)(keys[-2])
-        params["lm_head"] = of(_dense(cfg, cfg.vocab_size, None), x)(keys[-1])
-        return params
+        return init_params(
+            rng, cfg, [SdarMoeLayer(cfg)] * cfg.num_layers,
+            {"norm": norm(cfg, None),
+             "lm_head": dense(cfg, cfg.vocab_size, None)},
+            length=cfg.block_length)
 
     @nn.compact
     def __call__(self, input_ids, positions=None, paged_kv=None,
@@ -261,14 +183,9 @@ class SdarMoeModel(nn.Module):
         rows before `logits_from` go through the layers for their K/V alone:
         the final norm and the head run over the others."""
         cfg = self.cfg
-        if lora is not None:
-            raise NotImplementedError("SdarMoeModel has no LoRA banks")
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        if positions.ndim == 1:
-            positions = jnp.broadcast_to(positions[None, :], (b, s))
-        x = _embed(cfg, "embed_tokens")(input_ids)
+        no_lora(self, lora)
+        positions = batch_positions(input_ids, positions)
+        x = embed(cfg, "embed_tokens")(input_ids)
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i in range(cfg.num_layers):
@@ -276,8 +193,8 @@ class SdarMoeModel(nn.Module):
             x, kv_pages = SdarMoeLayer(cfg, name=f"layers_{i}")(
                 x, positions, kv_pages, paged)
             new_caches.append(kv_pages)
-        x = _norm(cfg, "norm")(x[logits_from:])
-        logits = _dense(cfg, cfg.vocab_size, "lm_head")(x)
+        x = norm(cfg, "norm")(x[logits_from:])
+        logits = dense(cfg, cfg.vocab_size, "lm_head")(x)
         logits = jnp.where(jnp.arange(cfg.vocab_size) == cfg.mask_token_id,
                            -jnp.inf, logits)
         if paged_kv is not None:

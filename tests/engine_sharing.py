@@ -25,14 +25,13 @@ def share_decode_programs(eng):
     THE INVARIANT THIS RESTS ON: `LLMEngine._decode_fn` and `_block_decode`
     close over nothing but what the constructor was given besides `params`:
     the model (under a mesh a clone, so such engines share nothing), the
-    config, the mesh and `param_transform`. All four are the key. Params,
+    config and the mesh. All three are the key. Params,
     pools, page tables, LoRA banks and PRNG keys are arguments of the jitted
     program, so an engine's own reach it. A decode program that came to
     close over anything else of its engine would make these tests run
     another engine's program without a word: put that thing in the key, or
     stop sharing."""
-    key = (id(eng.model), dataclasses.astuple(eng.cfg), eng.mesh,
-           eng.param_transform)
+    key = (id(eng.model), dataclasses.astuple(eng.cfg), eng.mesh)
     _, eng._decode_fns = _DECODE_FNS.setdefault(
         key, (eng.model, eng._decode_fns))
     return eng

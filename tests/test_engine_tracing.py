@@ -79,8 +79,9 @@ def _profiler_events(log_dir):
 def traced(tmp_path_factory):
     """Three requests through two slots under the profiler: A runs alone,
     the second is admitted while A's decode window is in flight (the next
-    window is queued behind its prefill, inside the admission), the third
-    has to wait for a slot; after an idle stretch a fourth ends it."""
+    window is queued behind its prefill, inside the admission), the third,
+    made once the engine holds the second, has to wait for a slot; after an
+    idle stretch a fourth ends it."""
     log_dir = str(tmp_path_factory.mktemp("trace"))
     srv = _server()
     srv.generate_all([5, 6, 7], max_tokens=3)       # build the programs
@@ -99,8 +100,14 @@ def traced(tmp_path_factory):
 
         others = [threading.Thread(target=run, args=("B", [9] * 20, 90)),
                   threading.Thread(target=run, args=("C", [3] * 9, 6))]
-        for t in others:
-            t.start()
+        others[0].start()
+        # C is made only once the engine holds B (queued or admitted): the
+        # two threads' start order decides nothing.
+        eng, deadline = srv.engine, time.monotonic() + 60
+        while len(eng.waiting) + len(eng.running) < 2:
+            assert time.monotonic() < deadline, "B never reached the engine"
+            time.sleep(0.001)
+        others[1].start()
         results["A"] = first + list(stream)
         for t in others:
             t.join(120)

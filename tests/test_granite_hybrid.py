@@ -22,8 +22,9 @@ from benchmark.manifest import Manifest  # noqa: E402
 from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.models.granite_hybrid import (  # noqa: E402
-    ATTENTION, MAMBA, GraniteHybridConfig, GraniteHybridModel, SharedMlp,
-    SparseMoe)
+    ATTENTION, MAMBA, ROUTER_LOGIT_STD, GraniteHybridConfig,
+    GraniteHybridModel, SharedMlp)
+from ray_tpu.models.layers import SparseMoe  # noqa: E402
 from ray_tpu.ops import moe, ssm  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -312,8 +313,11 @@ def test_two_shares_and_the_shared_expert_once_add_up_to_the_whole_layer(
     counted once, are the uncut reference's layer output."""
     _, _, kw, reference = tiny
     whole = GraniteHybridConfig.tiny()
+    routed = lambda held: SparseMoe(
+        whole, num_experts=8, intermediate=whole.intermediate_size, top_k=3,
+        router_std=ROUTER_LOGIT_STD, held=held)
     u = jax.random.normal(jax.random.PRNGKey(7), (2, 24, whole.hidden_size))
-    p = SparseMoe(whole).init(jax.random.PRNGKey(8), u)["params"]
+    p = routed(None).init(jax.random.PRNGKey(8), u)["params"]
     shared = SharedMlp(whole).init(jax.random.PRNGKey(9), u)["params"]
     f32 = lambda a: jnp.asarray(a, jnp.float32)
     flat = u.reshape(-1, whole.hidden_size)
@@ -322,12 +326,11 @@ def test_two_shares_and_the_shared_expert_once_add_up_to_the_whole_layer(
                 + reference._shared(shared, flat, kw, f32))
     parts, rows = [], 0
     for first in (0, 4):
-        cfg = dataclasses.replace(whole, experts_held=(first, 4))
         mine = {"router": p["router"],
                 "gate_up": p["gate_up"][first:first + 4],
                 "down": p["down"][first:first + 4]}
-        y, sown = SparseMoe(cfg).apply({"params": mine}, u,
-                                       mutable=["expert_load"])
+        y, sown = routed((first, 4)).apply({"params": mine}, u,
+                                           mutable=["expert_load"])
         load = moe.Load(*sown["expert_load"]["load"][0])
         assert int(load.rows_routed) == 2 * 24 * 3
         rows += int(load.rows_held)

@@ -323,7 +323,7 @@ def test_prefill_program_holds_no_logits_of_every_position(tiny):
     assert every in sizing.lower_prefill(llama, ec, 128, 3, None).as_text()
 
 
-@pytest.mark.parametrize("what", ["mesh", "lora_rank", "param_transform"])
+@pytest.mark.parametrize("what", ["mesh", "lora_rank"])
 def test_engine_refuses_what_is_not_built_for_state_layers(tiny, what):
     model, params, _, _ = tiny
     kw, cfg = {}, {}
@@ -331,10 +331,8 @@ def test_engine_refuses_what_is_not_built_for_state_layers(tiny, what):
         from ray_tpu.parallel.mesh import create_mesh
 
         kw["mesh"] = create_mesh({"tensor": 2}, devices=jax.devices()[:2])
-    elif what == "lora_rank":
-        cfg["lora_rank"] = 4
     else:
-        kw["param_transform"] = lambda p: p
+        cfg["lora_rank"] = 4
     with pytest.raises(NotImplementedError, match=what.split("_")[0]):
         LLMEngine(model, params, EngineConfig(max_seqs=2, **cfg), **kw)
 

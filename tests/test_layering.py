@@ -1,8 +1,11 @@
 """The packages below the serving stack import only downward: the model
 files, the kernels, the sharding helpers and the trainer know nothing of
 `ray_tpu.llm` or `ray_tpu.serve` (proxy -> handle -> replica -> OpenAIServer
--> LLMServer -> LLMEngine -> model -> ops). Read from the AST, so an import
-inside a function counts like one at the top of a file."""
+-> LLMServer -> LLMEngine -> model -> ops). Inside `ray_tpu/models/` a
+family's file imports `layers.py`, `initializers.py` and no other family's
+file, and those two import no family: a change to one family's file moves no
+other family's programs. Read from the AST, so an import inside a function
+counts like one at the top of a file."""
 
 import ast
 import os
@@ -49,6 +52,53 @@ def test_package_imports_nothing_above_it(package):
                     for p in files for line, module in _imports(p)
                     if _above(module)})
     assert not found, f"imports of {ABOVE}: {found}"
+
+
+def _family_modules():
+    """{module name: file} of the model families the serving path builds."""
+    from ray_tpu.models import FAMILIES
+
+    names = {attr.partition(":")[0] for fam in FAMILIES.values()
+             for attr in (fam.config, fam.model)}
+    return {name: os.path.join(REPO, *name.split(".")) + ".py"
+            for name in names}
+
+
+def _family_imports(path, families, repo=REPO):
+    """Lines of `path` that import a module of `families` other than the
+    file's own."""
+    own = os.path.relpath(path, repo)[:-len(".py")].replace(os.sep, ".")
+    return sorted({line for line, module in _imports(path, repo)
+                   for name in families if name != own
+                   and (module == name or module.startswith(name + "."))})
+
+
+def test_no_family_imports_another_and_the_shared_modules_import_none():
+    families = _family_modules()
+    assert len(families) == 6 and all(map(os.path.exists, families.values()))
+    shared = [os.path.join(REPO, "ray_tpu", "models", f)
+              for f in ("layers.py", "initializers.py")]
+    found = {os.path.relpath(path, REPO): lines
+             for path in list(families.values()) + shared
+             if (lines := _family_imports(path, families))}
+    assert not found, f"imports of a model family's file: {found}"
+
+
+def test_the_family_walk_sees_a_neighbour_however_it_is_imported(tmp_path):
+    pkg = tmp_path / "ray_tpu" / "models"
+    pkg.mkdir(parents=True)
+    families = ["ray_tpu.models.jamba", "ray_tpu.models.granite_hybrid"]
+    src = pkg / "granite_hybrid.py"
+    src.write_text("from ray_tpu.models.jamba import _conv_init\n"
+                   "from ray_tpu.models import layers, jamba\n"
+                   "from . import granite_hybrid\n"
+                   "def f():\n    from .jamba import JambaModel\n"
+                   "    import ray_tpu.models.jamba_extras\n")
+    assert _family_imports(str(src), families, str(tmp_path)) == [1, 2, 5]
+    shared = pkg / "layers.py"
+    shared.write_text("from ray_tpu.models.initializers import kernel_init\n"
+                      "from ray_tpu.models.granite_hybrid import SparseMoe\n")
+    assert _family_imports(str(shared), families, str(tmp_path)) == [2]
 
 
 def test_the_walk_sees_function_level_and_relative_imports(tmp_path):

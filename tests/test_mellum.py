@@ -25,7 +25,7 @@ from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E4
 from ray_tpu._private import flight_recorder  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.llm._internal.paged import PagedCacheConfig  # noqa: E402
-from ray_tpu.models.llama import apply_rope, rope_freqs  # noqa: E402
+from ray_tpu.models.layers import apply_rope, rope_freqs  # noqa: E402
 from ray_tpu.models.mellum import (FULL, SLIDING, MellumConfig,  # noqa: E402
                                    MellumModel)
 from ray_tpu.ops import moe  # noqa: E402
@@ -396,9 +396,6 @@ def test_limits_of_a_model_with_rings_raise_by_name(tiny):
     with pytest.raises(NotImplementedError, match="MellumModel has ring "
                        "layers.*LoRA"):
         LLMEngine(model, params, dataclasses.replace(cfg, lora_rank=4))
-    with pytest.raises(NotImplementedError, match="MellumModel has ring "
-                       "layers.*param_transform"):
-        LLMEngine(model, params, cfg, param_transform=lambda p: p)
     from ray_tpu import models
     from ray_tpu.llm._internal.server import load_model_and_params
 

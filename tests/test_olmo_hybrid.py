@@ -337,7 +337,7 @@ def test_engine_cache_is_what_the_model_says(tiny):
                       "state_bytes": 6 * 3 * (3 * 384 + 4 * 24 * 128) * 4}
 
 
-@pytest.mark.parametrize("what", ["mesh", "lora_rank", "param_transform"])
+@pytest.mark.parametrize("what", ["mesh", "lora_rank"])
 def test_engine_refuses_what_is_not_built_for_state_layers(tiny, what):
     model, params, _, _ = tiny
     kw, cfg = {}, {}
@@ -345,10 +345,8 @@ def test_engine_refuses_what_is_not_built_for_state_layers(tiny, what):
         from ray_tpu.parallel.mesh import create_mesh
 
         kw["mesh"] = create_mesh({"tensor": 2}, devices=jax.devices()[:2])
-    elif what == "lora_rank":
-        cfg["lora_rank"] = 4
     else:
-        kw["param_transform"] = lambda p: p
+        cfg["lora_rank"] = 4
     with pytest.raises(NotImplementedError, match=what.split("_")[0]):
         LLMEngine(model, params, EngineConfig(max_seqs=2, **cfg), **kw)
 

@@ -22,7 +22,7 @@ from benchmark.manifest import Manifest  # noqa: E402
 from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.llm._internal.paged import PagedCacheConfig  # noqa: E402
-from ray_tpu.models.llama import apply_rope  # noqa: E402
+from ray_tpu.models.layers import apply_rope  # noqa: E402
 from ray_tpu.models.sdar_moe import (  # noqa: E402
     SdarMoeConfig,
     SdarMoeModel,
@@ -392,7 +392,7 @@ def test_low_confidence_rule_matches_the_reference_pass_by_pass():
 
 
 # -- what the engine does not build for this family ---------------------------
-@pytest.mark.parametrize("what", ["mesh", "lora_rank", "param_transform"])
+@pytest.mark.parametrize("what", ["mesh", "lora_rank"])
 def test_engine_refuses_what_is_not_built_for_block_generation(tiny, what):
     model, params, _, _ = tiny
     kw, cfg = {}, {}
@@ -400,10 +400,8 @@ def test_engine_refuses_what_is_not_built_for_block_generation(tiny, what):
         from ray_tpu.parallel.mesh import create_mesh
 
         kw["mesh"] = create_mesh({"tensor": 2}, devices=jax.devices()[:2])
-    elif what == "lora_rank":
-        cfg["lora_rank"] = 4
     else:
-        kw["param_transform"] = lambda p: p
+        cfg["lora_rank"] = 4
     with pytest.raises(NotImplementedError,
                        match="SdarMoeModel.*" + what.split("_")[0]):
         LLMEngine(model, params, EngineConfig(max_seqs=2, **cfg), **kw)
