@@ -218,6 +218,12 @@ class LLMEngine:
     steps read). Such a model runs under the same limits as one with state
     layers: a shared page run has no ring to restore.
 
+    The layers in `model.latent_layer_ids` (latent attention) hold one pool
+    of the allocator's pages, a token's row `latent_width` values for all
+    heads. A prefill of such a model attends over the call's own keys and
+    reads no cached page, so it too runs under those limits: prefix sharing
+    over latent pages is not built.
+
     A model that says `num_logits_to_keep = 1` gets its prefill's final norm
     and head on each row's last prompt position only (`logits_at`): logits
     [nb, 1, V] where the other families compute [nb, bucket, V] and keep a
@@ -249,9 +255,11 @@ class LLMEngine:
         # refuses one that is not whole pages.
         self._ring_window = (int(model.sliding_window)
                              if model.ring_layer_ids else 0)
+        self._latent_layers = len(model.latent_layer_ids)
         special = ("has state layers" if self._state_layers else
                    "generates by blocks" if self._block > 1 else
-                   "has ring layers" if self._ring_window else "")
+                   "has ring layers" if self._ring_window else
+                   "has latent layers" if self._latent_layers else "")
         if special and cfg.lora_rank > 0:
             raise NotImplementedError(
                 f"{type(model).__name__} {special} and cannot run with "
@@ -334,7 +342,7 @@ class LLMEngine:
         if special and self.prefix_cache is not None:
             # A shared page carries K/V and no state (and no ring): a
             # sharer's state layers would start from zero in the middle of
-            # its prompt.
+            # its prompt. (A latent layer's prefill reads no cached page.)
             logger.info("%s %s: prefix sharing is off",
                         type(model).__name__, special)
             self.prefix_cache = None
@@ -396,20 +404,25 @@ class LLMEngine:
     def _describe_cache(self) -> Dict[str, int]:
         """Layers and bytes of the cache by kind, from shapes alone and as
         the device lays them out: a minor axis fills whole lanes. A model
-        with ring layers reports them apart from the allocator's pages."""
+        with ring layers reports them apart from the allocator's pages, and
+        one with latent layers those apart from K/V."""
         state = set(self.model.state_layer_ids)
         ring = set(self.model.ring_layer_ids)
+        latent = set(self.model.latent_layer_ids)
         size = lambda layer: sum(map(_laid_out_bytes,
                                      jax.tree.leaves(layer)))
+        other = state | ring | latent
         report = {
-            "kv_layers": len(self.caches) - len(state) - len(ring),
+            "kv_layers": len(self.caches) - len(other),
             "state_layers": len(state),
             "kv_bytes": sum(size(c) for i, c in enumerate(self.caches)
-                            if i not in state | ring),
+                            if i not in other),
             "state_bytes": sum(size(self.caches[i]) for i in state)}
-        if ring:
-            report.update(ring_layers=len(ring), ring_bytes=sum(
-                size(self.caches[i]) for i in ring))
+        for kind, layers in (("ring", ring), ("latent", latent)):
+            if layers:
+                report.update({f"{kind}_layers": len(layers),
+                               f"{kind}_bytes": sum(
+                                   size(self.caches[i]) for i in layers)})
         return report
 
     # ------------------------------------------------------------------
@@ -1053,10 +1066,12 @@ class LLMEngine:
         # (a chained window's rows are up to a window further on): every
         # active row's length, and what of it lies inside the window.
         reach = {}
-        if self._ring_window:
+        if self._ring_window or self._latent_layers:
             held = self.seq_lens[list(self.running)]
-            reach = {"context_tokens": int(held.sum()), "window_tokens": int(
-                np.minimum(held, self._ring_window).sum())}
+            reach = {"context_tokens": int(held.sum())}
+        if self._ring_window:
+            reach["window_tokens"] = int(
+                np.minimum(held, self._ring_window).sum())
         with _fr.span("ray_tpu.engine.dispatch_decode", **reach,
                       active=len(self.running), max_seqs=self.cfg.max_seqs,
                       steps=K, free_slots=len(self._free_slots),

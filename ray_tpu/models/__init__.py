@@ -39,6 +39,8 @@ FAMILIES = {
         "ray_tpu.models.granite_hybrid:GraniteHybridModel"),
     "mellum": Family("ray_tpu.models.mellum:MellumConfig",
                      "ray_tpu.models.mellum:MellumModel"),
+    "sarvam_mla": Family("ray_tpu.models.sarvam_mla:SarvamMlaConfig",
+                         "ray_tpu.models.sarvam_mla:SarvamMlaModel"),
 }
 
 

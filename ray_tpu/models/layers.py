@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.initializers import embed_init, kernel_init
 from ray_tpu.ops.moe import moe_layer
-from ray_tpu.ops.paged_attention import init_kv_pages, init_ring_pages
+from ray_tpu.ops.paged_attention import (init_kv_pages, init_latent_pages,
+                                         init_ring_pages)
 
 
 class RMSNorm(nn.Module):
@@ -102,12 +103,14 @@ def embed(cfg: Any, name: Optional[str]) -> nn.Embed:
                     name=name)
 
 
-def stack_init(key, shape, dtype):
+def stack_init(key, shape, dtype, std: float = 1.0):
     """An expert stack [E, fan_in, features]: each expert's kernel as
-    `kernel_init` draws a projection (float32, rounded, in blocks)."""
+    `kernel_init` draws a projection (float32, rounded, in blocks), at `std`
+    times its deviation."""
     e, fan_in, features = shape
     return kernel_init(key, (e * fan_in, features), dtype,
-                       fan_in).reshape(shape)
+                       fan_in if std == 1.0 else fan_in / std ** 2
+                       ).reshape(shape)
 
 
 def dt_bias_init(key, shape, dtype):
@@ -137,6 +140,11 @@ class Mlp(nn.Module):
         return dense(cfg, cfg.hidden_size, "down_proj")(nn.silu(gate) * up)
 
 
+# Standard deviation of a seeded router's bias on the choice (sigmoid scores
+# lie in (0, 1); the eighth and ninth largest of 128 lie some 0.01 apart).
+ROUTER_BIAS_STD = 0.02
+
+
 class SparseMoe(nn.Module):
     """A layer's routed experts (`ops/moe.py`): the router's kernel float32
     over all `num_experts`, seeded with logits of standard deviation
@@ -144,13 +152,20 @@ class SparseMoe(nn.Module):
     its file), the experts two stacks in the compute dtype, each
     `intermediate` wide. `held = (first, count)`: this chip holds those
     experts' stacks and computes their part of the sum; None: all. `cfg`
-    gives `dtype`, `param_dtype` and `hidden_size`."""
+    gives `dtype`, `param_dtype` and `hidden_size`. `scoring` "sigmoid"
+    (`ops.moe.route`): the layer also holds the float32 `bias` [num_experts]
+    that takes part in the choice alone, drawn small and not zero so that a
+    seeded model's choosing and weighing differ; the chosen weights sum to
+    `scale`. `down_std`: the seeded `down` stacks' deviation, in lecun's."""
     cfg: Any
     num_experts: int
     intermediate: int
     top_k: int
     router_std: float
     held: Optional[Tuple[int, int]] = None
+    scoring: str = "softmax"
+    scale: float = 1.0
+    down_std: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -163,11 +178,17 @@ class SparseMoe(nn.Module):
         gate_up = self.param("gate_up", stack_init, (count, hid, 2 * inter),
                              cfg.param_dtype)
         down = self.param("down", stack_init, (count, inter, hid),
-                          cfg.param_dtype)
+                          cfg.param_dtype, self.down_std)
+        routing = {}
+        if self.scoring != "softmax":
+            routing = dict(scoring=self.scoring, scale=self.scale,
+                           bias=self.param(
+                               "bias", nn.initializers.normal(ROUTER_BIAS_STD),
+                               (self.num_experts,), jnp.float32))
         b, s, _ = x.shape
         y, load = moe_layer(x.reshape(b * s, hid), router,
                             gate_up.astype(cfg.dtype), down.astype(cfg.dtype),
-                            self.top_k, held=self.held)
+                            self.top_k, held=self.held, **routing)
         # `ops.moe.Load` of this call, for whoever asks for the collection
         # (the engine's programs).
         self.sow("expert_load", "load", jnp.stack(load))
@@ -232,6 +253,10 @@ class Decoder(nn.Module):
     # `sliding_window` keys, not pages from the allocator.
     ring_layer_ids: ClassVar[Tuple[int, ...]] = ()
     sliding_window: ClassVar[int] = 0
+    # Layers whose cache entry is one pool of the allocator's pages, a
+    # token's row `latent_width` values for all heads (latent attention).
+    latent_layer_ids: ClassVar[Tuple[int, ...]] = ()
+    latent_width: ClassVar[int] = 0
     # Layers that sow an `expert_load` (`ops.moe.Load`) a forward, for the
     # engine's token-at-a-time programs to sum and report.
     expert_layer_ids: ClassVar[Tuple[int, ...]] = ()
@@ -247,20 +272,27 @@ class Decoder(nn.Module):
         """The serving engine's cache of a family without sharding rules, an
         entry a layer: on a state layer (zeros [max_seqs, *tail] in the
         compute dtype, zeros [max_seqs, *state] float32), a row per engine
-        slot; on a ring layer `max_seqs` rings of pages; (k_pages, v_pages)
-        from the allocator's pool on the others."""
+        slot; on a ring layer `max_seqs` rings of pages; on a latent layer
+        one pool of rows; (k_pages, v_pages) from the allocator's pool on
+        the others."""
         if mesh is not None:
             raise NotImplementedError(
                 f"{type(self).__name__}: neither its parameters nor its "
                 "layers' caches have a sharding under a mesh (tensor "
                 "parallelism is not built for this family)")
         cfg, n = self.cfg, cache_cfg.max_seqs
-        kv = cfg.num_kv_heads, cfg.head_dim, cfg.dtype
         states, rings = self.state_layer_ids, self.ring_layer_ids
-        return [(jnp.zeros((n, *tail), cfg.dtype),
-                 jnp.zeros((n, *state), jnp.float32))
-                if i in states else
-                init_ring_pages(cache_cfg, self.sliding_window, *kv)
-                if i in rings else
-                init_kv_pages(cache_cfg, *kv)
-                for i in range(cfg.num_layers)]
+
+        def entry(i):
+            if i in states:
+                return (jnp.zeros((n, *tail), cfg.dtype),
+                        jnp.zeros((n, *state), jnp.float32))
+            if i in self.latent_layer_ids:
+                return init_latent_pages(cache_cfg, self.latent_width,
+                                         cfg.dtype)
+            kv = cfg.num_kv_heads, cfg.head_dim, cfg.dtype
+            if i in rings:
+                return init_ring_pages(cache_cfg, self.sliding_window, *kv)
+            return init_kv_pages(cache_cfg, *kv)
+
+        return [entry(i) for i in range(cfg.num_layers)]

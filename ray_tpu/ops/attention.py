@@ -163,9 +163,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _flash_fwd_core(qt, kt, vt, cfg):
-    """Forward on [B,H,S,D] layout. Returns (out, lse)."""
+    """Forward on [B,H,S,D] layout. Returns (out, lse). The values may have
+    a width of their own (latent attention's keys are 192 wide, its values
+    128): the call is then named `mla_flash`, so a trace tells the two
+    apart."""
     causal, scale, block_q, block_k, interpret = cfg
     b, h, sq, d = qt.shape
+    dv = vt.shape[3]
     skv = kt.shape[2]
     num_k_blocks = skv // block_k
     grid = (b, h, sq // block_q, num_k_blocks)
@@ -178,26 +182,26 @@ def _flash_fwd_core(qt, kt, vt, cfg):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), qt.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_q, 1)),
             _vmem((block_q, 1)),
-            _vmem((block_q, d)),
+            _vmem((block_q, dv)),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if dv == d else "mla_flash",
     )(qt, kt, vt)
     return out, lse
 
@@ -385,6 +389,8 @@ def flash_attention(
     mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     """Pallas flash attention. q [B,Sq,H,D], k/v [B,Skv,Hkv,D] → [B,Sq,H,D].
+    v may be [B,Skv,Hkv,Dv] of another width (→ [B,Sq,H,Dv], the kernel
+    `mla_flash`): forward only, no scores tensor and no padding of v to D.
 
     Differentiable: forward saves per-row logsumexp, backward runs two Pallas
     kernels (dq with k sequential; dk/dv with q sequential) — the
@@ -442,7 +448,11 @@ def flash_attention(
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     cfg = (causal, scale, block_q, block_k, interpret)
-    out = _flash_core(qt, kt, vt, cfg)
+    if v.shape[-1] != d:
+        # (the backward kernels take one width)
+        out, _ = _flash_fwd_core(qt, kt, vt, cfg)
+    else:
+        out = _flash_core(qt, kt, vt, cfg)
     return out.transpose(0, 2, 1, 3)
 
 

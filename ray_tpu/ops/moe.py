@@ -6,6 +6,9 @@ file calls).
     p = softmax(x @ router) in float32;  the top_k largest, divided by their
     sum;  y = sum_e w_e * down_e(silu(gate_e x) * up_e x)
 
+(or, `route(scoring="sigmoid")`: sigmoid scores, chosen with a learned bias,
+weighed without it, the sum scaled)
+
 TPU-first design: everything is static-shaped. The `T * top_k` assignments
 are sorted by expert and laid out so that every expert's rows start on a tile
 of `tm` rows (`Plan`): a tile then belongs to one expert, and the grouped
@@ -75,17 +78,31 @@ MIN_TILE_ROWS, MAX_TILE_ROWS = 16, 128
 COMBINE_TOKENS = 256
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int,
-          dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:
-    """x [T,H], router [H,E] -> (weights [T,k] summing to one, experts [T,k]).
-    Probabilities, the choice and the renormalisation are computed in `dtype`
-    (float32: a near-tie between two experts must not be decided by the
-    activations' rounding)."""
+def route(x: jax.Array, router: jax.Array, top_k: int, dtype=jnp.float32,
+          scoring: str = "softmax", bias: Optional[jax.Array] = None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """x [T,H], router [H,E] -> (weights [T,k] summing to `scale`, experts
+    [T,k]). Scores, the choice and the renormalisation are computed in
+    `dtype` (float32: a near-tie between two experts must not be decided by
+    the activations' rounding). `scoring` "softmax": the top_k largest
+    probabilities. "sigmoid" (bias-balanced routing): an expert's score is
+    sigmoid(logit), the top_k largest of score + `bias` [E] are chosen, and
+    the chosen weigh by their scores without it."""
     logits = jnp.dot(x.astype(dtype), router.astype(dtype),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, top_k)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(
+            scores if bias is None else scores + bias.astype(dtype), top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        raise ValueError(f"route: scoring {scoring!r}")
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -316,19 +333,20 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
               down: jax.Array, top_k: int,
               use_kernel: Optional[bool] = None,
               interpret: Optional[bool] = None,
-              held: Optional[Tuple[int, int]] = None):
+              held: Optional[Tuple[int, int]] = None, **routing):
     """The layer over x [T,H]: router [H,E] float32, gate_up [E,H,2I] (an
     expert's gate columns, then its up columns), down [E,I,H]. Returns
     (y [T,H], `Load` of this call). With `held = (first, count)` the stacks
     are [count, ...], the experts of the router's columns first ..
     first+count-1, and y is their part of the sum: what a token's other
-    chosen experts would add is computed where they are held."""
+    chosen experts would add is computed where they are held. `routing`:
+    `route`'s `scoring`, `bias` and `scale`."""
     num_experts, two_i = router.shape[1], gate_up.shape[2]
     count = num_experts if held is None else held[1]
     if gate_up.shape[0] != count or down.shape[0] != count:
         raise ValueError(f"moe_layer: stacks of {gate_up.shape[0]} and "
                          f"{down.shape[0]} experts where {count} are held")
-    weights, experts = route(x, router, top_k)
+    weights, experts = route(x, router, top_k, **routing)
     p = plan(experts, num_experts, held=held)
     run = functools.partial(gmm, p=p, use_kernel=use_kernel,
                             interpret=interpret)

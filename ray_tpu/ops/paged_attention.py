@@ -68,6 +68,31 @@ def init_kv_pages(cache_cfg, kv_heads: int, head_dim: int,
             jnp.zeros(shape, dtype, device=sharding))
 
 
+LANES = 128  # a tile of the device's memory is this many lanes wide
+
+
+def init_latent_pages(cache_cfg, width: int, dtype=jnp.bfloat16):
+    """One latent-attention layer's pool from the allocator's pages: a token's
+    `width` values on whole tiles of lanes, [P, ps, 640] for 576, the lanes
+    past `width` zero. The device pads a minor axis to whole tiles whatever
+    its shape says (so the bytes are these either way), and the chip's
+    compiler takes no DMA of a page whose lanes are not whole tiles; stated,
+    the padding is the kernel's to read as it lies. One array and not two of
+    512 and 64 lanes: the second would be padded to 128, the same 640, and
+    cost the decode kernel a second DMA a page."""
+    lanes = -(-width // LANES) * LANES
+    return jnp.zeros((cache_cfg.num_pages, cache_cfg.page_size, lanes), dtype)
+
+
+def latent_write(pages: jax.Array, rows: jax.Array, page_table: jax.Array,
+                 positions: jax.Array, mask: jax.Array) -> jax.Array:
+    """`paged_write` of rows [B,S,width] into a latent pool, each padded with
+    zeros to the pool's lanes."""
+    pad = pages.shape[-1] - rows.shape[-1]
+    return paged_write(pages, jnp.pad(rows, ((0, 0), (0, 0), (0, pad))),
+                       page_table, positions, mask)
+
+
 def paged_write(pages: jax.Array, new_kv: jax.Array, page_table: jax.Array,
                 positions: jax.Array, mask: jax.Array,
                 wrap: bool = False) -> jax.Array:
@@ -464,3 +489,156 @@ def paged_attention_decode_kernel(
         interpret=interpret,
         name="paged_decode" if window is None else "swa_decode",
     )(page_table, seq_lens, q, k_pages, v_pages)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA): one pool, read once, keys and values alike
+# ---------------------------------------------------------------------------
+def latent_attention(q: jax.Array, pages: jax.Array, page_table: jax.Array,
+                     seq_lens: jax.Array, rank: int, scale: float,
+                     use_kernel: Optional[bool] = None) -> jax.Array:
+    """One decode step of a latent-attention layer in its absorbed form:
+    q [B,H,width], head h's query already carried into the latent's space
+    (`rank` lanes) beside its rotary part, over the rows at positions below
+    `seq_lens` of a latent pool [P,ps,lanes] (the step's own row written).
+    softmax(scale q . row) over all the row's lanes, times the rows' first
+    `rank` lanes -> [B,H,rank]; the caller carries that out of the latent's
+    space. On a TPU the kernel `mla_decode` walks the pages; elsewhere they
+    are gathered whole."""
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    # (the pool's padding lanes are zero: so are the query's)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pages.shape[-1] - q.shape[-1])))
+    if use_kernel:
+        return mla_decode(q, pages, page_table, seq_lens, rank, scale)
+    rows = paged_gather(pages, page_table).astype(jnp.float32)  # [B,C,width]
+    logits = jnp.einsum("bhw,bkw->bhk", q.astype(jnp.float32), rows) * scale
+    visible = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+    logits = jnp.where(visible[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", probs,
+                      rows[..., :rank]).astype(q.dtype)
+
+
+def _mla_decode_kernel(pt_ref, lens_ref, q_ref, pages_hbm, o_ref, buf, sem,
+                       m_scr, l_scr, acc_scr, *, rank: int, scale: float):
+    """Grid (B,): one program a sequence, over all its heads, the page walk
+    of `_paged_decode_kernel` (chunks of C pages, a page one DMA of
+    [ps, width], the next chunk's DMAs in flight under this chunk's flash
+    update) over ONE pool: the query tile [H, width] against a chunk's rows
+    gives every head's scores in one contraction, and the weights against
+    the same rows' first `rank` lanes the running sum [H, rank]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    _, C, ps, width = buf.shape
+    # (never past the page table: `_paged_decode_kernel` says why)
+    seq_len = jnp.minimum(lens_ref[b], pt_ref.shape[1] * ps)
+    n_pages = jax.lax.div(seq_len + ps - 1, ps)
+    n_chunks = jax.lax.div(n_pages + C - 1, C)
+
+    def page_copy(ci, slot, j):
+        return pltpu.make_async_copy(pages_hbm.at[pt_ref[b, ci * C + j]],
+                                     buf.at[slot, j], sem.at[slot, j])
+
+    def start_chunk(ci, slot):
+        for j in range(C):  # static unroll: C independent page DMAs
+
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                page_copy(ci, slot, j).start()
+
+            @pl.when(ci * C + j >= n_pages)
+            def _zero(j=j):
+                # (a weight of exactly 0 times garbage may be NaN)
+                buf[slot, j] = jnp.zeros_like(buf[slot, j])
+
+    def wait_chunk(ci, slot):
+        for j in range(C):
+
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                page_copy(ci, slot, j).wait()
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        start_chunk(0, 0)
+
+    def chunk(ci, carry):
+        slot = jax.lax.rem(ci, 2)
+
+        @pl.when(ci + 1 < n_chunks)
+        def _prefetch():
+            start_chunk(ci + 1, 1 - slot)
+
+        wait_chunk(ci, slot)
+        rows = buf[slot].reshape(C * ps, width)
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, C*ps]
+        pos = ci * C * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < seq_len, s, NEG_INF)
+        m_prev = m_scr[...]  # [H, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [H, rank]
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+        o_ref.dtype)
+
+
+def mla_decode(q: jax.Array, pages: jax.Array, page_table: jax.Array,
+               seq_lens: jax.Array, rank: int, scale: float,
+               pages_per_chunk: Optional[int] = None,
+               interpret: Optional[bool] = None) -> jax.Array:
+    """Pallas decode attention over a latent pool: q [B,H,lanes] over pages
+    [P,ps,lanes] -> [B,H,rank] (`latent_attention` says what of what). Grid
+    (B,); a page is read once, as keys and as values. `pages_per_chunk`
+    defaults to what KV_CHUNK_VMEM_BYTES holds of this pool's pages, twice
+    (the chunk in use and the one in flight)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, width = q.shape
+    _, ps, _ = pages.shape
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    if pages_per_chunk is None:
+        page_bytes = ps * width * pages.dtype.itemsize
+        pages_per_chunk = max(1, KV_CHUNK_VMEM_BYTES // (2 * page_bytes))
+    C = min(pages_per_chunk, page_table.shape[1])
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, rank=rank, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, width), lambda bi, pt, lens: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, rank),
+                                   lambda bi, pt, lens: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, C, ps, width), pages.dtype),
+                pltpu.SemaphoreType.DMA((2, C)),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="mla_decode",
+    )(page_table, seq_lens, q, pages)

@@ -1,8 +1,9 @@
-"""The seam between a family's file and `models/layers.py`, for all six
+"""The seam between a family's file and `models/layers.py`, for all seven
 families at their tests' tiny configurations: a seed's parameter tree is the
 one the family's own initializer drew before the initializers became one
-(digests recorded at that commit), every model answers what the engine reads
-off it, and the cache is one entry a layer."""
+(digests recorded at that commit; the seventh family's at the commit that
+brought it), every model answers what the engine reads off it, and the cache
+is one entry a layer."""
 
 import hashlib
 
@@ -41,12 +42,17 @@ DIGESTS = {
     "mellum": (
         "df9aedbafd47af6cef419e18385640e01eefbc35bfe0b783436681e2be82706a",
         "e220cce234348517df00df37a378bf33a8b5418813e0b947538ba4852932dee5"),
+    # (written on `models/layers.py` from the start: taken at its own commit)
+    "sarvam_mla": (
+        "13957818c63bcebd0f2da94fe35ddfceeeb28821c4a7b0088cb358186ebcc91e",
+        "bc958b5ff90e0df234ce1bd05416ab9bae4ad98928c0ff007dcb90b349ec5adb"),
 }
 
 # What the engine reads off a model: the value of a family that does not say
 # otherwise, then what each tiny configuration says.
 READ = {"state_layer_ids": (), "ring_layer_ids": (), "expert_layer_ids": (),
-        "block_length": 1, "num_logits_to_keep": 0, "sliding_window": 0}
+        "block_length": 1, "num_logits_to_keep": 0, "sliding_window": 0,
+        "latent_layer_ids": (), "latent_width": 0}
 SAYS = {
     "llama": {},
     "olmo_hybrid": {"state_layer_ids": (0, 1, 2, 4, 5, 6)},
@@ -57,6 +63,9 @@ SAYS = {
                        "num_logits_to_keep": 1},
     "mellum": {"ring_layer_ids": (0, 1, 2), "expert_layer_ids": (0, 1, 2, 3),
                "num_logits_to_keep": 1, "sliding_window": 8},
+    "sarvam_mla": {"latent_layer_ids": (0, 1, 2),
+                   "expert_layer_ids": (1, 2), "num_logits_to_keep": 1,
+                   "latent_width": 40},
 }
 
 
@@ -113,6 +122,11 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
     caches = model.init_cache(cache_cfg)
     assert len(caches) == layers
     for i, entry in enumerate(caches):
+        if i in model.latent_layer_ids:
+            # one pool of the allocator's pages, 40 values on whole lanes
+            assert entry.shape == (cache_cfg.num_pages, cache_cfg.page_size,
+                                   128)
+            continue
         first, second = entry
         if i in model.state_layer_ids:
             assert first.shape[0] == second.shape[0] == cache_cfg.max_seqs
@@ -139,7 +153,7 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
         assert sharded[0][0].sharding.mesh.shape["tensor"] == 2
 
 
-def test_the_five_without_banks_refuse_lora_in_one_wording():
+def test_the_six_without_banks_refuse_lora_in_one_wording():
     for name in FAMILIES:
         model = _tiny(name)
         if name == "llama":

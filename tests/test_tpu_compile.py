@@ -76,7 +76,8 @@ def _kernel_names(text):
             instruction = line.split(" = ", 1)[0]
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "ssm_scan", "ssd_scan",
-                                 "moe_gmm", "swa_decode", "swa_flash")
+                                 "moe_gmm", "swa_decode", "swa_flash",
+                                 "mla_decode", "mla_flash")
                      if k in instruction}
     return held
 
@@ -479,6 +480,70 @@ def test_mellum_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     for keys in (4096, 4608):                   # no [rows, heads, q, keys]
         assert f"[{nb},32,4096,{keys}]" not in text
         assert f"[32,4096,{keys}]" not in text
+
+
+def test_sarvam_decode_walks_the_latent_pool_in_place(topology, monkeypatch):
+    """The chip compiler's HLO of `sarvam-long-decode`'s decode window at the
+    published widths and all six layers (32 of 128 experts held, a quarter of
+    the vocabulary): every layer's step is `mla_decode` over one pool of
+    [1281, 64, 640] (576 values a token on whole lanes), the experts `moe_gmm`
+    over the 32 held; no instruction rewrites a pool, and nothing has the
+    shape of a cache up-projected to its 64 heads' keys and values; it peaks
+    at 11.43 GiB of a v5e's 15.75 (compile, PR 48)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("sarvam-long-decode")
+    assert model.cfg.num_layers == 6 and model.cfg.experts_held == (0, 32)
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_decode(model, ec, one).compile()
+    peak, _ = sizing.peak_gib(compiled)
+    assert 0.25 * sizing.USABLE_GIB < peak <= 11.432 + 0.05
+    text = compiled.as_text()
+    caches = sizing.cache_shapes(model, ec, None)
+    assert [c.shape for c in caches] == [(16 * 80 + 1, 64, 640)] * 6
+    assert _pool_layout_changes(text, math.prod(caches[0].shape)) == []
+    assert {"mla_decode", "moe_gmm"} <= _kernel_names(text)
+    assert not {"paged_decode", "mla_flash", "flash_fwd"} & _kernel_names(text)
+    assert "bf16[32,4096,4096]" in text            # the held experts' stacks
+    # per head, a cached token's keys and values are 256 values: no array of
+    # every page's or every slot's tokens times 64 heads of them
+    for tokens in (1281 * 64, 16 * 5120, 5120):
+        assert f"[{tokens},16384]" not in text
+        assert f"[{tokens},64,256]" not in text and \
+            f"[{tokens},64,128]" not in text
+
+
+@pytest.mark.parametrize("nb", [1, 16])
+def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
+                                                     nb):
+    """Prefill of one prompt and of 16 in the 4,096 bucket, the largest
+    program of `sarvam-long-decode`, at all six layers: attention in the
+    published form through the flash forward at keys of 192 and values of 128
+    (`mla_flash`), a row of the wave at a time, so neither the scores nor a
+    wave's up-projected keys and values are made; the pool is written in
+    place; the head runs on one position a row; the wave peaks at 14.49 GiB
+    of a v5e's 15.75 (compile, PR 48), under the issue's 15.0."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = _cell_at_depth("sarvam-long-decode")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    assert peak <= (14.492 if nb == 16 else 12.261) + 0.05 < 15.0
+    assert parts["temp"] < (3.8 if nb == 16 else 1.55)
+    text = compiled.as_text()
+    assert {"mla_flash", "moe_gmm"} <= _kernel_names(text)
+    assert not {"mla_decode", "paged_decode", "flash_fwd"} \
+        & _kernel_names(text)
+    pool = sizing.cache_shapes(model, ec, None)[0]
+    assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert f"[{nb},4096,65536]" not in text and f"f32[{nb},65536]" in text
+    assert "[64,4096,4096]" not in text            # no [heads, q, keys]
+    if nb > 1:                                     # no wave's q, k or v
+        assert f"[{nb},4096,64,192]" not in text
+        assert f"[{nb},4096,12288]" not in text
 
 
 @pytest.mark.parametrize("tm,tiles,experts,k,n", [
