@@ -42,13 +42,21 @@ out: two thirds of its time), and the chosen rows come back through
 array, `k` rows on a bf16 tile's sixteen sublanes, costs a relayout). A
 Pallas kernel that fetched a token's rows by DMA is not on offer: `y` lies in
 HBM in tiles of eight rows, two rows a 32-bit word, and the chip's compiler
-takes no slice of it that is not whole tiles. Between the two matmuls the
-gate-and-up product is still converted to float32 whole before the
-activation reads it: sliced first it is one fusion and a third of the
-traffic, but the activation's output then lives beside the product it reads
-and beside the second matmul's output, and the compiler's buffer assignment
-puts it on top of both (a wave's prefill 0.26-0.47 GiB higher, compile, PR
-46), where the float32 copy dies in the gathered rows' place.
+takes no slice of it that is not whole tiles.
+
+The gate-and-up call writes the activation (`gmm(..., act=True)`; on the TPU
+a kernel body of its own under the same name, `moe_gmm`: a trace's two calls
+a layer differ by their results, `[M, I]` and `[M, H]`): a grid step takes
+the tile's rows and two blocks of the one `gate_up` stack, the expert's gate
+columns and the up columns that stand `I` further on, makes both float32
+products in VMEM, rounds each to the rows' dtype where the product used to
+be stored, and writes `silu(gate) * up` of the tile: `[M, I]`, what the down
+call reads. The product `[M, 2I]`, its float32 copy and the activation's
+pass over every padded row, used or not, are no arrays of the program (as
+XLA passes they were an eighth to a quarter of a one-prompt layer, builder's
+chip runs, PR 49; as the one fusion XLA allows, the activation's buffer lay
+on top of both its neighbours, a wave's prefill 0.26-0.47 GiB higher:
+compile, PR 46).
 
 A chip that shares a layer with others by expert parallelism holds some of
 the router's columns (`held = (first, count)`, static): routing stays over
@@ -204,14 +212,34 @@ def _gmm_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, rhs_ref, out_ref):
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _rhs_columns(tm: int, k: int, n: int, itemsize: int) -> int:
+def _swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
+    """silu(gate) * up in float32, rounded once to the operands' dtype."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+            ).astype(gate.dtype)
+
+
+def _gmm_act_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, gate_ref,
+                    up_ref, out_ref):
+    @pl.when(pl.program_id(1) < tiles_used_ref[0])
+    def _():
+        rows = lhs_ref[...]
+        # (each product rounded as the call that stored it rounded it)
+        gate, up = (jnp.dot(rows, ref[0], preferred_element_type=jnp.float32)
+                    .astype(out_ref.dtype) for ref in (gate_ref, up_ref))
+        out_ref[...] = _swiglu(gate, up)
+
+
+def _rhs_columns(tm: int, k: int, n: int, itemsize: int,
+                 blocks: int = 1) -> int:
     """Columns of a weight block [k, tn]: the most that divide `n`, are whole
-    lanes and leave a grid step's blocks (the weights, the tile's rows and
-    its output, each double-buffered, and the float32 product) within the
-    kernel's share of VMEM; all of `n` where it has no whole lanes. The more
-    columns, the fewer times the rows are read: once a block of columns."""
+    lanes and leave a grid step's blocks (`blocks` of the weights, the tile's
+    rows and its output, each double-buffered, and a float32 product a weight
+    block) within the kernel's share of VMEM; all of `n` where it has no
+    whole lanes. The more columns, the fewer times the rows are read: once a
+    block of columns."""
     def step_bytes(tn):
-        return 2 * (k * tn + tm * k + tm * tn) * itemsize + 4 * tm * tn
+        return (2 * (blocks * k * tn + tm * k + tm * tn) * itemsize
+                + blocks * 4 * tm * tn)
 
     whole = [tn for tn in range(128, n + 1, 128) if n % tn == 0] or [n]
     fit = [tn for tn in whole
@@ -242,20 +270,35 @@ def _rhs_map(j, t, tile_expert, used):
     return (tile_expert[tile], 0, col)
 
 
+def _up_map(ahead, j, t, tile_expert, used):
+    """The second weight block of a call that takes an expert's gate columns
+    and its up columns: the same expert's, `ahead` column blocks further on
+    (so it changes where the first does, and is fetched as often)."""
+    expert, _, col = _rhs_map(j, t, tile_expert, used)
+    return (expert, 0, col + ahead)
+
+
 def _out_map(j, t, tile_expert, used):
     return _held(j, t, used)
 
 
 def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
         use_kernel: Optional[bool] = None,
-        interpret: Optional[bool] = None) -> jax.Array:
+        interpret: Optional[bool] = None, act: bool = False) -> jax.Array:
     """Grouped matmul: lhs [M,K] (rows as `p` lays them), rhs [E,K,N] ->
     [M,N], row r times the weights of its tile's expert. Rows of unused tiles
     are left as they are (nothing reads them). On the TPU a Pallas kernel,
     grid (column blocks, tiles), one `dot` of a tile's rows [tm, K] with a
-    block [K, tn] a step; elsewhere one batched einsum over tiles."""
+    block [K, tn] a step; elsewhere one batched einsum over tiles.
+
+    `act`: rhs is a gate-and-up stack [E,K,2I] and the call returns the
+    activation [M,I], silu(gate) * up of the two halves of the product, each
+    rounded to the rows' dtype first (the product as a call without `act`
+    returns it) and multiplied in float32. The kernel takes two blocks
+    [K, tn] of the stack a step, column block j of `I` and the one I / tn
+    further on, and the product is never an array."""
     m, k = lhs.shape
-    _, _, n = rhs.shape
+    n = rhs.shape[2] // 2 if act else rhs.shape[2]
     tm = p.tm
     tiles = m // tm
     if use_kernel is None:
@@ -264,19 +307,23 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
         out = jnp.einsum("tmk,tkn->tmn", lhs.reshape(tiles, tm, k),
                          jnp.take(rhs, p.tile_expert, axis=0),
                          preferred_element_type=jnp.float32)
-        return out.reshape(m, n).astype(lhs.dtype)
+        out = out.reshape(m, -1).astype(lhs.dtype)
+        return _swiglu(out[:, :n], out[:, n:]) if act else out
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    tn = _rhs_columns(tm, k, n, rhs.dtype.itemsize)
+    tn = _rhs_columns(tm, k, n, rhs.dtype.itemsize, 2 if act else 1)
+    weights = [pl.BlockSpec((1, k, tn), _rhs_map)]
+    if act:
+        weights.append(pl.BlockSpec((1, k, tn),
+                                    functools.partial(_up_map, n // tn)))
     return pl.pallas_call(
-        _gmm_kernel,
+        _gmm_act_kernel if act else _gmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, tiles),
-            in_specs=[pl.BlockSpec((tm, k), _lhs_map),
-                      pl.BlockSpec((1, k, tn), _rhs_map)],
+            in_specs=[pl.BlockSpec((tm, k), _lhs_map), *weights],
             out_specs=pl.BlockSpec((tm, tn), _out_map)),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -284,7 +331,7 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="moe_gmm",
-    )(p.tile_expert, p.tiles_used, lhs, rhs)
+    )(p.tile_expert, p.tiles_used, lhs, *[rhs] * len(weights))
 
 
 @functools.partial(jax.jit, static_argnames="masked")
@@ -336,12 +383,14 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
               held: Optional[Tuple[int, int]] = None, **routing):
     """The layer over x [T,H]: router [H,E] float32, gate_up [E,H,2I] (an
     expert's gate columns, then its up columns), down [E,I,H]. Returns
-    (y [T,H], `Load` of this call). With `held = (first, count)` the stacks
+    (y [T,H], `Load` of this call). Two grouped matmuls: the gate-and-up
+    call returns the rows' activation [M,I] (`gmm(..., act=True)`), which the
+    down call takes as it is. With `held = (first, count)` the stacks
     are [count, ...], the experts of the router's columns first ..
     first+count-1, and y is their part of the sum: what a token's other
     chosen experts would add is computed where they are held. `routing`:
     `route`'s `scoring`, `bias` and `scale`."""
-    num_experts, two_i = router.shape[1], gate_up.shape[2]
+    num_experts = router.shape[1]
     count = num_experts if held is None else held[1]
     if gate_up.shape[0] != count or down.shape[0] != count:
         raise ValueError(f"moe_layer: stacks of {gate_up.shape[0]} and "
@@ -352,10 +401,8 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
                             interpret=interpret)
     # (`row_token` lies in [0, T) by construction: the gather says so, and no
     # pass over the rows it took puts NaN where an index would be out)
-    gu = run(jnp.take(x, p.row_token, axis=0, mode="clip"),
-             gate_up).astype(jnp.float32)
-    act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
-    y = run(act.astype(x.dtype), down)                      # [M,H]
+    rows = jnp.take(x, p.row_token, axis=0, mode="clip")
+    y = run(run(rows, gate_up, act=True), down)             # [M,I] -> [M,H]
     out = combine(y, weights, p.dest, masked=held is not None)
     t = x.shape[0]
     i32 = lambda v: jnp.asarray(v, jnp.int32)

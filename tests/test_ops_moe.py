@@ -132,7 +132,7 @@ def test_gmm_kernel_in_interpret_mode_matches_the_einsum(tokens, n,
     rhs = jax.random.normal(jax.random.PRNGKey(7), (E, H, n)) * H ** -0.5
     lhs = jnp.take(x, p.row_token, axis=0)
     # several column blocks at n = 256
-    monkeypatch.setattr(moe, "_rhs_columns", lambda tm, k, n, _: min(n, 128))
+    monkeypatch.setattr(moe, "_rhs_columns", lambda tm, k, n, *_: min(n, 128))
     got = moe.gmm(lhs, rhs, p, use_kernel=True, interpret=True)
     want = moe.gmm(lhs, rhs, p, use_kernel=False)
     live = int(p.tiles_used[0]) * p.tm
@@ -171,6 +171,32 @@ def test_tiles_are_short_at_decode_and_long_at_prefill():
         assert moe._rhs_columns(tm, 768, 4096, 2) == 4096
     assert moe._rhs_columns(128, 4096, 28672, 2) == 2048
     assert moe._rhs_columns(16, H, 2 * I, 4) == 2 * I
+
+
+@pytest.mark.parametrize("tm", [16, 128])
+@pytest.mark.parametrize("k,inter,want", [
+    (4096, 768, 768),      # Granite: every gate and every up column a step
+    (2048, 768, 768),      # SDAR
+    (2304, 896, 896),      # Mellum
+    (4096, 2048, 1024),    # Sarvam: two column blocks, as its plain call has
+    (4096, 14336, 1024),   # a dense model's width: fourteen
+    (H, I, I),             # no whole lanes: all of `I`
+])
+def test_the_activating_call_takes_the_columns_two_weight_blocks_leave(
+        tm, k, inter, want):
+    """`gmm(..., act=True)` holds two weight blocks a step (the expert's
+    gate columns and its up columns) and two float32 products: the column
+    block is the widest under which they, the rows and the output fit the
+    kernel's share of VMEM, so the rows are read as often as the plain call
+    over the same stack reads them."""
+    tg = moe._rhs_columns(tm, k, inter, 2, 2)
+    assert tg == want and inter % tg == 0
+    # two weight blocks, the rows and the output double-buffered in bf16,
+    # two float32 products
+    step = 2 * (2 * k * tg + tm * k + tm * tg) * 2 + 2 * 4 * tm * tg
+    assert step <= moe.VMEM_BLOCKS_SHARE * moe.VMEM_LIMIT_BYTES
+    # the passes over the rows: those of the plain call over [K, 2I]
+    assert inter // tg == 2 * inter // moe._rhs_columns(tm, k, 2 * inter, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -243,6 +269,69 @@ def test_kernel_is_the_einsum_bit_for_bit_in_every_regime(regime,
     assert bool((got[:live] == want[:live]).all())
 
 
+def _product_then_activation(lhs, rhs, p, gmm=moe.gmm, **how):
+    """The gate-and-up call as the layer made it before the call wrote the
+    activation: the product [M, 2I] stored in the rows' dtype, converted to
+    float32 whole, silu(gate) * up as a pass of its own."""
+    gu = gmm(lhs, rhs, p, **how).astype(jnp.float32)
+    inter = rhs.shape[2] // 2
+    return (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]).astype(lhs.dtype)
+
+
+# name -> (I, columns a weight block is cut to or None for `_rhs_columns`'s)
+ACT_BLOCKS = {
+    "two_column_blocks": (256, 128),
+    "one_column_block": (128, None),
+    "no_whole_lanes": (96, None),
+    "three_column_blocks": (384, 128),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("blocks", list(ACT_BLOCKS))
+@pytest.mark.parametrize("regime", list(REGIMES))
+def test_activating_kernel_is_product_then_activation_bit_for_bit(
+        regime, blocks, dtype, monkeypatch):
+    """`gmm(..., act=True)` through the interpreted kernel and through the
+    einsum against the parent's three steps (the product through the kernel,
+    its float32 copy, the activation): every row some assignment owns is the
+    same float, under a share, with a tail of unused tiles, with no tile in
+    use, with `I` not whole lanes and with one, two and three column blocks
+    (the up block then stands that many blocks ahead of the gate block). In
+    float32 nothing is rounded between the product and the activation, and
+    the CPU's matmul sums a row in an order that follows the block's width:
+    to `TOL` there."""
+    experts, columns, held, tm, _ = REGIMES[regime]()
+    inter, cut = ACT_BLOCKS[blocks]
+    p = moe.plan(experts, columns, tm, held=held)
+    count = columns if held is None else held[1]
+    k = 64
+    ks = jax.random.split(jax.random.PRNGKey(len(regime) + inter), 2)
+    lhs = jax.random.normal(ks[0], (len(p.row_token), k), dtype)
+    rhs = (jax.random.normal(ks[1], (count, k, 2 * inter)) * k ** -0.5
+           ).astype(dtype)
+    if cut is not None:
+        monkeypatch.setattr(moe, "_rhs_columns", lambda *_: cut)
+    else:
+        assert moe._rhs_columns(p.tm, k, inter, lhs.dtype.itemsize, 2) == inter
+    got = moe.gmm(lhs, rhs, p, use_kernel=True, interpret=True, act=True)
+    plain = moe.gmm(lhs, rhs, p, use_kernel=False, act=True)
+    want = _product_then_activation(lhs, rhs, p, use_kernel=True,
+                                    interpret=True)
+    live = int(p.tiles_used[0]) * p.tm
+    assert got.dtype == want.dtype == plain.dtype == dtype
+    assert got.shape == want.shape == plain.shape == (len(p.row_token), inter)
+    assert bool(jnp.isfinite(got[:live].astype(jnp.float32)).all())
+    for made in (got, plain):
+        if dtype == jnp.bfloat16:
+            assert bool((made[:live] == want[:live]).all())
+        else:
+            np.testing.assert_allclose(np.asarray(made)[:live],
+                                       np.asarray(want)[:live], atol=TOL,
+                                       rtol=TOL)
+
+
 def _fetches(index_map, grid, tile_expert, used):
     """Walk `grid` in the order Pallas does (last axis innermost) through one
     of the kernel's index maps: (steps at which the block's index differs
@@ -268,21 +357,32 @@ def _parent_rhs_map(last):
     return rhs_map
 
 
+@pytest.mark.parametrize("call", ["plain", "act"])
 @pytest.mark.parametrize("regime", list(REGIMES))
 @pytest.mark.parametrize("cols", [1, 4])
-def test_an_expert_is_fetched_once_a_column_block(regime, cols):
+def test_an_expert_is_fetched_once_a_column_block(regime, cols, call):
     """The grid walked through the kernel's own index maps, no chip: a
     weight block is fetched where its index differs from the step before.
     Fetches = experts touched x column blocks, however many tiles an expert
     owns; a skipped step fetches no weights and no rows and writes no block
     of its own; and where every expert owns one tile the parent's order
-    fetched as many."""
+    fetched as many. The call that writes the activation takes a second
+    weight block a step, `cols` column blocks ahead of the first (the
+    expert's up columns behind its gate columns), fetched where the first
+    is and nowhere else."""
     experts, columns, held, tm, tiles_each = REGIMES[regime]()
     p = moe.plan(experts, columns, tm, held=held)
     tile_expert, used = np.asarray(p.tile_expert), np.asarray(p.tiles_used)
     tiles, touched = len(tile_expert), int((np.asarray(p.sizes) > 0).sum())
     grid = (cols, tiles)
     rhs, rhs_index = _fetches(moe._rhs_map, grid, tile_expert, used)
+    if call == "act":
+        up, up_index = _fetches(functools.partial(moe._up_map, cols), grid,
+                                tile_expert, used)
+        assert (up == rhs).all()
+        assert (up_index[:, :2] == rhs_index[:, :2]).all()
+        assert (up_index[:, 2] == rhs_index[:, 2] + cols).all()
+        assert up_index[:, 2].max() < 2 * cols
     lhs, _ = _fetches(moe._lhs_map, grid, tile_expert, used)
     out, out_index = _fetches(moe._out_map, grid, tile_expert, used)
     skipped = np.tile(np.arange(tiles) >= used[0], cols)
@@ -440,6 +540,34 @@ def test_layer_is_the_per_row_planned_layer_bit_for_bit(tokens, k, columns,
     got, load = run()
     monkeypatch.setattr(moe, "plan", _plan_per_row)
     want, load_want = run()
+    assert got.dtype == want.dtype and got.shape == want.shape == x.shape
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    assert bool((got == want).all())
+    assert [int(v) for v in load] == [int(v) for v in load_want]
+
+
+@pytest.mark.parametrize("through", ["einsum", "kernel"])
+@pytest.mark.parametrize("tokens,k,columns,held", LAYERS)
+def test_layer_is_the_layer_with_the_activation_outside_bit_for_bit(
+        tokens, k, columns, held, through, monkeypatch):
+    """The layer whose gate-and-up call writes the activation against the
+    layer that stored the product, converted it whole and activated it in a
+    pass of its own: same dtypes, same rounding points, every float."""
+    x, router, gate_up, down, share = _layer_inputs(tokens, k, columns, held)
+    run = lambda: moe.moe_layer(x, router, gate_up, down, k,
+                                use_kernel=through == "kernel",
+                                interpret=True, held=share)
+    got, load = run()
+    gmm, calls = moe.gmm, []
+
+    def outside(lhs, rhs, p, act=False, **how):
+        calls.append(act)
+        return (_product_then_activation(lhs, rhs, p, gmm, **how) if act
+                else gmm(lhs, rhs, p, **how))
+
+    monkeypatch.setattr(moe, "gmm", outside)
+    want, load_want = run()
+    assert calls == [True, False]
     assert got.dtype == want.dtype and got.shape == want.shape == x.shape
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     assert bool((got == want).all())

@@ -404,9 +404,10 @@ def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     `granite-prompt-heavy`: compiled at six layers (the temporaries are a
     layer's: the expert layer's rows laid out by expert are the largest, and
     attention keeps one row's scores) with the other four layers' weights and
-    state added from their shapes, it peaks no higher than before `moe_gmm`
-    held an expert's whole weight block in VMEM (11.855 GiB of a v5e's
-    15.75, the whole vocabulary held); the scan is the Pallas kernel
+    state added from their shapes, it peaks at 11.555 GiB of a v5e's 15.75,
+    the whole vocabulary held (compile, PR 49: 11.855 while the gate-and-up
+    product `[M, 2I]` and its float32 copy were arrays of the program; the
+    gate-and-up call writes the activation); the scan is the Pallas kernel
     `ssd_scan`, the experts `moe_gmm` inside its `vmem_limit_bytes` (the
     chip's compiler refuses a kernel that is not), and no logits of every
     position are made."""
@@ -421,8 +422,8 @@ def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     rest = (_program_bytes(whole, ec) - _program_bytes(model, ec)) / sizing.GIB
     assert 3.4 < rest < 3.6            # 4 of 10 layers' weights and state
     # not the 4.5 GiB of a wave's scores
-    assert parts["temp"] < (3.5 if nb == 8 else 1.0)
-    assert peak + rest <= 11.855
+    assert parts["temp"] < (2.0 if nb == 8 else 0.75)
+    assert peak + rest <= (11.555 if nb == 8 else 10.309) + 0.005
     text = compiled.as_text()
     assert {"ssd_scan", "moe_gmm"} <= _kernel_names(text)
     assert f"[{nb},2048,100352]" not in text and f"f32[{nb},100352]" in text
@@ -462,8 +463,10 @@ def test_mellum_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     program of `mellum-code-context`, at all eight layers: both kinds of
     layer attend over the call's own keys through a flash forward
     (`flash_fwd` causal, `swa_flash` banded), so no scores of 4,096 queries
-    are made; the head runs on one position a row; the wave peaks at 10.95
-    GiB of a v5e's 15.75 (compile, PR 44)."""
+    are made; the head runs on one position a row; the wave peaks at 10.127
+    GiB of a v5e's 15.75 and one prompt at 7.885 (compile, PR 49: 10.953 and
+    8.05 while the expert layers' gate-and-up product and its float32 copy
+    were arrays of the program)."""
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -471,8 +474,8 @@ def test_mellum_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
-    assert peak <= (10.953 if nb == 8 else 8.05) + 0.05 < sizing.USABLE_GIB
-    assert parts["temp"] < (3.7 if nb == 8 else 0.8)
+    assert peak <= (10.127 if nb == 8 else 7.885) + 0.005 < sizing.USABLE_GIB
+    assert parts["temp"] < (2.9 if nb == 8 else 0.6)
     text = compiled.as_text()
     assert {"flash_fwd", "swa_flash", "moe_gmm"} <= _kernel_names(text)
     assert not {"swa_decode", "paged_decode"} & _kernel_names(text)
@@ -522,8 +525,10 @@ def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     published form through the flash forward at keys of 192 and values of 128
     (`mla_flash`), a row of the wave at a time, so neither the scores nor a
     wave's up-projected keys and values are made; the pool is written in
-    place; the head runs on one position a row; the wave peaks at 14.49 GiB
-    of a v5e's 15.75 (compile, PR 48), under the issue's 15.0."""
+    place; the head runs on one position a row; the wave peaks at 13.649 GiB
+    of a v5e's 15.75 and one prompt at 11.797 (compile, PR 49: 14.492 and
+    12.261 while a row's gate-and-up product and its float32 copy were
+    arrays of the program), under the issue's 15.0."""
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -531,8 +536,8 @@ def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
-    assert peak <= (14.492 if nb == 16 else 12.261) + 0.05 < 15.0
-    assert parts["temp"] < (3.8 if nb == 16 else 1.55)
+    assert peak <= (13.649 if nb == 16 else 11.797) + 0.005 < 15.0
+    assert parts["temp"] < (2.95 if nb == 16 else 1.08)
     text = compiled.as_text()
     assert {"mla_flash", "moe_gmm"} <= _kernel_names(text)
     assert not {"mla_decode", "paged_decode", "flash_fwd"} \
@@ -546,19 +551,31 @@ def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
         assert f"[{nb},4096,12288]" not in text
 
 
-@pytest.mark.parametrize("tm,tiles,experts,k,n", [
-    (128, 196, 36, 4096, 1536),    # Granite, one prompt of 2,048: gate and up
-    (128, 1316, 36, 768, 4096),    # a wave of 8: down
-    (16, 39, 36, 4096, 1536),      # its decode step of 8 rows
-    (16, 152, 128, 2048, 1536),    # SDAR's forward over 64 tokens
-    (128, 64, 8, 4096, 28672),     # a stack too wide for one block: 14
+@pytest.mark.parametrize("tm,tiles,experts,k,n,act", [
+    (128, 196, 36, 4096, 1536, False),   # Granite, one prompt of 2,048: gate
+                                         # and up as a plain product
+    (128, 1316, 36, 768, 4096, False),   # a wave of 8: down
+    (16, 39, 36, 4096, 1536, False),     # its decode step of 8 rows
+    (16, 152, 128, 2048, 1536, False),   # SDAR's forward over 64 tokens
+    (128, 64, 8, 4096, 28672, False),    # a stack too wide for one block: 14
+    # the gate-and-up call as the layer makes it, the activation written:
+    (128, 196, 36, 4096, 1536, True),    # Granite, one prompt of 2,048
+    (16, 39, 36, 4096, 1536, True),      # its decode step
+    (128, 320, 64, 2304, 1792, True),    # Mellum, one prompt of 4,096
+    (16, 152, 128, 2048, 1536, True),    # SDAR's forward over 64 tokens
+    (128, 288, 32, 4096, 4096, True),    # Sarvam, one prompt: two blocks of
+                                         # 1,024 gate and 1,024 up columns
+    (16, 38, 32, 4096, 4096, True),      # its decode step of 16 rows
+    (128, 64, 8, 4096, 28672, True),     # too wide for one block: 14
 ])
 def test_moe_gmm_one_chip_holds_its_blocks_in_vmem(topology, tm, tiles,
-                                                   experts, k, n):
-    """`moe_gmm` alone at the cells' shapes: the Mosaic compiler takes the
-    weight block `_rhs_columns` chose (every column of an expert at the
-    widths served, 12 MiB double-buffered at Granite's gate and up) within
-    `VMEM_LIMIT_BYTES`."""
+                                                   experts, k, n, act):
+    """`moe_gmm` alone at the cells' shapes, as the plain product and as the
+    call that writes the activation: the Mosaic compiler takes the weight
+    blocks `_rhs_columns` chose (every column of an expert at the widths
+    served but Sarvam's, 12 MiB double-buffered at Granite's gate and up, as
+    one block or as the activating call's two) within `VMEM_LIMIT_BYTES`;
+    the activating call returns `[M, I]`."""
     from ray_tpu.ops import moe
 
     one = SingleDeviceSharding(topology.devices[0])
@@ -566,13 +583,15 @@ def test_moe_gmm_one_chip_holds_its_blocks_in_vmem(topology, tm, tiles,
 
     def run(lhs, rhs, tile_expert, tiles_used):
         p = moe.Plan(tm, None, None, tile_expert, tiles_used, None)
-        return moe.gmm(lhs, rhs, p, use_kernel=True, interpret=False)
+        return moe.gmm(lhs, rhs, p, use_kernel=True, interpret=False, act=act)
 
     text = _compiled_text(run, s((tiles * tm, k), jnp.bfloat16),
                           s((experts, k, n), jnp.bfloat16),
                           s((tiles,), jnp.int32), s((1,), jnp.int32))
-    assert "moe_gmm" in _kernel_names(text)
-    assert f"bf16[{tiles * tm},{n}]" in text
+    assert _kernel_names(text) == {"moe_gmm"}
+    assert f"bf16[{tiles * tm},{n // 2 if act else n}]" in text
+    if act and n != k:                # (at Sarvam's widths the rows' shape)
+        assert f"[{tiles * tm},{n}]" not in text
 
 
 @pytest.mark.parametrize("tokens,k,columns,held", [
@@ -605,6 +624,9 @@ def test_moe_plan_compiles_to_no_loop_gather_of_scalars_or_scatter(
 @pytest.mark.parametrize("tokens,k,columns,held,h,inter", [
     (2048, 10, 72, (0, 36), 4096, 768),   # Granite's one-prompt prefill
     (8, 8, 64, None, 2304, 896),          # Mellum's decode step
+    (4096, 8, 64, None, 2304, 896),       # Mellum's one-prompt prefill
+    (4096, 8, 128, (0, 32), 4096, 2048),  # Sarvam's one-prompt prefill
+    (64, 8, 128, None, 2048, 768),        # SDAR's forward over 64 tokens
 ])
 def test_moe_layer_compiles_to_rows_moved_once_each_way(
         topology, tokens, k, columns, held, h, inter):
@@ -613,7 +635,11 @@ def test_moe_layer_compiles_to_rows_moved_once_each_way(
     pass over all `[M, H]` rows behind it (626 us of 940 at Granite's
     prefill), rows gathered token-major a relayout `[tokens, k, H]` (0.2 ms
     a 1,024 tokens; builder's chip runs, PR 45 and 46). Neither is left; the
-    kernels are the two `moe_gmm` calls and no other."""
+    kernels are the two `moe_gmm` calls and no other, and the first of them
+    writes the activation `[M, I]`: no instruction's result is the
+    gate-and-up product `[M, 2I]`, stored or converted to float32 (two
+    passes over every padded row, 443 us of 3,589 at Granite's prefill and
+    2,531 of 9,642 at Sarvam's; builder's chip runs, PR 49)."""
     from ray_tpu.ops import moe
 
     one = SingleDeviceSharding(topology.devices[0])
@@ -639,8 +665,14 @@ def test_moe_layer_compiles_to_rows_moved_once_each_way(
     for b in {block, min(tokens, 1024)}:    # (the blocks were of 1,024)
         assert f"bf16[{b},{k},{h}]" not in results
         assert f"f32[{b},{k},{h}]" not in results
-    assert len([n for n, d in defs
-                if "tpu_custom_call" in d and "moe_gmm" in n]) == 2
+    assert f"bf16[{rows},{inter}]" in results         # (the activation is)
+    # (at Sarvam's widths 2I is H: the rows' shape is not the product's)
+    product = {f"{dt}[{rows},{2 * inter}]" for dt in ("f32", "bf16")}
+    assert not (product - {f"bf16[{rows},{h}]"}) & set(results)
+    calls = [d for n, d in defs
+             if "tpu_custom_call" in d and re.match(r"\s*%moe_gmm[.\d]*$", n)]
+    assert [d.split("{", 1)[0] for d in calls] == [
+        f"bf16[{rows},{inter}]", f"bf16[{rows},{h}]"]
     assert _kernel_names(text) == {"moe_gmm"}
 
 
