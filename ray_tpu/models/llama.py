@@ -21,10 +21,15 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_tpu.models.layers import Decoder, RMSNorm, apply_rope
-from ray_tpu.ops.attention import attention_reference, flash_attention
+from ray_tpu.ops.attention import (
+    FLASH_KEPT,
+    attention_reference,
+    flash_attention,
+)
 from ray_tpu.ops.paged_attention import init_kv_pages, paged_write_attend
 from ray_tpu.parallel.sharding import ParamShardingRules
 
@@ -86,6 +91,12 @@ LLAMA_SHARDING = ParamShardingRules([
     (r"lm_head/kernel", ("embed_fsdp", "vocab")),
     (r"norm|input_layernorm|post_attention_layernorm", ("embed",)),
 ])
+
+
+# What a remat'd layer keeps for its backward beside its input: 28 KB a token
+# at Mistral's widths against the 8 KB of the input alone. The wide FFN
+# products (28 KB a token each) have no name and are recomputed.
+KEPT = ("attn_q", "attn_k", "attn_v", "attn_proj") + FLASH_KEPT
 
 
 def lora_delta(x, bank, idx):
@@ -158,6 +169,11 @@ class Attention(nn.Module):
                 mesh=self.mesh)
             return o_proj(out), kv_pages
 
+        # The narrow values a remat'd layer keeps (`KEPT`): q, k, v as
+        # rotated, k and v at their KV heads, and below the projection.
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_k")
+        v = checkpoint_name(v, "attn_v")
         if cfg.attention_impl == "ring" and self.mesh is not None:
             from ray_tpu.parallel.ring import ring_attention
 
@@ -166,7 +182,7 @@ class Attention(nn.Module):
             out = flash_attention(q, k, v, causal=True, mesh=self.mesh)
         else:
             out = attention_reference(q, k, v, causal=True)
-        return o_proj(out), None
+        return checkpoint_name(o_proj(out), "attn_proj"), None
 
 
 class Mlp(nn.Module):
@@ -238,7 +254,9 @@ class LlamaModel(Decoder):
                      param_dtype=jnp.float32, name="embed_tokens")(input_ids)
         layer_cls = DecoderLayer
         if cfg.remat and paged_kv is None:
-            layer_cls = nn.remat(DecoderLayer, static_argnums=())
+            layer_cls = nn.remat(
+                DecoderLayer, static_argnums=(),
+                policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         paged = (page_table, write_mask, seq_lens)
         new_caches = []
         for i in range(cfg.num_layers):

@@ -17,10 +17,14 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh
 
 NEG_INF = -1e30
+# The names a remat policy may keep of the flash kernel's forward: its output
+# and its row sums, so that a recomputation calls no `flash_fwd`.
+FLASH_KEPT = ("flash_out", "flash_lse")
 
 
 def _gqa_expand(k: jax.Array, v: jax.Array, num_heads: int) -> Tuple[jax.Array, jax.Array]:
@@ -365,6 +369,10 @@ def _flash_core(qt, kt, vt, cfg):
 
 def _flash_core_fwd(qt, kt, vt, cfg):
     out, lse = _flash_fwd_core(qt, kt, vt, cfg)
+    out = checkpoint_name(out, "flash_out")
+    # Kept as [B, H, S]: the kernel's [B, H, S, 1] lies in HBM with its one
+    # lane padded to 128.
+    lse = checkpoint_name(lse[..., 0], "flash_lse")[..., None]
     return out, (qt, kt, vt, out, lse)
 
 

@@ -48,8 +48,14 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        mask: Optional[jax.Array] = None) -> jax.Array:
     """Mean next-token cross entropy. logits [B,S,V], labels [B,S]."""
     logits = logits.astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    # logsumexp minus the label's logit: no [B,S,V] float32 array of
+    # log-probabilities. The logit is picked by comparison, not by a gather:
+    # its gradient is then a select inside the softmax's fusion, in float32,
+    # where a gather's would be a scatter into a [B,S,V] array of its own.
+    hit = jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1) == labels[..., None]
+    nll = jax.nn.logsumexp(logits, axis=-1) \
+        - jnp.where(hit, logits, 0.0).sum(axis=-1)
     if mask is None:
         return nll.mean()
     mask = mask.astype(jnp.float32)

@@ -21,7 +21,9 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark.manifest import Manifest  # noqa: E402
-from engine_sharing import reference_logprobs, share_decode_programs  # noqa: E402
+from engine_sharing import (cell_at_depth, decode_call,  # noqa: E402
+                            prefill_call, reference_logprobs,
+                            share_decode_programs)
 from ray_tpu._private import flight_recorder  # noqa: E402
 from ray_tpu.llm._internal.engine import EngineConfig, LLMEngine, Request  # noqa: E402
 from ray_tpu.llm._internal.paged import PagedCacheConfig  # noqa: E402
@@ -501,8 +503,11 @@ def test_causal_flash_and_unwindowed_decode_trace_to_the_programs_they_were():
                                           interpret=False)
     loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
     assert _traced(fwd, q, kv, kv) == "8c9e33acb02e3c85"
+    # (the forward rule names its output and row sums for a remat policy
+    # since the PR that lets `train-2k`'s layers keep them: the kernels'
+    # calls are the ones "d5fcbc3d1d5754d0" held, two `name`s beside them)
     assert _traced(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) \
-        == "d5fcbc3d1d5754d0"
+        == "e8441c57bd170860"
     decode = lambda q, k, v, t, n: paged_attention_decode_kernel(
         q, k, v, t, n, interpret=False)
     pages = s((65, 64, 512), jnp.bfloat16)
@@ -513,3 +518,26 @@ def test_causal_flash_and_unwindowed_decode_trace_to_the_programs_they_were():
         q, k, v, t, n, interpret=False, window=1024)
     text = str(jax.make_jaxpr(windowed)(*args))
     assert "swa_decode" in text and "paged_decode" not in text
+
+
+@pytest.mark.parametrize("cell,layers,prefill,traced", [
+    ("decode-heavy", 2, None, "47958ad5751c0dcc"),
+    ("decode-heavy", 2, (128, 16), "9632b2247d55bd12"),
+    ("mellum-code-context", 4, (4096, 1), "432f400032fe8cc0"),
+])
+def test_serving_programs_trace_to_the_programs_they_were(
+        monkeypatch, cell, layers, prefill, traced):
+    """The engine's own programs at the cells' widths (Mistral at two layers,
+    Mellum at one period), traced as on a TPU: the train step's remat
+    policy, the names it keeps and the loss's new form are behind
+    `paged_kv is None` and the flash kernel's differentiation rule, which
+    no serving program reaches. Mistral's decode window and its wave of
+    128-token prompts, and Mellum's prefill of one 4,096-token prompt (the
+    one serving user of `flash_attention`), hash to what they hashed to at
+    the parent of the PR that brought the policy (6092ef5)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth(cell, layers)
+    fn, args = (decode_call(model, ec) if prefill is None else
+                prefill_call(model, ec, *prefill))    # (bucket, prompts)
+    text = str(fn.trace(*args).jaxpr)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == traced

@@ -24,6 +24,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from engine_sharing import (cell_at_depth, cell_model, decode_call,
+                            prefill_call)
 from ray_tpu._private import accelerators
 from ray_tpu.llm._internal.paged import PagedCacheConfig
 from ray_tpu.ops.paged_attention import (
@@ -192,50 +194,13 @@ def _pool_layout_changes(text, pool_elements):
 
 
 def _lower_decode(model, ec, sharding):
-    """The decode program as the engine calls it, where `sizing.lower_decode`
-    (the benchmark's, not this PR's to edit) describes one last token a row
-    and a window of a static `decode_steps`: a window's token steps are a
-    traced argument, the loop's trip count; for a model that generates by
-    blocks the count stays static and what is carried between windows is two
-    blocks' ids [rows, 2 * block_length] (the one awaiting its commit and
-    the one a row is on)."""
-    from benchmark import sizing
-
-    eng = sizing._bare_engine(model, ec)
-    b, block = eng.cfg.max_seqs, getattr(model, "block_length", 1)
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    steps = (s((), jnp.int32),) if block == 1 else ()
-    return eng._decode_fn(False, False).lower(
-        sizing.param_shapes(model, sharding),
-        sizing.cache_shapes(model, ec, sharding),
-        s((b, 2 * block) if block > 1 else (b,), jnp.int32),
-        s((b, eng.cfg.max_pages_per_seq), jnp.int32), s((b,), jnp.int32),
-        s((b,), jnp.bool_), s((b,), jnp.float32), s((b,), jnp.float32),
-        s((b,), jnp.int32), s((b, 2), jnp.uint32), None, s((b,), jnp.int32),
-        *steps)
+    fn, args = decode_call(model, ec, sharding)
+    return fn.lower(*args)
 
 
 def _lower_prefill(model, ec, bucket, nb, sharding):
-    """The prefill program as the engine calls it, where
-    `sizing.lower_prefill` describes the arguments it had before a decode
-    window was chained behind it: every slot's last token and length ride
-    through it [max_seqs] and come back with the wave's rows scattered in,
-    as the key table does. Block generation samples nothing in its prefill
-    and passes none."""
-    from benchmark import sizing
-
-    eng = sizing._bare_engine(model, ec)
-    b, mp = eng.cfg.max_seqs, eng.cfg.max_pages_per_seq
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    carry = ((None, None) if getattr(model, "block_length", 1) > 1
-             else (s((b,), jnp.int32), s((b,), jnp.int32)))
-    return eng._prefill_fn(bucket, nb, False, False).lower(
-        sizing.param_shapes(model, sharding),
-        sizing.cache_shapes(model, ec, sharding),
-        s((nb, bucket), jnp.int32), s((nb, mp), jnp.int32),
-        s((nb,), jnp.int32), s((nb,), jnp.int32), s((nb,), jnp.float32),
-        s((nb,), jnp.float32), s((nb,), jnp.int32), s((b, 2), jnp.uint32),
-        s((nb,), jnp.int32), None, s((nb,), jnp.int32), *carry)
+    fn, args = prefill_call(model, ec, bucket, nb, sharding)
+    return fn.lower(*args)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -291,27 +256,8 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
         assert "paged_decode" in _kernel_names(text)
 
 
-def _cell_at_depth(cell, layers=None):
-    """(the cell's model at its first `layers` layers or all, its engine
-    shapes)."""
-    from benchmark.manifest import Manifest
-
-    manifest = Manifest(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    made = manifest.cell(cell)
-    cfg = manifest.config(made["config"])
-    family = manifest.family(cfg["family"])
-    kw = family.model_kwargs(cfg)
-    if layers is not None and "layer_types" in kw:
-        kw["layer_types"] = kw["layer_types"][:layers]
-    elif layers is not None:
-        kw["num_layers"] = layers
-    return family.model(kw), manifest.traffic(made["traffic"])[
-        "engine_config"]
-
-
 def _jamba_at_depth(layers=None):
-    return _cell_at_depth("jamba-prompt-heavy", layers)
+    return cell_at_depth("jamba-prompt-heavy", layers)
 
 
 def _program_bytes(model, ec):
@@ -382,7 +328,7 @@ def test_granite_decode_streams_its_share_and_updates_the_pool_in_place(
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("granite-prompt-heavy", 6)
+    model, ec = cell_at_depth("granite-prompt-heavy", 6)
     one = SingleDeviceSharding(topology.devices[0])
     text = _lower_decode(model, ec, one).compile().as_text()
     caches = sizing.cache_shapes(model, ec, None)
@@ -414,8 +360,8 @@ def test_granite_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("granite-prompt-heavy", 6)
-    whole, _ = _cell_at_depth("granite-prompt-heavy")
+    model, ec = cell_at_depth("granite-prompt-heavy", 6)
+    whole, _ = cell_at_depth("granite-prompt-heavy")
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_prefill(model, ec, 2048, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
@@ -440,7 +386,7 @@ def test_mellum_decode_walks_rings_and_pages_in_place(topology, monkeypatch):
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("mellum-code-context")
+    model, ec = cell_at_depth("mellum-code-context")
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_decode(model, ec, one).compile()
     peak, _ = sizing.peak_gib(compiled)
@@ -470,7 +416,7 @@ def test_mellum_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("mellum-code-context")
+    model, ec = cell_at_depth("mellum-code-context")
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
@@ -496,7 +442,7 @@ def test_sarvam_decode_walks_the_latent_pool_in_place(topology, monkeypatch):
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("sarvam-long-decode")
+    model, ec = cell_at_depth("sarvam-long-decode")
     assert model.cfg.num_layers == 6 and model.cfg.experts_held == (0, 32)
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_decode(model, ec, one).compile()
@@ -532,7 +478,7 @@ def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, ec = _cell_at_depth("sarvam-long-decode")
+    model, ec = cell_at_depth("sarvam-long-decode")
     one = SingleDeviceSharding(topology.devices[0])
     compiled = _lower_prefill(model, ec, 4096, nb, one).compile()
     peak, parts = sizing.peak_gib(compiled)
@@ -692,6 +638,38 @@ def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
         s((h,), jnp.float32), s((h,), jnp.float32), s((b,), jnp.int32))
     assert "ssd_scan" in _kernel_names(text)
     assert f"f32[{b},{h * p},{n}]" in text     # the state, N on the lanes
+
+
+def test_train_step_recomputes_the_wide_ffn_products_and_nothing_else(
+        topology, monkeypatch):
+    """The chip compiler's HLO of `train-2k`'s whole step (8 x 2,048, depth
+    8, adafactor): a remat'd layer keeps q, k, v, the flash kernel's output
+    and row sums and the attention projection, so `flash_fwd` runs once a
+    layer, the k and v projections' matmuls are the forward's and the weight
+    gradients' and no third, the only matmuls under `rematted_computation`
+    are `gate_proj` and `up_proj`, and the step fits the chip with a GiB to
+    spare."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, traffic = cell_model("train-2k")
+    depth = model.cfg.num_layers
+    step = sizing.lower_train_step(
+        model, traffic["batch"], traffic["seq"], traffic["learning_rate"],
+        SingleDeviceSharding(topology.devices[0])).compile()
+    text = step.as_text()
+    calls = [line.split(" = ", 1)[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert sum("flash_fwd" in name for name in calls) == depth
+    matmuls = [line for line in text.splitlines() if " convolution(" in line]
+    results = lambda shape: sum(f" = {shape}{{" in line for line in matmuls)
+    assert results("bf16[8,2048,8,128]") == 2 * depth       # k, v: forward
+    assert results("bf16[8,2048,14336]") == 5 * depth
+    again = [re.search(r"/(\w+)/dot_general", line).group(1)
+             for line in matmuls if "rematted_computation" in line]
+    assert sorted(again) == sorted(["gate_proj", "up_proj"] * depth)
+    peak, parts = sizing.peak_gib(step)
+    assert 13.0 <= peak <= sizing.USABLE_GIB - 1.0, (peak, parts)
 
 
 # ---------------------------------------------------------------------------
