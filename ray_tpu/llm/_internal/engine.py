@@ -146,11 +146,17 @@ class _Window(NamedTuple):
 # under the names the spans carry it by.
 _EXPERT_LOAD = ("experts_touched", "expert_load_max", "expert_rows_held",
                 "expert_rows_routed", "expert_tiles")
+# What a layer with an index pool sows as `page_load` in a decode step
+# (`ops.paged_attention.select_pages`): the pages its rows' KV heads walked
+# and the pages those rows hold.
+_PAGE_LOAD = ("pages_selected", "pages_visible")
+_SOWN = ["expert_load", "page_load"]
 
 
 def _sown_load(sown):
-    """[layers, 5] int32: the `expert_load` collection of one forward, a row
-    a layer that routes; [0, 5] of a model that sows none."""
+    """[layers, n] int32: the `expert_load` (n = 5) or `page_load` (n = 2)
+    collection of one forward, a row a layer that sows; [0, 5] of a model
+    that sows none."""
     load = jax.tree.leaves(sown)
     return (jnp.stack(load) if load
             else jnp.zeros((0, len(_EXPERT_LOAD)), jnp.int32))
@@ -233,6 +239,12 @@ class LLMEngine:
     (`ops.moe.Load`, one a layer and forward) has it summed over a decode
     window's steps and carried behind the window's tokens, and returned by
     its prefills: the `emit` and `prefill_dispatch` spans report it.
+
+    The layers in `model.index_layer_ids` (learned sparse attention) hold an
+    index pool beside their K/V pages and choose, a decode step and KV head,
+    the pages they walk; what they sow as `page_load` (pages selected, pages
+    visible) rides behind a window's tokens as an `expert_load` does and is
+    reported by the `emit` span.
 
     A model with `block_length` B > 1 generates by diffusion over aligned
     blocks of B positions (`_block_decode`): prefill only fills the cache
@@ -359,7 +371,7 @@ class LLMEngine:
         self._windows = {"unchained": 0, "none": 0, "finish": 0,
                          "admission": 0}
         # What the prefills and decode windows read so far have routed.
-        self._expert_load = dict.fromkeys(_EXPERT_LOAD, 0)
+        self._expert_load = dict.fromkeys(self._load_names, 0)
         # Compile record: (kind, key) -> [jit cache size after the last
         # call, argument signature it was last traced for], and the last
         # records of programs built and retraced.
@@ -387,6 +399,11 @@ class LLMEngine:
         return int(self.model.block_length)
 
     @property
+    def _load_names(self) -> Tuple[str, ...]:
+        """What a row of the load behind a window's tokens counts."""
+        return _PAGE_LOAD if self.model.index_layer_ids else _EXPERT_LOAD
+
+    @property
     def _head_last(self) -> bool:
         """Whether a prefill runs the head on each row's last prompt
         position only: the model says so (`num_logits_to_keep`), as it says
@@ -404,18 +421,21 @@ class LLMEngine:
     def _describe_cache(self) -> Dict[str, int]:
         """Layers and bytes of the cache by kind, from shapes alone and as
         the device lays them out: a minor axis fills whole lanes. A model
-        with ring layers reports them apart from the allocator's pages, and
-        one with latent layers those apart from K/V."""
+        with ring layers reports them apart from the allocator's pages, one
+        with latent layers those apart from K/V, and one with index layers
+        says how many of its K/V layers hold an index pool and its bytes."""
         state = set(self.model.state_layer_ids)
         ring = set(self.model.ring_layer_ids)
         latent = set(self.model.latent_layer_ids)
         size = lambda layer: sum(map(_laid_out_bytes,
                                      jax.tree.leaves(layer)))
         other = state | ring | latent
+        index = self.model.index_layer_ids
         report = {
             "kv_layers": len(self.caches) - len(other),
             "state_layers": len(state),
-            "kv_bytes": sum(size(c) for i, c in enumerate(self.caches)
+            # (an index layer's third array is counted apart, below)
+            "kv_bytes": sum(size(c[:2]) for i, c in enumerate(self.caches)
                             if i not in other),
             "state_bytes": sum(size(self.caches[i]) for i in state)}
         for kind, layers in (("ring", ring), ("latent", latent)):
@@ -423,6 +443,9 @@ class LLMEngine:
                 report.update({f"{kind}_layers": len(layers),
                                f"{kind}_bytes": sum(
                                    size(self.caches[i]) for i in layers)})
+        if index:   # those of the K/V layers that hold an index pool
+            report.update(index_layers=len(index), index_bytes=sum(
+                size(self.caches[i][2]) for i in index))
         return report
 
     # ------------------------------------------------------------------
@@ -569,7 +592,7 @@ class LLMEngine:
                 positions=positions, paged_kv=caches,
                 page_table=page_table, write_mask=active[:, None],
                 seq_lens=seq_lens + 1, lora=lora, lora_idx=lora_idx,
-                mutable=["expert_load"])
+                mutable=_SOWN)
             logits = logits[:, 0].astype(jnp.float32)  # [B, V]
             toks, nxt, lp = sample(keys, logits, temps, top_ps, top_ks)
             # inactive slots keep their chain position
@@ -582,9 +605,9 @@ class LLMEngine:
             """`steps` (traced, at most K) token steps for every row; the
             results keep K rows, of which the first `steps` are filled. Left
             out (whoever lowers the program from shapes alone), K. Where the
-            model sows an `expert_load`, the tokens come flat with its sums
-            over the window's steps [layers, 5] behind them, in the one
-            int32 result (as `_block_decode`'s)."""
+            model sows an `expert_load` or a `page_load`, the tokens come flat
+            with its sums over the window's steps [layers, 5 or 2] behind
+            them, in the one int32 result (as `_block_decode`'s)."""
             B = last_tokens.shape[0]
             # A free slot's row is stale while windows chain on the device
             # (the host's mirror says 0, the device's what its last request
@@ -596,8 +619,9 @@ class LLMEngine:
             out_ti = jnp.zeros((K, B, L), jnp.int32)
             # (the model says which layers sow: a trace of the forward to
             # find out would cost every family seconds a program)
-            load = jnp.zeros((len(model.expert_layer_ids),
-                              len(_EXPERT_LOAD)), jnp.int32)
+            load = jnp.zeros((len(model.index_layer_ids
+                                  or model.expert_layer_ids),
+                              len(self._load_names)), jnp.int32)
 
             def body(j, carry):
                 (caches, toks, lens, keys, out, out_lp, out_tv,
@@ -806,7 +830,7 @@ class LLMEngine:
                 paged_kv=caches, page_table=rows,
                 write_mask=mask, seq_lens=starts + true_lens,
                 lora=lora, lora_idx=lora_idx, slots=slots,
-                mutable=["expert_load"], **at)
+                mutable=_SOWN, **at)
             if self._block > 1:
                 # Cache fill only: the first block's passes sample its
                 # tokens, and with the logits unused no head is compiled
@@ -1066,7 +1090,8 @@ class LLMEngine:
         # (a chained window's rows are up to a window further on): every
         # active row's length, and what of it lies inside the window.
         reach = {}
-        if self._ring_window or self._latent_layers:
+        if (self._ring_window or self._latent_layers
+                or self.model.index_layer_ids):
             held = self.seq_lens[list(self.running)]
             reach = {"context_tokens": int(held.sum())}
         if self._ring_window:
@@ -1088,11 +1113,13 @@ class LLMEngine:
         return _Window(toks, last, lens, lp, dict(self.running), K)
 
     def _count_load(self, load) -> Dict[str, int]:
-        """A program's expert load (sums over its forwards, whatever its
-        shape) as its span's arguments, sums over the layers; added to the
-        sums `expert_load_report` gives."""
-        sums = np.asarray(load).reshape(-1, len(_EXPERT_LOAD)).sum(axis=0)
-        args = dict(zip(_EXPERT_LOAD, map(int, sums)))
+        """A program's expert load, or the pages its index layers walked
+        (sums over its forwards, whatever its shape) as its span's arguments,
+        sums over the layers; added to the sums `expert_load_report`
+        gives."""
+        names = self._load_names
+        sums = np.asarray(load).reshape(-1, len(names)).sum(axis=0)
+        args = dict(zip(names, map(int, sums)))
         for name, n in args.items():
             self._expert_load[name] += n
         return args
@@ -1101,7 +1128,8 @@ class LLMEngine:
         """`ops.moe.Load` summed over every prefill and decode window read so
         far, layers and forwards (all 0 for a model without experts):
         `expert_tiles` over `experts_touched` is how many tiles shared one
-        read of an expert's weights."""
+        read of an expert's weights. Of a model with index layers, the pages
+        its decode steps walked and those their rows held."""
         return dict(self._expert_load)
 
     def windows_report(self) -> Dict[str, int]:
@@ -1134,10 +1162,14 @@ class LLMEngine:
                 lp = tuple(np.asarray(a) for a in lp)
         load = {}
         if toks.ndim == 1:
-            # Behind the tokens: the expert load summed over the window's
-            # forwards (a model that routes).
+            # Behind the tokens: the expert load (a model that routes) or
+            # the pages walked (one with index layers), summed over the
+            # window's forwards.
             cut = max(1, self.cfg.decode_steps) * self.cfg.max_seqs
             load = self._count_load(toks[cut:])
+            if self.model.index_layer_ids:
+                # the selections (a layer and token step) those pages are of
+                load["select_calls"] = steps * len(self.model.index_layer_ids)
             toks = toks[:cut].reshape(-1, self.cfg.max_seqs)
         if out is None:
             return False

@@ -41,6 +41,8 @@ FAMILIES = {
                      "ray_tpu.models.mellum:MellumModel"),
     "sarvam_mla": Family("ray_tpu.models.sarvam_mla:SarvamMlaConfig",
                          "ray_tpu.models.sarvam_mla:SarvamMlaModel"),
+    "minicpm_sala": Family("ray_tpu.models.minicpm_sala:MiniCPMSalaConfig",
+                           "ray_tpu.models.minicpm_sala:MiniCPMSalaModel"),
 }
 
 

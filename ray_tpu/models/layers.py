@@ -23,8 +23,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.initializers import embed_init, kernel_init
 from ray_tpu.ops.moe import moe_layer
-from ray_tpu.ops.paged_attention import (init_kv_pages, init_latent_pages,
-                                         init_ring_pages)
+from ray_tpu.ops.paged_attention import (init_index_pages, init_kv_pages,
+                                         init_latent_pages, init_ring_pages)
 
 
 class RMSNorm(nn.Module):
@@ -257,6 +257,12 @@ class Decoder(nn.Module):
     # token's row `latent_width` values for all heads (latent attention).
     latent_layer_ids: ClassVar[Tuple[int, ...]] = ()
     latent_width: ClassVar[int] = 0
+    # Layers whose (k_pages, v_pages) have an index pool beside them: a
+    # page's `index_segments` segment means, by which a query chooses the
+    # pages it attends to (learned sparse attention). Each sows a
+    # `page_load` (pages selected, pages visible) a decode step.
+    index_layer_ids: ClassVar[Tuple[int, ...]] = ()
+    index_segments: ClassVar[int] = 4
     # Layers that sow an `expert_load` (`ops.moe.Load`) a forward, for the
     # engine's token-at-a-time programs to sum and report.
     expert_layer_ids: ClassVar[Tuple[int, ...]] = ()
@@ -272,9 +278,10 @@ class Decoder(nn.Module):
         """The serving engine's cache of a family without sharding rules, an
         entry a layer: on a state layer (zeros [max_seqs, *tail] in the
         compute dtype, zeros [max_seqs, *state] float32), a row per engine
-        slot; on a ring layer `max_seqs` rings of pages; on a latent layer
-        one pool of rows; (k_pages, v_pages) from the allocator's pool on
-        the others."""
+        slot, the state alone where the family gives no `tail`; on a ring
+        layer `max_seqs` rings of pages; on a latent layer one pool of rows;
+        (k_pages, v_pages) from the allocator's pool on the others, and on an
+        index layer the pool of segment means as the third."""
         if mesh is not None:
             raise NotImplementedError(
                 f"{type(self).__name__}: neither its parameters nor its "
@@ -285,14 +292,19 @@ class Decoder(nn.Module):
 
         def entry(i):
             if i in states:
-                return (jnp.zeros((n, *tail), cfg.dtype),
-                        jnp.zeros((n, *state), jnp.float32))
+                held = jnp.zeros((n, *state), jnp.float32)
+                return held if tail is None else (
+                    jnp.zeros((n, *tail), cfg.dtype), held)
             if i in self.latent_layer_ids:
                 return init_latent_pages(cache_cfg, self.latent_width,
                                          cfg.dtype)
             kv = cfg.num_kv_heads, cfg.head_dim, cfg.dtype
             if i in rings:
                 return init_ring_pages(cache_cfg, self.sliding_window, *kv)
+            if i in self.index_layer_ids:
+                return (*init_kv_pages(cache_cfg, *kv), init_index_pages(
+                    cache_cfg, self.index_segments,
+                    cfg.num_kv_heads * cfg.head_dim))
             return init_kv_pages(cache_cfg, *kv)
 
         return [entry(i) for i in range(cfg.num_layers)]

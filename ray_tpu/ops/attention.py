@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -574,3 +574,307 @@ def _compiler_params():
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
+# ---------------------------------------------------------------------------
+# Learned block-sparse attention (InfLLM-V2, arXiv:2506.07900): a query past
+# `dense_len` attends to `topk` key blocks it chooses by compressed keys
+# ---------------------------------------------------------------------------
+class SparseSizes(NamedTuple):
+    """The selection's sizes (MiniCPM4's `sparse_config`). A compressed key
+    is the mean of `kernel_size` keys, one every `kernel_stride`; a query
+    chooses `topk` blocks of `block_size` keys, the first `init_blocks` and
+    those of the last `window_size` keys among them; a query that sees fewer
+    than `dense_len` keys attends to all of them."""
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    topk: int = 64
+    dense_len: int = 8192
+
+    def check(self) -> "SparseSizes":
+        if (self.kernel_size != 2 * self.kernel_stride
+                or self.block_size % self.kernel_stride
+                or self.window_size % self.block_size
+                or self.dense_len % self.block_size):
+            raise ValueError(
+                f"{self}: a compressed key is two segments of kernel_stride, "
+                "and blocks are whole segments")
+        return self
+
+    @property
+    def per_block(self) -> int:
+        """Segments of `kernel_stride` keys (and compressed keys that begin)
+        in a block."""
+        return self.block_size // self.kernel_stride
+
+    @property
+    def window_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+
+def compressed_scores(q, means, t, sizes: SparseSizes, scale: float):
+    """What a KV head's group of query heads makes of the compressed keys.
+    q [..., G, Q, D] (G heads of the group, Q queries), means [..., N, D] the
+    segment means (float32), t [..., Q] the queries' positions -> r
+    [..., Q, N] float32: the sum over the group's heads of each head's softmax
+    over the compressed keys c_i = (m_i + m_(i+1)) / 2 it sees (those with
+    16 i + 31 <= t), -1 where it does not see one."""
+    stride, size = sizes.kernel_stride, sizes.kernel_size
+    keys = 0.5 * (means + jnp.concatenate(
+        [means[..., 1:, :], jnp.zeros_like(means[..., :1, :])], axis=-2))
+    scores = jnp.einsum("...gqd,...nd->...gqn", q.astype(jnp.float32), keys,
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    seen = (jnp.arange(means.shape[-2]) * stride + size - 1
+            <= t[..., None])                                   # [..., Q, N]
+    scores = jnp.where(seen[..., None, :, :], scores, NEG_INF)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    weights = jnp.where(seen[..., None, :, :], jnp.exp(scores - top), 0.0)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    r = jnp.sum(weights / jnp.maximum(total, 1e-30), axis=-3)
+    return jnp.where(seen, r, -1.0)
+
+
+def choose_blocks(r, t, sizes: SparseSizes):
+    """r [..., Q, N] (`compressed_scores`), t [..., Q] -> the `topk` blocks
+    each query attends to, [..., Q, topk] int32 in ascending order (all N /
+    per_block of them where there are fewer). A block's
+    score is the largest r of the compressed keys that overlap it (those that
+    begin in it and the one before); the first `init_blocks` and the
+    `window_blocks` that end with the query's own count as chosen, blocks
+    past the query's own are out, ties go to the lower index. A query with
+    fewer than `topk` blocks behind it lists blocks past its own at the end:
+    the caller passes such a query by (`t + 1 < dense_len`)."""
+    per = sizes.per_block
+    blocks = r.shape[-1] // per
+    grouped = r.reshape(r.shape[:-1] + (blocks, per))
+    before = jnp.concatenate(
+        [jnp.full_like(grouped[..., :1, -1], -1.0), grouped[..., :-1, -1]],
+        axis=-1)
+    score = jnp.maximum(jnp.max(grouped, axis=-1), before)     # [..., Q, NB]
+    block = jnp.arange(blocks)
+    own = (t // sizes.block_size)[..., None]
+    forced = (block < sizes.init_blocks) | (
+        (block <= own) & (block > own - sizes.window_blocks))
+    score = jnp.where(forced, jnp.inf, jnp.where(block <= own, score, -2.0))
+    _, chosen = jax.lax.top_k(score, min(sizes.topk, blocks))
+    return jnp.sort(chosen, axis=-1).astype(jnp.int32)
+
+
+QUERY_TILE = 512
+
+
+def select_blocks(q, k, sizes: SparseSizes, scale: Optional[float] = None):
+    """The key blocks every query of a sequence attends to, over the call's
+    own q [B,S,H,D] and k [B,S,Hkv,D] at positions 0..S-1 -> [B,Hkv,NB,S]
+    int32, 1 where the query at position t (last axis) attends to block j:
+    the blocks up to its own while t + 1 < `dense_len`, its chosen `topk`
+    from there on. Queries are scored `QUERY_TILE` at a time (a tile's
+    scores are [H, QUERY_TILE, S / kernel_stride] float32), and only the
+    tiles that reach `dense_len`. (A row's padding lies past every real
+    query, and what a padded query chooses is never read.)"""
+    sizes.check()
+    b, s, h, d = q.shape
+    hk = k.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    bs, stride = sizes.block_size, sizes.kernel_stride
+    nb = -(-s // bs)
+    block = jnp.arange(nb)[:, None]
+    t_all = jnp.arange(s)
+    causal = jnp.broadcast_to((block <= t_all[None, :] // bs)[None, None],
+                              (b, hk, nb, s)).astype(jnp.int32)
+    if s <= sizes.dense_len - 1 or nb <= sizes.topk:
+        return causal
+    first = (sizes.dense_len - 1) // QUERY_TILE * QUERY_TILE
+    kp = jnp.pad(k.astype(jnp.float32),
+                 ((0, 0), (0, nb * bs - s), (0, 0), (0, 0)))
+    means = kp.reshape(b, nb * sizes.per_block, stride, hk, d).mean(axis=2)
+    means = means.transpose(0, 2, 1, 3)                        # [B,Hkv,N,D]
+    tiles = -(-(s - first) // QUERY_TILE)
+    qp = jnp.pad(q[:, first:], ((0, 0), (0, first + tiles * QUERY_TILE - s),
+                                (0, 0), (0, 0)))
+    qp = qp.reshape(b, tiles, QUERY_TILE, hk, h // hk, d).transpose(
+        1, 0, 3, 4, 2, 5)                                   # [T,B,Hkv,G,Q,D]
+
+    def tile(args):
+        qt, start = args
+        t = jnp.broadcast_to(start + jnp.arange(QUERY_TILE),
+                             (b, hk, QUERY_TILE))
+        chosen = choose_blocks(
+            compressed_scores(qt, means, t, sizes, scale), t, sizes)
+        picked = jnp.zeros((b, hk, QUERY_TILE, nb), jnp.int32)
+        picked = jnp.put_along_axis(picked, chosen, 1, axis=-1,
+                                    inplace=False)
+        return picked.transpose(0, 1, 3, 2)                 # [B,Hkv,NB,Q]
+
+    picked = jax.lax.map(tile, (qp, first + jnp.arange(tiles) * QUERY_TILE))
+    picked = picked.transpose(1, 2, 3, 0, 4).reshape(
+        b, hk, nb, tiles * QUERY_TILE)[..., :s - first]
+    sparse = jnp.concatenate([causal[..., :first], picked], axis=-1)
+    return jnp.where(t_all + 1 < sizes.dense_len, causal, sparse)
+
+
+def sparse_attention_plain(q, k, v, chosen, block_size: int,
+                           scale: Optional[float] = None):
+    """Causal attention in which the query at position t sees key j iff
+    `chosen[b, g, j // block_size, t]` (`select_blocks`) and j <= t, as a
+    masked dense softmax: q [B,S,H,D], k/v [B,S,Hkv,D]. What `sparse_flash`
+    computes, in plain `jax.numpy` (the path without a cache, and the
+    kernel's oracle)."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, s, h, d = q.shape
+    rep = h // k.shape[2]
+    k, v = _gqa_expand(k, v, h)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    ids = jnp.arange(s)
+    of_key = jnp.repeat(chosen, block_size, axis=2)[:, :, :s]  # [B,Hkv,S,S]
+    visible = (of_key.transpose(0, 1, 3, 2) > 0) & (ids[None, :]
+                                                    <= ids[:, None])
+    logits = jnp.where(jnp.repeat(visible, rep, axis=1), logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST).astype(q.dtype)
+
+
+def _sparse_flash_kernel(any_ref, q_ref, k_ref, v_ref, c_ref, o_ref, m_scr,
+                         l_scr, acc_scr, *, scale: float, block_q: int,
+                         block_k: int, block_size: int, rep: int,
+                         num_k_blocks: int):
+    """Grid (B, H, q tiles, k tiles), the scores of a tile held TRANSPOSED,
+    keys down the sublanes and queries along the lanes: `c_ref` [block_k /
+    block_size, block_q] says which of the tile's key blocks each query
+    chose, and a block's flag is one row broadcast down its block_size
+    sublanes; the running maximum and sum are rows [1, block_q], the
+    accumulator [D, block_q]. `any_ref` (scalar prefetch) [B, Hkv, q tiles,
+    k tiles], flat: whether any query of the tile chose (and may see) any key of
+    it; a tile none did is passed over."""
+    bi, hi = pl.program_id(0), pl.program_id(1)
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    tile = ((bi * (pl.num_programs(1) // rep) + hi // rep)
+            * pl.num_programs(2) + qi) * num_k_blocks + ki
+
+    @pl.when(any_ref[tile] != 0)
+    def _compute():
+        q = q_ref[0, 0]                            # [block_q, d]
+        k = k_ref[0, 0]                            # [block_k, d]
+        s = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [bk, bq]
+        k_ids = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 0)
+        q_ids = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+        flags = c_ref[0, 0]                        # [bk / block_size, bq]
+        chose = jnp.concatenate(
+            [jnp.broadcast_to(flags[j:j + 1], (block_size, block_q))
+             for j in range(block_k // block_size)], axis=0)
+        s = jnp.where((chose > 0) & (k_ids <= q_ids), s, NEG_INF)
+        m_prev = m_scr[...]                        # [1, bq]
+        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=0, keepdims=True)
+        m_scr[...] = m_new
+        v = v_ref[0, 0]                            # [bk, d]
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # [d, bq]
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finish():
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def sparse_flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    chosen: jax.Array,
+    *,
+    block_size: int,
+    scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal attention under a block mask a query position, over the call's
+    own q [B,S,H,D] and k/v [B,S,Hkv,D] (the Pallas kernel `sparse_flash`;
+    forward only): the query at position t sees key j iff j <= t and
+    `chosen[b, g, j // block_size, t]` (`select_blocks`: [B,Hkv,S/block_size,
+    S] int32), g its KV head. The mathematics is `sparse_attention_plain`'s
+    exactly: a tile reads a block some of its queries did not choose and
+    masks it for them, and passes over a key tile none of its queries chose
+    (or may see: above the diagonal). Every query must see a key of the
+    first key tile (block 0 is always chosen). K and V keep their Hkv heads:
+    a query head's index map reads its group's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, s, h, d = q.shape
+    hk = k.shape[2]
+    rep = h // hk
+    if s % block_size:
+        raise ValueError(f"sequence {s} is not whole blocks of {block_size}")
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    while s % block_q:
+        block_q //= 2
+    while s % block_k:
+        block_k //= 2
+    if block_k % block_size:
+        raise ValueError(f"key tiles of {block_k} are not whole blocks of "
+                         f"{block_size}")
+    nq, nk, per = s // block_q, s // block_k, block_k // block_size
+    # (a tile above the diagonal holds no pair a query may see)
+    tiles = chosen.reshape(b, hk, nk, per, nq, block_q).max(axis=(3, 5))
+    tiles = tiles.transpose(0, 1, 3, 2) * (
+        jnp.arange(nk)[None, :] * block_k
+        <= jnp.arange(nq)[:, None] * block_q + block_q - 1)
+    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    # (a key tile above the diagonal repeats the diagonal's index, so nothing
+    # is fetched for it)
+    below = lambda qi, ki: jnp.minimum(ki, (qi * block_q + block_q - 1)
+                                       // block_k)
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda bi, hi, qi, ki, any_: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda bi, hi, qi, ki, any_: (bi, hi // rep, below(qi, ki), 0))
+    out = pl.pallas_call(
+        functools.partial(_sparse_flash_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, block_size=block_size, rep=rep,
+                          num_k_blocks=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, nq, nk),
+            in_specs=[
+                q_spec, kv_spec, kv_spec,
+                pl.BlockSpec((1, 1, per, block_q),
+                             lambda bi, hi, qi, ki, any_: (
+                                 bi, hi // rep, below(qi, ki), qi)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, d, block_q),
+                lambda bi, hi, qi, ki, any_: (bi, hi, 0, qi)),
+            scratch_shapes=[_vmem((1, block_q)), _vmem((1, block_q)),
+                            _vmem((d, block_q))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, d, s), q.dtype),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="sparse_flash",
+    )(tiles.astype(jnp.int32).reshape(-1), qt, kt, vt, chosen)
+    return out.transpose(0, 3, 1, 2)

@@ -17,6 +17,15 @@ the recurrence above, token by token):
 - `gdn_decode`: one token a row against the state pool. On a TPU one Pallas
   kernel (`name="gdn_decode"`) reads each state once and writes it once, in
   place; elsewhere `jax.numpy`.
+
+Lightning attention (arXiv:2401.04658) is the same memory under a simpler
+rule: a constant decay lambda in (0, 1] a head and no delta correction,
+
+    S <- lambda S + k^T v;   o = q S
+
+`lightning_chunked` is its chunkwise form for a padded bucket and
+`lightning_step` its one-token step against the state pool, both plain
+`jax.numpy` in float32 (as `gdn_chunked` and `ops/ssm.py`'s steps are).
 """
 
 from __future__ import annotations
@@ -194,3 +203,85 @@ def gdn_decode(q, k, v, g, beta, state, active,
     s = s + k[..., :, None] * u[..., None, :]
     o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HIGHEST)
     return o, jnp.where(active[:, None, None, None], s, state)
+
+
+# ---------------------------------------------------------------------------
+# Lightning attention: a constant decay a head
+# ---------------------------------------------------------------------------
+LIGHTNING_CHUNK = 128
+
+
+def lightning_chunked(q, k, v, log_decay, lengths=None,
+                      chunk: int = LIGHTNING_CHUNK):
+    """o_t = sum_{j<=t} lambda^(t-j) (q_t . k_j) v_j from a zero state, and
+    the state after a row's last position. q, k, v [B,S,H,D] (any scale on q
+    is the caller's), log_decay [H] = log lambda <= 0, `lengths` [B]: a
+    row's positions from there on are padding (their outputs mean nothing,
+    and they neither decay nor write the state handed back); None: S. ->
+    (o [B,S,H,D] float32, state [B,H,D,D] float32).
+
+    Chunk c of C positions, of which n_c are not padding:
+        O = ((Q K^T) * D) V + Lambda * (Q S_c),  D_ij = lambda^(i-j) (i >= j),
+        Lambda_i = lambda^(i+1);
+        S_(c+1) = lambda^(n_c) S_c + sum_(j<n_c) lambda^(n_c-1-j) k_j^T v_j
+    Only non-negative powers of lambda, so nothing overflows. Everything a
+    chunk needs but its incoming state is one batched product; the states
+    are a linear recurrence over the chunks' sums."""
+    b, s, h, d = q.shape
+    n = -(-s // chunk)
+    pad = n * chunk - s
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
+
+    def chunks(x):  # [B,S,H,D] -> [B,N,H,C,D]
+        x = x.astype(jnp.float32)
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)])
+        return x.reshape(b, n, chunk, h, d).transpose(0, 1, 3, 2, 4)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    log_decay = log_decay.astype(jnp.float32)[:, None]          # [H,1]
+    at = jnp.arange(chunk, dtype=jnp.float32)
+    # (masked before the exponential, which overflows above the diagonal)
+    within = jnp.exp(jnp.where(
+        at[:, None] >= at[None, :],
+        log_decay[..., None] * (at[:, None] - at[None, :]), -jnp.inf))
+    scores = jnp.einsum("bnhtd,bnhsd->bnhts", q, k, precision=_HIGHEST)
+    own = jnp.einsum("bnhts,bnhsd->bnhtd", scores * within, v,
+                     precision=_HIGHEST)
+    # A chunk's positions that are not padding, [B,N]; what each leaves in
+    # the state at the end of them.
+    held = jnp.clip(lengths[:, None] - jnp.arange(n) * chunk, 0, chunk
+                    ).astype(jnp.float32)
+    left = held[..., None, None] - 1.0 - at                     # [B,N,1,C]
+    weight = jnp.where(left >= 0, jnp.exp(log_decay * jnp.maximum(left, 0.0)),
+                       0.0)                                     # [B,N,H,C]
+    sums = jnp.einsum("bnhtk,bnhtv->bnhkv", k * weight[..., None], v,
+                      precision=_HIGHEST)
+    through = jnp.exp(log_decay[:, 0] * held[..., None])        # [B,N,H]
+
+    def step(state, xs):
+        kept, added = xs
+        return state * kept[..., None, None] + added, state
+
+    state, before = jax.lax.scan(
+        step, jnp.zeros((b, h, d, d), jnp.float32),
+        (jnp.moveaxis(through, 1, 0), jnp.moveaxis(sums, 1, 0)))
+    carried = jnp.einsum("bnhtk,bnhkv->bnhtv", q, jnp.moveaxis(before, 0, 1),
+                         precision=_HIGHEST)
+    o = own + carried * jnp.exp(log_decay * (at + 1.0))[..., None]
+    o = o.transpose(0, 1, 3, 2, 4).reshape(b, n * chunk, h, d)
+    return o[:, :s], state
+
+
+def lightning_step(q, k, v, log_decay, state, active):
+    """One token a row against the state pool: S <- lambda S + k^T v, o = q S
+    with the new S. q, k, v [B,H,D], log_decay [H], state [B,H,D,D] float32,
+    active [B] bool (an inactive row's state stays) -> (o [B,H,D] float32,
+    state). With the pool donated, the update is in place."""
+    f32 = lambda x: x.astype(jnp.float32)
+    q, k, v = f32(q), f32(k), f32(v)
+    new = (state * jnp.exp(f32(log_decay))[:, None, None]
+           + k[..., :, None] * v[..., None, :])
+    o = jnp.einsum("bhk,bhkv->bhv", q, new, precision=_HIGHEST)
+    return o, jnp.where(active[:, None, None, None], new, state)

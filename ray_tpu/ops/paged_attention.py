@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.ops import attention
+
 NEG_INF = -1e30
 # VMEM the decode kernel's double-buffered K and V page chunks may take
 # (of the 16 MiB a v5e kernel gets by default; the rest is the compiler's).
@@ -642,3 +644,304 @@ def mla_decode(q: jax.Array, pages: jax.Array, page_table: jax.Array,
         interpret=interpret,
         name="mla_decode",
     )(page_table, seq_lens, q, pages)
+
+
+# ---------------------------------------------------------------------------
+# Learned block-sparse attention: an index of segment means beside K/V, a
+# list of chosen pages a row and KV head, and the walk over that list
+# ---------------------------------------------------------------------------
+def init_index_pages(cache_cfg, segments: int, width: int):
+    """One sparse-attention layer's index pool beside its (k_pages, v_pages):
+    a page's `segments` segment means (the mean of each run of page_size /
+    segments keys, all KV heads side by side as a key's row is), float32
+    [P, segments, HK*D] from the allocator's page count."""
+    return jnp.zeros((cache_cfg.num_pages, segments, width), jnp.float32)
+
+
+def index_write(m_pages: jax.Array, k: jax.Array, page_table: jax.Array,
+                positions: jax.Array, mask: jax.Array,
+                page_size: int) -> jax.Array:
+    """A prefill's whole segments into the index pool: k [B,S,HK,D] at
+    `positions` [B,S] (a row's first at a segment's start, S whole segments)
+    of pages of `page_size` keys; a segment is written where `mask` [B,S]
+    holds its last key."""
+    num_pages, segments, width = m_pages.shape
+    b, s = positions.shape
+    seg = page_size // segments
+    means = k.astype(jnp.float32).reshape(b, s // seg, seg, width).mean(axis=2)
+    at = positions[:, ::seg]
+    page = jnp.take_along_axis(page_table, at // page_size, axis=1)
+    page = jnp.where(mask[:, seg - 1::seg], page, num_pages)  # OOB -> dropped
+    return m_pages.at[page.reshape(-1),
+                      (at % page_size // seg).reshape(-1)].set(
+        means.reshape(-1, width), mode="drop")
+
+
+def index_step(m_pages: jax.Array, k_pages: jax.Array, page_table: jax.Array,
+               positions: jax.Array, mask: jax.Array) -> jax.Array:
+    """A decode step's part of the index: where the key just written at
+    `positions` [B] (where `mask` [B]) is the last of its segment, that
+    segment's mean, taken from the page's own rows, is written. In place on
+    a donated pool: a gather of B segments and a scatter of B rows."""
+    num_pages, segments, _ = m_pages.shape
+    ps = k_pages.shape[1]
+    seg = ps // segments
+    page = jnp.take_along_axis(page_table, (positions // ps)[:, None],
+                               axis=1)[:, 0]
+    which = positions % ps // seg
+    rows = (which * seg)[:, None] + jnp.arange(seg)
+    means = k_pages[page[:, None], rows].astype(jnp.float32).mean(axis=1)
+    done = mask & (positions % seg == seg - 1)
+    return m_pages.at[jnp.where(done, page, num_pages), which].set(
+        means, mode="drop")
+
+
+def select_pages(q: jax.Array, m_pages: jax.Array, page_table: jax.Array,
+                 seq_lens: jax.Array, sizes, scale: Optional[float] = None,
+                 active: Optional[jax.Array] = None):
+    """The pages a decode step's queries walk. q [B,H,D] at positions
+    `seq_lens` - 1 (already written), `m_pages` the layer's index pool,
+    `sizes` an `ops.attention.SparseSizes` whose block is a page. A row
+    shorter than `dense_len` lists its own pages in order; a longer one, for
+    each KV head, the `topk` pages its group chooses (`compressed_scores`,
+    `choose_blocks` over the row's own pages' segment means), in ascending
+    order. -> (pages [B,HK,L] physical, counts [B,HK,L] valid tokens of
+    each, used [B,HK] entries in use, load [2] int32 = (pages_selected,
+    pages_visible): sums over the `active` rows and KV heads of `used` and of
+    ceil(len / page)), L = max(topk, dense_len / page): one shape, so one
+    decode program for both kinds of row."""
+    b, h, d = q.shape
+    _, per, width = m_pages.shape
+    hk = width // d
+    bs = sizes.block_size
+    mp = page_table.shape[1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    means = jnp.take(m_pages, page_table, axis=0).reshape(b, mp * per, hk, d)
+    t = jnp.broadcast_to((seq_lens - 1)[:, None, None], (b, hk, 1))
+    r = attention.compressed_scores(q.reshape(b, hk, h // hk, 1, d),
+                                    means.transpose(0, 2, 1, 3), t, sizes,
+                                    scale)
+    chosen = attention.choose_blocks(r, t, sizes)[:, :, 0]     # [B,HK,topk]
+    width_l = max(sizes.topk, sizes.dense_len // bs)
+    own = jnp.arange(width_l, dtype=jnp.int32)
+    sparse = (seq_lens >= sizes.dense_len)[:, None]            # [B,1]
+    held = -(-seq_lens // bs)                                  # pages a row
+    idx = jnp.where(sparse[..., None], jnp.pad(
+        chosen, ((0, 0), (0, 0), (0, width_l - chosen.shape[-1]))), own)
+    pages = jnp.take_along_axis(page_table[:, None, :],
+                                jnp.minimum(idx, mp - 1), axis=2)
+    counts = jnp.clip(seq_lens[:, None, None] - idx * bs, 0, bs)
+    used = jnp.broadcast_to(jnp.where(sparse, sizes.topk, held[:, None]),
+                            (b, hk))
+    if active is None:
+        active = jnp.ones((b,), bool)
+    load = jnp.stack([jnp.sum(jnp.where(active[:, None], used, 0)),
+                      jnp.sum(jnp.where(active, held, 0)) * hk])
+    return (pages.astype(jnp.int32), counts.astype(jnp.int32),
+            used.astype(jnp.int32), load.astype(jnp.int32))
+
+
+def _sparse_decode_kernel(pages_ref, counts_ref, used_ref, q_ref, k_hbm,
+                          v_hbm, o_ref, kbuf, vbuf, ksem, vsem, m_scr, l_scr,
+                          acc_scr, *, scale: float):
+    """Grid (B, HK): one program a row and KV head, its group's query heads
+    [G, D] against the pages of `pages_ref[row]` in the order listed, entry j
+    valid for its first `counts_ref[row, j]` tokens, `used_ref[row]` entries
+    in all. `_paged_decode_kernel`'s pipeline (chunks of C pages, a page one
+    DMA, the next chunk's DMAs in flight under this chunk's flash update)
+    over a list and not a table's row, and of a page only this KV head's
+    lanes [ps, D]: the other head's keys are not read."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    g = pl.program_id(1)
+    row = pl.program_id(0) * pl.num_programs(1) + g
+    _, C, ps, d = kbuf.shape
+    width = pages_ref.shape[1]
+    n_pages = used_ref[row]
+    n_chunks = jax.lax.div(n_pages + C - 1, C)
+    lanes = pl.ds(pl.multiple_of(g * d, d), d)
+
+    def entry(ci, j):
+        return jnp.minimum(ci * C + j, width - 1)
+
+    def page_copies(ci, buf, j):
+        page = pages_ref[row, entry(ci, j)]
+        return (pltpu.make_async_copy(k_hbm.at[page, :, lanes],
+                                      kbuf.at[buf, j], ksem.at[buf, j]),
+                pltpu.make_async_copy(v_hbm.at[page, :, lanes],
+                                      vbuf.at[buf, j], vsem.at[buf, j]))
+
+    def start_chunk(ci, buf):
+        for j in range(C):  # static unroll: C independent page DMAs
+
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                for copy in page_copies(ci, buf, j):
+                    copy.start()
+
+            @pl.when(ci * C + j >= n_pages)
+            def _zero(j=j):
+                # (a weight of exactly 0 times garbage may be NaN)
+                vbuf[buf, j] = jnp.zeros_like(vbuf[buf, j])
+                kbuf[buf, j] = jnp.zeros_like(kbuf[buf, j])
+
+    def wait_chunk(ci, buf):
+        for j in range(C):
+
+            @pl.when(ci * C + j < n_pages)
+            def _(j=j):
+                for copy in page_copies(ci, buf, j):
+                    copy.wait()
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        start_chunk(0, 0)
+
+    def chunk(ci, carry):
+        buf = jax.lax.rem(ci, 2)
+
+        @pl.when(ci + 1 < n_chunks)
+        def _prefetch():
+            start_chunk(ci + 1, 1 - buf)
+
+        wait_chunk(ci, buf)
+        k = kbuf[buf].reshape(C * ps, d)
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [G, C*ps]
+        at = jax.lax.broadcasted_iota(jnp.int32, (1, C * ps), 1)
+        # each entry's count of valid tokens (0 past the entries in use)
+        valid = jnp.zeros((1, C * ps), jnp.int32)
+        for j in range(C):
+            count = jnp.where(ci * C + j < n_pages,
+                              counts_ref[row, entry(ci, j)], 0)
+            valid = jnp.where(at // ps == j, count, valid)
+        s = jnp.where(at % ps < valid, s, NEG_INF)
+        m_prev = m_scr[...]  # [G, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        v = vbuf[buf].reshape(C * ps, d)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [G, D]
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+    o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+        o_ref.dtype)
+
+
+def sparse_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                  pages: jax.Array, counts: jax.Array, used: jax.Array,
+                  scale: Optional[float] = None,
+                  pages_per_chunk: Optional[int] = None,
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """Pallas decode attention over listed pages: q [B,H,D], the query heads
+    of KV head g over the first `used[b, g]` of `pages[b, g]` (physical
+    pages of [P,ps,HK*D] pools, in any order), the first `counts[b, g, j]`
+    tokens of entry j -> [B,H,D]. Grid (B, HK); `_sparse_decode_kernel` has
+    the pipeline. `pages_per_chunk` defaults to what KV_CHUNK_VMEM_BYTES
+    holds of one KV head's lanes of these pages, twice for K and for V."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    _, ps, width = k_pages.shape
+    hk = width // d
+    entries = pages.shape[-1]
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    if pages_per_chunk is None:
+        page_bytes = ps * d * k_pages.dtype.itemsize
+        pages_per_chunk = max(1, KV_CHUNK_VMEM_BYTES // (4 * page_bytes))
+    C = min(pages_per_chunk, entries)
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    group = pl.BlockSpec((1, 1, h // hk, d), lambda bi, gi, *_: (bi, gi, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_sparse_decode_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, hk),
+            in_specs=[group, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=group,
+            scratch_shapes=[
+                pltpu.VMEM((2, C, ps, d), k_pages.dtype),
+                pltpu.VMEM((2, C, ps, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, C)),
+                pltpu.SemaphoreType.DMA((2, C)),
+                pltpu.VMEM((h // hk, 1), jnp.float32),
+                pltpu.VMEM((h // hk, 1), jnp.float32),
+                pltpu.VMEM((h // hk, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hk, h // hk, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="sparse_decode",
+    )(pages.reshape(b * hk, entries), counts.reshape(b * hk, entries),
+      used.reshape(b * hk), q.reshape(b, hk, h // hk, d), k_pages, v_pages)
+    return out.reshape(b, h, d)
+
+
+def listed_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                     pages: jax.Array, counts: jax.Array, used: jax.Array,
+                     scale: Optional[float] = None,
+                     use_kernel: Optional[bool] = None) -> jax.Array:
+    """`sparse_decode`'s result: on a TPU the kernel, elsewhere the listed
+    pages gathered whole and a plain softmax over their valid tokens."""
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if use_kernel:
+        return sparse_decode(q, k_pages, v_pages, pages, counts, used, scale)
+    b, h, d = q.shape
+    _, ps, width = k_pages.shape
+    hk, entries = width // d, pages.shape[-1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+
+    def own_lanes(pool):  # [B,HK,L,ps,HK*D] -> KV head g's lanes of row g
+        rows = jnp.take(pool, pages, axis=0).reshape(
+            b, hk, entries * ps, hk, d).astype(jnp.float32)
+        return jnp.stack([rows[:, g, :, g] for g in range(hk)], axis=1)
+
+    k, v = own_lanes(k_pages), own_lanes(v_pages)              # [B,HK,T,D]
+    valid = (jnp.arange(ps) < counts[..., None]) & (
+        jnp.arange(entries)[:, None] < used[..., None, None])
+    logits = jnp.einsum("bgqd,bgkd->bgqk", q.astype(jnp.float32).reshape(
+        b, hk, h // hk, d), k, precision=jax.lax.Precision.HIGHEST) * scale
+    logits = jnp.where(valid.reshape(b, hk, 1, entries * ps), logits,
+                       NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bgqk,bgkd->bgqd", probs, v,
+                      precision=jax.lax.Precision.HIGHEST).reshape(
+        b, h, d).astype(q.dtype)
+
+
+def sparse_write_attend(q: jax.Array, k: jax.Array, v: jax.Array, cache,
+                        page_table: jax.Array, positions: jax.Array,
+                        write_mask: jax.Array, seq_lens: jax.Array, sizes,
+                        scale: Optional[float] = None):
+    """One sparse-attention layer's decode step over its (k_pages, v_pages,
+    m_pages): the token's k and v [B,1,HK,D] written at `positions` [B,1]
+    where `write_mask` allows, the segment mean its key completes (if any),
+    the pages chosen (`select_pages`), and q [B,1,H,D] attended over them.
+    Returns (out [B,1,H,D], the cache, load [2] = pages selected and
+    visible)."""
+    k_pages, v_pages, m_pages = cache
+    k_pages = paged_write(k_pages, k, page_table, positions, write_mask)
+    v_pages = paged_write(v_pages, v, page_table, positions, write_mask)
+    m_pages = index_step(m_pages, k_pages, page_table, positions[:, 0],
+                         write_mask[:, 0])
+    pages, counts, used, load = select_pages(
+        q[:, 0], m_pages, page_table, seq_lens, sizes, scale,
+        active=write_mask[:, 0])
+    out = listed_attention(q[:, 0], k_pages, v_pages, pages, counts, used,
+                           scale)
+    return out[:, None], (k_pages, v_pages, m_pages), load

@@ -46,13 +46,18 @@ DIGESTS = {
     "sarvam_mla": (
         "13957818c63bcebd0f2da94fe35ddfceeeb28821c4a7b0088cb358186ebcc91e",
         "bc958b5ff90e0df234ce1bd05416ab9bae4ad98928c0ff007dcb90b349ec5adb"),
+    # (PR 51: taken at its own commit)
+    "minicpm_sala": (
+        "6c7369cfb4d351b98d50e06e7d49ad0229da8eb733853a625dbc703bc989f74e",
+        "8e3cdcff8e8ab57f47ef5845ca9049f689e41c9e721e4cb55507c53a6ffd42ac"),
 }
 
 # What the engine reads off a model: the value of a family that does not say
 # otherwise, then what each tiny configuration says.
 READ = {"state_layer_ids": (), "ring_layer_ids": (), "expert_layer_ids": (),
         "block_length": 1, "num_logits_to_keep": 0, "sliding_window": 0,
-        "latent_layer_ids": (), "latent_width": 0}
+        "latent_layer_ids": (), "latent_width": 0, "index_layer_ids": (),
+        "index_segments": 4}
 SAYS = {
     "llama": {},
     "olmo_hybrid": {"state_layer_ids": (0, 1, 2, 4, 5, 6)},
@@ -66,6 +71,8 @@ SAYS = {
     "sarvam_mla": {"latent_layer_ids": (0, 1, 2),
                    "expert_layer_ids": (1, 2), "num_logits_to_keep": 1,
                    "latent_width": 40},
+    "minicpm_sala": {"state_layer_ids": (0, 2, 3), "index_layer_ids": (1,),
+                     "num_logits_to_keep": 1},
 }
 
 
@@ -117,8 +124,10 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
     layers = model.cfg.num_layers
     assert all(0 <= i < layers and type(i) is int for attr in READ
                if attr.endswith("_ids") for i in getattr(model, attr))
-    cache_cfg = PagedCacheConfig(num_pages=9, page_size=8, max_seqs=2,
-                                 max_pages_per_seq=4)
+    # (a page of a family that selects pages is its selection block)
+    cache_cfg = PagedCacheConfig(
+        num_pages=9, page_size=getattr(model.cfg, "block_size", 8),
+        max_seqs=2, max_pages_per_seq=4)
     caches = model.init_cache(cache_cfg)
     assert len(caches) == layers
     for i, entry in enumerate(caches):
@@ -127,11 +136,22 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
             assert entry.shape == (cache_cfg.num_pages, cache_cfg.page_size,
                                    128)
             continue
-        first, second = entry
+        if i in model.state_layer_ids and not isinstance(entry, tuple):
+            # a state without a convolution's tail beside it
+            assert entry.shape[0] == cache_cfg.max_seqs
+            assert entry.dtype == jnp.float32
+            continue
+        first, second, *index = entry
         if i in model.state_layer_ids:
             assert first.shape[0] == second.shape[0] == cache_cfg.max_seqs
             assert second.dtype == jnp.float32
         else:
+            # beside the K/V of a layer that selects pages, a page's
+            # segment means, float32
+            assert [x.shape for x in index] == (
+                [(cache_cfg.num_pages, model.index_segments, first.shape[2])]
+                if i in model.index_layer_ids else [])
+            assert all(x.dtype == jnp.float32 for x in index)
             # K/V pages: the allocator's pool, or `max_seqs` rings of two
             pages = (2 * 2 if i in model.ring_layer_ids
                      else cache_cfg.num_pages)
@@ -153,7 +173,7 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
         assert sharded[0][0].sharding.mesh.shape["tensor"] == 2
 
 
-def test_the_six_without_banks_refuse_lora_in_one_wording():
+def test_the_seven_without_banks_refuse_lora_in_one_wording():
     for name in FAMILIES:
         model = _tiny(name)
         if name == "llama":

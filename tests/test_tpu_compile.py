@@ -79,7 +79,8 @@ def _kernel_names(text):
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "ssm_scan", "ssd_scan",
                                  "moe_gmm", "swa_decode", "swa_flash",
-                                 "mla_decode", "mla_flash")
+                                 "mla_decode", "mla_flash", "sparse_decode",
+                                 "sparse_flash")
                      if k in instruction}
     return held
 
@@ -495,6 +496,81 @@ def test_sarvam_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
     if nb > 1:                                     # no wave's q, k or v
         assert f"[{nb},4096,64,192]" not in text
         assert f"[{nb},4096,12288]" not in text
+
+
+def _sala_pools(model, ec):
+    """(a sparse layer's K pool, its index pool, a lightning layer's state
+    pool) of `sala-long-context`'s cache, as shapes."""
+    from benchmark import sizing
+
+    caches = sizing.cache_shapes(model, ec, None)
+    k_pages, _, m_pages = caches[model.index_layer_ids[0]]
+    return k_pages, m_pages, caches[model.state_layer_ids[0]]
+
+
+def test_sala_decode_walks_chosen_pages_and_updates_its_pools_in_place(
+        topology, monkeypatch):
+    """The chip compiler's HLO of `sala-long-context`'s decode window at the
+    published widths and all sixteen layers: the four sparse layers' steps
+    are `sparse_decode` over lists of 128 entries a row and KV head (a KV
+    head's lanes of a page one DMA), the twelve lightning layers' plain XLA
+    on the state pool; no instruction rewrites a K/V pool, an index pool or
+    a state pool, and nothing has the shape of a row's whole context
+    gathered; it peaks at 11.44 GiB of a v5e's 15.75 (compile, PR 51)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth("sala-long-context")
+    assert len(model.index_layer_ids) == 4 and len(
+        model.state_layer_ids) == 12
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_decode(model, ec, one).compile()
+    peak, _ = sizing.peak_gib(compiled)
+    assert 0.25 * sizing.USABLE_GIB < peak <= 11.437 + 0.05
+    text = compiled.as_text()
+    k_pages, m_pages, state = _sala_pools(model, ec)
+    assert k_pages.shape == (8 * 272 + 1, 64, 256)
+    assert m_pages.shape == (8 * 272 + 1, 4, 256) and state.shape == (
+        8, 32, 128, 128)
+    for pool in (k_pages, m_pages, state):
+        assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert _kernel_names(text) == {"sparse_decode"}
+    # the selection gathers a row's segment means (a sixteenth of its keys,
+    # float32), never its keys or values
+    assert "f32[8,272,4,256]" in text
+    for gathered in ("[8,272,64,256]", "[8,17408,256]", "[8,17408,2,128]"):
+        assert gathered not in text
+
+
+@pytest.mark.parametrize("nb", [1, 8])
+def test_sala_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch, nb):
+    """Prefill of one prompt and of 8 in the 16,384 bucket, the largest
+    program of `sala-long-context`, at all sixteen layers: a row of the wave
+    at a time through every layer (the embedding inside the loop: a wave's
+    hidden states are never an array), the sparse layers through
+    `sparse_flash` under the mask `select_blocks` made, no scores tensor;
+    the pools are written in place after the loop; the head runs on one
+    position a row; the wave peaks at 14.478 GiB of a v5e's 15.75 and one
+    prompt at 11.675 (compile, PR 51), under the issue's 15.0."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth("sala-long-context")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_prefill(model, ec, 16384, nb, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    assert peak <= (14.478 if nb == 8 else 11.675) + 0.005 < 15.0
+    text = compiled.as_text()
+    assert _kernel_names(text) == {"sparse_flash"}
+    k_pages, m_pages, _ = _sala_pools(model, ec)
+    for pool in (k_pages, m_pages):
+        assert _pool_layout_changes(text, math.prod(pool.shape)) == []
+    assert f"[{nb},16384,73448]" not in text and f"f32[{nb},73448]" in text
+    assert "[32,16384,16384]" not in text          # no [heads, q, keys]
+    assert "[32,16384,1024]" not in text           # nor every query's scores
+    assert "f32[2,16,512,1024]" in text            # a tile of 512 queries'
+    if nb > 1:                                     # no wave's hidden states
+        assert f"[{nb},16384,4096]" not in text
 
 
 @pytest.mark.parametrize("tm,tiles,experts,k,n,act", [
