@@ -24,7 +24,9 @@ the norms' and gates' shapes.
                head of q and k, no rotation;  the query at t with t + 1 <
                dense_len: causal softmax over keys 0..t;  else over the keys
                of the `topk` blocks its KV head's group chose
-               (`ops.attention.compressed_scores`, `choose_blocks`);
+               (`ops.attention.compressed_scores`, `chosen_mask`: a block
+               is in where fewer than `topk` blocks beat it, ties to the
+               lower index, found by counting and never by a sort);
                o * sigmoid(u W_g);  W_o
 
 Serving cache, per layer (`Decoder.init_cache`): a lightning layer holds the
@@ -34,12 +36,15 @@ paged K/V from the engine's allocator and beside them the index pool, a
 page's segment means (`ops/paged_attention.py`). A page is a selection
 block. A prefill runs a row of its wave at a time through all the layers
 (the hidden states of 8 x 16,384 positions would be 1 GiB a copy), over the
-row's own q, k, v: `lightning_chunked`, and `select_blocks` with the
-`sparse_flash` kernel, no scores tensor; then the wave's states, keys,
+row's own q, k, v: `lightning_chunked`, and `select_blocks` (the counted
+mask, a tile of 512 queries at a time) with the `sparse_flash` kernel, no
+scores tensor; then the wave's states, keys,
 values and whole segments' means are written. It reads no cached page, so
 the engine shares no prefix for this family. A decode step updates the
 states (`lightning_step`), writes its token's K/V and the segment mean it
-completes, chooses pages (`select_pages`) and walks them (`sparse_decode`).
+completes, chooses pages (`select_pages`: the same mask over a row's own
+pages, listed in ascending order by `choose_blocks`) and walks them
+(`sparse_decode`).
 """
 
 from __future__ import annotations

@@ -637,15 +637,19 @@ def compressed_scores(q, means, t, sizes: SparseSizes, scale: float):
     return jnp.where(seen, r, -1.0)
 
 
-def choose_blocks(r, t, sizes: SparseSizes):
-    """r [..., Q, N] (`compressed_scores`), t [..., Q] -> the `topk` blocks
-    each query attends to, [..., Q, topk] int32 in ascending order (all N /
-    per_block of them where there are fewer). A block's
-    score is the largest r of the compressed keys that overlap it (those that
-    begin in it and the one before); the first `init_blocks` and the
-    `window_blocks` that end with the query's own count as chosen, blocks
-    past the query's own are out, ties go to the lower index. A query with
-    fewer than `topk` blocks behind it lists blocks past its own at the end:
+def chosen_mask(r, t, sizes: SparseSizes):
+    """r [..., Q, N] (`compressed_scores`), t [..., Q] -> [..., Q, NB] bool,
+    NB = N / per_block: the `topk` blocks each query attends to (all of them
+    where there are fewer). A block's score is the largest r of the
+    compressed keys that overlap it (those that begin in it and the one
+    before); the first `init_blocks` and the `window_blocks` that end with
+    the query's own count as chosen (+inf), blocks past the query's own are
+    out (-2, under an unseen key's -1), ties go to the lower index. A block is
+    in iff fewer than `topk` blocks beat it, block i beating j where its
+    score is larger, or equal with i < j: `lax.top_k`'s set, found by
+    counting and not by sorting (the comparisons [..., Q, NB, NB] are one
+    fused reduction and never an array). A query with fewer than `topk` blocks
+    behind it has blocks past its own among its chosen, the lowest first:
     the caller passes such a query by (`t + 1 < dense_len`)."""
     per = sizes.per_block
     blocks = r.shape[-1] // per
@@ -659,8 +663,27 @@ def choose_blocks(r, t, sizes: SparseSizes):
     forced = (block < sizes.init_blocks) | (
         (block <= own) & (block > own - sizes.window_blocks))
     score = jnp.where(forced, jnp.inf, jnp.where(block <= own, score, -2.0))
-    _, chosen = jax.lax.top_k(score, min(sizes.topk, blocks))
-    return jnp.sort(chosen, axis=-1).astype(jnp.int32)
+    rival, mine = score[..., None, :], score[..., :, None]  # [..., Q, j, i]
+    beaten = (rival > mine) | (
+        (rival == mine) & (block[None, :] < block[:, None]))
+    return jnp.sum(beaten, axis=-1, dtype=jnp.int32) < min(sizes.topk, blocks)
+
+
+def choose_blocks(r, t, sizes: SparseSizes):
+    """r [..., Q, N] (`compressed_scores`), t [..., Q] -> the blocks
+    `chosen_mask` says each query attends to as a list, [..., Q, topk] int32
+    in ascending order (all N / per_block of them where there are fewer):
+    entry k is the chosen block with k chosen blocks before it, both counted
+    ([..., Q, NB, NB] and [..., Q, topk, NB] comparisons summed inside their
+    fusions): no sort, no scan and no scatter."""
+    mask = chosen_mask(r, t, sizes)
+    blocks = mask.shape[-1]
+    block = jnp.arange(blocks, dtype=jnp.int32)
+    place = jnp.sum(mask[..., None, :] & (block[None, :] < block[:, None]),
+                    axis=-1, dtype=jnp.int32)                  # [..., Q, NB]
+    entry = jnp.arange(min(sizes.topk, blocks))[:, None]
+    listed = mask[..., None, :] & (place[..., None, :] == entry)
+    return jnp.sum(jnp.where(listed, block, 0), axis=-1)
 
 
 QUERY_TILE = 512
@@ -673,8 +696,11 @@ def select_blocks(q, k, sizes: SparseSizes, scale: Optional[float] = None):
     the blocks up to its own while t + 1 < `dense_len`, its chosen `topk`
     from there on. Queries are scored `QUERY_TILE` at a time (a tile's
     scores are [H, QUERY_TILE, S / kernel_stride] float32), and only the
-    tiles that reach `dense_len`. (A row's padding lies past every real
-    query, and what a padded query chooses is never read.)"""
+    tiles that reach `dense_len`; a tile's part of the result is
+    `chosen_mask` itself, turned keys' blocks down: the `topk` are counted
+    (ties to the lower index) and never listed, so nothing is sorted and
+    nothing scattered. (A row's padding lies past every real query, and
+    what a padded query chooses is never read.)"""
     sizes.check()
     b, s, h, d = q.shape
     hk = k.shape[2]
@@ -702,12 +728,9 @@ def select_blocks(q, k, sizes: SparseSizes, scale: Optional[float] = None):
         qt, start = args
         t = jnp.broadcast_to(start + jnp.arange(QUERY_TILE),
                              (b, hk, QUERY_TILE))
-        chosen = choose_blocks(
+        picked = chosen_mask(
             compressed_scores(qt, means, t, sizes, scale), t, sizes)
-        picked = jnp.zeros((b, hk, QUERY_TILE, nb), jnp.int32)
-        picked = jnp.put_along_axis(picked, chosen, 1, axis=-1,
-                                    inplace=False)
-        return picked.transpose(0, 1, 3, 2)                 # [B,Hkv,NB,Q]
+        return picked.astype(jnp.int32).transpose(0, 1, 3, 2)  # [B,Hkv,NB,Q]
 
     picked = jax.lax.map(tile, (qp, first + jnp.arange(tiles) * QUERY_TILE))
     picked = picked.transpose(1, 2, 3, 0, 4).reshape(

@@ -704,12 +704,13 @@ def select_pages(q: jax.Array, m_pages: jax.Array, page_table: jax.Array,
     `sizes` an `ops.attention.SparseSizes` whose block is a page. A row
     shorter than `dense_len` lists its own pages in order; a longer one, for
     each KV head, the `topk` pages its group chooses (`compressed_scores`,
-    `choose_blocks` over the row's own pages' segment means), in ascending
-    order. -> (pages [B,HK,L] physical, counts [B,HK,L] valid tokens of
-    each, used [B,HK] entries in use, load [2] int32 = (pages_selected,
-    pages_visible): sums over the `active` rows and KV heads of `used` and of
-    ceil(len / page)), L = max(topk, dense_len / page): one shape, so one
-    decode program for both kinds of row."""
+    `choose_blocks` over the row's own pages' segment means: a page is in
+    where fewer than `topk` beat it, ties to the lower index, counted and
+    not sorted), in ascending order. -> (pages [B,HK,L] physical, counts
+    [B,HK,L] valid tokens of each, used [B,HK] entries in use, load [2]
+    int32 = (pages_selected, pages_visible): sums over the `active` rows and
+    KV heads of `used` and of ceil(len / page)), L = max(topk, dense_len /
+    page): one shape, so one decode program for both kinds of row."""
     b, h, d = q.shape
     _, per, width = m_pages.shape
     hk = width // d

@@ -152,9 +152,9 @@ def test_engine_in_bf16_stays_within_its_tolerance():
 
 
 def _forced_left_out(monkeypatch):
-    choose = attention_ops.choose_blocks
+    mask = attention_ops.chosen_mask
     monkeypatch.setattr(
-        attention_ops, "choose_blocks", lambda r, t, sizes: choose(
+        attention_ops, "chosen_mask", lambda r, t, sizes: mask(
             r, t, sizes._replace(init_blocks=0,
                                  window_size=sizes.block_size)))
 
@@ -309,6 +309,82 @@ def test_a_table_of_fewer_pages_than_topk_lists_a_rows_own():
     assert [int(p) for p in pages[0, 1, :3]] == [2, 4, 1]
     assert [int(c) for c in counts[0, 1]] == [16, 16, 8, 0]
     assert used.tolist() == [[3, 3]]
+
+
+def _sorted_choose_blocks(r, t, sizes):
+    """`choose_blocks` as a sort (what it was until the mask was counted):
+    the block scores through `lax.top_k`, the chosen put back in ascending
+    order. The oracle of the counted form."""
+    per = sizes.per_block
+    blocks = r.shape[-1] // per
+    grouped = r.reshape(r.shape[:-1] + (blocks, per))
+    before = jnp.concatenate(
+        [jnp.full_like(grouped[..., :1, -1], -1.0), grouped[..., :-1, -1]],
+        axis=-1)
+    score = jnp.maximum(jnp.max(grouped, axis=-1), before)
+    block = jnp.arange(blocks)
+    own = (t // sizes.block_size)[..., None]
+    forced = (block < sizes.init_blocks) | (
+        (block <= own) & (block > own - sizes.window_blocks))
+    score = jnp.where(forced, jnp.inf, jnp.where(block <= own, score, -2.0))
+    _, chosen = jax.lax.top_k(score, min(sizes.topk, blocks))
+    return jnp.sort(chosen, axis=-1).astype(jnp.int32)
+
+
+SERVED = SparseSizes()   # the published selection: 64 of 256 and more blocks
+# name -> (sizes, r's shape [..., Q, N], the queries' positions [..., Q])
+SELECTIONS = {
+    # dense_len - 1 and dense_len (a block's first key), a block's last key
+    # and the next block's first, a segment's end, the last position
+    "tiny": (SIZES, (2, 2, 7, 44),
+             jnp.asarray([63, 64, 79, 80, 151, 174, 175])),
+    # fewer than `topk` blocks behind the query (blocks past its own fill
+    # the list, the lowest first), and fewer than `topk` in the table
+    "tiny-short": (SIZES, (1, 2, 3, 44), jnp.asarray([5, 20, 47])),
+    "tiny-narrow": (SIZES, (1, 2, 2, 12), jnp.asarray([5, 40])),
+    # the prefill's first selecting tile (dense_len - 1 is its last query)
+    # and its last (the last position) at 16,384: NB 256, 512 queries
+    "tile-first": (SERVED, (1, 2, 512, 1024), 7680 + jnp.arange(512)),
+    "tile-last": (SERVED, (1, 2, 512, 1024), 15872 + jnp.arange(512)),
+    # a decode step's 8 rows over tables of 272 pages, one query each
+    "decode": (SERVED, (8, 2, 1, 1088), jnp.asarray(
+        [8191, 8192, 8255, 8256, 12345, 16383, 17343, 17407])[:, None, None]),
+}
+
+
+@pytest.mark.parametrize("scores", ["continuous", "eighths", "equal"])
+@pytest.mark.parametrize("case", list(SELECTIONS))
+def test_the_counted_selection_is_the_sorted_one(case, scores):
+    """`choose_blocks` (a rank by counting, then the mask's compaction)
+    against `lax.top_k` and a sort, element for element: over continuous
+    scores, scores quantised to eighths (ties at the `topk`-th place and
+    everywhere else) and scores all equal (the lowest indices win), with
+    the unseen compressed keys at -1 as `compressed_scores` leaves them;
+    and `chosen_mask` is that list's membership, `topk` ones a query."""
+    sizes, shape, t = SELECTIONS[case]
+    t = jnp.broadcast_to(t, shape[:-1])
+    r = jax.random.uniform(jax.random.PRNGKey(len(case)), shape)
+    if scores == "eighths":
+        r = jnp.round(r * 8) / 8
+    elif scores == "equal":
+        r = jnp.full(shape, 0.25)
+    seen = (jnp.arange(shape[-1]) * sizes.kernel_stride
+            + sizes.kernel_size - 1 <= t[..., None])
+    r = jnp.where(seen, r, -1.0)
+    want = np.asarray(_sorted_choose_blocks(r, t, sizes))
+    listed = min(sizes.topk, shape[-1] // sizes.per_block)
+    got = attention_ops.choose_blocks(r, t, sizes)
+    assert got.dtype == jnp.int32 and got.shape == shape[:-1] + (listed,)
+    assert np.array_equal(np.asarray(got), want)
+    mask = np.asarray(attention_ops.chosen_mask(r, t, sizes))
+    assert mask.dtype == bool and (mask.sum(-1) == listed).all()
+    assert np.take_along_axis(mask, want, -1).all()
+    # the forced blocks are in: the first, and the window's up to the own
+    own = np.asarray(t) // sizes.block_size
+    assert mask[..., 0].all()
+    for back in range(sizes.window_blocks):
+        at = np.maximum(own - back, 0)[..., None]
+        assert np.take_along_axis(mask, at, -1).all()
 
 
 def test_select_blocks_is_the_references_choice_a_position():

@@ -508,6 +508,34 @@ def _sala_pools(model, ec):
     return k_pages, m_pages, caches[model.state_layer_ids[0]]
 
 
+def _outside_fusions(text):
+    """A compiled program's text without its fusions' bodies: what is left
+    names the arrays the program holds and the instructions it launches."""
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", text))
+    kept, body = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and line.split(" ", 1)[0] in fused:
+            body = True
+        if not body:
+            kept.append(line)
+        if line == "}":
+            body = False
+    return "\n".join(kept)
+
+
+def _selection_sorts_nothing(text, pairs):
+    """The selection counts (`ops.attention.chosen_mask`): the program sorts
+    no scores and no list (the one `sort` a wave's prefill holds is the
+    compiler's own, over the 131,072 indices of a pool's scatter, one
+    dimension), and the comparisons of every block with every other, `pairs`,
+    live inside a fusion and are never an array."""
+    for line in text.splitlines():
+        if " sort(" in line:
+            assert all("," not in dims for dims in re.findall(
+                r"\w+\[([\d,]*)\]", line.split(" sort(")[0])), line[:200]
+    assert pairs in text and pairs not in _outside_fusions(text)
+
+
 def test_sala_decode_walks_chosen_pages_and_updates_its_pools_in_place(
         topology, monkeypatch):
     """The chip compiler's HLO of `sala-long-context`'s decode window at the
@@ -516,7 +544,10 @@ def test_sala_decode_walks_chosen_pages_and_updates_its_pools_in_place(
     head's lanes of a page one DMA), the twelve lightning layers' plain XLA
     on the state pool; no instruction rewrites a K/V pool, an index pool or
     a state pool, and nothing has the shape of a row's whole context
-    gathered; it peaks at 11.44 GiB of a v5e's 15.75 (compile, PR 51)."""
+    gathered; the 64 pages are found by counting, so the program holds no
+    `sort` and the comparisons `[8,2,1,272,272]` only inside a fusion; it
+    peaks at 11.435 GiB of a v5e's 15.75 (compile, PR 52; 11.437 with the
+    sorts, PR 51)."""
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -540,6 +571,7 @@ def test_sala_decode_walks_chosen_pages_and_updates_its_pools_in_place(
     assert "f32[8,272,4,256]" in text
     for gathered in ("[8,272,64,256]", "[8,17408,256]", "[8,17408,2,128]"):
         assert gathered not in text
+    _selection_sorts_nothing(text, "[8,2,1,272,272]")
 
 
 @pytest.mark.parametrize("nb", [1, 8])
@@ -548,10 +580,13 @@ def test_sala_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch, nb):
     program of `sala-long-context`, at all sixteen layers: a row of the wave
     at a time through every layer (the embedding inside the loop: a wave's
     hidden states are never an array), the sparse layers through
-    `sparse_flash` under the mask `select_blocks` made, no scores tensor;
-    the pools are written in place after the loop; the head runs on one
-    position a row; the wave peaks at 14.478 GiB of a v5e's 15.75 and one
-    prompt at 11.675 (compile, PR 51), under the issue's 15.0."""
+    `sparse_flash` under the mask `select_blocks` made, no scores tensor,
+    and the mask by counting: no `sort`, a tile's comparisons
+    `[1,2,512,256,256]` only inside a fusion; the pools are written in place
+    after the loop; the head runs on one position a row; the wave peaks at
+    14.478 GiB of a v5e's 15.75 and one prompt at 11.674 (compile, PR 52:
+    14.4776 and 11.6743; with the sorts 14.4783 and 11.6749, PR 51), under
+    the issue's 15.0."""
     from benchmark import sizing
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -569,6 +604,7 @@ def test_sala_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch, nb):
     assert "[32,16384,16384]" not in text          # no [heads, q, keys]
     assert "[32,16384,1024]" not in text           # nor every query's scores
     assert "f32[2,16,512,1024]" in text            # a tile of 512 queries'
+    _selection_sorts_nothing(text, "[1,2,512,256,256]")
     if nb > 1:                                     # no wave's hidden states
         assert f"[{nb},16384,4096]" not in text
 
