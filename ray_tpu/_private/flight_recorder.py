@@ -196,6 +196,82 @@ def mark(name: str, **args) -> None:
     _record_span(name, time.time(), None, args)
 
 
+class _Laps:
+    """What `laps` returns: nanoseconds a part, and the last lap's end."""
+    __slots__ = ("ns", "_t")
+
+    def __init__(self, parts):
+        self.ns = dict.fromkeys(parts, 0)
+        self._t = time.perf_counter_ns()
+
+    def lap(self, part: str) -> int:
+        now = time.perf_counter_ns()
+        d = now - self._t
+        self._t = now
+        self.ns[part] += d
+        return d
+
+    def ms(self) -> Dict[str, float]:
+        return {f"{part}_ms": ns / 1e6 for part, ns in self.ns.items()}
+
+
+class _NoLaps:
+    """The shared no-op `laps` returns with the recorder disabled."""
+    __slots__ = ()
+
+    def lap(self, part: str) -> int:
+        return 0
+
+    def ms(self) -> Dict[str, float]:
+        return {}
+
+
+_NO_LAPS = _NoLaps()
+
+
+def laps(*parts: str):
+    """For a loop too hot for a span an iteration (a stream's items): a
+    thread's time split among `parts`. `.lap(part)` gives `part` the time
+    since the lap before it (the first, since this call) and returns it,
+    so the parts sum to the whole; `.ms()` is `{part_ms: ...}`, the
+    arguments of the one mark the loop ends in."""
+    if not _ENABLED:
+        return _NO_LAPS
+    return _Laps(parts)
+
+
+# --------------------------------------------------------------------------
+# What the thread executing a task knows of it beyond its spec: when this
+# process received the call (the `dispatch` phase's start) and, once the
+# serve replica's handler has read it out of the call's context, the id of
+# the request it serves. Marks carry that id as `rid`, so one string joins
+# a request's events in every process it crosses; it is handed on whether
+# the recorder is on or not.
+# --------------------------------------------------------------------------
+
+_task = threading.local()
+
+
+def enter_task(entry_ns: int) -> None:
+    """Executor thread, before user code runs: the worker's receipt stamp
+    of the call (0 when it took none), and no request named yet."""
+    _task.entry_ns = entry_ns
+    _task.rid = ""
+
+
+def task_entry_ns() -> int:
+    return getattr(_task, "entry_ns", 0)
+
+
+def set_request_id(rid: str) -> None:
+    _task.rid = rid
+
+
+def request_id() -> str:
+    """The id of the request this thread is serving; "" outside one."""
+    return getattr(_task, "rid", "")
+
+
 # --------------------------------------------------------------------------
 # Wire accounting: per-(kind, lane) tx/rx counters fed from rpc.py's frame
 # build/read paths. Row layout keeps hot-path code to list-index increments.

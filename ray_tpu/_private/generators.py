@@ -12,19 +12,28 @@ store, borrow protocol, and `ray.get` work on them unchanged."""
 from __future__ import annotations
 
 import asyncio
-from typing import Optional
+from typing import Dict, Optional
 
 
 class GeneratorState:
     """Owner-side progress of one streaming task."""
 
-    __slots__ = ("count", "reported", "consumed", "event")
+    __slots__ = ("count", "reported", "consumed", "event", "landed",
+                 "held_ns", "held_max_ns", "starved_ns")
 
     def __init__(self):
         self.count: Optional[int] = None  # total items, known at end
         self.reported = 0  # items the executor has shipped
         self.consumed = 0  # items the local consumer has pulled
         self.event = asyncio.Event()
+        # With the flight recorder on (all on the owner's loop, one clock):
+        # when each unconsumed item landed, how long items lay here before
+        # the consumer asked (its sum and its worst), and how long the
+        # consumer waited for items that had not landed.
+        self.landed: Dict[int, int] = {}
+        self.held_ns = 0
+        self.held_max_ns = 0
+        self.starved_ns = 0
 
     def pulse(self) -> None:
         self.event.set()

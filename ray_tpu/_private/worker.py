@@ -2727,6 +2727,9 @@ class Worker:
         t_entry = time.perf_counter_ns() if _fr._ENABLED else 0
         decoded = [deser_spec(s) if isinstance(s, bytes) else s
                    for s in specs]
+        if t_entry:
+            for s in decoded:
+                s.__dict__["_t_entry"] = t_entry  # as _sched_key: unshipped
         loop = asyncio.get_running_loop()
 
         def is_batchable_sync(spec: TaskSpec):
@@ -2788,6 +2791,7 @@ class Worker:
             t0 = time.perf_counter_ns()
             if isinstance(spec, (bytes, bytearray, memoryview)):
                 spec = deser_spec(spec)
+            spec.__dict__["_t_entry"] = t_entry
             t1 = time.perf_counter_ns()
             reply = await self._rpc_push_actor_task_decoded(spec)
             t2 = time.perf_counter_ns()
@@ -2797,6 +2801,7 @@ class Worker:
             return reply
         if isinstance(spec, (bytes, bytearray, memoryview)):
             spec = deser_spec(spec)
+        spec.__dict__["_t_entry"] = t_entry
         reply = await self._rpc_push_actor_task_decoded(spec)
         if t_entry and isinstance(reply, dict):
             reply["_frs"] = time.perf_counter_ns() - t_entry
@@ -2886,6 +2891,7 @@ class Worker:
             args, kwargs = self._resolve_spec_args_sync(spec)
             args_ready_ts = time.time()
             self._current_task_id = spec.task_id
+            _fr.enter_task(spec.__dict__.get("_t_entry", 0))
             t_exec = time.perf_counter_ns() if _fr._ENABLED else 0
             result = method(*args, **kwargs)
             t_done = time.perf_counter_ns() if t_exec else 0
@@ -3062,19 +3068,40 @@ class Worker:
                     ser.METADATA_ERROR, [item[1]], []))
             self.ref_counter.add_owned_ref(oid)
             st.reported = max(st.reported, index + 1)
+            if _fr._ENABLED:
+                st.landed[index] = time.perf_counter_ns()
         if count is not None:
             st.count = count
         st.pulse()
-        return {"unconsumed": st.reported - st.consumed}
+        if not _fr._ENABLED:
+            return {"unconsumed": st.reported - st.consumed}
+        # The owner's account of the stream so far rides the reply the
+        # producer blocks on anyway: it ends in `ray_tpu.stream.sent`.
+        return {"unconsumed": st.reported - st.consumed,
+                "held_ns": st.held_ns, "held_max_ns": st.held_max_ns,
+                "starved_ns": st.starved_ns}
 
     async def gen_next(self, task_id: TaskID,
                        idx: int) -> Optional[ObjectID]:
         """Owner side: wait until item idx exists (returns its ObjectID) or
-        the stream is known to have ended before idx (returns None)."""
+        the stream is known to have ended before idx (returns None). One
+        clock read says who was late: an item that lay here first was held
+        for the consumer, a consumer that asked first was starved by the
+        producer until the item landed."""
         st = self._gen_state(task_id)
+        asked = time.perf_counter_ns() if _fr._ENABLED else 0
         while True:
             if idx < st.reported:
                 st.consumed = max(st.consumed, idx + 1)
+                landed = st.landed.pop(idx, 0)
+                if landed and asked:
+                    if landed <= asked:
+                        held = asked - landed
+                        st.held_ns += held
+                        if held > st.held_max_ns:
+                            st.held_max_ns = held
+                    else:
+                        st.starved_ns += landed - asked
                 return ObjectID.for_task_return(task_id, idx)
             if st.count is not None and idx >= st.count:
                 return None
@@ -3084,31 +3111,66 @@ class Worker:
         """Executor side: ship each yielded value to the owner as its own
         object. Runs on the task executor thread; every report is a blocking
         RPC (transport backpressure) plus a pause while the owner holds too
-        many unconsumed items."""
+        many unconsumed items.
+
+        The thread's time is split where it goes (inside the user's
+        generator, serializing, blocked on the owner's reply, paused):
+        clock reads and additions per item, and one `ray_tpu.stream.sent`
+        mark a stream, which also carries the owner's account of the same
+        stream as its last reply gave it."""
         cfg = get_config()
         owner = tuple(spec.owner_address)
         idx = 0
+        laps = _fr.laps("body", "serialize", "report", "paused")
+        nbytes = report_max = unconsumed_max = 0
+        last: Dict[str, Any] = {}  # the owner's newest reply
+
+        def too_many(reply) -> bool:
+            """The owner holds more unconsumed items than it should."""
+            return (reply is not None and reply.get("unconsumed", 0)
+                    > cfg.generator_backpressure_num_objects)
+
         try:
             for value in gen:
+                laps.lap("body")
                 obj = ser.serialize(value)
-                if obj.total_bytes() > cfg.max_inline_object_size:
+                size = obj.total_bytes()
+                if size > cfg.max_inline_object_size:
                     oid = ObjectID.for_task_return(spec.task_id, idx)
                     self.put_shm_or_spill(oid, obj)
                     item: Tuple = ("shm", self.node_id.binary())
                 else:
                     item = ("inline", obj.metadata,
                             ser.wire_buffers(obj.buffers))
+                nbytes += size
+                laps.lap("serialize")
                 reply = self._send_gen_item(owner, spec.task_id, idx, item)
                 idx += 1
-                while (reply is not None and reply.get("unconsumed", 0)
-                        > cfg.generator_backpressure_num_objects):
-                    time.sleep(0.02)
-                    reply = self._send_gen_item(owner, spec.task_id, None,
-                                                None)
+                report_max = max(report_max, laps.lap("report"))
+                if reply is not None:
+                    unconsumed_max = max(unconsumed_max,
+                                         reply.get("unconsumed", 0))
+                if too_many(reply):
+                    while too_many(reply):
+                        time.sleep(0.02)
+                        reply = self._send_gen_item(owner, spec.task_id,
+                                                    None, None)
+                    laps.lap("paused")
+                last = reply or last
+            laps.lap("body")
         except BaseException as e:  # noqa: BLE001
+            laps.lap("body")
             err = self._error_result(e)
-            self._send_gen_item(owner, spec.task_id, idx, err)
+            last = self._send_gen_item(owner, spec.task_id, idx, err) or last
             idx += 1
+            laps.lap("report")
+        _fr.mark("ray_tpu.stream.sent", rid=_fr.request_id(),
+                 task=spec.task_id.hex(), items=idx, bytes=nbytes,
+                 report_max_ms=report_max / 1e6,
+                 unconsumed_max=unconsumed_max,
+                 held_ms=last.get("held_ns", 0) / 1e6,
+                 held_max_ms=last.get("held_max_ns", 0) / 1e6,
+                 starved_ms=last.get("starved_ns", 0) / 1e6, **laps.ms())
         return {"results": [], "generator_count": idx}
 
     def _send_gen_item(self, owner: Tuple[str, int], task_id: TaskID,

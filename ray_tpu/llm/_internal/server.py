@@ -219,14 +219,20 @@ class LLMServer:
         An engine step's tokens arrive together, and `more` on each dict
         says how many of them are still behind it: a consumer that writes
         somewhere sends what it has when `more` is 0. The first dict
-        carries `ttft_s` and the engine's id of the request (`rid`), the
-        last one `delivered_s`, the time.perf_counter() at which the engine
-        loop handed over the step that finished the request."""
-        rid = uuid.uuid4().hex[:12]
+        carries the engine's id of the request (`rid`), the last one
+        `delivered_s`, the time.perf_counter() at which the engine loop
+        handed over the step that finished the request.
+
+        The engine's id is the request's own where this thread serves one
+        (the serve replica names it: the id the proxy made, the `rid` of
+        every mark on the request's path), else one made here; a second
+        request under an id still running gets a suffix."""
+        rid = _fr.request_id() or uuid.uuid4().hex[:12]
         q: "queue.Queue" = queue.Queue()
         with self._lock:
+            if rid in self._queues:
+                rid = f"{rid}-{uuid.uuid4().hex[:6]}"
             self._queues[rid] = q
-        t0 = time.perf_counter()
         self._pending.put(Request(rid, list(prompt_ids),
                                   max_tokens=max_tokens,
                                   temperature=temperature,
@@ -249,7 +255,6 @@ class LLMServer:
                         out["logprob"] = so.logprob
                         out["top_logprobs"] = so.top_logprobs
                     if first:
-                        out["ttft_s"] = time.perf_counter() - t0
                         out["rid"] = rid
                         first = False
                     finished = so.finished
@@ -274,7 +279,6 @@ class LLMServer:
         toks = []
         lps: List[Any] = []
         tops: List[Any] = []
-        ttft = None
         for item in self.generate(prompt_ids, max_tokens, temperature,
                                   stop_token, lora_id, top_p, top_k,
                                   seed, logprobs):
@@ -282,8 +286,7 @@ class LLMServer:
             if "logprob" in item:
                 lps.append(item["logprob"])
                 tops.append(item["top_logprobs"])
-            ttft = ttft if ttft is not None else item.get("ttft_s")
-        out = {"tokens": toks, "ttft_s": ttft}
+        out: Dict[str, Any] = {"tokens": toks}
         if lps:
             out["logprobs"] = lps
             out["top_logprobs"] = tops

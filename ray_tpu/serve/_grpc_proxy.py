@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 from typing import Any, Dict, Optional
 
 import ray_tpu
@@ -23,6 +24,7 @@ from ray_tpu.exceptions import (
     unwrap_backpressure,
 )
 from ray_tpu.serve._common import CONTROLLER_NAME
+from ray_tpu.serve._proxy import RequestAccount
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -44,6 +46,14 @@ def _grpc_overload_status(e: BaseException):
             getattr(e, "cause", None), RayActorError):
         return grpc.StatusCode.UNAVAILABLE, "replica_died"
     return None, None
+
+
+def _client_request_id(context) -> str:
+    """The client's `x-request-id` metadata, "" without one."""
+    for key, value in context.invocation_metadata() or ():
+        if key == "x-request-id":
+            return value if isinstance(value, str) else value.decode()
+    return ""
 
 
 def _decode_payload(request) -> Any:
@@ -75,6 +85,7 @@ class GrpcProxyActor:
         self._deployments: Dict[str, Any] = {}  # name -> routing info
         self._version = -1
         self._server = None
+        self._streams = 0  # PredictStream calls open
         # deployment -> sheds since the last delivered ingress report.
         self._shed_accum: Dict[str, int] = {}
         from ray_tpu.util import metrics as um
@@ -109,20 +120,28 @@ class GrpcProxyActor:
                 loop = asyncio.get_running_loop()
                 name = handle.deployment_name
                 timeout_s = proxy._timeout_for(name)
+                acct = RequestAccount(_client_request_id(context), False,
+                                      proxy._streams)
+                handle = handle._for_request(acct.rid, acct.t_read)
                 try:
                     payload = _decode_payload(request)
                     out = await asyncio.wait_for(
-                        loop.run_in_executor(
-                            None, lambda: handle.remote(payload).result(
-                                timeout=timeout_s)),
+                        loop.run_in_executor(None, acct.pooled(
+                            lambda: handle.remote(payload).result(
+                                timeout=timeout_s))),
                         timeout_s + 5.0)
+                    acct.took_item()
+                    acct.status = "OK"
+                    return _encode_payload(out, pb)
                 except Exception as e:  # noqa: BLE001
                     code, reason = _grpc_overload_status(e)
-                    if code is not None:
+                    code = code or grpc.StatusCode.INTERNAL
+                    acct.status = code.name
+                    if reason is not None:
                         proxy._shed(name, reason)
-                        await context.abort(code, repr(e))
-                    await context.abort(grpc.StatusCode.INTERNAL, repr(e))
-                return _encode_payload(out, pb)
+                    await context.abort(code, repr(e))
+                finally:
+                    acct.close(handle)
 
             async def PredictStream(self, request, context):
                 handle = await proxy._resolve(request.application)
@@ -132,35 +151,52 @@ class GrpcProxyActor:
                         f"no application {request.application!r}")
                 loop = asyncio.get_running_loop()
                 name = handle.deployment_name
+                acct = RequestAccount(_client_request_id(context), True,
+                                      proxy._streams)
+                handle = handle.options(stream=True)._for_request(
+                    acct.rid, acct.t_read)
                 payload = _decode_payload(request)
-                gen = await loop.run_in_executor(
-                    None,
-                    lambda: handle.options(stream=True).remote(payload))
-                it = iter(gen)
-                _END = object()
+                proxy._streams += 1
+                try:
+                    gen = await loop.run_in_executor(None, handle.remote,
+                                                     payload)
+                    it = iter(gen)
+                    _END = object()
 
-                def _next():
-                    try:
-                        return next(it)
-                    except StopIteration:
-                        return _END
+                    def _next():
+                        try:
+                            item = next(it)
+                        except StopIteration:
+                            return _END
+                        acct.took_item()
+                        return item
 
-                first = True
-                while True:
-                    try:
-                        item = await asyncio.wait_for(
-                            loop.run_in_executor(None, _next),
-                            proxy._timeout_for(name) + 5.0)
-                    except Exception as e:  # noqa: BLE001
-                        code, reason = _grpc_overload_status(e)
-                        if code is not None and first:
-                            proxy._shed(name, reason)
-                            await context.abort(code, repr(e))
-                        raise
-                    if item is _END:
-                        return
-                    first = False
-                    yield _encode_payload(item, pb)
+                    while True:
+                        try:
+                            item = await asyncio.wait_for(
+                                loop.run_in_executor(
+                                    None, acct.pooled(_next)),
+                                proxy._timeout_for(name) + 5.0)
+                        except Exception as e:  # noqa: BLE001
+                            code, reason = _grpc_overload_status(e)
+                            acct.status = (
+                                code or grpc.StatusCode.UNKNOWN).name
+                            if code is not None and not acct.items:
+                                proxy._shed(name, reason)
+                                await context.abort(code, repr(e))
+                            raise
+                        if item is _END:
+                            acct.status = "OK"
+                            return
+                        reply = _encode_payload(item, pb)
+                        t_write = time.perf_counter_ns()
+                        yield reply  # back here when gRPC has taken it
+                        acct.wrote(len(reply.payload), t_write)
+                finally:
+                    # 0 still: the client cancelled, the handler was closed
+                    acct.status = acct.status or "CANCELLED"
+                    proxy._streams -= 1
+                    acct.close(handle)
 
             async def ListApplications(self, request, context):
                 await proxy._force_refresh()

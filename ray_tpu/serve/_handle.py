@@ -11,6 +11,7 @@ from __future__ import annotations
 import random
 import threading
 import time
+import uuid
 from typing import Any, Dict, Optional
 
 import ray_tpu
@@ -50,9 +51,7 @@ class _RouterCache:
         # report delivered to the controller — piggybacked on the
         # long-poll as part of the autoscaling signal.
         self.shed_delta = 0
-        import uuid as _uuid
-
-        self.reporter = "handle:" + _uuid.uuid4().hex[:8]
+        self.reporter = "handle:" + uuid.uuid4().hex[:8]
         # Multiplexing affinity: model_id -> replica_id last used for it
         # (reference: the router prefers replicas with the model loaded).
         self.model_replica: Dict[str, str] = {}
@@ -105,6 +104,11 @@ class DeploymentResponse:
     def ref(self):
         return self._ref
 
+    @property
+    def request_id(self) -> str:
+        """The `rid` of this request's marks in every process it crosses."""
+        return self._handle._request_id
+
     def __del__(self):
         self._finish()
 
@@ -122,6 +126,11 @@ class DeploymentResponseGenerator:
         self._call_args = call_args
         self._call_kwargs = call_kwargs or {}
         self._done = False
+
+    @property
+    def request_id(self) -> str:
+        """The `rid` of this request's marks in every process it crosses."""
+        return self._handle._request_id
 
     def __iter__(self):
         attempts = 0
@@ -163,31 +172,46 @@ class DeploymentResponseGenerator:
 
 class DeploymentHandle:
     def __init__(self, deployment_name: str, method_name: str = "__call__",
-                 stream: bool = False, multiplexed_model_id: str = ""):
+                 stream: bool = False, multiplexed_model_id: str = "",
+                 _cache: Optional[_RouterCache] = None):
         self.deployment_name = deployment_name
         self._method_name = method_name
         self._stream = stream
         self._multiplexed_model_id = multiplexed_model_id
-        self._cache = _RouterCache()
+        # Variants of one handle (`options`) share its router state.
+        self._cache = _cache or _RouterCache()
+        # One request's variant (`_for_request`) alone has these: the id its
+        # marks carry in every process, time.perf_counter() when the request
+        # was read, and, once submitted, its `pre_ms`.
+        self._request_id = ""
+        self._t_read = 0.0
+        self._pre_ms = 0.0
 
     # -- fluent API (reference: handle.options / method access) ----------
     def options(self, *, method_name: Optional[str] = None,
                 stream: Optional[bool] = None,
                 multiplexed_model_id: Optional[str] = None
                 ) -> "DeploymentHandle":
-        h = DeploymentHandle(
+        return DeploymentHandle(
             self.deployment_name,
             method_name if method_name is not None else self._method_name,
             self._stream if stream is None else stream,
             self._multiplexed_model_id if multiplexed_model_id is None
-            else multiplexed_model_id)
-        h._cache = self._cache  # share router state across variants
-        return h
+            else multiplexed_model_id, self._cache)
 
     def __getattr__(self, name: str) -> "DeploymentHandle":
         if name.startswith("_"):
             raise AttributeError(name)
         return self.options(method_name=name)
+
+    def _for_request(self, request_id: str,
+                     t_read: float) -> "DeploymentHandle":
+        """This handle for one request: a proxy gives the id it made (or its
+        client's) and when it read the request; `remote` on a bare handle
+        makes both. Every attempt of the request goes out under them."""
+        h = self.options()
+        h._request_id, h._t_read = request_id, t_read
+        return h
 
     # -- routing ---------------------------------------------------------
     # The controller PUSHES table changes through a long-poll kept open by
@@ -350,8 +374,13 @@ class DeploymentHandle:
         """One pick+submit attempt; outstanding[rid] is incremented and the
         caller owns decrementing it when the call completes."""
         rid, actor = self._pick_replica(args, kwargs, wait_deadline)
-        ctx = ({"multiplexed_model_id": self._multiplexed_model_id}
-               if self._multiplexed_model_id else None)
+        # The request's way in as far as this process saw it: read (parse,
+        # the proxy's executor hop, the pick above) to handed to the
+        # runtime. The replica states it in `ray_tpu.request.arrived`.
+        self._pre_ms = (time.perf_counter() - self._t_read) * 1e3
+        ctx = {"rid": self._request_id, "pre_ms": self._pre_ms}
+        if self._multiplexed_model_id:
+            ctx["multiplexed_model_id"] = self._multiplexed_model_id
         try:
             if self._stream:
                 out = actor.handle_request.options(
@@ -366,10 +395,12 @@ class DeploymentHandle:
             raise
 
     def remote(self, *args, **kwargs):
-        rid, out = self._invoke_once(args, kwargs)
-        if self._stream:
-            return DeploymentResponseGenerator(out, self, rid, args, kwargs)
-        return DeploymentResponse(out, self, rid, args, kwargs)
+        h = self if self._request_id else self._for_request(
+            uuid.uuid4().hex[:12], time.perf_counter())
+        rid, out = h._invoke_once(args, kwargs)
+        if h._stream:
+            return DeploymentResponseGenerator(out, h, rid, args, kwargs)
+        return DeploymentResponse(out, h, rid, args, kwargs)
 
     # -- backpressure retry (the handle's bounded pending queue) ---------
     def _enter_queue(self, first_exc: Exception) -> None:

@@ -24,9 +24,11 @@ from __future__ import annotations
 import asyncio
 import json
 import time
-from typing import Any, Dict, Optional, Tuple
+import uuid
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import ray_tpu
+from ray_tpu._private import flight_recorder as _fr
 from ray_tpu.exceptions import (
     GetTimeoutError,
     NoHealthyReplicasError,
@@ -54,6 +56,66 @@ DEFAULT_REQUEST_TIMEOUT_S = 60.0
 _RETRY_AFTER = b"retry-after: 1\r\n"
 
 
+class RequestAccount:
+    """What one request cost in a proxy (HTTP or gRPC), stated once, in the
+    mark `ray_tpu.proxy.request`, when its response is written or has
+    failed. Per item it reads the clock and adds; a ring event an item
+    would push everything else out of the recorder's ring. All durations
+    are of this process's clock; the request's id (`rid`: the client's
+    `x-request-id`, else made here) is what joins the mark to the
+    replica's `ray_tpu.request.*` and `ray_tpu.stream.sent`."""
+
+    def __init__(self, request_id: str, stream: bool, open_streams: int):
+        self.t_read = time.perf_counter()
+        self.rid = request_id[:64] or uuid.uuid4().hex[:12]
+        self.stream = stream
+        self.open_streams = open_streams  # besides this one, at the read
+        self.status: Any = 0  # what the client was answered; 0: nothing
+        self.items = self.bytes = 0
+        self.first_item_ms = 0.0
+        self.pool_wait_ns = self.pool_wait_max_ns = 0
+        self.next_ns = self.write_ns = 0
+
+    def pooled(self, fn: Callable[[], Any]) -> Callable[[], Any]:
+        """`fn` for `run_in_executor`: how long it waited for one of the
+        pool's threads (made here, on the loop, to begun there) and how
+        long it then ran (`gen_next` and `get` of one item). A request has
+        one such call in flight, so the two threads never add at once."""
+        t_asked = time.perf_counter_ns()
+
+        def run():
+            t_begun = time.perf_counter_ns()
+            wait = t_begun - t_asked
+            self.pool_wait_ns += wait
+            if wait > self.pool_wait_max_ns:
+                self.pool_wait_max_ns = wait
+            try:
+                return fn()
+            finally:
+                self.next_ns += time.perf_counter_ns() - t_begun
+
+        return run
+
+    def took_item(self) -> None:
+        if not self.items:
+            self.first_item_ms = (time.perf_counter() - self.t_read) * 1e3
+        self.items += 1
+
+    def wrote(self, nbytes: int, t_begun_ns: int) -> None:
+        self.bytes += nbytes
+        self.write_ns += time.perf_counter_ns() - t_begun_ns
+
+    def close(self, handle) -> None:
+        _fr.mark("ray_tpu.proxy.request", rid=self.rid, status=self.status,
+                 stream=self.stream, items=self.items, bytes=self.bytes,
+                 open_streams=self.open_streams, pre_ms=handle._pre_ms,
+                 first_item_ms=self.first_item_ms,
+                 pool_wait_ms=self.pool_wait_ns / 1e6,
+                 pool_wait_max_ms=self.pool_wait_max_ns / 1e6,
+                 next_ms=self.next_ns / 1e6, write_ms=self.write_ns / 1e6,
+                 total_ms=(time.perf_counter() - self.t_read) * 1e3)
+
+
 class ProxyActor:
     def __init__(self, port: int = 0,
                  max_concurrent_requests: int = MAX_CONCURRENT_REQUESTS,
@@ -72,6 +134,7 @@ class ProxyActor:
         self._max_header_bytes = int(max_header_bytes)
         self._default_timeout_s = float(request_timeout_s)
         self._ongoing = 0
+        self._streams = 0  # of them, streams open
         self._ready = False
         self._draining = False
         # deployment -> sheds since the last delivered ingress report.
@@ -350,7 +413,8 @@ class ProxyActor:
                               headers: Dict[str, str], body: bytes,
                               writer: asyncio.StreamWriter,
                               prefix: str, name: str) -> bool:
-        handle = self._handles[name]
+        acct = RequestAccount(headers.get("x-request-id", ""), False,
+                              self._streams)
         timeout_s = self._timeout_for(name)
         payload: Any = None
         if body:
@@ -367,34 +431,43 @@ class ProxyActor:
         }
         # Streaming: the x-serve-stream header, or OpenAI-style
         # {"stream": true} in a JSON body.
-        stream = (headers.get("x-serve-stream", "").lower() in ("1", "true")
-                  or (isinstance(payload, dict)
-                      and payload.get("stream") is True))
+        stream = acct.stream = (
+            headers.get("x-serve-stream", "").lower() in ("1", "true")
+            or (isinstance(payload, dict) and payload.get("stream") is True))
+        handle = self._handles[name].options(stream=stream)._for_request(
+            acct.rid, acct.t_read)
         loop = asyncio.get_running_loop()
+        if stream:
+            self._streams += 1
         try:
             if stream:
-                gen = await loop.run_in_executor(
-                    None, lambda: handle.options(stream=True).remote(request))
+                gen = await loop.run_in_executor(None, handle.remote,
+                                                 request)
                 it = iter(gen)
                 _END = object()
 
                 def _next():
                     try:
-                        return next(it)
+                        item = next(it)
                     except StopIteration:
                         return _END
+                    acct.took_item()
+                    return item
 
                 # Peek the first item: a {"__http__": {...}} envelope lets
                 # the deployment pick the response content-type (SSE for
                 # OpenAI-compatible endpoints). The peek also absorbs any
                 # backpressure retry BEFORE the 200 status line commits.
                 first = await asyncio.wait_for(
-                    loop.run_in_executor(None, _next), timeout_s)
+                    loop.run_in_executor(None, acct.pooled(_next)),
+                    timeout_s)
                 ctype = b"application/json"
                 if isinstance(first, dict) and "__http__" in first:
                     ctype = str(first["__http__"].get(
                         "content_type", "application/json")).encode()
-                    first = await loop.run_in_executor(None, _next)
+                    first = await loop.run_in_executor(
+                        None, acct.pooled(_next))
+                acct.status = 200
                 writer.write(
                     b"HTTP/1.1 200 OK\r\ncontent-type: " + ctype +
                     b"\r\ntransfer-encoding: chunked\r\n\r\n")
@@ -408,10 +481,13 @@ class ProxyActor:
                         chunk = item.encode()
                     else:
                         chunk = (json.dumps(item, default=str) + "\n").encode()
+                    t_write = time.perf_counter_ns()
                     writer.write(hex(len(chunk))[2:].encode() + b"\r\n"
                                  + chunk + b"\r\n")
                     await writer.drain()
-                    item = await loop.run_in_executor(None, _next)
+                    acct.wrote(len(chunk), t_write)
+                    item = await loop.run_in_executor(
+                        None, acct.pooled(_next))
                 writer.write(b"0\r\n\r\n")
                 await writer.drain()
                 return True
@@ -420,10 +496,11 @@ class ProxyActor:
             # stuck replica pick), the client still gets its 504.
             resp = await asyncio.wait_for(
                 loop.run_in_executor(
-                    None,
-                    lambda: handle.remote(request).result(
-                        timeout=timeout_s)),
+                    None, acct.pooled(
+                        lambda: handle.remote(request).result(
+                            timeout=timeout_s))),
                 timeout_s + 5.0)
+            acct.took_item()
             status = 200
             ctype = b"application/json"
             if isinstance(resp, dict) and "__http__" in resp:
@@ -433,10 +510,17 @@ class ProxyActor:
                     "content_type", "application/json")).encode()
                 resp = resp.get("body")
             data = json.dumps(resp, default=str).encode()
+            acct.status = status
+            t_write = time.perf_counter_ns()
             await self._respond(writer, status, data, ctype=ctype)
+            acct.wrote(len(data), t_write)
             return True
         except Exception as e:
+            if isinstance(e, ConnectionError) and writer.is_closing():
+                acct.status = 499  # the client went away: no one to answer
+                raise
             status, reason, note = _classify_error(e)
+            acct.status = status
             if reason is not None:
                 self._shed(name, reason)
                 await self._respond(
@@ -446,6 +530,10 @@ class ProxyActor:
             logger.exception("request failed")
             await self._respond(writer, 500, str(e).encode())
             return True
+        finally:
+            if stream:
+                self._streams -= 1
+            acct.close(handle)
 
     async def _respond(self, writer, status: int, body: bytes,
                        ctype: bytes = b"text/plain", extra: bytes = b"",

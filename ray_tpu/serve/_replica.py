@@ -8,7 +8,10 @@ streaming generators instead of a bespoke ASGI bridge."""
 from __future__ import annotations
 
 import inspect
+import time
 from typing import Any, Dict, Optional, Tuple
+
+from ray_tpu._private import flight_recorder as _fr
 
 
 # How often a serve-managed replica pushes its load report to the
@@ -92,9 +95,29 @@ class ReplicaActor:
             raise AttributeError(f"deployment has no method {method_name!r}")
         return fn
 
+    def _arrived(self, ctx: Optional[Dict[str, Any]]) -> None:
+        """First line of both entries, on the handler thread: the end of a
+        request's way in. `pre_ms` is the caller's own account (request read
+        to the call handed to the runtime, `_handle.py`), `dispatch_ms` this
+        worker's (receipt of the actor call to here); neither subtracts one
+        process's clock from another's. Names the request for the marks
+        behind this one on the thread: the engine's, the stream's. A call
+        made on the actor itself, past every handle, is no request."""
+        rid = (ctx or {}).get("rid")
+        if not rid:
+            return
+        _fr.set_request_id(rid)
+        entry_ns = _fr.task_entry_ns()
+        _fr.mark("ray_tpu.request.arrived", rid=rid,
+                 pre_ms=ctx.get("pre_ms", 0.0),
+                 dispatch_ms=((time.perf_counter_ns() - entry_ns) / 1e6
+                              if entry_ns else 0.0),
+                 ongoing=self._ongoing)
+
     def handle_request(self, method_name: str, args: Tuple, kwargs: Dict,
                        ctx: Optional[Dict[str, Any]] = None):
         """Streaming entry (called with num_returns="dynamic")."""
+        self._arrived(ctx)
         with self._track(), self._request_ctx(ctx):
             result = self._resolve_method(method_name)(*args, **kwargs)
             if inspect.isgenerator(result):
@@ -106,6 +129,7 @@ class ReplicaActor:
     def handle_request_unary(self, method_name: str, args: Tuple,
                              kwargs: Dict,
                              ctx: Optional[Dict[str, Any]] = None):
+        self._arrived(ctx)
         with self._track(), self._request_ctx(ctx):
             return self._resolve_method(method_name)(*args, **kwargs)
 
@@ -136,7 +160,6 @@ class ReplicaActor:
     def _track(self):
         import contextlib
         import os
-        import time
 
         @contextlib.contextmanager
         def cm():
@@ -212,8 +235,6 @@ class ReplicaActor:
         timeout) so a failed push restores its shed delta; the controller
         handle is re-resolved after any failure — it survives controller
         restarts by name."""
-        import time
-
         from ray_tpu._private.backoff import delay_for_attempt
         from ray_tpu.serve._common import CONTROLLER_NAME
 
@@ -249,8 +270,6 @@ class ReplicaActor:
         up to ``timeout_s``. Returns the number still in flight at the end
         (0 = fully drained); the controller kills the actor either way.
         Runs on an executor thread, so in-flight request threads proceed."""
-        import time
-
         with self._ongoing_lock:
             self._draining = True
         deadline = time.monotonic() + max(0.0, timeout_s)

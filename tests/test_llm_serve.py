@@ -27,7 +27,16 @@ def test_llm_deployment_streams_tokens(serve_instance):
     items = list(gen)
     assert len(items) == 6
     assert all(isinstance(i["token"], int) for i in items)
-    assert "ttft_s" in items[0]
+    # the engine timed the first token under the request's one id, the
+    # handle's: the replica's `ray_tpu.request.first_token` mark
+    from ray_tpu.util import state
+
+    assert items[0]["rid"] == gen.request_id
+    (first,) = [e["args"] for node in state.flight_record()["nodes"].values()
+                for w in node["workers"].values() for e in w["events"]
+                if e.get("name") == "ray_tpu.request.first_token"
+                and e["args"]["rid"] == gen.request_id]
+    assert first["queue_ms"] >= 0 and first["prefill_ms"] > 0
 
     # Unary path + stats through the same replica.
     out = handle.options(method_name="generate_all").remote(

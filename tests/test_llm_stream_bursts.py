@@ -217,8 +217,13 @@ def test_generate_yields_one_dict_per_token(app):
     items = list(app.server.generate([5, 17, 42], max_tokens=n))
     assert len(items) == n
     assert all(isinstance(i["token"], int) for i in items)
-    assert "ttft_s" in items[0] and "rid" in items[0]
-    assert not any("ttft_s" in i for i in items[1:])
+    # the first dict names the request, and the engine's `first_token`
+    # mark under that name times its first token, once
+    assert "rid" in items[0] and not any("rid" in i for i in items[1:])
+    (first,) = [e["args"] for e in fr.dump_events()
+                if e.get("name") == "ray_tpu.request.first_token"
+                and e["args"]["rid"] == items[0]["rid"]]
+    assert first["queue_ms"] >= 0 and first["prefill_ms"] > 0
     # `more` counts down inside a step: the prefill's token alone, then
     # whole windows, then what was left of the last one
     assert [i["more"] for i in items] == (
