@@ -423,7 +423,9 @@ class LLMEngine:
         the device lays them out: a minor axis fills whole lanes. A model
         with ring layers reports them apart from the allocator's pages, one
         with latent layers those apart from K/V, and one with index layers
-        says how many of its K/V layers hold an index pool and its bytes."""
+        says how many of its K/V layers hold an index pool and its bytes;
+        one with state layers says what share of their bytes is padding of
+        the lanes."""
         state = set(self.model.state_layer_ids)
         ring = set(self.model.ring_layer_ids)
         latent = set(self.model.latent_layer_ids)
@@ -438,6 +440,11 @@ class LLMEngine:
             "kv_bytes": sum(size(c[:2]) for i, c in enumerate(self.caches)
                             if i not in other),
             "state_bytes": sum(size(self.caches[i]) for i in state)}
+        if state:
+            logical = sum(_leaf_bytes(x) for i in state
+                          for x in jax.tree.leaves(self.caches[i]))
+            report["state_padding_pct"] = round(
+                100.0 * (1 - logical / report["state_bytes"]), 2)
         for kind, layers in (("ring", ring), ("latent", latent)):
             if layers:
                 report.update({f"{kind}_layers": len(layers),

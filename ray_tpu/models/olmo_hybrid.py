@@ -11,9 +11,10 @@ and `dt_bias`.
 
 Serving cache, per layer (`init_cache`): a full layer holds paged K/V like
 `LlamaModel`; a linear layer holds, per engine slot, the last three inputs of
-its convolutions and the float32 state [heads, key_dim, value_dim]. A state
-row is the slot's index: nothing is allocated, prefill overwrites the rows it
-is given from zero, decode updates every active row in place.
+its convolutions and the float32 state [heads, key_dim, value_dim], laid out
+as `ops/linear_attention.py` `state_shape` says. A state row is the slot's
+index: nothing is allocated, prefill overwrites the rows it is given from
+zero, decode updates every active row in place.
 """
 
 from __future__ import annotations
@@ -30,7 +31,8 @@ from ray_tpu.models.layers import (Decoder, Mlp, RMSNorm, batch_positions,
                                    dense, dt_bias_init, embed, init_params,
                                    no_lora, norm)
 from ray_tpu.ops.attention import attention_reference
-from ray_tpu.ops.linear_attention import gdn_chunked, gdn_decode
+from ray_tpu.ops.linear_attention import (gdn_chunked, gdn_decode, pack,
+                                          state_shape)
 from ray_tpu.ops.paged_attention import paged_write_attend
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -167,7 +169,7 @@ class GatedDeltaNet(nn.Module):
             new_state = (tail, new_s)
         elif state is not None:
             new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
-                         state[1].at[rows].set(new_s))
+                         state[1].at[rows].set(pack(new_s)))
         o = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="o_norm")(o)
         gate = dense(cfg, h * dv, "g_proj")(x).reshape(b, s, h, dv)
         y = (o * jax.nn.silu(f32(gate))).astype(cfg.dtype)
@@ -235,14 +237,16 @@ class OlmoHybridModel(Decoder):
 
     def init_cache(self, cache_cfg, mesh=None):
         """Per layer: (k_pages, v_pages) on a full layer; (conv_tail
-        [max_seqs, 3, C], S [max_seqs, H, dk, dv] float32) on a linear one, a
-        row per engine slot."""
+        [max_seqs, 3, C], S [max_seqs, *state_shape(H, dk, dv)] float32, the
+        heads laid along the lanes as `ops/linear_attention.py` says) on a
+        linear one, a row per engine slot."""
         cfg = self.cfg
         return super().init_cache(
             cache_cfg, mesh,
             tail=(cfg.linear_conv_kernel_dim - 1, cfg.conv_channels),
-            state=(cfg.linear_num_key_heads, cfg.linear_key_head_dim,
-                   cfg.linear_value_head_dim))
+            state=state_shape(cfg.linear_num_key_heads,
+                              cfg.linear_key_head_dim,
+                              cfg.linear_value_head_dim))
 
     @nn.nowrap
     def init_params(self, rng):

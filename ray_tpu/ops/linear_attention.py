@@ -18,6 +18,12 @@ the recurrence above, token by token):
   kernel (`name="gdn_decode"`) reads each state once and writes it once, in
   place; elsewhere `jax.numpy`.
 
+The pool's layout is written here and nowhere else (`state_shape`, `pack`,
+`unpack`): a slot's heads lie `p` side by side along the lanes, `p` from the
+head count and the value width alone, so that a row fills whole 128-lane
+tiles where a divisor of the heads allows (30 heads of 192 values: pairs,
+384 lanes).
+
 Lightning attention (arXiv:2401.04658) is the same memory under a simpler
 rule: a constant decay lambda in (0, 1] a head and no delta correction,
 
@@ -37,10 +43,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ray_tpu.ops.paged_attention import LANES
+
 CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
-# Largest state block (padded to the 128-lane tile) one kernel step holds; the
-# pipeline keeps four of them (in and out, double-buffered).
+# Largest state block (as laid out on 128-lane tiles) one kernel step holds;
+# the pipeline keeps four of them (in and out, double-buffered).
 _STATE_BLOCK_BYTES = 1 << 20
 
 
@@ -101,35 +109,96 @@ def gdn_chunked(q, k, v, g, beta, chunk: int = CHUNK):
 
 
 # ---------------------------------------------------------------------------
+# The state pool: the one place its layout is written
+# ---------------------------------------------------------------------------
+def _laid_out(width: int) -> int:
+    """The lanes a minor axis of `width` fills: whole tiles of 128."""
+    return -(-width // LANES) * LANES
+
+
+def heads_per_lane_block(h: int, dv: int) -> int:
+    """How many heads `p` of a slot's state lie side by side along the lanes:
+    the smallest divisor of `h` that leaves the fewest padded lanes (`dv`
+    192, `h` 30 -> 2: 384 lanes and none padded; `dv` a multiple of 128 ->
+    1)."""
+    return min((p for p in range(1, h + 1) if h % p == 0),
+               key=lambda p: (h // p * _laid_out(p * dv), p))
+
+
+def state_shape(h: int, dk: int, dv: int) -> Tuple[int, int, int]:
+    """A slot's row of the pool, `[h/p, dk, p*dv]` float32: head `p*i + j` is
+    the lanes `[j*dv, (j+1)*dv)` of lane block `i`."""
+    p = heads_per_lane_block(h, dv)
+    return h // p, dk, p * dv
+
+
+def pack(state):
+    """`[B,H,dk,dv]`, a state a head, -> the pool's rows
+    `[B, *state_shape(H, dk, dv)]`."""
+    b, h, dk, dv = state.shape
+    p = heads_per_lane_block(h, dv)
+    if p == 1:
+        return state
+    return state.reshape(b, h // p, p, dk, dv).swapaxes(2, 3).reshape(
+        b, h // p, dk, p * dv)
+
+
+def unpack(rows, h: int):
+    """The pool's rows `[B, H/p, dk, p*dv]` -> `[B,H,dk,dv]`."""
+    b, blocks, dk, width = rows.shape
+    p = h // blocks
+    if p == 1:
+        return rows
+    return rows.reshape(b, blocks, dk, p, width // p).swapaxes(2, 3).reshape(
+        b, h, dk, width // p)
+
+
+# ---------------------------------------------------------------------------
 # Decode: one token a row against the state pool
 # ---------------------------------------------------------------------------
-def _heads_per_block(h: int, dk: int, dv: int) -> int:
-    padded = dk * (-(-dv // 128) * 128) * 4
-    fit = max(1, _STATE_BLOCK_BYTES // padded)
-    return max(d for d in range(1, h + 1) if h % d == 0 and d <= fit)
+def _blocks_per_step(blocks: int, dk: int, width: int) -> int:
+    """Lane blocks `[dk, width]` one kernel step holds: the most that divide
+    a row's and fit `_STATE_BLOCK_BYTES` as laid out."""
+    fit = max(1, _STATE_BLOCK_BYTES // (dk * _laid_out(width) * 4))
+    return max(d for d in range(1, blocks + 1)
+               if blocks % d == 0 and d <= fit)
 
 
 def _gdn_decode_kernel(active_ref, cols_ref, rows_ref, s_ref, o_ref, s_out,
-                       *, heads: int):
-    """Grid (rows, head blocks). cols [dk, 4*heads]: per head the columns k,
-    alpha*beta*k, q, alpha; rows [heads, dv]: beta*v. With them
+                       *, blocks: int, p: int):
+    """Grid (rows, steps of `blocks` lane blocks). A lane block is `p` heads
+    side by side, `dv` lanes each. cols [dk, 4*p*blocks]: per head the
+    columns k, alpha*beta*k, q, alpha; rows [blocks, p*dv]: beta*v. With
+    them
         u = beta v - S^T (alpha beta k);  S' = alpha S + k u^T;  o = S'^T q
-    is the recurrence above, each state read once and written once."""
+    is the recurrence above, each state read once and written once. A lane
+    takes its own head's columns (a select a column for `p` 2), so the sums
+    down the sublanes never cross heads."""
     row = pl.program_id(0)
+    dk, width = s_ref.shape[2:]
+    dv = width // p
 
     @pl.when(active_ref[row] != 0)
     def _update():
-        for j in range(heads):  # static: every slice is a constant
-            s = s_ref[0, j]  # [dk, dv]
-            k = cols_ref[0, 0, :, 4 * j:4 * j + 1]
-            kab = cols_ref[0, 0, :, 4 * j + 1:4 * j + 2]
-            q = cols_ref[0, 0, :, 4 * j + 2:4 * j + 3]
-            alpha = cols_ref[0, 0, :, 4 * j + 3:4 * j + 4]
-            u = rows_ref[0, 0, j:j + 1, :] - jnp.sum(
-                s * kab, axis=0, keepdims=True)  # [1, dv]
+        # below[j]: the lanes of heads 0..j of a lane block (none for p = 1)
+        below = [jax.lax.broadcasted_iota(jnp.int32, (dk, width), 1)
+                 < (j + 1) * dv for j in range(p - 1)]
+
+        def column(at):  # [dk, width]: lane l meets head l // dv's column
+            head = lambda j: cols_ref[0, 0, :, at + 4 * j:at + 4 * j + 1]
+            col = head(p - 1)
+            for j in range(p - 2, -1, -1):
+                col = jnp.where(below[j], head(j), col)
+            return col
+
+        for i in range(blocks):  # static: every slice is a constant
+            s = s_ref[0, i]  # [dk, p*dv]
+            k, kab, q, alpha = (column(4 * p * i + c) for c in range(4))
+            u = rows_ref[0, 0, i:i + 1, :] - jnp.sum(
+                s * kab, axis=0, keepdims=True)  # [1, p*dv]
             s = alpha * s + k * u
-            s_out[0, j] = s
-            o_ref[0, 0, j:j + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
+            s_out[0, i] = s
+            o_ref[0, 0, i:i + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
 
     @pl.when(active_ref[row] == 0)
     def _keep():
@@ -139,41 +208,48 @@ def _gdn_decode_kernel(active_ref, cols_ref, rows_ref, s_ref, o_ref, s_out,
 
 def gdn_decode_kernel(q, k, v, g, beta, state, active,
                       interpret: Optional[bool] = None):
-    """q, k [B,H,dk], v [B,H,dv], g, beta [B,H] float32, state [B,H,dk,dv]
-    float32, active [B] bool -> (o [B,H,dv], state). The state is updated in
-    place (`input_output_aliases`); an inactive row's is left as it was."""
+    """q, k [B,H,dk], v [B,H,dv], g, beta [B,H] float32, state the pool's
+    rows [B, *state_shape(H, dk, dv)] float32, active [B] bool -> (o
+    [B,H,dv], state). The state is updated in place
+    (`input_output_aliases`); an inactive row's is left as it was."""
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     b, h, dk = q.shape
     dv = v.shape[-1]
-    hb = _heads_per_block(h, dk, dv)
-    nb = h // hb
+    blocks, _, width = state.shape[1:]
+    p = h // blocks
+    hb = _blocks_per_step(blocks, dk, width)
+    nb = blocks // hb
     alpha = jnp.exp(g)
     cols = jnp.stack(
         [k, k * (alpha * beta)[..., None], q,
          jnp.broadcast_to(alpha[..., None], k.shape)], axis=-1)  # [B,H,dk,4]
-    cols = cols.reshape(b, nb, hb, dk, 4).transpose(0, 1, 3, 2, 4).reshape(
-        b, nb, dk, 4 * hb)
-    rows = (v * beta[..., None]).reshape(b, nb, hb, dv)
+    cols = cols.reshape(b, nb, hb * p, dk, 4).transpose(
+        0, 1, 3, 2, 4).reshape(b, nb, dk, 4 * p * hb)
+    rows = (v * beta[..., None]).reshape(b, nb, hb, width)
     o, state = pl.pallas_call(
-        functools.partial(_gdn_decode_kernel, heads=hb),
+        functools.partial(_gdn_decode_kernel, blocks=hb, p=p),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nb),
             in_specs=[
-                pl.BlockSpec((1, 1, dk, 4 * hb),
+                pl.BlockSpec((1, 1, dk, 4 * p * hb),
                              lambda r, c, act: (r, c, 0, 0)),
-                pl.BlockSpec((1, 1, hb, dv), lambda r, c, act: (r, c, 0, 0)),
-                pl.BlockSpec((1, hb, dk, dv), lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, 1, hb, width),
+                             lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, hb, dk, width),
+                             lambda r, c, act: (r, c, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, hb, dv), lambda r, c, act: (r, c, 0, 0)),
-                pl.BlockSpec((1, hb, dk, dv), lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, 1, hb, width),
+                             lambda r, c, act: (r, c, 0, 0)),
+                pl.BlockSpec((1, hb, dk, width),
+                             lambda r, c, act: (r, c, 0, 0)),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, nb, hb, dv), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, nb, hb, width), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, jnp.float32)],
         # Operand 3 (after the prefetched `active`) is the state: same buffer
         # in and out, so the pool is never copied.
@@ -190,19 +266,20 @@ def gdn_decode(q, k, v, g, beta, state, active,
                use_kernel: Optional[bool] = None
                ) -> Tuple[jax.Array, jax.Array]:
     """One token a row: shapes as `gdn_decode_kernel`. The Pallas kernel on a
-    TPU, `jax.numpy` elsewhere (as `paged_attention`'s `use_kernel`)."""
+    TPU; elsewhere `jax.numpy` on the unpacked rows, packed again (as
+    `paged_attention`'s `use_kernel`)."""
     f32 = lambda x: x.astype(jnp.float32)
     q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
         return gdn_decode_kernel(q, k, v, g, beta, state, active)
-    s = state * jnp.exp(g)[..., None, None]
+    s = unpack(state, q.shape[1]) * jnp.exp(g)[..., None, None]
     u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s, k,
                                           precision=_HIGHEST))
     s = s + k[..., :, None] * u[..., None, :]
     o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HIGHEST)
-    return o, jnp.where(active[:, None, None, None], s, state)
+    return o, jnp.where(active[:, None, None, None], pack(s), state)
 
 
 # ---------------------------------------------------------------------------

@@ -295,7 +295,7 @@ def test_pool_is_float32_with_the_channels_last(tiny):
     assert eng.cache_report == {
         "kv_layers": 1, "state_layers": 3,
         "kv_bytes": 2 * pages[0] * pages[1] * 128 * 4,
-        "state_bytes": 3 * 3 * (3 + 16) * 128 * 4}
+        "state_bytes": 3 * 3 * (3 + 16) * 128 * 4, "state_padding_pct": 0.0}
     # at the published widths the channels fill the lanes: nothing is padded
     big = JambaModel(JambaConfig())
     shapes = jax.eval_shape(

@@ -104,7 +104,8 @@ def test_engine_follows_the_reference_on_both_sides_of_dense_len(tiny):
         "kv_layers": 1, "state_layers": 3, "index_layers": 1,
         "kv_bytes": 2 * 49 * PAGE * 128 * 4,      # 32 values on 128 lanes
         "index_bytes": 49 * 4 * 128 * 4,
-        "state_bytes": 3 * 3 * 4 * 16 * 128 * 4}
+        "state_bytes": 3 * 3 * 4 * 16 * 128 * 4,
+        "state_padding_pct": 87.5}                # 16 values on 128 lanes
     assert eng.prefix_cache is None
     prompts = [_ids(n, seed=n) for n in WAVE]
     outs = _generate(eng, prompts, 30)

@@ -289,30 +289,80 @@ def test_request_finishing_inside_a_chained_window(tiny):
     _same(seen["C"], _alone(model, params, third, 12))
 
 
-# -- (e) the Pallas kernel, interpreted, against jax.numpy ------------------
-def test_gdn_decode_kernel_matches_jnp_and_skips_inactive_rows():
-    q, k, v, g, beta = (x[:, 0] for x in _qkvgb(3, 1, seed=5))
-    _, state = _recurrence(*_qkvgb(3, 9, seed=6))
+# -- (e) the pool's layout, and the Pallas kernel, interpreted ---------------
+# (H, dk, dv) -> heads side by side along the lanes: the published widths,
+# the tiny model's, and a lane-aligned one (the layout of before PR 55)
+WIDTHS = {(30, 96, 192): 2, (4, 24, 48): 2, (4, 32, 128): 1}
+
+
+@pytest.mark.parametrize("dims", WIDTHS)
+def test_pool_lays_heads_side_by_side_along_the_lanes(dims):
+    h, dk, dv = dims
+    p = WIDTHS[dims]
+    assert la.state_shape(h, dk, dv) == (h // p, dk, p * dv)
+    state = jax.random.normal(jax.random.PRNGKey(3), (2, h, dk, dv))
+    rows = la.pack(state)
+    assert rows.shape == (2, h // p, dk, p * dv)
+    np.testing.assert_array_equal(la.unpack(rows, h), state)
+    for head in (0, 1, h - 1):   # head p*i + j: lanes [j*dv, (j+1)*dv) of i
+        i, j = divmod(head, p)
+        np.testing.assert_array_equal(
+            rows[:, i, :, j * dv:(j + 1) * dv], state[:, head])
+    if p == 1:   # nothing to move: the array itself
+        assert rows is state and la.unpack(rows, h) is rows
+
+
+@pytest.mark.parametrize("h,dv,p", [
+    (30, 192, 2),    # 384 lanes, none padded (6 would do as well: smallest)
+    (30, 128, 1), (30, 256, 1), (8, 64, 2),
+    (4, 48, 2),      # 96 of 128: as few padded as four side by side
+    (7, 192, 7),     # a prime number of heads: all of them or one
+    (6, 32, 3),      # 96 of 128 twice; pairs would take three tiles
+    (12, 32, 4),
+])
+def test_heads_side_by_side_follow_from_the_shape_alone(h, dv, p):
+    assert la.heads_per_lane_block(h, dv) == p
+
+
+@pytest.mark.parametrize("dims", WIDTHS)
+def test_gdn_decode_kernel_matches_the_recurrence_and_skips_inactive_rows(
+        dims):
+    h, dk, dv = dims
+    widths = dict(h=h, dk=dk, dv=dv)
+    q, k, v, g, beta = (x[:, 0] for x in _qkvgb(3, 1, seed=5, **widths))
+    _, state = _recurrence(*_qkvgb(3, 9, seed=6, **widths))
+    pool = la.pack(state)
     active = jnp.asarray([True, False, True])
-    want_o, want_s = la.gdn_decode(q, k, v, g, beta, state, active,
+    want_o, want_s = la.gdn_decode(q, k, v, g, beta, pool, active,
                                    use_kernel=False)
     one_o, one_s = _recurrence(*(x[:, None] for x in (q, k, v, g, beta)),
                                state=state)
-    got_o, got_s = la.gdn_decode_kernel(q, k, v, g, beta, state, active,
+    got_o, got_s = la.gdn_decode_kernel(q, k, v, g, beta, pool, active,
                                         interpret=True)
+    assert got_s.shape == want_s.shape == pool.shape
     rows = np.asarray([0, 2])
-    np.testing.assert_allclose(want_o[rows], one_o[rows, 0], atol=1e-6)
-    np.testing.assert_allclose(got_o[rows], want_o[rows], atol=1e-6)
-    np.testing.assert_allclose(got_s, want_s, atol=1e-6)
-    np.testing.assert_array_equal(got_s[1], state[1])   # bit for bit
-    np.testing.assert_array_equal(want_s[1], state[1])
-    assert float(jnp.abs(got_s[0] - state[0]).max()) > 1e-3
+    np.testing.assert_allclose(want_o[rows], one_o[rows, 0], atol=2e-6)
+    np.testing.assert_allclose(got_o[rows], one_o[rows, 0], atol=2e-6)
+    np.testing.assert_allclose(la.unpack(got_s, h)[rows], one_s[rows],
+                               atol=2e-6)
+    np.testing.assert_allclose(got_s, want_s, atol=2e-6)
+    np.testing.assert_array_equal(got_s[1], pool[1])   # bit for bit
+    np.testing.assert_array_equal(want_s[1], pool[1])
+    np.testing.assert_array_equal(got_o[1], 0.0)
+    assert float(jnp.abs(got_s[0] - pool[0]).max()) > 1e-3
 
 
-def test_gdn_decode_kernel_blocks_heads_at_the_published_widths():
-    # 30 heads of [96, 192] float32 (padded to 256 lanes): 10 to a block
-    assert la._heads_per_block(30, 96, 192) == 10
-    assert la._heads_per_block(4, 24, 48) == 4
+@pytest.mark.parametrize("dims,blocks", [
+    # 15 pairs of [96, 384] float32, 147 KB and no lane padded: 5 a step
+    ((30, 96, 192), 5),
+    ((4, 24, 48), 2),
+    ((4, 32, 128), 4),
+    # 64 heads of [128, 128]: 16 fill the megabyte a step may hold
+    ((64, 128, 128), 16),
+])
+def test_gdn_decode_kernel_blocks_heads_at_the_published_widths(dims,
+                                                                blocks):
+    assert la._blocks_per_step(*la.state_shape(*dims)) == blocks
 
 
 # -- (f) what the engine builds, and refuses, for a model with state --------
@@ -324,17 +374,47 @@ def test_engine_cache_is_what_the_model_says(tiny):
     pages = (3 * 16 + 1, 8, 4 * 32)    # [P, ps, HK * D]
     for i, (a, b) in enumerate(eng.caches):
         if i in model.state_layer_ids:
+            # four heads of [24, 48], two side by side along the lanes
             assert (a.shape, b.shape) == ((3, 3, 4 * (24 + 24 + 48)),
-                                          (3, 4, 24, 48))
+                                          (3, 2, 24, 96))
             assert b.dtype == jnp.float32
         else:
             assert a.shape == b.shape == pages
-    # as the device lays them out: the 48-wide rows of a state fill a
-    # 128-lane tile (the published 192 fill 256: PERF.md section 7.11)
+    # as the device lays them out: a pair's 96-wide rows fill a 128-lane
+    # tile, a quarter of it padding; the tails' 384 fill three
     report = eng.cache_report
     assert report == {"kv_layers": 2, "state_layers": 6,
                       "kv_bytes": 2 * 2 * int(np.prod(pages)) * 4,
-                      "state_bytes": 6 * 3 * (3 * 384 + 4 * 24 * 128) * 4}
+                      "state_bytes": 6 * 3 * (3 * 384 + 2 * 24 * 128) * 4,
+                      "state_padding_pct": round(
+                          100 * (1 - (3 * 384 + 2 * 24 * 96)
+                                 / (3 * 384 + 2 * 24 * 128)), 2)}
+
+
+def test_published_widths_fill_the_lanes():
+    """The cell's pool from shapes alone: 16 slots of 15 pairs of
+    [96, 2 * 192] float32 and no lane padded, where 30 heads of [96, 192]
+    lay in 256 lanes each (24.43% of the state layers' bytes, 141.6 MB of
+    the cell's twelve layers)."""
+    from benchmark import sizing
+
+    model = OlmoHybridModel(OlmoHybridConfig(layer_types=(LINEAR,) * 3
+                                             + ("full_attention",)))
+    ec = EngineConfig(max_seqs=16, page_size=64, max_pages_per_seq=20)
+    report = {}
+
+    def build(params):
+        eng = LLMEngine(model, params, ec)
+        report.update(eng.cache_report)
+        return eng.caches
+
+    caches = jax.eval_shape(build, sizing.param_shapes(model, None))
+    tail, state = caches[0]
+    assert (tail.shape, tail.dtype) == ((16, 3, 11520), jnp.bfloat16)
+    assert (state.shape, state.dtype) == ((16, 15, 96, 384), jnp.float32)
+    assert report["state_bytes"] == 3 * 16 * (3 * 11520 * 2
+                                              + 30 * 96 * 192 * 4)
+    assert report["state_padding_pct"] == 0.0
 
 
 @pytest.mark.parametrize("what", ["mesh", "lora_rank"])
@@ -415,6 +495,7 @@ def test_spans_and_stats_say_what_the_cache_holds():
         srv._running = False
     assert (cache["kv_layers"], cache["state_layers"]) == (2, 6)
     assert cache["kv_bytes"] > 0 and cache["state_bytes"] > 0
+    assert cache["state_padding_pct"] == 21.05   # 96 lanes of a tile's 128
     events = [e for e in fr.dump_events()
               if e.get("kind") == "span" and e["ts"] >= began]
     built = [e for e in events

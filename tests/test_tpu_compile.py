@@ -77,7 +77,8 @@ def _kernel_names(text):
         if "tpu_custom_call" in line and " = " in line:
             instruction = line.split(" = ", 1)[0]
             held |= {k for k in ("paged_decode", "flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv", "ssm_scan", "ssd_scan",
+                                 "flash_bwd_dkv", "gdn_decode", "ssm_scan",
+                                 "ssd_scan",
                                  "moe_gmm", "swa_decode", "swa_flash",
                                  "mla_decode", "mla_flash", "sparse_decode",
                                  "sparse_flash")
@@ -255,6 +256,46 @@ def test_no_program_copies_a_kv_pool(topology, monkeypatch, cell, program):
     assert _pool_layout_changes(text, math.prod(pool.shape)) == []
     if program == "decode":
         assert "paged_decode" in _kernel_names(text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_state_pool_fills_the_lanes_and_is_never_copied(
+        topology, monkeypatch, program):
+    """The chip compiler's HLO of `hybrid-decode-heavy`'s decode window and
+    of a prefill at the published widths (one period: three gated-delta
+    layers and the full layer after them): a layer's state pool is
+    `f32[16,15,96,384]`, two heads of [96, 192] side by side along the
+    lanes (`ops/linear_attention.py` `state_shape`), and no instruction
+    copies or transposes an array of its size: `gdn_decode` updates it in
+    place and a prefill scatters its rows into it. (The prefill is of half
+    the slots: what it packs for the scatter is the wave's states, `nb` of
+    them, and a wave of all sixteen is as large as the pool without being
+    it.) The program's arguments are their logical bytes: with 192 values
+    in 256 lanes a layer's pool held 16 x 30 x 96 x 64 floats of padding,
+    35.4 MB over these three layers and 141.6 MB over the cell's twelve."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth("hybrid-decode-heavy", 4)
+    assert model.state_layer_ids == (0, 1, 2)
+    one = SingleDeviceSharding(topology.devices[0])
+    if program == "decode":
+        fn, args = decode_call(model, ec, one)
+    else:
+        fn, args = prefill_call(model, ec, 128, ec["max_seqs"] // 2, one)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    state = sizing.cache_shapes(model, ec, None)[0][1]
+    assert (state.shape, state.dtype) == ((16, 15, 96, 384), jnp.float32)
+    assert "f32[16,15,96,384]" in text and "f32[16,30,96,192]" not in text
+    assert _pool_layout_changes(text, math.prod(state.shape)) == []
+    if program == "decode":
+        assert {"gdn_decode", "paged_decode"} <= _kernel_names(text)
+    logical = sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+                  for x in jax.tree.leaves(args))
+    held = compiled.memory_analysis().argument_size_in_bytes
+    padding = 16 * 30 * 96 * 64 * 4     # a layer's, at 192 values in 256
+    assert 0 <= held - logical < padding // 8, (held, logical)
 
 
 def _jamba_at_depth(layers=None):
