@@ -230,6 +230,10 @@ class LLMEngine:
     reads no cached page, so it too runs under those limits: prefix sharing
     over latent pages is not built.
 
+    The layers in `model.cacheless_layer_ids` (an expert layer alone) keep
+    nothing between steps: their entry is empty, passes through both programs
+    as it is, and is counted apart (`cacheless_layers`), not as a K/V layer.
+
     A model that says `num_logits_to_keep = 1` gets its prefill's final norm
     and head on each row's last prompt position only (`logits_at`): logits
     [nb, 1, V] where the other families compute [nb, bucket, V] and keep a
@@ -425,13 +429,14 @@ class LLMEngine:
         with latent layers those apart from K/V, and one with index layers
         says how many of its K/V layers hold an index pool and its bytes;
         one with state layers says what share of their bytes is padding of
-        the lanes."""
+        the lanes; one with layers that hold nothing counts them."""
         state = set(self.model.state_layer_ids)
         ring = set(self.model.ring_layer_ids)
         latent = set(self.model.latent_layer_ids)
+        cacheless = set(self.model.cacheless_layer_ids)
         size = lambda layer: sum(map(_laid_out_bytes,
                                      jax.tree.leaves(layer)))
-        other = state | ring | latent
+        other = state | ring | latent | cacheless
         index = self.model.index_layer_ids
         report = {
             "kv_layers": len(self.caches) - len(other),
@@ -453,6 +458,8 @@ class LLMEngine:
         if index:   # those of the K/V layers that hold an index pool
             report.update(index_layers=len(index), index_bytes=sum(
                 size(self.caches[i][2]) for i in index))
+        if cacheless:
+            report["cacheless_layers"] = len(cacheless)
         return report
 
     # ------------------------------------------------------------------

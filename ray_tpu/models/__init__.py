@@ -43,6 +43,8 @@ FAMILIES = {
                          "ray_tpu.models.sarvam_mla:SarvamMlaModel"),
     "minicpm_sala": Family("ray_tpu.models.minicpm_sala:MiniCPMSalaConfig",
                            "ray_tpu.models.minicpm_sala:MiniCPMSalaModel"),
+    "nemotron_h": Family("ray_tpu.models.nemotron_h:NemotronHConfig",
+                         "ray_tpu.models.nemotron_h:NemotronHModel"),
 }
 
 

@@ -14,11 +14,8 @@ layer held as two stacks. What the config does not state is listed under
                 out = a + r * (moe(u) + shared(u)),  r = residual_multiplier
     attention   num_heads query heads over num_kv_heads K/V heads, no bias,
                 no positional encoding, softmax(attention_multiplier q k^T)
-    mamba       (z, xBC, dt) = in_proj(u); xBC = silu(conv4(xBC) + b), the
-                convolution over x, B and C together; dt = softplus(dt +
-                dt_bias) a head; the recurrence of ops/ssm.py (`ssd_*`) with
-                A = -exp(A_log) a head; g = y silu(z); g rsqrt(mean(g^2) +
-                eps) w over all of d_inner; out_proj
+    mamba       `layers.Mamba2Mixer` with one group of B and C (the gated
+                norm over all of d_inner)
     experts     ops/moe.py over all `num_experts` columns of the router,
                 softmax over the top k; `experts_held = (first, count)`: this
                 chip holds those experts' weights and computes their part of
@@ -44,12 +41,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.layers import (Decoder, SparseMoe, batch_positions,
-                                   conv_init, dense, dt_bias_init, embed,
-                                   init_params, no_lora, norm)
+from ray_tpu.models.layers import (Decoder, Mamba2Mixer, SparseMoe,
+                                   batch_positions, dense, embed, init_params,
+                                   no_lora, norm)
 from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops.paged_attention import paged_attention, paged_write
-from ray_tpu.ops.ssm import causal_conv, ssd_scan, ssd_scan_plain, ssd_step
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -133,81 +129,6 @@ class GraniteHybridConfig:
             mamba_d_state=16, mamba_chunk_size=16, attention_multiplier=0.125,
             max_seq_len=512, dtype=jnp.float32, param_dtype=jnp.float32),
             **kw})
-
-
-def _a_log_init(key, shape, dtype):
-    """Mamba-2: A = -(1 ... heads), one a head, held as log(-A)."""
-    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32)).astype(
-        dtype)
-
-
-class Mamba2Mixer(nn.Module):
-    """`state` is None (no cache: the recurrence token by token over the
-    whole sequence) or the layer's (conv_tail, S) pool, with `rows` = the pool
-    rows a prefill overwrites, or None for decode (one token for every row of
-    the pool)."""
-    cfg: GraniteHybridConfig
-
-    @nn.compact
-    def __call__(self, u, mask=None, state=None, rows=None):
-        cfg = self.cfg
-        b, s, _ = u.shape
-        d, n, heads, width = (cfg.d_inner, cfg.mamba_d_state,
-                              cfg.mamba_n_heads, cfg.mamba_d_conv)
-        f32 = lambda t: t.astype(jnp.float32)
-        if mask is None:
-            mask = jnp.ones((b, s), bool)
-        z, xbc, dt = jnp.split(
-            dense(cfg, 2 * d + 2 * n + heads, "in_proj")(u),
-            [d, d + cfg.conv_dim], axis=-1)
-        taps = self.param("conv1d_weight", conv_init, (width, cfg.conv_dim),
-                          cfg.param_dtype)
-        bias = self.param("conv1d_bias", conv_init, (cfg.conv_dim,),
-                          cfg.param_dtype)
-        decode = state is not None and rows is None
-        conv, window = causal_conv(xbc, taps, bias,
-                                   state[0] if decode else None)
-        if decode:
-            tail = jnp.where(mask[:, :, None], window[:, 1:], state[0])
-        else:
-            # The last width-1 inputs before position true_len.
-            true_len = jnp.sum(mask, axis=-1)
-            tail = jnp.take_along_axis(
-                window, (true_len[:, None] + jnp.arange(width - 1))[..., None],
-                axis=1)
-        # Padding is zero from here on: it changes no state (dt = 0 below),
-        # and what a skipped chunk of the scan leaves there is never read.
-        xbc = jnp.where(mask[:, :, None], jax.nn.silu(conv), 0.0).astype(
-            cfg.dtype)
-        x, bm, cm = jnp.split(xbc, [d, d + n], axis=-1)
-        x = x.reshape(b, s, heads, cfg.mamba_d_head)
-        dt_bias = self.param("dt_bias", dt_bias_init, (heads,), jnp.float32)
-        dt = jnp.where(mask[:, :, None],
-                       jax.nn.softplus(f32(dt) + dt_bias), 0.0)
-        a = -jnp.exp(f32(self.param("A_log", _a_log_init, (heads,),
-                                    jnp.float32)))
-        skip = f32(self.param("D", nn.initializers.ones, (heads,),
-                              jnp.float32))
-        new_state = None
-        if decode:
-            y, pool = ssd_step(x[:, 0], dt[:, 0], bm[:, 0], cm[:, 0], a, skip,
-                               state[1], mask[:, 0])
-            y, new_state = y[:, None], (tail, pool)
-        elif state is None:
-            y, _ = ssd_scan_plain(x, dt, bm, cm, a, skip)
-        else:
-            y, last = ssd_scan(x, dt, bm, cm, a, skip, true_len,
-                               chunk=cfg.mamba_chunk_size)
-            new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
-                         state[1].at[rows].set(last))
-        # The gated norm, over all of d_inner (one group).
-        g = f32(y).reshape(b, s, d) * jax.nn.silu(f32(z))
-        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-                              + cfg.rms_norm_eps)
-        g = g * f32(self.param("norm", nn.initializers.ones, (d,),
-                               jnp.float32))
-        return dense(cfg, cfg.hidden_size, "out_proj")(
-            g.astype(cfg.dtype)), new_state
 
 
 class Attention(nn.Module):

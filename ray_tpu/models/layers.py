@@ -1,10 +1,10 @@
 """What more than one model family uses, under public names: the RMSNorm and
 the rotary embedding, the seeded projection, norm and embedding of a family
-whose checkpoint is bf16, the SwiGLU MLP, the routed experts, the draws two
-families share, and `Decoder`, the base of every family's model class: what
-the serving engine reads off a model, each name with the value of a family
-that does not say otherwise, the seeded initializer and the cache a slot
-indexes.
+whose checkpoint is bf16, the SwiGLU MLP, the routed experts, the Mamba-2
+mixer, the draws two families share, and `Decoder`, the base of every
+family's model class: what the serving engine reads off a model, each name
+with the value of a family that does not say otherwise, the seeded
+initializer and the cache a slot indexes.
 
 A family's file (`models/<family>.py`) states its architecture with these and
 with `ray_tpu/ops/`; it imports no other family's file, and this module
@@ -25,6 +25,7 @@ from ray_tpu.models.initializers import embed_init, kernel_init
 from ray_tpu.ops.moe import moe_layer
 from ray_tpu.ops.paged_attention import (init_index_pages, init_kv_pages,
                                          init_latent_pages, init_ring_pages)
+from ray_tpu.ops.ssm import causal_conv, ssd_scan, ssd_scan_plain, ssd_step
 
 
 class RMSNorm(nn.Module):
@@ -156,7 +157,12 @@ class SparseMoe(nn.Module):
     (`ops.moe.route`): the layer also holds the float32 `bias` [num_experts]
     that takes part in the choice alone, drawn small and not zero so that a
     seeded model's choosing and weighing differ; the chosen weights sum to
-    `scale`. `down_std`: the seeded `down` stacks' deviation, in lecun's."""
+    `scale`. `down_std`: the seeded `down` stacks' deviation, in lecun's.
+    `act` "relu2": an expert is down(relu(up r)^2), its first stack `up`
+    [count, input, intermediate] where a gated one's ("swiglu") is
+    `gate_up`. `rows`, where the caller hands them in beside x: the rows the
+    experts take and give back (a latent of each token, its width the
+    stacks' input) while the router reads x."""
     cfg: Any
     num_experts: int
     intermediate: int
@@ -166,18 +172,22 @@ class SparseMoe(nn.Module):
     scoring: str = "softmax"
     scale: float = 1.0
     down_std: float = 1.0
+    act: str = "swiglu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, rows=None):
         cfg = self.cfg
         hid, inter = cfg.hidden_size, self.intermediate
+        wide = hid if rows is None else rows.shape[-1]
         count = self.num_experts if self.held is None else self.held[1]
         router = self.param("router", nn.initializers.variance_scaling(
             self.router_std ** 2, "fan_in", "truncated_normal"),
             (hid, self.num_experts), jnp.float32)
-        gate_up = self.param("gate_up", stack_init, (count, hid, 2 * inter),
-                             cfg.param_dtype)
-        down = self.param("down", stack_init, (count, inter, hid),
+        up = (self.param("gate_up", stack_init, (count, wide, 2 * inter),
+                         cfg.param_dtype) if self.act == "swiglu" else
+              self.param("up", stack_init, (count, wide, inter),
+                         cfg.param_dtype))
+        down = self.param("down", stack_init, (count, inter, wide),
                           cfg.param_dtype, self.down_std)
         routing = {}
         if self.scoring != "softmax":
@@ -186,13 +196,107 @@ class SparseMoe(nn.Module):
                                "bias", nn.initializers.normal(ROUTER_BIAS_STD),
                                (self.num_experts,), jnp.float32))
         b, s, _ = x.shape
-        y, load = moe_layer(x.reshape(b * s, hid), router,
-                            gate_up.astype(cfg.dtype), down.astype(cfg.dtype),
-                            self.top_k, held=self.held, **routing)
+        y, load = moe_layer(
+            x.reshape(b * s, hid), router, up.astype(cfg.dtype),
+            down.astype(cfg.dtype), self.top_k, held=self.held,
+            act=self.act,
+            rows=None if rows is None else rows.reshape(b * s, wide),
+            **routing)
         # `ops.moe.Load` of this call, for whoever asks for the collection
         # (the engine's programs).
         self.sow("expert_load", "load", jnp.stack(load))
-        return y.reshape(b, s, hid)
+        return y.reshape(b, s, wide)
+
+
+def a_log_init(key, shape, dtype):
+    """Mamba-2: A = -(1 ... heads), one a head, held as log(-A)."""
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32)).astype(
+        dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 (SSD) mixer of a family whose config says `hidden_size`,
+    `mamba_n_heads`, `mamba_d_head`, `mamba_d_state`, `mamba_d_conv`,
+    `mamba_chunk_size` and `rms_norm_eps`:
+
+        (z, xBC, dt) = in_proj(u); xBC = silu(conv(xBC) + b), the
+        convolution over x and the `groups` B and C together; dt =
+        softplus(dt + dt_bias) a head; the recurrence of ops/ssm.py
+        (`ssd_*`, head h on group h // (heads / groups)) with A = -exp(A_log)
+        a head; g = y silu(z); g rsqrt(mean(g^2) + eps) w within each of
+        the `groups` runs of d_inner / groups channels; out_proj
+
+    `state` is None (no cache: the recurrence token by token over the
+    whole sequence) or the layer's (conv_tail, S) pool, with `rows` = the pool
+    rows a prefill overwrites, or None for decode (one token for every row of
+    the pool)."""
+    cfg: Any
+    groups: int = 1
+
+    @nn.compact
+    def __call__(self, u, mask=None, state=None, rows=None):
+        cfg, groups = self.cfg, self.groups
+        b, s, _ = u.shape
+        n, heads, width = (cfg.mamba_d_state, cfg.mamba_n_heads,
+                           cfg.mamba_d_conv)
+        d = heads * cfg.mamba_d_head
+        conv_dim = d + 2 * groups * n
+        f32 = lambda t: t.astype(jnp.float32)
+        if mask is None:
+            mask = jnp.ones((b, s), bool)
+        z, xbc, dt = jnp.split(
+            dense(cfg, d + conv_dim + heads, "in_proj")(u),
+            [d, d + conv_dim], axis=-1)
+        taps = self.param("conv1d_weight", conv_init, (width, conv_dim),
+                          cfg.param_dtype)
+        bias = self.param("conv1d_bias", conv_init, (conv_dim,),
+                          cfg.param_dtype)
+        decode = state is not None and rows is None
+        conv, window = causal_conv(xbc, taps, bias,
+                                   state[0] if decode else None)
+        if decode:
+            tail = jnp.where(mask[:, :, None], window[:, 1:], state[0])
+        else:
+            # The last width-1 inputs before position true_len.
+            true_len = jnp.sum(mask, axis=-1)
+            tail = jnp.take_along_axis(
+                window, (true_len[:, None] + jnp.arange(width - 1))[..., None],
+                axis=1)
+        # Padding is zero from here on: it changes no state (dt = 0 below),
+        # and what a skipped chunk of the scan leaves there is never read.
+        xbc = jnp.where(mask[:, :, None], jax.nn.silu(conv), 0.0).astype(
+            cfg.dtype)
+        x, bm, cm = jnp.split(xbc, [d, d + groups * n], axis=-1)
+        x = x.reshape(b, s, heads, cfg.mamba_d_head)
+        bm, cm = (m.reshape(b, s, groups, n) for m in (bm, cm))
+        dt_bias = self.param("dt_bias", dt_bias_init, (heads,), jnp.float32)
+        dt = jnp.where(mask[:, :, None],
+                       jax.nn.softplus(f32(dt) + dt_bias), 0.0)
+        a = -jnp.exp(f32(self.param("A_log", a_log_init, (heads,),
+                                    jnp.float32)))
+        skip = f32(self.param("D", nn.initializers.ones, (heads,),
+                              jnp.float32))
+        new_state = None
+        if decode:
+            y, pool = ssd_step(x[:, 0], dt[:, 0], bm[:, 0], cm[:, 0], a, skip,
+                               state[1], mask[:, 0])
+            y, new_state = y[:, None], (tail, pool)
+        elif state is None:
+            y, _ = ssd_scan_plain(x, dt, bm, cm, a, skip)
+        else:
+            y, last = ssd_scan(x, dt, bm, cm, a, skip, true_len,
+                               chunk=cfg.mamba_chunk_size)
+            new_state = (state[0].at[rows].set(tail.astype(state[0].dtype)),
+                         state[1].at[rows].set(last))
+        # The gated norm, within each group's channels.
+        g = (f32(y).reshape(b, s, d) * jax.nn.silu(f32(z))).reshape(
+            b, s, groups, d // groups)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        g = g.reshape(b, s, d) * f32(self.param(
+            "norm", nn.initializers.ones, (d,), jnp.float32))
+        return dense(cfg, cfg.hidden_size, "out_proj")(
+            g.astype(cfg.dtype)), new_state
 
 
 def batch_positions(input_ids, positions):
@@ -257,6 +361,9 @@ class Decoder(nn.Module):
     # token's row `latent_width` values for all heads (latent attention).
     latent_layer_ids: ClassVar[Tuple[int, ...]] = ()
     latent_width: ClassVar[int] = 0
+    # Layers that keep nothing between steps (an expert layer alone, an MLP
+    # alone): their cache entry is empty, `()`, in and out of both programs.
+    cacheless_layer_ids: ClassVar[Tuple[int, ...]] = ()
     # Layers whose (k_pages, v_pages) have an index pool beside them: a
     # page's `index_segments` segment means, by which a query chooses the
     # pages it attends to (learned sparse attention). Each sows a
@@ -280,8 +387,9 @@ class Decoder(nn.Module):
         compute dtype, zeros [max_seqs, *state] float32), a row per engine
         slot, the state alone where the family gives no `tail`; on a ring
         layer `max_seqs` rings of pages; on a latent layer one pool of rows;
-        (k_pages, v_pages) from the allocator's pool on the others, and on an
-        index layer the pool of segment means as the third."""
+        nothing, `()`, on a cacheless layer; (k_pages, v_pages) from the
+        allocator's pool on the others, and on an index layer the pool of
+        segment means as the third."""
         if mesh is not None:
             raise NotImplementedError(
                 f"{type(self).__name__}: neither its parameters nor its "
@@ -298,6 +406,8 @@ class Decoder(nn.Module):
             if i in self.latent_layer_ids:
                 return init_latent_pages(cache_cfg, self.latent_width,
                                          cfg.dtype)
+            if i in self.cacheless_layer_ids:
+                return ()
             kv = cfg.num_kv_heads, cfg.head_dim, cfg.dtype
             if i in rings:
                 return init_ring_pages(cache_cfg, self.sliding_window, *kv)

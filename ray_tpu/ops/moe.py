@@ -7,7 +7,10 @@ file calls).
     sum;  y = sum_e w_e * down_e(silu(gate_e x) * up_e x)
 
 (or, `route(scoring="sigmoid")`: sigmoid scores, chosen with a learned bias,
-weighed without it, the sum scaled)
+weighed without it, the sum scaled; or, `moe_layer(act="relu2")`: experts
+without a gate, down_e(relu(up_e r)^2); or, `moe_layer(rows=)`: experts that
+work on another array's rows than the one the router reads, a narrower latent
+of the token)
 
 TPU-first design: everything is static-shaped. The `T * top_k` assignments
 are sorted by expert and laid out so that every expert's rows start on a tile
@@ -56,7 +59,9 @@ pass over every padded row, used or not, are no arrays of the program (as
 XLA passes they were an eighth to a quarter of a one-prompt layer, builder's
 chip runs, PR 49; as the one fusion XLA allows, the activation's buffer lay
 on top of both its neighbours, a wave's prefill 0.26-0.47 GiB higher:
-compile, PR 46).
+compile, PR 46). An expert without a gate has one `up` stack and a body
+of its own under the same name (`gmm(..., act="relu2")`): one block a step,
+the product rounded, the ReLU squared in float32 and rounded once.
 
 A chip that shares a layer with others by expert parallelism holds some of
 the router's columns (`held = (first, count)`, static): routing stays over
@@ -229,6 +234,22 @@ def _gmm_act_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, gate_ref,
         out_ref[...] = _swiglu(gate, up)
 
 
+def _relu2(up: jax.Array) -> jax.Array:
+    """relu(up)^2 in float32, rounded once to the operand's dtype."""
+    r = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return (r * r).astype(up.dtype)
+
+
+def _gmm_relu2_kernel(tile_expert_ref, tiles_used_ref, lhs_ref, rhs_ref,
+                      out_ref):
+    @pl.when(pl.program_id(1) < tiles_used_ref[0])
+    def _():
+        # (the product rounded as the call that stored it rounded it)
+        out_ref[...] = _relu2(jnp.dot(
+            lhs_ref[...], rhs_ref[0],
+            preferred_element_type=jnp.float32).astype(out_ref.dtype))
+
+
 def _rhs_columns(tm: int, k: int, n: int, itemsize: int,
                  blocks: int = 1) -> int:
     """Columns of a weight block [k, tn]: the most that divide `n`, are whole
@@ -284,21 +305,29 @@ def _out_map(j, t, tile_expert, used):
 
 def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
         use_kernel: Optional[bool] = None,
-        interpret: Optional[bool] = None, act: bool = False) -> jax.Array:
+        interpret: Optional[bool] = None,
+        act: Optional[str] = None) -> jax.Array:
     """Grouped matmul: lhs [M,K] (rows as `p` lays them), rhs [E,K,N] ->
     [M,N], row r times the weights of its tile's expert. Rows of unused tiles
     are left as they are (nothing reads them). On the TPU a Pallas kernel,
     grid (column blocks, tiles), one `dot` of a tile's rows [tm, K] with a
     block [K, tn] a step; elsewhere one batched einsum over tiles.
 
-    `act`: rhs is a gate-and-up stack [E,K,2I] and the call returns the
+    `act` "swiglu" (or True, as its callers wrote it while it was the only
+    one): rhs is a gate-and-up stack [E,K,2I] and the call returns the
     activation [M,I], silu(gate) * up of the two halves of the product, each
     rounded to the rows' dtype first (the product as a call without `act`
     returns it) and multiplied in float32. The kernel takes two blocks
     [K, tn] of the stack a step, column block j of `I` and the one I / tn
-    further on, and the product is never an array."""
+    further on, and the product is never an array. `act` "relu2": rhs is an
+    up stack [E,K,I] and the call returns relu(product)^2 [M,I], the product
+    rounded first and squared in float32."""
+    act = {False: None, True: "swiglu"}.get(act, act)
+    if act not in (None, "swiglu", "relu2"):
+        raise ValueError(f"gmm: act {act!r}")
+    gated = act == "swiglu"
     m, k = lhs.shape
-    n = rhs.shape[2] // 2 if act else rhs.shape[2]
+    n = rhs.shape[2] // 2 if gated else rhs.shape[2]
     tm = p.tm
     tiles = m // tm
     if use_kernel is None:
@@ -308,18 +337,21 @@ def gmm(lhs: jax.Array, rhs: jax.Array, p: Plan,
                          jnp.take(rhs, p.tile_expert, axis=0),
                          preferred_element_type=jnp.float32)
         out = out.reshape(m, -1).astype(lhs.dtype)
-        return _swiglu(out[:, :n], out[:, n:]) if act else out
+        if gated:
+            return _swiglu(out[:, :n], out[:, n:])
+        return _relu2(out) if act else out
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    tn = _rhs_columns(tm, k, n, rhs.dtype.itemsize, 2 if act else 1)
+    tn = _rhs_columns(tm, k, n, rhs.dtype.itemsize, 2 if gated else 1)
     weights = [pl.BlockSpec((1, k, tn), _rhs_map)]
-    if act:
+    if gated:
         weights.append(pl.BlockSpec((1, k, tn),
                                     functools.partial(_up_map, n // tn)))
     return pl.pallas_call(
-        _gmm_act_kernel if act else _gmm_kernel,
+        (_gmm_act_kernel if gated else
+         _gmm_relu2_kernel if act else _gmm_kernel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, tiles),
@@ -376,24 +408,27 @@ def combine(y: jax.Array, weights: jax.Array, dest: jax.Array,
     return block(weights, dest)
 
 
-def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
+def moe_layer(x: jax.Array, router: jax.Array, up: jax.Array,
               down: jax.Array, top_k: int,
               use_kernel: Optional[bool] = None,
               interpret: Optional[bool] = None,
-              held: Optional[Tuple[int, int]] = None, **routing):
-    """The layer over x [T,H]: router [H,E] float32, gate_up [E,H,2I] (an
-    expert's gate columns, then its up columns), down [E,I,H]. Returns
-    (y [T,H], `Load` of this call). Two grouped matmuls: the gate-and-up
-    call returns the rows' activation [M,I] (`gmm(..., act=True)`), which the
-    down call takes as it is. With `held = (first, count)` the stacks
-    are [count, ...], the experts of the router's columns first ..
-    first+count-1, and y is their part of the sum: what a token's other
-    chosen experts would add is computed where they are held. `routing`:
-    `route`'s `scoring`, `bias` and `scale`."""
+              held: Optional[Tuple[int, int]] = None, act: str = "swiglu",
+              rows: Optional[jax.Array] = None, **routing):
+    """The layer over x [T,H]: router [H,E] float32, up [E,K,2I] (an
+    expert's gate columns, then its up columns; `act` "relu2": [E,K,I], an
+    expert down(relu(up r)^2)), down [E,I,K]. The experts' rows are x's (K =
+    H), or those of `rows` [T,K], another array of the same tokens (a latent
+    of theirs: the router still reads x). Returns (y [T,K], `Load` of this
+    call). Two grouped matmuls: the up call returns the rows' activation
+    [M,I] (`gmm(..., act=)`), which the down call takes as it is. With `held
+    = (first, count)` the stacks are [count, ...], the experts of the
+    router's columns first .. first+count-1, and y is their part of the sum:
+    what a token's other chosen experts would add is computed where they are
+    held. `routing`: `route`'s `scoring`, `bias` and `scale`."""
     num_experts = router.shape[1]
     count = num_experts if held is None else held[1]
-    if gate_up.shape[0] != count or down.shape[0] != count:
-        raise ValueError(f"moe_layer: stacks of {gate_up.shape[0]} and "
+    if up.shape[0] != count or down.shape[0] != count:
+        raise ValueError(f"moe_layer: stacks of {up.shape[0]} and "
                          f"{down.shape[0]} experts where {count} are held")
     weights, experts = route(x, router, top_k, **routing)
     p = plan(experts, num_experts, held=held)
@@ -401,8 +436,9 @@ def moe_layer(x: jax.Array, router: jax.Array, gate_up: jax.Array,
                             interpret=interpret)
     # (`row_token` lies in [0, T) by construction: the gather says so, and no
     # pass over the rows it took puts NaN where an index would be out)
-    rows = jnp.take(x, p.row_token, axis=0, mode="clip")
-    y = run(run(rows, gate_up, act=True), down)             # [M,I] -> [M,H]
+    rows = jnp.take(x if rows is None else rows, p.row_token, axis=0,
+                    mode="clip")
+    y = run(run(rows, up, act=act), down)                   # [M,I] -> [M,K]
     out = combine(y, weights, p.dest, masked=held is not None)
     t = x.shape[0]
     i32 = lambda v: jnp.asarray(v, jnp.int32)

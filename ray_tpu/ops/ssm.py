@@ -20,18 +20,19 @@ leaves for the next token. Plain `jax.numpy` forms of the same run on the
 CPU.
 
 Mamba-2 (SSD, arXiv:2405.21060) is another recurrence: the channels come in
-heads of P, a head has ONE scalar decay a token, and B and C are shared by
-all heads,
+heads of P, a head has ONE scalar decay a token, and B and C come in G
+groups, each shared by H / G heads in a row (head h reads group g = h // (H /
+G); Granite has one group, Nemotron-H eight),
 
-    S_t[h] = exp(dt_t[h] A[h]) . S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t   [P, N]
-    y_t[h] = S_t[h] C_t + D[h] . x_t[h]
+    S_t[h] = exp(dt_t[h] A[h]) . S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t[g] [P, N]
+    y_t[h] = S_t[h] C_t[g] + D[h] . x_t[h]
 
 so that a chunk of Q positions is matrix products (`ssd_scan`): with c_i the
 running sum of dt A inside the chunk, Y = ((C B^T) o L) (dt x) + exp(c) .
 (C S_0^T), L_ij = exp(c_i - c_j) for j <= i, and the chunk hands on S_Q =
-exp(c_Q) S_0 + (exp(c_Q - c) dt x)^T B. A state is [H, P, N] float32, the N
-states on the lanes. `ssd_step` is its one-token update, `ssd_scan_plain`
-the recurrence token by token.
+exp(c_Q) S_0 + (exp(c_Q - c) dt x)^T B, C B^T once a group. A state is
+[H, P, N] float32, the N states on the lanes. `ssd_step` is its one-token
+update, `ssd_scan_plain` the recurrence token by token.
 """
 
 from __future__ import annotations
@@ -230,22 +231,34 @@ def ssm_step(x, dt, b, c, z, a, d, h, active):
             jnp.where(active[:, None, None], new, h))
 
 
+def _ssd_token(x, dt, b, c, a, s):
+    """One position of the recurrence, float32: x [B, H, P], dt [B, H], b, c
+    [B, G, N], a [H], s [B, H, P, N]. Returns (S_t [B, H, P, N], S_t C_t
+    [B, H, P]). The heads that share a group lie side by side, so a group is
+    a split of the heads' axis (a major one: no data moves)."""
+    groups = b.shape[1]
+    by_group = lambda t: t.reshape(t.shape[0], groups, -1, *t.shape[2:])
+    over_heads = lambda m: m[:, :, None, None, :]            # [B, G, 1, 1, N]
+    new = (by_group(jnp.exp(dt * a)[:, :, None, None] * s)
+           + by_group(dt[:, :, None] * x)[..., None] * over_heads(b))
+    y = jnp.sum(new * over_heads(c), axis=-1)
+    return new.reshape(s.shape), y.reshape(x.shape)
+
+
 def ssd_scan_plain(x, dt, b, c, a, d, s0=None):
     """Mamba-2's recurrence token by token (`lax.scan`), float32: x
     [B, L, H, P], dt [B, L, H] (after softplus; 0 at a position that must
-    change nothing), b, c [B, L, N], a [H] = -exp(A_log), d [H], s0
-    [B, H, P, N] or zero. Returns (y [B, L, H, P] float32, S_L [B, H, P, N]):
-    y before the gate and its norm, which are the model's."""
+    change nothing), b, c [B, L, G, N] (G divides H), a [H] = -exp(A_log),
+    d [H], s0 [B, H, P, N] or zero. Returns (y [B, L, H, P] float32, S_L
+    [B, H, P, N]): y before the gate and its norm, which are the model's."""
     f32 = lambda t: t.astype(jnp.float32)
     x, dt, b, c = map(f32, (x, dt, b, c))
     if s0 is None:
         s0 = jnp.zeros(x.shape[:1] + x.shape[2:] + b.shape[-1:], jnp.float32)
 
     def token(s, xs):
-        xt, dtt, bt, ct = xs  # [B, H, P], [B, H], [B, N], [B, N]
-        s = (jnp.exp(dtt * a)[:, :, None, None] * s
-             + (dtt[:, :, None] * xt)[..., None] * bt[:, None, None, :])
-        return s, jnp.sum(s * ct[:, None, None, :], axis=-1)
+        xt, dtt, bt, ct = xs  # [B, H, P], [B, H], [B, G, N], [B, G, N]
+        return _ssd_token(xt, dtt, bt, ct, a, s)
 
     time_major = lambda t: jnp.swapaxes(t, 0, 1)
     s, y = jax.lax.scan(token, s0, tuple(map(time_major, (x, dt, b, c))))
@@ -254,27 +267,29 @@ def ssd_scan_plain(x, dt, b, c, a, d, s0=None):
 
 def ssd_step(x, dt, b, c, a, d, s, active):
     """One token a row on the state pool: x [B, H, P], dt [B, H], b, c
-    [B, N], s [B, H, P, N] float32 (a row per engine slot), active [B] bool.
-    Returns (y [B, H, P] float32, s): an inactive row's state is left as it
-    was. Plain `jax.numpy`, in place on the donated pool (as `ssm_step`)."""
+    [B, G, N], s [B, H, P, N] float32 (a row per engine slot), active [B]
+    bool. Returns (y [B, H, P] float32, s): an inactive row's state is left
+    as it was. Plain `jax.numpy`, in place on the donated pool (as
+    `ssm_step`)."""
     f32 = lambda t: t.astype(jnp.float32)
     x, dt, b, c = map(f32, (x, dt, b, c))
-    new = (jnp.exp(dt * a)[:, :, None, None] * s
-           + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
-    y = jnp.sum(new * c[:, None, None, :], axis=-1) + d[:, None] * x
-    return y, jnp.where(active[:, None, None, None], new, s)
+    new, y = _ssd_token(x, dt, b, c, a, s)
+    return (y + d[:, None] * x,
+            jnp.where(active[:, None, None, None], new, s))
 
 
 def _ssd_scan_kernel(lens_ref, x_ref, cols_ref, rows_ref, b_ref, c_ref,
                      d_ref, y_ref, s_ref, g_scr, dx_scr, *, chunk: int,
-                     heads: int, width: int):
+                     heads: int, width: int, per_group: int):
     """Grid (rows, chunks, head blocks), the head blocks innermost: the
     row's whole state [H*P, N] is the output block, which stays in VMEM from
     the row's first chunk to its last, and C B^T of a chunk (lower triangle)
-    is made once, at its first head block. cols [chunk, 2*heads]: per head
-    the running sum c of dt A inside the chunk, then dt, down the sublanes;
-    rows [heads, chunk]: c along the lanes. A chunk past the row's length is
-    skipped (index maps stop at the last chunk in use)."""
+    is made once a group, at the first of its `per_group` head blocks (b and
+    c are the block's group's: the index maps pick it). cols [chunk,
+    2*heads]: per head the running sum c of dt A inside the chunk, then dt,
+    down the sublanes; rows [heads, chunk]: c along the lanes. A chunk past
+    the row's length is skipped (index maps stop at the last chunk in
+    use)."""
     r, t, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     block = heads * width
 
@@ -291,7 +306,7 @@ def _ssd_scan_kernel(lens_ref, x_ref, cols_ref, rows_ref, b_ref, c_ref,
         mm = x_ref.dtype
         bm, cm = b_ref[0].astype(mm), c_ref[0].astype(mm)    # [chunk, N]
 
-        @pl.when(g == 0)
+        @pl.when(g % per_group == 0)
         def _cb():
             cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -335,21 +350,23 @@ def _ssd_scan_kernel(lens_ref, x_ref, cols_ref, rows_ref, b_ref, c_ref,
 def ssd_scan_kernel(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
                     heads: int = SSD_HEADS,
                     interpret: Optional[bool] = None):
-    """Shapes as `ssd_scan`. x is read once and y written once, B and C once
-    a chunk, the state written once a row; the matrix products take x's
-    dtype (float32 sums), the decays and the state are float32."""
+    """Shapes as `ssd_scan`. x is read once and y written once, a group's B
+    and C once a chunk, the state written once a row; the matrix products
+    take x's dtype (float32 sums), the decays and the state are float32."""
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     rows, length, h, p = x.shape
-    n = b.shape[-1]
+    groups, n = b.shape[-2:]
     chunk = min(chunk, length)
-    heads = min(heads, h)
-    if length % chunk or h % heads:
+    heads = min(heads, h // groups)     # a block's heads lie in one group
+    if length % chunk or h % (groups * heads):
         raise ValueError(f"ssd_scan: {length} positions in chunks of {chunk},"
-                         f" {h} heads in blocks of {heads}")
+                         f" {h} heads of {groups} groups in blocks of "
+                         f"{heads}")
     chunks, blocks, block = length // chunk, h // heads, heads * p
+    per_group = blocks // groups
     dt = dt.astype(jnp.float32)
     # The running sum of dt A from each chunk's first position on.
     cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(
@@ -363,11 +380,13 @@ def ssd_scan_kernel(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
         return jnp.minimum(t, last)
 
     tile = lambda r, t, g, lens: (r, at(r, t, lens), g)
-    shared = pl.BlockSpec((1, chunk, n),
-                          lambda r, t, g, lens: (r, at(r, t, lens), 0))
+    # b, c [rows, length, G * N]: a head block's group is g // per_group.
+    shared = pl.BlockSpec(
+        (1, chunk, n),
+        lambda r, t, g, lens: (r, at(r, t, lens), g // per_group))
     y, s = pl.pallas_call(
         functools.partial(_ssd_scan_kernel, chunk=chunk, heads=heads,
-                          width=p),
+                          width=p, per_group=per_group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(rows, chunks, blocks),
@@ -396,7 +415,8 @@ def ssd_scan_kernel(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
         interpret=interpret,
         name="ssd_scan",
     )(lens.astype(jnp.int32), x.reshape(rows, length, h * p), cols, along,
-      b, c, jnp.repeat(d.astype(jnp.float32), p).reshape(1, h * p))
+      b.reshape(rows, length, groups * n), c.reshape(rows, length, groups * n),
+      jnp.repeat(d.astype(jnp.float32), p).reshape(1, h * p))
     return y.reshape(x.shape), s.reshape(rows, h, p, n)
 
 
@@ -404,8 +424,9 @@ def ssd_scan(x, dt, b, c, a, d, lens, chunk: int = SSD_CHUNK,
              use_kernel: Optional[bool] = None
              ) -> Tuple[jax.Array, jax.Array]:
     """A prefill's positions from a zero state: x [B, L, H, P] and b, c
-    [B, L, N] (the compute dtype), dt [B, L, H] float32, a [H] = -exp(A_log)
-    and d [H] float32, lens [B] the rows' true lengths. A position at or past
+    [B, L, G, N] (the compute dtype; head h reads group h // (H / G)), dt
+    [B, L, H] float32, a [H] = -exp(A_log) and d [H] float32, lens [B] the
+    rows' true lengths. A position at or past
     its row's length must come with dt = 0 and a finite x: it then changes
     nothing, and the kernel does not walk a chunk (of `chunk` positions)
     that holds only such. Returns (y [B, L, H, P] in x's dtype, the state

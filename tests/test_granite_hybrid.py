@@ -93,8 +93,9 @@ def _scan_inputs(b, length, heads=8, width=16, n=16, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     x = jax.random.normal(ks[0], (b, length, heads, width))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, length, heads)) - 2.0)
-    bm = jax.random.normal(ks[2], (b, length, n))
-    cm = jax.random.normal(ks[3], (b, length, n))
+    # (one group of B and C: [B, L, 1, N])
+    bm = jax.random.normal(ks[2], (b, length, n))[:, :, None]
+    cm = jax.random.normal(ks[3], (b, length, n))[:, :, None]
     a = -jnp.exp(jax.random.normal(ks[4], (heads,)))
     return x, dt, bm, cm, a, jnp.linspace(0.5, 1.5, heads)
 

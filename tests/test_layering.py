@@ -75,7 +75,7 @@ def _family_imports(path, families, repo=REPO):
 
 def test_no_family_imports_another_and_the_shared_modules_import_none():
     families = _family_modules()
-    assert len(families) == 8 and all(map(os.path.exists, families.values()))
+    assert len(families) == 9 and all(map(os.path.exists, families.values()))
     shared = [os.path.join(REPO, "ray_tpu", "models", f)
               for f in ("layers.py", "initializers.py")]
     found = {os.path.relpath(path, REPO): lines

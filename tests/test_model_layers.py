@@ -50,11 +50,16 @@ DIGESTS = {
     "minicpm_sala": (
         "6c7369cfb4d351b98d50e06e7d49ad0229da8eb733853a625dbc703bc989f74e",
         "8e3cdcff8e8ab57f47ef5845ca9049f689e41c9e721e4cb55507c53a6ffd42ac"),
+    # (PR 56: taken at its own commit)
+    "nemotron_h": (
+        "0c82c4ba40fdbb4f957ea444ee802d9e314edeaa757384333c320c5d5c98bab9",
+        "60a1b911386f56ee67296ccf88509468d9d09bd62de31667d7fe26adfe8c1f25"),
 }
 
 # What the engine reads off a model: the value of a family that does not say
 # otherwise, then what each tiny configuration says.
 READ = {"state_layer_ids": (), "ring_layer_ids": (), "expert_layer_ids": (),
+        "cacheless_layer_ids": (),
         "block_length": 1, "num_logits_to_keep": 0, "sliding_window": 0,
         "latent_layer_ids": (), "latent_width": 0, "index_layer_ids": (),
         "index_segments": 4}
@@ -73,6 +78,8 @@ SAYS = {
                    "latent_width": 40},
     "minicpm_sala": {"state_layer_ids": (0, 2, 3), "index_layer_ids": (1,),
                      "num_logits_to_keep": 1},
+    "nemotron_h": {"state_layer_ids": (0, 4), "expert_layer_ids": (1, 3),
+                   "cacheless_layer_ids": (1, 3), "num_logits_to_keep": 1},
 }
 
 
@@ -131,6 +138,9 @@ def test_a_model_answers_what_the_engine_reads_and_caches_a_layer_an_entry(
     caches = model.init_cache(cache_cfg)
     assert len(caches) == layers
     for i, entry in enumerate(caches):
+        if i in model.cacheless_layer_ids:
+            assert entry == ()      # a block that keeps nothing
+            continue
         if i in model.latent_layer_ids:
             # one pool of the allocator's pages, 40 values on whole lanes
             assert entry.shape == (cache_cfg.num_pages, cache_cfg.page_size,

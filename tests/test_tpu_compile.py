@@ -179,18 +179,22 @@ _LAYOUT_CHANGE = re.compile(
     r"^\s*(?:ROOT )?%\S+ = (.+?) (copy|transpose)\(")
 
 
-def _pool_layout_changes(text, pool_elements):
+def _pool_layout_changes(text, pool_elements, dtype=None):
     """The `copy` and `transpose` instructions of a compiled program (inside
     fusions too) whose result has as many elements as one layer's K or V
-    pool: a pool changing its physical layout between two of its users.
-    (`copy-start` is not one: at the cut depth the compiler may move a pool
-    to another memory space, in the layout it has.)"""
+    pool (and, where given, its HLO `dtype`: a weight of another dtype can
+    have a pool's element count): a pool changing its physical layout
+    between two of its users. (`copy-start` is not one: at the cut depth the
+    compiler may move a pool to another memory space, in the layout it
+    has.)"""
     found = []
     for line in text.splitlines():
         m = _LAYOUT_CHANGE.match(line)
         if m and any(
                 math.prod(int(n) for n in dims.split(",")) == pool_elements
-                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+                and dtype in (None, kind)
+                for kind, dims in re.findall(r"(\w+)\[([\d,]+)\]",
+                                             m.group(1))):
             found.append(line.strip()[:160])
     return found
 
@@ -787,7 +791,8 @@ def test_ssd_scan_one_chip_at_the_published_head_shapes(topology):
     text = _compiled_text(
         functools.partial(ssd_scan_kernel, interpret=False),
         s((b, length, h, p), jnp.bfloat16), s((b, length, h), jnp.float32),
-        s((b, length, n), jnp.bfloat16), s((b, length, n), jnp.bfloat16),
+        s((b, length, 1, n), jnp.bfloat16),
+        s((b, length, 1, n), jnp.bfloat16),
         s((h,), jnp.float32), s((h,), jnp.float32), s((b,), jnp.int32))
     assert "ssd_scan" in _kernel_names(text)
     assert f"f32[{b},{h * p},{n}]" in text     # the state, N on the lanes
@@ -902,3 +907,68 @@ def test_chips_are_counted_from_device_files(monkeypatch, vfio, dev, want):
     # The host's bounds describe its type, not what this VM was given.
     monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
     assert accelerators._count_tpu_chips() == want
+
+
+def test_nemotron_decode_holds_five_pools_one_layers_pages_and_no_more(
+        topology, monkeypatch):
+    """The chip compiler's HLO of `nemotron-decode-heavy`'s decode window at
+    the published widths and all eleven blocks (5 Mamba-2 with eight groups
+    of B and C, 1 attention, 5 expert blocks holding 128 of 512 experts): the
+    grouped one-token step is plain `jax.numpy` on the donated pool, and no
+    instruction rewrites a block's float32 state [16, 128, 64, 128] or the
+    one layer's K/V pool; an expert block has no cache entry at all; the
+    experts are the grouped-matmul kernel over stacks of 128 in the 1,024-wide
+    latent, the up call's body the squared ReLU (`bf16[rows, 2688]` out of
+    `bf16[128, 1024, 2688]`), the attention block the paged kernel; it peaks
+    at 10.571 GiB of a v5e's 15.75, 67% (the configuration's
+    `memory_analysis`; compile, PR 56)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth("nemotron-decode-heavy")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_decode(model, ec, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    assert 0.6 * sizing.USABLE_GIB < peak <= 10.571 + 0.01
+    text = compiled.as_text()
+    caches = sizing.cache_shapes(model, ec, None)
+    assert [len(jax.tree.leaves(c)) for c in caches] == [
+        2, 0, 2, 0, 2, 0, 2, 2, 0, 2, 0]
+    state, pages = caches[0][1], caches[7][0]
+    assert (state.shape, state.dtype) == ((16, 128, 64, 128), jnp.float32)
+    assert pages.shape == (16 * 20 + 1, 64, 2 * 128)
+    assert f"f32[{','.join(map(str, state.shape))}]" in text
+    # (by dtype too: the attention block's bf16 q and o kernels [4096, 4096]
+    # have as many elements as a float32 state pool)
+    for pool, kind in ((state, "f32"), (pages, "bf16")):
+        assert _pool_layout_changes(text, math.prod(pool.shape), kind) == []
+    assert {"paged_decode", "moe_gmm"} <= _kernel_names(text)
+    assert "bf16[128,1024,2688]" in text and "bf16[512," not in text
+    # ten `moe_gmm` calls a token step: an up and a down call a block
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and re.match(r"\s*%moe_gmm", line)]
+    assert len(calls) == 10
+    assert sum("2688]" in c.split(" = ", 1)[1].split("{", 1)[0]
+               for c in calls) == 5
+
+
+@pytest.mark.parametrize("nb", [1, 16])
+def test_nemotron_prefill_of_a_full_wave_fits_the_chip(topology, monkeypatch,
+                                                       nb):
+    """Prefill of one prompt and of 16 in the 128 bucket, the largest program
+    of `nemotron-decode-heavy`, at all eleven blocks: the scan is the Pallas
+    kernel `ssd_scan` with B and C of eight groups, the experts `moe_gmm`,
+    the head on one position a row; the wave peaks at 11.027 GiB of a v5e's
+    15.75 and one prompt at 10.575 (compile, PR 56)."""
+    from benchmark import sizing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, ec = cell_at_depth("nemotron-decode-heavy")
+    one = SingleDeviceSharding(topology.devices[0])
+    compiled = _lower_prefill(model, ec, 128, nb, one).compile()
+    peak, parts = sizing.peak_gib(compiled)
+    assert peak <= (11.027 if nb == 16 else 10.575) + 0.01
+    assert parts["temp"] < (0.6 if nb == 16 else 0.1)
+    text = compiled.as_text()
+    assert {"ssd_scan", "moe_gmm"} <= _kernel_names(text)
+    assert f"[{nb},128,131072]" not in text and f"f32[{nb},131072]" in text
